@@ -31,11 +31,7 @@ from typing import Any, Iterable, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:  # jax >= 0.4.34
-    from jax.extend.core import ClosedJaxpr, Jaxpr
-except ImportError:  # pragma: no cover — older jax
-    from jax.core import ClosedJaxpr, Jaxpr  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from .schema import Finding
 

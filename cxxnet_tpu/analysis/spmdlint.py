@@ -51,11 +51,7 @@ import dataclasses
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
-
-try:  # jax >= 0.4.34
-    from jax.extend.core import ClosedJaxpr, Jaxpr
-except ImportError:  # pragma: no cover — older jax
-    from jax.core import ClosedJaxpr, Jaxpr  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from .schema import Finding
 

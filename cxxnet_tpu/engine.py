@@ -113,7 +113,39 @@ trace the attribute is ``None``.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
+
+#: platform of the devices the code being traced will run on (set by
+#: :func:`placed_on`); None = an uncommitted computation, which JAX places
+#: on its default backend
+_PLACED_ON: contextvars.ContextVar = contextvars.ContextVar(
+    "cxxnet_placed_on", default=None)
+
+
+@contextlib.contextmanager
+def placed_on(platform: str):
+    """Declare the platform of the devices the code traced inside runs
+    on.  ``NetTrainer.jit`` wraps every traced body with its own devices'
+    platform, so platform-gated lowerings (the Pallas kernels and their
+    shape gates, :func:`on_tpu`) follow where the step is placed rather
+    than which backend the process defaults to."""
+    token = _PLACED_ON.set(platform)
+    try:
+        yield
+    finally:
+        _PLACED_ON.reset(token)
+
+
+def on_tpu() -> bool:
+    """Will the code being traced run on a TPU?"""
+    platform = _PLACED_ON.get()
+    if platform is None:
+        import jax
+        platform = jax.default_backend()
+    return platform == "tpu"
+
 
 def _is_positive_float(val: str) -> bool:
     try:
@@ -197,6 +229,35 @@ class _Options:
 
 
 opts = _Options()
+
+
+#: the fixed place of JAX's persistent compilation cache when the
+#: environment names none: inside the checkout, derived from this
+#: package's own location — the directory is part of the cache key, so
+#: it must be the same path in every process and every run
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def enable_compile_cache(dev: str = "tpu"):
+    """Give JAX a persistent compilation cache and return its directory,
+    or ``None`` when the run caches nothing.  Called where a program
+    starts (``LearnTask.run``, the wrapper's ``Net``, ``bench.py``,
+    ``chip_smoke.py``), never at import.  A set
+    ``JAX_COMPILATION_CACHE_DIR`` wins for every device: jax reads it
+    into ``jax_compilation_cache_dir`` itself, so nothing is set here.
+    Otherwise ``dev``, the run's device spec, decides: a ``dev = cpu``
+    run gets no cache (XLA:CPU logs a multi-KB machine-feature error for
+    every entry it loads, and CPU compiles are tests and dry runs), any
+    other gets :data:`COMPILE_CACHE_DIR`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if dev.startswith("cpu"):
+        return None
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def snapshot() -> dict:

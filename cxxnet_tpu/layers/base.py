@@ -363,6 +363,10 @@ class Layer:
     type_names: Tuple[str, ...] = ()
     # True for loss layers (self-loop + contributes a loss term)
     is_loss: bool = False
+    # True when the layer's input holds integer ids it indexes with
+    # (token ids): the net then feeds it uncast — rounding ids to a
+    # bfloat16 compute dtype keeps 8 bits and corrupts every id >= 256
+    index_input: bool = False
     # keys this subclass's set_param consumes beyond LAYER_PARAM_KEYS —
     # the declared-key registry (analysis/registry.py) harvests these;
     # keep them in sync with the set_param branches

@@ -57,10 +57,10 @@ def _single_device_attention(q, k, v, causal: bool, seg=None):
     the segment-masked variants (packed documents): the triangular-flash
     segment kernel where the grid allows, the lax fallback elsewhere —
     the two are pairtested in interpret mode (tests/test_text.py)."""
-    from ..engine import opts
+    from ..engine import on_tpu, opts
     from ..ops import pallas_kernels as pk
     s, hd = q.shape[2], q.shape[3]
-    if (pk._on_tpu() and pk.flash_attention_available(s, hd)
+    if (on_tpu() and pk.flash_attention_available(s, hd)
             and opts.flash_attn == "1"):
         if seg is not None:
             if causal:
@@ -89,6 +89,7 @@ class EmbeddingLayer(Layer):
     """
 
     type_names = ("embedding",)
+    index_input = True
     extra_config_keys = (
         K("vocab_size", "int", lo=1),
         K("pos_embed", "int", lo=0, hi=1),
@@ -204,9 +205,9 @@ class LayerNormLayer(Layer):
         x = inputs[0]
         n, c, s, d = x.shape
         rows = n * c * s
-        from ..engine import opts
+        from ..engine import on_tpu, opts
         from ..ops import pallas_kernels as pk
-        if (pk._on_tpu() and opts.pallas_ln in ("1", "x")  # default-on (r6)
+        if (on_tpu() and opts.pallas_ln in ("1", "x")  # default-on (r6)
                 and pk.layernorm_pallas_supported(rows, d)):
             # single-sweep Pallas kernel: the XLA lowering left
             # ~1.9 ms/site convert_reduce fusions in the d2048 step

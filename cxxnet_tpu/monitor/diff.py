@@ -12,9 +12,7 @@ implementation exists:
   peak-live, comm share/overlap, latency percentiles) and exit nonzero
   on any regression past ``rel`` — a CI gate, not just a report;
 * ``bench.py --against BENCH_rNN.json`` — the same engine over a bench
-  payload vs a recorded round;
-* ``tests/test_bench_guard.py`` — the ±10% ``device_step_ms`` guard
-  routes its comparison through :func:`compare`.
+  payload vs a recorded round.
 
 Verdict semantics: ``b`` is the candidate, ``a`` the baseline;
 ``rel_delta = (b - a) / |a|``.  A comparison regresses when the delta

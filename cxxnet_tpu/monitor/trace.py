@@ -145,6 +145,18 @@ def _parse_event_metadata_entry(buf: bytes) -> Tuple[int, str, str]:
     return key, name, display
 
 
+def op_event_name(name: str) -> str:
+    """The HLO instruction name of an XLA-op event.  The TPU runtime of
+    jax 0.9 / libtpu 0.0.34 names an op event by its whole instruction
+    line (``%fusion.220 = (bf16[8192,2048]{...}, ...) fusion(...)``) and
+    leaves ``display_name`` empty, where earlier runtimes used the bare
+    instruction name; every consumer (the collective classifier, the
+    compiled-HLO scope join, per-op totals) keys on the bare name."""
+    if name.startswith("%"):
+        return name[1:].split(" = ", 1)[0]
+    return name
+
+
 def _parse_plane(buf: bytes) -> XPlane:
     name, lines, event_names, event_display = "", [], {}, {}
     for field, _, val in _fields(buf):
@@ -154,7 +166,7 @@ def _parse_plane(buf: bytes) -> XPlane:
             lines.append(_parse_line(val))
         elif field == 4:
             k, v, d = _parse_event_metadata_entry(val)
-            event_names[k] = v
+            event_names[k] = op_event_name(v)
             if d:
                 event_display[k] = d
     return XPlane(name, lines, event_names, event_display)
@@ -391,14 +403,19 @@ class ProfileWindow:
     location/length in ``last_window_dir`` / ``last_window_steps`` for
     the report emitters.  All hooks are no-ops when ``trace_dir`` is
     empty, and — for one-shot windows — once the window closed.
+
+    ``sync`` is called before the trace stops: dispatch is asynchronous,
+    so without waiting for the device the window's last steps are still
+    in flight when the profiler closes and their device events are lost.
     """
 
-    def __init__(self, trace_dir: str, start_step: int = -1,
+    def __init__(self, trace_dir: str, sync, start_step: int = -1,
                  num_steps: int = 0, every: int = 0):
         self.trace_dir = trace_dir
         self.start_step = start_step
         self.num_steps = num_steps
         self.every = every
+        self.sync = sync
         self.active = False
         self.done = False
         self._steps_traced = 0
@@ -455,6 +472,7 @@ class ProfileWindow:
 
     def stop(self) -> None:
         import jax
+        self.sync()
         jax.profiler.stop_trace()
         self.active = False
         self.last_window_steps = self._steps_traced

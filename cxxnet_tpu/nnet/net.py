@@ -130,6 +130,20 @@ class Network:
         return buffers
 
     # -- forward ------------------------------------------------------------
+    def cast_input(self, nid: int, v: jnp.ndarray) -> jnp.ndarray:
+        """An input node's value in the compute dtype — except ids a
+        layer indexes with (``Layer.index_input``), which stay as they
+        came.  Casting float32 token ids to bfloat16 rounds them to 8
+        bits; XLA's excess-precision elision hides that in a
+        straight-line step but not inside ``lax.scan``, where ids >= 256
+        came out rounded and the top id out of range (NaN rows from the
+        embedding gather)."""
+        if v.dtype == self.dtype or any(
+                c.layer.index_input and nid in c.nindex_in
+                for c in self.connections):
+            return v
+        return v.astype(self.dtype)
+
     def forward(self, params: Params, buffers: Params,
                 inputs: Dict[int, jnp.ndarray], ctx: ForwardContext,
                 until: Optional[int] = None
@@ -146,7 +160,7 @@ class Network:
         from ..layers.base import conn_scope_name, materialize
         nodes: List[Optional[jnp.ndarray]] = [None] * self.cfg.num_nodes
         for nid, v in inputs.items():
-            nodes[nid] = v.astype(self.dtype) if v.dtype != self.dtype else v
+            nodes[nid] = self.cast_input(nid, v)
         new_buffers = dict(buffers)
         fuse = getattr(self, "fuse_groups", None)
         fuse_skip = getattr(self, "fuse_skip", frozenset())

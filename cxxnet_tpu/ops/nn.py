@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..engine import opts
+from ..engine import on_tpu, opts
 
 # LRN dispatch (config key pallas_lrn / env CXXNET_PALLAS_LRN).  Default
 # "band" (round 4): the channel-window sum as a (C, C) banded matmul on
@@ -42,7 +42,7 @@ def _lrn_hwcn_fits(shape) -> bool:
     # larger than the estimate (measured VMEM OOM at n=2) — and the
     # layout-match argument only holds for lane-full batches anyway.
     n, c, h, w = shape
-    return (jax.default_backend() == "tpu" and n % 128 == 0
+    return (on_tpu() and n % 128 == 0
             and w <= 64 and w * c * 128 * 4 <= (3 << 20))
 
 
@@ -182,17 +182,16 @@ def s2d_input(x: jnp.ndarray, stride: int, kh: int, kw: int,
 # (AlexNet conv1), where XLA's dilated-dy wgrad starves the MXU (~26%
 # efficiency, BASELINE.md): "s2d" (default) computes dW through the
 # space-to-depth identity (dense stride-1 inner wgrad, pure XLA);
-# "pallas" uses the in-VMEM im2col Pallas kernel (interpret-only for now —
-# its minor-dim reshapes are rejected by Mosaic on real TPU); "off" keeps
+# "pallas" uses the in-VMEM im2col Pallas kernel (CPU interpret mode only:
+# on a TPU Mosaic refuses its minor-dim reshape, a compile error); "off" keeps
 # XLA's dilated formulation.
 # (config key fast_wgrad / env CXXNET_FAST_WGRAD -> engine.opts)
 
 
 def use_fast_wgrad(cin: int, stride: int, num_group: int) -> bool:
     """The geometry class where XLA's dilated wgrad starves the MXU."""
-    import jax
     return (opts.fast_wgrad != "off" and num_group == 1 and stride >= 2
-            and cin <= 4 and jax.default_backend() == "tpu")
+            and cin <= 4 and on_tpu())
 
 
 # grouped-conv lowering: "fgc" (default) XLA feature_group_count;
@@ -241,12 +240,8 @@ def _conv_bias_fast_bwd(stride, pad_y, pad_x, res, dy):
         db = db.astype(w.dtype)
     elif opts.fast_wgrad == "pallas":
         from .pallas_kernels import conv_wgrad_s2d_pallas
-        # interpret=True: Mosaic rejects the kernel's minor-dim reshapes on
-        # real TPU (see conv_wgrad_s2d_pallas), so this mode is a
-        # correctness/debugging path, not a fast one
         dw, db = conv_wgrad_s2d_pallas(x, dy, kh=kh, kw=kw, stride=stride,
-                                       pad_y=pad_y, pad_x=pad_x,
-                                       interpret=True)
+                                       pad_y=pad_y, pad_x=pad_x)
         dw = dw.astype(w.dtype)
         db = db.astype(w.dtype)
     else:  # "s2d": dense stride-1 inner wgrad via the s2d identity
@@ -422,7 +417,7 @@ def _hwcn_pool_ok(x, ksize_y: int, ksize_x: int, stride: int,
     and SAS gradient semantics depending on the call site)."""
     from .pallas_kernels import max_pool_hwcn_supported
     return (pad_y == 0 and pad_x == 0 and ksize_y == ksize_x
-            and jax.default_backend() == "tpu"
+            and on_tpu()
             and x.shape[0] % 128 == 0
             and max_pool_hwcn_supported(x.shape, stride))
 

@@ -36,25 +36,20 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu import fails on some CPU-only builds; interpret mode needs none
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+# every kernel passes interpret=not on_tpu(): on a TPU a kernel is compiled
+# by Mosaic, always — one that Mosaic refuses is a compile error, never a
+# switch to interpret mode or to the reference lowering
+from ..engine import on_tpu
 
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+_VMEM = pltpu.VMEM
 
 
 def _block_spec(nb: int, c: int, hw: int):
     """(NB, C, HW) batch-tile per grid step, resident in VMEM.  NB > 1
     matters: one-row blocks ran 1024 programs per call on AlexNet shapes and
     the per-program overhead swamped the kernel."""
-    if _VMEM is None:
-        return pl.BlockSpec((nb, c, hw), lambda i: (i, 0, 0))
     return pl.BlockSpec((nb, c, hw), lambda i: (i, 0, 0), memory_space=_VMEM)
 
 
@@ -144,7 +139,7 @@ def _lrn_fwd_res(x, nsize, alpha, beta, knorm):
     n, c, h, w = x.shape
     x3 = x.reshape(n, c, h * w)
     out = _call_per_batch(_lrn_fwd_kernel, x.dtype, nsize, alpha / nsize,
-                          beta, knorm, x3, interpret=not _on_tpu())
+                          beta, knorm, x3, interpret=not on_tpu())
     return out.reshape(n, c, h, w), x
 
 
@@ -153,7 +148,7 @@ def _lrn_bwd_res(nsize, alpha, beta, knorm, res, g):
     n, c, h, w = x.shape
     dx = _call_per_batch(_lrn_bwd_kernel, x.dtype, nsize, alpha / nsize,
                          beta, knorm, x.reshape(n, c, h * w),
-                         g.reshape(n, c, h * w), interpret=not _on_tpu())
+                         g.reshape(n, c, h * w), interpret=not on_tpu())
     return (dx.reshape(n, c, h, w),)
 
 
@@ -336,18 +331,17 @@ def _lrn_hwcn_call(kernel, out_dtype, nsize, salpha, beta, knorm, args,
     kern = functools.partial(kernel, nsize=nsize, salpha=salpha, beta=beta,
                              knorm=knorm,
                              **({} if untiled else {"halo": halo}))
-    kw = {} if _VMEM is None else {"memory_space": _VMEM}
     spec = pl.BlockSpec((hb, w, cb, nb),
-                        lambda i, j, k: (i, 0, j, k), **kw)
+                        lambda i, j, k: (i, 0, j, k), memory_space=_VMEM)
     lo_spec = pl.BlockSpec(
         (hb, w, hblk, nb),
         lambda i, j, k: (i, 0, jnp.maximum(j * (cb // hblk) - 1, 0), k),
-        **kw)
+        memory_space=_VMEM)
     hi_spec = pl.BlockSpec(
         (hb, w, hblk, nb),
         lambda i, j, k: (i, 0, jnp.minimum((j + 1) * (cb // hblk),
                                            c // hblk - 1), k),
-        **kw)
+        memory_space=_VMEM)
     per_arg = [spec] if untiled else [spec, lo_spec, hi_spec]
     return pl.pallas_call(
         kern,
@@ -375,7 +369,7 @@ def _lrn_hwcn_fwd_res(x, nsize, alpha, beta, knorm):
     xt = jnp.transpose(x, (2, 3, 1, 0))       # (H, W, C, N)
     out = _lrn_hwcn_call(_lrn_hwcn_fwd_kernel, x.dtype, nsize,
                          alpha / nsize, beta, knorm, (xt,),
-                         interpret=not _on_tpu())
+                         interpret=not on_tpu())
     return jnp.transpose(out, (3, 2, 0, 1)), x
 
 
@@ -385,7 +379,7 @@ def _lrn_hwcn_bwd_res(nsize, alpha, beta, knorm, res, g):
     gt = jnp.transpose(g, (2, 3, 1, 0))
     dx = _lrn_hwcn_call(_lrn_hwcn_bwd_kernel, x.dtype, nsize,
                         alpha / nsize, beta, knorm, (xt, gt),
-                        interpret=not _on_tpu())
+                        interpret=not on_tpu())
     return (jnp.transpose(dx, (3, 2, 0, 1)),)
 
 
@@ -595,15 +589,14 @@ def _mp_hwcn_fwd(xt, k, s, interpret):
     wpad = max(-(-w // s), (k - 1) // s + ow) * s
     nb = 128 if n % 128 == 0 else n
     cb = _pick_cb(c, (w * nb * 4) * (k + 2), 10 << 20)
-    kw = {} if _VMEM is None else {"memory_space": _VMEM}
 
     x_specs = [
         pl.BlockSpec((1, w, cb, nb),
                      lambda bc, bn, r, i=i: (jnp.minimum(s * r + i, h - 1),
-                                             0, bc, bn), **kw)
+                                             0, bc, bn), memory_space=_VMEM)
         for i in range(k)]
     o_spec = pl.BlockSpec((1, ow, cb, nb),
-                          lambda bc, bn, r: (r, 0, bc, bn), **kw)
+                          lambda bc, bn, r: (r, 0, bc, bn), memory_space=_VMEM)
     kern = functools.partial(_mp_hwcn_fwd_kernel, k=k, s=s, ow=ow,
                              wpad=wpad, h_in=h)
     return pl.pallas_call(
@@ -622,7 +615,6 @@ def _mp_hwcn_bwd(xt, pt, dpt, k, s, interpret, hb=None, relu_mask=False):
     wpad = max(-(-w // s), (k - 1) // s + ow) * s  # see _mp_hwcn_fwd
     ncand = -(-k // s)
     nb = 128 if n % 128 == 0 else n
-    kw = {} if _VMEM is None else {"memory_space": _VMEM}
     if hb is None or hb > 1:
         # tile plan shared with max_pool_hwcn_supported (_mp_mr_plan).
         # Under _MR_BWD_VMEM_CAP every proven AlexNet shape picks the same
@@ -639,8 +631,9 @@ def _mp_hwcn_bwd(xt, pt, dpt, k, s, interpret, hb=None, relu_mask=False):
             return imap
 
         x_spec = pl.BlockSpec((hb, w, cb, nb),
-                              lambda bc, bn, bh: (bh, 0, bc, bn), **kw)
-        p_specs = [pl.BlockSpec((1, ow, cb, nb), p_imap(i), **kw)
+                              lambda bc, bn, bh: (bh, 0, bc, bn),
+                              memory_space=_VMEM)
+        p_specs = [pl.BlockSpec((1, ow, cb, nb), p_imap(i), memory_space=_VMEM)
                    for i in range(nref)]
         kern = functools.partial(_mp_hwcn_bwd_kernel_mr, k=k, s=s, ow=ow,
                                  wpad=wpad, oh=oh, h_in=h, hb=hb,
@@ -663,8 +656,9 @@ def _mp_hwcn_bwd(xt, pt, dpt, k, s, interpret, hb=None, relu_mask=False):
         return imap
 
     x_spec = pl.BlockSpec((1, w, cb, nb),
-                          lambda bc, bn, hrow: (hrow, 0, bc, bn), **kw)
-    p_specs = [pl.BlockSpec((1, ow, cb, nb), cand_imap(i), **kw)
+                          lambda bc, bn, hrow: (hrow, 0, bc, bn),
+                          memory_space=_VMEM)
+    p_specs = [pl.BlockSpec((1, ow, cb, nb), cand_imap(i), memory_space=_VMEM)
                for i in range(ncand)]
     kern = functools.partial(_mp_hwcn_bwd_kernel, k=k, s=s, ow=ow,
                              wpad=wpad, oh=oh, h_in=h,
@@ -690,14 +684,14 @@ def max_pool_hwcn(x: jnp.ndarray, k: int, s: int) -> jnp.ndarray:
 
 def _mp_fwd_res(x, k, s):
     xt = jnp.transpose(x, (2, 3, 1, 0))
-    pt = _mp_hwcn_fwd(xt, k, s, interpret=not _on_tpu())
+    pt = _mp_hwcn_fwd(xt, k, s, interpret=not on_tpu())
     return jnp.transpose(pt, (3, 2, 0, 1)), (xt, pt)
 
 
 def _mp_bwd_res(k, s, res, g):
     xt, pt = res
     dpt = jnp.transpose(g, (2, 3, 1, 0))
-    dxt = _mp_hwcn_bwd(xt, pt, dpt, k, s, interpret=not _on_tpu())
+    dxt = _mp_hwcn_bwd(xt, pt, dpt, k, s, interpret=not on_tpu())
     return (jnp.transpose(dxt, (3, 2, 0, 1)),)
 
 
@@ -721,7 +715,7 @@ def max_pool_relu_hwcn(x: jnp.ndarray, k: int, s: int) -> jnp.ndarray:
 
 def _mpr_fwd_res(x, k, s):
     xt = jnp.transpose(x, (2, 3, 1, 0))
-    pt = _mp_hwcn_fwd(xt, k, s, interpret=not _on_tpu())
+    pt = _mp_hwcn_fwd(xt, k, s, interpret=not on_tpu())
     y = jnp.maximum(jnp.transpose(pt, (3, 2, 0, 1)), 0)
     return y, (xt, pt)
 
@@ -729,7 +723,7 @@ def _mpr_fwd_res(x, k, s):
 def _mpr_bwd_res(k, s, res, g):
     xt, pt = res
     dpt = jnp.transpose(g, (2, 3, 1, 0))
-    dxt = _mp_hwcn_bwd(xt, pt, dpt, k, s, interpret=not _on_tpu(),
+    dxt = _mp_hwcn_bwd(xt, pt, dpt, k, s, interpret=not on_tpu(),
                        relu_mask=True)
     return (jnp.transpose(dxt, (3, 2, 0, 1)),)
 
@@ -798,7 +792,7 @@ def conv_wgrad_hwcn_pallas(x: jnp.ndarray, dy: jnp.ndarray, *, kh: int,
     dilated-dy wgrad starves the MXU.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     from .nn import s2d_input
     n, c, h, w = x.shape
     _, co, oh, ow = dy.shape
@@ -814,17 +808,18 @@ def conv_wgrad_hwcn_pallas(x: jnp.ndarray, dy: jnp.ndarray, *, kh: int,
     dy_t = jnp.transpose(dy, (2, 3, 1, 0))       # (OH, OW, co, N)
     while n % nb:
         nb //= 2
-    kw_ = {} if _VMEM is None else {"memory_space": _VMEM}
     dy_spec = pl.BlockSpec((1, ow, co, nb),
-                           lambda bn, r: (r, 0, 0, bn), **kw_)
+                           lambda bn, r: (r, 0, 0, bn), memory_space=_VMEM)
     # rows r+i for i >= kb are never read; clamp their index maps
     hb = xs_t.shape[0]
     x_specs = [pl.BlockSpec((1, xs_t.shape[1], cin_b, nb),
                             lambda bn, r, i=i: (jnp.minimum(r + i, hb - 1),
-                                                0, 0, bn), **kw_)
+                                                0, 0, bn), memory_space=_VMEM)
                for i in range(3)]
-    dw_spec = pl.BlockSpec((co, taps_pad), lambda bn, r: (0, 0), **kw_)
-    db_spec = pl.BlockSpec((1, co, nb), lambda bn, r: (bn, 0, 0), **kw_)
+    dw_spec = pl.BlockSpec((co, taps_pad), lambda bn, r: (0, 0),
+                           memory_space=_VMEM)
+    db_spec = pl.BlockSpec((1, co, nb), lambda bn, r: (bn, 0, 0),
+                           memory_space=_VMEM)
     kern = functools.partial(_cw_hwcn_kernel, co=co, cin_b=cin_b, kb=kb,
                              ow=ow, taps_pad=taps_pad)
     dw_inner, db_part = pl.pallas_call(
@@ -911,7 +906,7 @@ def conv_wgrad_s2d_pallas(x: jnp.ndarray, dy: jnp.ndarray, *, kh: int,
     where XLA's dilated-dy formulation starves the MXU; see module comment.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     from .nn import s2d_input
     n, c, h, w = x.shape
     _, co, oh, ow = dy.shape
@@ -1138,12 +1133,11 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _scratch(*shapes):
-    assert pltpu is not None, "flash attention needs pallas TPU support"
     return [pltpu.VMEM(s, jnp.float32) for s in shapes]
 
 
 def flash_attention_available(s_len: int, d: int) -> bool:
-    return pltpu is not None and s_len % 128 == 0 and d <= 256
+    return s_len % 128 == 0 and d <= 256
 
 
 def _fa_tri_pairs(nq, nk, bq, bk, order):
@@ -1357,7 +1351,7 @@ def _norm_args(q, causal, scale, interpret):
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     return scale, interpret
 
 
@@ -1684,10 +1678,9 @@ def _ln_bwd_kernel_x(x_ref, g_ref, m_ref, r_ref, dy_ref, dx_ref, dg_ref,
 
 
 def _ln_specs(rows, d, rb):
-    kw = {} if _VMEM is None else {"memory_space": _VMEM}
-    return (pl.BlockSpec((rb, d), lambda i: (i, 0), **kw),
-            pl.BlockSpec((1, d), lambda i: (0, 0), **kw),
-            pl.BlockSpec((rb, 1), lambda i: (i, 0), **kw))
+    return (pl.BlockSpec((rb, d), lambda i: (i, 0), memory_space=_VMEM),
+            pl.BlockSpec((1, d), lambda i: (0, 0), memory_space=_VMEM),
+            pl.BlockSpec((rb, 1), lambda i: (i, 0), memory_space=_VMEM))
 
 
 def _ln_rows(rows: int, d: int) -> int:
@@ -1701,8 +1694,7 @@ def _ln_rows(rows: int, d: int) -> int:
 
 def layernorm_pallas_supported(rows: int, d: int) -> bool:
     rb = _ln_rows(rows, d)
-    return (pltpu is not None and d % 128 == 0
-            and rows % rb == 0 and rb >= 8
+    return (d % 128 == 0 and rows % rb == 0 and rb >= 8
             and d * rb * 4 * 6 <= (8 << 20))
 
 
@@ -1725,7 +1717,7 @@ def layernorm_pallas(x, gamma, beta, eps: float = 1e-5,
 
 def _ln_fwd_res(x, gamma, beta, eps, interpret, save_x=False):
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     rows, d = x.shape
     rb = _ln_rows(rows, d)
     assert rows % rb == 0, (
@@ -1752,7 +1744,7 @@ def _ln_fwd_res(x, gamma, beta, eps, interpret, save_x=False):
 
 def _ln_bwd_res(eps, interpret, save_x, res, dy):
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     rows, d = res[0].shape
     rb = _ln_rows(rows, d)
     assert rows % rb == 0, "layernorm_pallas: unsupported row count"
@@ -1810,8 +1802,7 @@ _FU_LANES = 1024
 def fused_adam_supported(p) -> bool:
     """Tensors the fused update kernel takes: bf16 working params (else
     there is no master and no convert to fuse) tiling as (8k, 1024)."""
-    return (pltpu is not None and p.dtype == jnp.bfloat16
-            and p.size % (8 * _FU_LANES) == 0)
+    return p.dtype == jnp.bfloat16 and p.size % (8 * _FU_LANES) == 0
 
 
 def _fused_adam_kernel(lr_ref, g_ref, m1_ref, m2_ref, w_ref,
@@ -1843,7 +1834,7 @@ def fused_adam_pallas(g, m1, m2, w32, lr_t, *, d1, d2, wd=0.0, clip=0.0,
     Gate with :func:`fused_adam_supported`.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     n = w32.size
     r = n // _FU_LANES
     rb = 128
@@ -1851,8 +1842,7 @@ def fused_adam_pallas(g, m1, m2, w32, lr_t, *, d1, d2, wd=0.0, clip=0.0,
         rb //= 2
     assert r % rb == 0, "fused_adam_pallas: gate with fused_adam_supported"
     sh = (r, _FU_LANES)
-    kw = {} if _VMEM is None else {"memory_space": _VMEM}
-    row = pl.BlockSpec((rb, _FU_LANES), lambda i: (i, 0), **kw)
+    row = pl.BlockSpec((rb, _FU_LANES), lambda i: (i, 0), memory_space=_VMEM)
     lr_spec = pl.BlockSpec((1, 1), lambda i: (0, 0),
                            memory_space=pltpu.SMEM)
     kern = functools.partial(_fused_adam_kernel, d1=d1, d2=d2, wd=wd,

@@ -66,7 +66,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..layers.base import ForwardContext, LabelInfo, as_mat
-from .pipeline import shard_map
 
 #: dp_reduce_dtype spellings -> wire dtype (None = reduce at native dtype)
 REDUCE_DTYPES = {"f32": None, "bf16": jnp.bfloat16}
@@ -270,7 +269,7 @@ def _run(trainer, params, data, label_vec, epoch, rng, eval_ids, mask,
         # dropout are unaffected — the fold is dead code for them)
         rng_l = None if rng is None else \
             jax.random.fold_in(rng, lax.axis_index("data"))
-        x = trainer._normalize_input(data).astype(trainer.dtype)
+        x = trainer.net.cast_input(0, trainer._normalize_input(data))
         fields = {name: label_vec[:, a:b]
                   for name, a, b in trainer._label_fields} \
             if label_vec is not None else {}
@@ -386,9 +385,9 @@ def _run(trainer, params, data, label_vec, epoch, rng, eval_ids, mask,
     if with_mask:
         in_specs.append(P("data"))
         args.append(mask)
-    fn = shard_map(spmd, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=(P(), P("data"), grad_specs),
-                   check_rep=False)
+    fn = jax.shard_map(spmd, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=(P(), P("data"), grad_specs),
+                       check_vma=False)
     return fn(*args)
 
 

@@ -25,24 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # deprecated path, removed in newer jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KW = "check_rep"
-except ImportError:  # pragma: no cover
-    _shard_map = jax.shard_map  # a function on the jax namespace
-    _REP_KW = "check_vma"
-
-
-def shard_map(f, **kw):
-    """Version shim: the replication-check kwarg was renamed
-    check_rep -> check_vma when shard_map left jax.experimental.
-    Accepts either spelling and forwards whichever this jax takes."""
-    for alias in ("check_rep", "check_vma"):
-        if alias in kw and _REP_KW != alias:
-            kw[_REP_KW] = kw.pop(alias)
-    return _shard_map(f, **kw)
-
-
 def stack_stage_params(params_list) -> Any:
     """[per-stage pytree, ...] -> one pytree with a leading stage dim."""
     return jax.tree.map(lambda *xs: jnp.stack(xs), *params_list)
@@ -85,10 +67,10 @@ def pipeline_apply(stage_fn: Callable, stacked_params: Any, x: jnp.ndarray,
         return lax.psum(out_last * mask, axis)
 
     pspec = jax.tree.map(lambda _: P(axis), stacked_params)
-    return shard_map(
+    return jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
-        check_rep=False)(stacked_params, x)
+        check_vma=False)(stacked_params, x)
 
 
 def pipeline_apply_hetero(stage_fns, params, x, *, mesh: Mesh,
@@ -202,10 +184,10 @@ def pipeline_apply_hetero(stage_fns, params, x, *, mesh: Mesh,
         # one spec leaf prefixing the whole extra subtree: microbatch dim
         # unsharded, per-microbatch batch dim sharded like the data
         in_specs += (P(None, *list(data_spec)[:1]),)
-    return shard_map(
+    return jax.shard_map(
         spmd, mesh=mesh,
         in_specs=in_specs, out_specs=(xspec, P(None)),
-        check_rep=False)(*operands)
+        check_vma=False)(*operands)
 
 
 def pipeline_1f1b(stage_fn, loss_fn, stacked_params, x, labels, *,
@@ -305,10 +287,10 @@ def pipeline_1f1b(stage_fn, loss_fn, stacked_params, x, labels, *,
         return loss, grads
 
     pspec = jax.tree.map(lambda _: P(axis), stacked_params)
-    return shard_map(
+    return jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(pspec, P(), P()), out_specs=(P(), pspec),
-        check_rep=False)(stacked_params, x, labels)
+        check_vma=False)(stacked_params, x, labels)
 
 
 def pipeline_1f1b_hetero(stage_fns, tail_loss_fn, params, x, *, mesh: Mesh,
@@ -552,10 +534,10 @@ def pipeline_1f1b_hetero(stage_fns, tail_loss_fn, params, x, *, mesh: Mesh,
         operands += (extra,)
         in_specs += (P(None, *list(data_spec)[:1]),)
     gspec = jax.tree.map(lambda _: P(), params)
-    return shard_map(
+    return jax.shard_map(
         spmd, mesh=mesh,
         in_specs=in_specs, out_specs=(P(), gspec, xspec, P(None)),
-        check_rep=False)(*operands)
+        check_vma=False)(*operands)
 
 
 def pipeline_train_step(stage_fn, loss_fn, stacked_params, x, labels, *,

@@ -154,21 +154,6 @@ def _chunk_for(s_len: int) -> int:
     return c
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static mesh-axis size.  ``lax.axis_size`` appeared after jax
-    0.4.x; there, ``jax.core.axis_frame`` returns the size directly."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    size = frame if isinstance(frame, int) else getattr(frame, "size", None)
-    if size is None:
-        raise RuntimeError(
-            f"cannot determine size of mesh axis {axis_name!r} on this jax "
-            "version (no lax.axis_size, axis_frame returned "
-            f"{type(frame).__name__})")
-    return size
-
-
 def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    axis_name: str, causal: bool = False,
                    scale: Optional[float] = None,
@@ -184,7 +169,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_local = q.shape[2]
     q_off = my * s_local
@@ -220,17 +205,17 @@ def sharded_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     hp = ("model" if "model" in mesh.axis_names
           and q.shape[1] % mesh.shape["model"] == 0 else None)
     spec = P(dp, hp, seq_axis, None)
-    from .pipeline import shard_map  # version shim (check_rep/check_vma)
     if seg is None:
         fn = functools.partial(ring_attention, axis_name=seq_axis,
                                causal=causal)
-        return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+        return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
     seg_spec = P(dp, seq_axis)
 
     def fn(q_, k_, v_, seg_):
         return ring_attention(q_, k_, v_, axis_name=seq_axis,
                               causal=causal, seg=seg_)
 
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec, seg_spec),
-                     out_specs=spec, check_vma=False)(q, k, v, seg)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(spec, spec, spec, seg_spec),
+                         out_specs=spec, check_vma=False)(q, k, v, seg)

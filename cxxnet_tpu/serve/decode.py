@@ -276,7 +276,6 @@ class DecodeEngine:
         return nodes[self._logits_node]
 
     def _build_prefill(self):
-        import jax
         import jax.numpy as jnp
         from ..layers.base import DecodeState
         t = self.trainer
@@ -300,7 +299,7 @@ class DecodeEngine:
                 for key, kv in dec.caches.items()}
             return out, new_caches
 
-        fn = jax.jit(pfill, donate_argnums=(2,))
+        fn = t.jit(pfill, donate_argnums=(2,))
         ids0 = np.zeros((1, 1, 1, S), np.float32)
         with warnings.catch_warnings():
             warnings.filterwarnings(
@@ -310,7 +309,6 @@ class DecodeEngine:
                             np.ones((1,), np.int32)).compile()
 
     def _build_step(self):
-        import jax
         import jax.numpy as jnp
         from ..layers.base import DecodeState
         t = self.trainer
@@ -328,7 +326,7 @@ class DecodeEngine:
             logits = self._run_net(params, buffers, ids, dec)
             return logits[:, 0, 0, :].astype(jnp.float32), dec.caches
 
-        fn = jax.jit(dstep, donate_argnums=(2,))
+        fn = t.jit(dstep, donate_argnums=(2,))
         with warnings.catch_warnings():
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
@@ -343,7 +341,6 @@ class DecodeEngine:
         ``(slots, width, vocab)`` f32 logits; row ``w`` of a slot is
         bitwise the single-token step's logits at ``positions[slot] +
         w`` (the layer-side mask contract)."""
-        import jax
         import jax.numpy as jnp
         from ..layers.base import DecodeState
         t = self.trainer
@@ -362,7 +359,7 @@ class DecodeEngine:
             logits = self._run_net(params, buffers, ids, dec)
             return logits[:, 0, :, :].astype(jnp.float32), dec.caches
 
-        fn = jax.jit(dblock, donate_argnums=(2,))
+        fn = t.jit(dblock, donate_argnums=(2,))
         with warnings.catch_warnings():
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
@@ -520,13 +517,12 @@ class DecodeEngine:
         ``(max_seqlen, vocab)`` f32.  The parity tests compare
         :meth:`prefill`/:meth:`step` logits against rows of this
         bitwise at f32 (causality keeps the pad positions invisible)."""
-        import jax
         tokens = np.asarray(tokens).reshape(-1)
         if tokens.shape[0] > self.max_seqlen:
             raise ValueError("full_logits: prompt exceeds max_seqlen")
         ids = np.zeros((1, 1, 1, self.max_seqlen), np.float32)
         ids[0, 0, 0, :tokens.shape[0]] = tokens.astype(np.float32)
-        logits = jax.jit(
+        logits = self.trainer.jit(
             lambda p, b, d: self._run_net(p, b, d, None))(
                 self.trainer.params, self.trainer.buffers, ids)
         return np.asarray(logits, np.float32)[0, 0]

@@ -169,7 +169,6 @@ class PredictEngine:
         here (the trace-time ``serve_step_traces`` bump is the retrace
         oracle) and compiled to an executable that rejects any other
         shape."""
-        import jax
         import jax.numpy as jnp
         t = self.trainer
         nid = t.net.final_node
@@ -181,7 +180,7 @@ class PredictEngine:
                 data = data.astype(jnp.bfloat16)
             return t.forward_eval(p, buffers, data, (nid,))[nid]
 
-        fn = jax.jit(
+        fn = t.jit(
             sstep,
             in_shardings=(t.param_shardings, t.repl, t.buffer_shardings,
                           t.batch_shard),
@@ -365,7 +364,6 @@ class PredictEngine:
         buckets exactly like :meth:`predict` — the buckets are the
         shapes validated divisible by the mesh data axis, so a ragged
         calibration batch still stages cleanly on a sharded mesh."""
-        import jax
         t = self.trainer
         nid = t.net.final_node
         x = np.asarray(x, np.float32)
@@ -380,7 +378,7 @@ class PredictEngine:
                     [chunk, np.zeros((b - take,) + self._in_shape,
                                      np.float32)])
             if b not in self._ref_fns:
-                self._ref_fns[b] = jax.jit(
+                self._ref_fns[b] = t.jit(
                     lambda p, bu, d: t.forward_eval(p, bu, d, (nid,))[nid],
                     in_shardings=(t.param_shardings, t.buffer_shardings,
                                   t.batch_shard),

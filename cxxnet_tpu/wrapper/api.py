@@ -15,6 +15,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import engine
 from ..io.data import DataBatch
 from ..io.factory import create_iterator, init_iterator
 from ..monitor import log as mlog
@@ -86,6 +87,7 @@ class Net:
     """Neural net object (CXNNetCreate parity)."""
 
     def __init__(self, dev: str = "tpu", cfg: str = ""):
+        engine.enable_compile_cache(dev)
         self._trainer = NetTrainer()
         self._trainer.set_param("dev", dev)
         for k, v in parse_config_string(cfg):

@@ -1,5 +1,7 @@
 #!/bin/sh
 # Usage: ./run.sh [MNIST.conf|MNIST_CONV.conf|LeNet.conf] [key=value ...]
+# The confs say dev = tpu, which fails where no TPU is visible: pass
+# dev=cpu to train on the CPU (./run.sh MNIST.conf dev=cpu).
 # Fetches MNIST if possible; falls back to the synthetic generator in
 # zero-egress environments (same idx format, trains the same configs).
 set -e
@@ -37,4 +39,4 @@ if ! have_all; then
 fi
 
 mkdir -p models
-PYTHONPATH=../..:$PYTHONPATH python -m cxxnet_tpu "$conf" model_dir=models "$@"
+PYTHONPATH=../.. python -m cxxnet_tpu "$conf" model_dir=models "$@"

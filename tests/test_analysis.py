@@ -520,8 +520,7 @@ def test_jaxpr_lint_clean_on_plain_net(_test_layers):
 
 
 def test_jaxpr_lint_flags_f64_promotion():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x * 2.0)(np.zeros(3, np.float64))
     findings = jaxpr_lint.jaxpr_findings(closed)
     assert any("float64" in f.message for f in findings)

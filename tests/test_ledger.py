@@ -5,8 +5,8 @@
   overlap clamp, partial dying round);
 * the tolerant JSONL reader skips a torn final line with ONE warning;
 * the comparison engine's directions, thresholds, and significance
-  floors (the one implementation obsv --diff / bench --against /
-  test_bench_guard share);
+  floors (the one implementation obsv --diff / bench --against
+  share);
 * CPU MNIST e2e: the emitted ledger's category sum lands within 5% of
   the measured run wall, and a TrainingDiverged run still lands one;
 * obsv --diff through the real CLI: exit 1 on a degraded run, exit 0

@@ -164,6 +164,23 @@ def test_scopes_from_planes_sees_wrapped_backward_paths():
     assert attribution.scopes_from_planes([p]) == ["07-norm"]
 
 
+def test_op_event_name_strips_instruction_text():
+    """libtpu 0.0.34 names op events by the whole HLO instruction line;
+    the scope join and the collective classifier key on the bare name."""
+    from cxxnet_tpu.monitor.trace import collective_kind, op_event_name
+    full = ("%fusion.220 = (bf16[8192,2048]{1,0:T(8,128)(2,1)}, "
+            "f32[8192,2048]{1,0:T(8,128)}) fusion(f32[8192,2048] %w32.1), "
+            "kind=kOutput, calls=%fused_computation.348")
+    assert op_event_name(full) == "fusion.220"
+    assert op_event_name("%all-reduce = f32[8]{0} all-reduce(f32[8] %x)") \
+        == "all-reduce"
+    assert collective_kind(op_event_name(
+        "%all-reduce-start.3 = f32[8]{0} all-reduce-start(%x)")) \
+        == ("all-reduce", "start")
+    for bare in ("fusion.9", "jit_step(15767990343410880262)", "3"):
+        assert op_event_name(bare) == bare
+
+
 def test_event_display_parsed():
     tpu = parse_xspace(FIXTURE)[0]
     assert tpu.event_display[1] == "jit(step)/jit(main)/00-conv/add.1"
@@ -334,7 +351,13 @@ metrics_sink = jsonl:{sink}
     lp = lps[0]
     assert lp["steps"] >= 1 and lp["round"] == 1
     rows_sum = sum(r["device_ms"] for r in lp["rows"])
-    assert rows_sum == pytest.approx(lp["ops_total_ms"], rel=1e-3)
+    # every row and the total are rounded to 1e-4 ms in the record, so
+    # the sum may sit half a unit per row away from the rounded total.
+    # On this ~0.09 ms CPU window rel=1e-3 alone is less than one unit:
+    # the parent (0c95060) failed 3 runs of 24 with
+    # `assert 0.0943 == 0.0942 ± 9.4e-05`
+    assert rows_sum == pytest.approx(
+        lp["ops_total_ms"], rel=1e-3, abs=0.5e-4 * (len(lp["rows"]) + 1))
     assert abs(rows_sum - lp["device_total_ms"]) \
         <= 0.1 * lp["device_total_ms"]
     layers = {r["layer"] for r in lp["rows"]}

@@ -586,7 +586,6 @@ def test_layernorm_default_on_and_layer_route(monkeypatch):
     monkeypatch.delenv("CXXNET_PALLAS_LN", raising=False)
     assert engine._Options().pallas_ln == "1"  # fresh default (no env)
     monkeypatch.setattr(engine.opts, "pallas_ln", "1")
-    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
     calls = []
     real = pk.layernorm_pallas
 
@@ -598,7 +597,8 @@ def test_layernorm_default_on_and_layer_route(monkeypatch):
     x = jnp.asarray(np.random.RandomState(0).randn(2, 1, 8, 128),
                     jnp.float32)
     params = layer.init_params(jax.random.PRNGKey(0), [x.shape])
-    (y,), _ = layer.forward(params, {}, [x], ForwardContext(train=True))
+    with engine.placed_on("tpu"):  # the layer believes it runs on a TPU
+        (y,), _ = layer.forward(params, {}, [x], ForwardContext(train=True))
     assert calls == [(16, 128)]
     np.testing.assert_allclose(
         np.asarray(y), np.asarray(_ln_ref(x, params["wmat"],

@@ -19,8 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from cxxnet_tpu import engine
@@ -85,7 +84,7 @@ def test_collective_walk_extracts_ordered_sequence():
         return lax.ppermute(y, "data", [(0, 1), (1, 0)])
 
     f = shard_map(body, mesh=mesh, in_specs=P("data"),
-                  out_specs=P("data"), check_rep=False)
+                  out_specs=P("data"), check_vma=False)
     closed = jax.make_jaxpr(f)(jnp.zeros((8, 4), jnp.float32))
     ops, findings = [], []
     spmdlint.collective_walk(closed.jaxpr, ops, findings)
@@ -103,7 +102,7 @@ def test_divergent_cond_branches_error():
                         lambda v: v * 2.0, x)
 
     f = shard_map(body, mesh=mesh, in_specs=P("data"),
-                  out_specs=P("data"), check_rep=False)
+                  out_specs=P("data"), check_vma=False)
     closed = jax.make_jaxpr(f)(jnp.zeros((8, 4), jnp.float32))
     ops, findings = [], []
     spmdlint.collective_walk(closed.jaxpr, ops, findings)
@@ -122,7 +121,7 @@ def test_matching_cond_branches_stay_quiet():
                         lambda v: lax.psum(v * 2.0, "data"), x)
 
     f = shard_map(body, mesh=mesh, in_specs=P("data"),
-                  out_specs=P("data"), check_rep=False)
+                  out_specs=P("data"), check_vma=False)
     closed = jax.make_jaxpr(f)(jnp.zeros((8, 4), jnp.float32))
     ops, findings = [], []
     spmdlint.collective_walk(closed.jaxpr, ops, findings)
@@ -259,7 +258,7 @@ class _DivergentCondLayer(Layer):
 
         f = shard_map(body, mesh=ctx.mesh,
                       in_specs=P("data"), out_specs=P("data"),
-                      check_rep=False)
+                      check_vma=False)
         return [f(x)], buffers
 
 
@@ -277,7 +276,7 @@ class _DeadAxisLayer(Layer):
             return [x], buffers
         f = shard_map(lambda v: v + lax.psum(v, "model") * 0.0,
                       mesh=ctx.mesh, in_specs=P("data"),
-                      out_specs=P("data"), check_rep=False)
+                      out_specs=P("data"), check_vma=False)
         return [f(x)], buffers
 
 
@@ -295,7 +294,7 @@ class _F32WireLayer(Layer):
             return [x], buffers
         f = shard_map(lambda v: lax.psum(v, "data"),
                       mesh=ctx.mesh, in_specs=P("data"),
-                      out_specs=P(), check_rep=False)
+                      out_specs=P(), check_vma=False)
         return [x + f(x).mean() * 0.0], buffers
 
 
