@@ -32,11 +32,11 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
-import time
 from typing import Any, List, Optional, Tuple, Union
 
 import numpy as np
 
+from ..monitor.spans import Phase, PhaseClock
 from .data import DataBatch, IIterator
 
 
@@ -160,16 +160,21 @@ class DevicePrefetcher:
     """
 
     def __init__(self, base: IIterator, stager, *, group_n: int = 1,
-                 depth: int = 2, metrics=None, for_eval: bool = False):
+                 depth: int = 2, metrics=None, for_eval: bool = False,
+                 clock: Optional[PhaseClock] = None):
         self.base = base
         self.stager = stager
         self.group_n = max(1, int(group_n))
         self.depth = int(depth)
         self.metrics = metrics
         self.for_eval = for_eval
-        # sync mode: host-iterator wall behind the last item (the
-        # consumer's next() wall minus this is staging time); async mode:
-        # queue depth observed at the last get (staged items ready)
+        # the consumer's phase clock (monitor/spans.py): next() books the
+        # loop's ``input_wait`` there; the train loop hands in its own
+        self.clock = clock if clock is not None else PhaseClock()
+        # host-iterator wall behind the last item (the ``host_next``
+        # phase: it travels with the item, from the producer thread when
+        # there is one), and in async mode the queue depth observed at
+        # the last get (staged items ready)
         self.last_wait_sec = 0.0
         self.last_depth = 0
         self._iter = None
@@ -178,14 +183,12 @@ class DevicePrefetcher:
         self._gen = 0
         self._failed: Optional[BaseException] = None
         self._done = False
-        # span tracing (monitor/spans.py, trace_sample-sampled): item
-        # counters for the producer's staging span vs the consumer's
-        # queue-wait span — the pair that shows whether the input
-        # pipeline is producing ahead of the loop or the loop is
-        # waiting on it
+        # items staged so far, over all epochs: item n is the loop's
+        # dispatch n, the number its ``host_next`` / ``stage`` phases
+        # carry into a profiler trace and the grid ``trace_sample``
+        # samples the ``prefetch_stage`` / ``prefetch_wait`` spans on
         # racelint: atomic(single-writer int bump: staged on the producer in async mode, on the consumer in sync mode — never both)
-        self._span_staged = 0
-        self._span_waited = 0
+        self._n_staged = 0
 
     @property
     def async_(self) -> bool:
@@ -213,15 +216,16 @@ class DevicePrefetcher:
         return [s.stage_batch(b) for b in group]
 
     def _epoch_items(self):
-        """One epoch's staged work items, each paired with the host
-        iterator wall that fed it (used for the iter-wait split in sync
-        mode; in async mode the producer absorbs that wait)."""
+        """One epoch's staged work items as ``(item, wait, n)``: the
+        host iterator wall that fed the item (the ``host_next`` phase;
+        it reaches the step record as ``host_next_sec``, and in sync mode
+        it is the loop's input wait) and the item's number."""
         pending: List[DataBatch] = []
         wait = 0.0
         while True:
-            t0 = time.perf_counter()
-            b = self.base.next()
-            wait += time.perf_counter() - t0
+            with Phase("host_next", self._n_staged) as waited:
+                b = self.base.next()
+            wait += waited.seconds
             done = b is None
             if not done:
                 if self.for_eval and b.extra_data:
@@ -229,9 +233,9 @@ class DevicePrefetcher:
                     # stream order (trainer.evaluate's legacy rule)
                     if pending:
                         group, pending = pending, []
-                        yield self._stage_traced(group), wait
+                        yield self._stage_numbered(group, wait)
                         wait = 0.0
-                    yield self._stage_traced([b]), wait
+                    yield self._stage_numbered([b], wait)
                     wait = 0.0
                     continue
                 if self.for_eval and self.group_n > 1:
@@ -243,24 +247,27 @@ class DevicePrefetcher:
                 pending.append(b)
             if pending and (done or len(pending) >= self.group_n):
                 group, pending = pending, []
-                yield self._stage_traced(group), wait
+                yield self._stage_numbered(group, wait)
                 wait = 0.0
             if done:
                 return
 
-    def _stage_traced(self, group: List[DataBatch]) -> StagedItem:
-        """_stage plus the sampled ``prefetch_stage`` span (producer
-        side: host stack/cast/device_put/input_s2d wall per item)."""
+    def _stage_numbered(self, group: List[DataBatch], wait: float
+                        ) -> Tuple[StagedItem, float, int]:
+        """_stage under the ``stage`` phase (producer side: host
+        stack/cast/device_put/input_s2d wall per item), whose two stamps
+        are also the sampled ``prefetch_stage`` span's."""
+        n = self._n_staged
+        # racelint: ok(race_rmw) — async and sync staging are mutually exclusive modes; one context ever bumps this
+        self._n_staged += 1
+        with Phase("stage", n) as staged:
+            item = self._stage(group)
         tracer = getattr(self.metrics, "tracer", None)
-        if tracer is not None and tracer.enabled:
-            n = self._span_staged
-            # racelint: ok(race_rmw) — async and sync staging are mutually exclusive modes; one context ever bumps this
-            self._span_staged += 1
-            if tracer.sampled(n):
-                with tracer.span("prefetch_stage", batches=len(group),
-                                 mode="async" if self.async_ else "sync"):
-                    return self._stage(group)
-        return self._stage(group)
+        if tracer is not None and tracer.sampled(n):
+            tracer.emit("prefetch_stage", staged.t0, staged.t1,
+                        batches=len(group),
+                        mode="async" if self.async_ else "sync")
+        return item, wait, n
 
     # ------------------------------------------------------ thread plumbing
     def before_first(self) -> None:
@@ -283,8 +290,8 @@ class DevicePrefetcher:
 
     def _producer(self, gen: int, q: "queue.Queue") -> None:
         try:
-            for item, wait in self._epoch_items():
-                if not generation_put(self, gen, q, (item, wait)):
+            for staged in self._epoch_items():
+                if not generation_put(self, gen, q, staged):
                     return
             generation_put(self, gen, q, None)
         except BaseException as e:  # noqa: BLE001 — must reach the consumer
@@ -301,37 +308,38 @@ class DevicePrefetcher:
         if not self.async_:
             assert self._iter is not None, "call before_first() first"
             try:
-                item, self.last_wait_sec = next(self._iter)
+                with self.clock.phase("input_wait"):
+                    item, self.last_wait_sec, _ = next(self._iter)
             except StopIteration:
                 self._done = True
                 return None
             except BaseException as e:  # latch: sync epochs die like async
                 self._failed = e
                 raise
+            # no producer thread: the staging ran inside that wait, on
+            # this thread.  It is the item's h2d_sec, which the records
+            # carry apart, and not time blocked on input
+            self.clock.book("input_wait", -item_h2d_sec(item))
             return item
         assert self._queue is not None, "call before_first() first"
-        # consumer-side span: the loop's wall blocked on the producer
-        # (sampled; near-zero dur = producer is keeping up)
-        tracer = getattr(self.metrics, "tracer", None)
-        tok = None
-        if tracer is not None and tracer.enabled:
-            n = self._span_waited
-            self._span_waited += 1
-            if tracer.sampled(n):
-                tok = tracer.begin("prefetch_wait")
-        v = self._queue.get()
-        if tok is not None:
-            tracer.end(tok)
+        # the loop's wall blocked on the producer (near zero = the
+        # producer is keeping up); the phase's two stamps are also the
+        # sampled ``prefetch_wait`` span's
+        with self.clock.phase("input_wait") as waited:
+            v = self._queue.get()
+            self.last_depth = self._queue.qsize()
+            if self.metrics is not None:
+                self.metrics.set_gauge("prefetch_depth", self.last_depth)
         if v is None:
             self._done = True
             return None
         if isinstance(v, ProducerError):
             self._failed = v.exc
             raise v.exc
-        item, _ = v
-        self.last_depth = self._queue.qsize()
-        if self.metrics is not None:
-            self.metrics.set_gauge("prefetch_depth", self.last_depth)
+        item, self.last_wait_sec, n = v
+        tracer = getattr(self.metrics, "tracer", None)
+        if tracer is not None and tracer.sampled(n):
+            tracer.emit("prefetch_wait", waited.t0, waited.t1)
         return item
 
     def __iter__(self):

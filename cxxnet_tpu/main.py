@@ -29,6 +29,7 @@ from .serve import SERVE_KEYS
 from .io.device_prefetch import DevicePrefetcher, StagedGroup, item_h2d_sec
 from .io.factory import create_iterator, init_iterator
 from .monitor import TrainingDiverged, log as mlog
+from .monitor.spans import HOST_FED_FIELDS, PhaseClock, phase_fields
 from .monitor.trace import ProfileWindow
 from .nnet.trainer import NetTrainer
 from .utils.config import parse_config_file, parse_keyval_args
@@ -829,18 +830,6 @@ class LearnTask:
             # round-0 save: the iterator chain is NOT quiescent yet (a
             # threadbuffer's producer primed at init() is mid-pull)
             self._save_model(capture_iter=False)
-        if self.synth_device_data:
-            self._train_synth_device()
-            return
-        if self.itr_train is None:
-            raise RuntimeError(
-                "task=train but the config has no 'data = train' iterator "
-                "section; add one (see example/MNIST/MNIST.conf) or use the "
-                "wrapper API for in-memory data")
-        if self.test_io:
-            mlog.notice("start I/O test")
-        cc = self.max_round
-        rounds_done = 0
         if self.prof_every > 0 and self.prof_start_step >= 0:
             # lint surfaces this at check time too (doc/check.md):
             # a step-pinned one-shot window and a recurring round
@@ -853,6 +842,18 @@ class LearnTask:
                              lambda: self.net.wait_for_device(),
                              self.prof_start_step, self.prof_num_steps,
                              every=self.prof_every)
+        if self.synth_device_data:
+            self._train_synth_device(prof)
+            return
+        if self.itr_train is None:
+            raise RuntimeError(
+                "task=train but the config has no 'data = train' iterator "
+                "section; add one (see example/MNIST/MNIST.conf) or use the "
+                "wrapper API for in-memory data")
+        if self.test_io:
+            mlog.notice("start I/O test")
+        cc = self.max_round
+        rounds_done = 0
         if self.sentinel and metrics.active:
             from .monitor.sentinel import SentinelBank
             self._sentinel_bank = SentinelBank(
@@ -890,10 +891,6 @@ class LearnTask:
         will_run = min(self.num_round - self.start_counter + 1,
                        self.max_round)
         prof_round = 1 if will_run > 1 else 0
-        # prof_start_step / prof_num_steps both count DISPATCHES (a
-        # multi_step group is one); trainer.sample_counter counts update
-        # steps, which diverges from dispatches under grouping
-        global_dispatch = 0
         # multi_step > 1 groups K batches into ONE device dispatch
         # (an on-device lax.scan), the TPU equivalent of the
         # reference's ThreadBuffer keeping the GPU queue full
@@ -914,117 +911,123 @@ class LearnTask:
         # (the reference's ThreadBuffer moved host decode off the
         # critical path; this moves the H2D transfer too), or inline
         # just before the dispatch timer when prefetch_device = 0
+        # the loop's phase clock (monitor/spans.py): every stretch of
+        # host time below is entered as a phase, flat, so the step and
+        # round records say where the wall went and a profiler trace
+        # shows the same spans beside the device planes.  clock.dispatch
+        # counts DISPATCHES, as prof_start_step / prof_num_steps do (a
+        # multi_step group is one); trainer.sample_counter counts update
+        # steps, which diverges from dispatches under grouping
+        clock = PhaseClock()
         src = None if self.test_io else DevicePrefetcher(
             self.itr_train, self.net, group_n=group_n,
-            depth=self.prefetch_device, metrics=metrics)
+            depth=self.prefetch_device, metrics=metrics, clock=clock)
+        step_mark = round_mark = clock.read()
         try:
             while self.start_counter <= self.num_round and cc > 0:
                 cc -= 1
                 mlog.info(f"update round {self.start_counter - 1}")
-                prof.maybe_start_round(rounds_done, prof_round)
-                round_t0 = time.time()
-                sample_counter = 0
-                n_round = 0
-                t_mark = time.time()
-                n_mark = 0
-                # host wall split for input-bound detection: time blocked
-                # on input (the host iterator, or the staging queue when
-                # prefetching) vs time spent dispatching steps vs time
-                # staging batches onto the device (h2d; off the critical
-                # path when the producer thread runs it)
-                iter_wait = dispatch_sec = h2d_total = 0.0
-                iter_wait_mark = dispatch_mark = h2d_mark = 0.0
-                depth_sum = depth_n = 0
-                self.net.start_round(self.start_counter)
-                if src is not None:
-                    src.before_first()
-                else:
-                    self.itr_train.before_first()
+                with clock.phase("round_boundary"):
+                    prof.maybe_start_round(rounds_done, prof_round)
+                    round_t0 = time.time()
+                    sample_counter = 0
+                    n_round = 0
+                    t_mark = time.time()
+                    n_mark = 0
+                    depth_sum = depth_n = 0
+                    self.net.start_round(self.start_counter)
+                    if src is not None:
+                        src.before_first()
+                    else:
+                        self.itr_train.before_first()
                 while True:
-                    t0 = time.perf_counter()
                     first_dispatch = False
                     if src is None:
                         # test_io = 1: host pipeline only, no staging
-                        batch = self.itr_train.next()
-                        iter_wait_mark += time.perf_counter() - t0
+                        with clock.phase("input_wait"):
+                            batch = self.itr_train.next()
                         if batch is None:
                             break
                         metas = (batch,)
                     else:
+                        # books its own input_wait: blocked on the staging
+                        # queue, or on the host iterator when nothing
+                        # prefetches
                         item = src.next()
-                        wall = time.perf_counter() - t0
                         if item is None:
                             break
+                        # measured where the work ran (the producer thread
+                        # when prefetching: off the critical path), these
+                        # travel with the item
+                        clock.book("host_next", src.last_wait_sec)
+                        clock.book("h2d", item_h2d_sec(item))
                         if src.async_:
-                            # blocked on the staging queue; the transfer
-                            # itself ran on the producer thread (h2d_mark
-                            # tracks it leaving the critical path)
-                            iter_wait_mark += wall
                             depth_sum += src.last_depth
                             depth_n += 1
-                        else:
-                            iter_wait_mark += src.last_wait_sec
-                        h2d_mark += item_h2d_sec(item)
-                        prof.maybe_start_step(global_dispatch)
-                        global_dispatch += 1
                         first_dispatch = self.compile_sec is None
-                        t0 = time.perf_counter()
-                        if isinstance(item, StagedGroup):
-                            self._update_group(item)
-                            metas = item.meta
-                        else:
-                            for sb in item:
-                                self.net.update(sb)
-                            metas = item
-                        dt = time.perf_counter() - t0
+                        with clock.phase("record"):
+                            prof.maybe_start_step(clock.dispatch)
+                        with clock.phase("compile" if first_dispatch
+                                         else "enqueue") as enqueued:
+                            if isinstance(item, StagedGroup):
+                                self._update_group(item)
+                                metas = item.meta
+                            else:
+                                for sb in item:
+                                    self.net.update(sb)
+                                metas = item
                         if first_dispatch:
                             # jit traces + compiles synchronously inside
-                            # the first dispatch: report it separately and
-                            # keep it out of the steady-state examples/sec
-                            # window (the old code silently folded it into
-                            # the first one)
-                            self.compile_sec = dt
-                            metrics.emit("compile", compile_sec=round(dt, 3),
-                                         round=self.start_counter - 1)
-                            mlog.info(f"compile: {dt:.1f} sec (first "
-                                      "dispatch, excluded from examples/sec)")
+                            # the first dispatch: report it separately
+                            # and keep it out of the steady-state
+                            # examples/sec window and of the step marks
+                            self._note_compile(enqueued.seconds)
+                            step_mark = clock.read()
                             t_mark, n_mark = time.time(), 0
-                        else:
-                            dispatch_mark += dt
-                        if prof.after_step():
-                            mlog.info("profile trace written to "
-                                      f"{prof.last_window_dir}")
-                            self._emit_trace_report(prof)
+                        with clock.phase("record"):
+                            if prof.after_step():
+                                mlog.info("profile trace written to "
+                                          f"{prof.last_window_dir}")
+                                self._emit_trace_report(prof)
                     for b in metas:
                         sample_counter += 1
                         n_real = b.batch_size - b.num_batch_padd
                         n_round += n_real
                         if not first_dispatch:
                             n_mark += n_real
-                        if sample_counter % self.print_step == 0:
-                            now = time.time()
-                            rate = n_mark / max(now - t_mark, 1e-9)
-                            # metrics.active alone: the bank only arms
-                            # with an active sink, and if the sink dies
-                            # mid-run (emit's OSError guard) this also
-                            # stops paying the D2H loss sync for
-                            # records nobody will see
-                            if metrics.active and self.test_io == 0:
-                                loss = getattr(self.net, "_last_loss", None)
+                        if sample_counter % self.print_step:
+                            continue
+                        now = time.time()
+                        rate = n_mark / max(now - t_mark, 1e-9)
+                        # metrics.active alone: the bank only arms with
+                        # an active sink, and if the sink dies mid-run
+                        # (emit's OSError guard) this also stops paying
+                        # the D2H loss sync for records nobody will see
+                        recording = metrics.active and self.test_io == 0
+                        loss = getattr(self.net, "_last_loss", None) \
+                            if recording else None
+                        if loss is not None:
+                            # the one place the host-fed loop waits for
+                            # the device: every step up to here is done
+                            # when the loss arrives
+                            with clock.phase("device_wait"):
+                                loss = float(np.asarray(loss))
+                        with clock.phase("record"):
+                            if recording:
+                                cut, step_mark = clock.cut(step_mark)
                                 rec = dict(
                                     round=self.start_counter - 1,
                                     step=sample_counter,
                                     global_step=self.net.sample_counter,
                                     elapsed_sec=round(now - start, 3),
                                     examples_per_sec=round(rate, 1),
-                                    iter_wait_sec=round(iter_wait_mark, 4),
-                                    dispatch_sec=round(dispatch_mark, 4),
-                                    h2d_sec=round(h2d_mark, 4),
+                                    wall_sec=round(cut["wall"], 6),
+                                    **phase_fields(cut, 6,
+                                                   HOST_FED_FIELDS),
                                     staging_depth=round(
                                         depth_sum / depth_n, 2)
                                     if depth_n else 0.0,
-                                    loss=None if loss is None
-                                    else float(np.asarray(loss)))
+                                    loss=loss)
                                 bub = getattr(self.net,
                                               "pipe_bubble_frac", 0.0)
                                 if bub:
@@ -1035,75 +1038,77 @@ class LearnTask:
                                 if bank is not None:
                                     bank.observe_step(rec)
                             t_mark, n_mark = now, 0
-                            iter_wait += iter_wait_mark
-                            dispatch_sec += dispatch_mark
-                            h2d_total += h2d_mark
-                            iter_wait_mark = dispatch_mark = h2d_mark = 0.0
                             depth_sum = depth_n = 0
                             mlog.info(
                                 f"round {self.start_counter - 1:8d}:"
                                 f"[{sample_counter:8d}] {int(now - start)} "
                                 f"sec elapsed, {rate:.1f} examples/sec")
                             self._report_diagnostics()
-                if prof.round_end():
-                    mlog.info("profile trace written to "
-                              f"{prof.last_window_dir}")
-                    self._emit_trace_report(prof)
-                rounds_done += 1
-                iter_wait += iter_wait_mark
-                dispatch_sec += dispatch_mark
-                h2d_total += h2d_mark
-                train_wall = time.time() - round_t0
-                if self.test_on_server:
-                    # per-round replica consistency check (the reference's
-                    # test_on_server weight check,
-                    # async_updater-inl.hpp:144-154)
-                    drift = self.net.check_weight_consistency()
-                    if drift != 0.0:
-                        raise RuntimeError(
-                            f"replica weights diverged (max abs diff {drift})")
-                round_metrics = {}
-                if self.test_io == 0:
-                    line = f"[{self.start_counter}]"
-                    # only print the train metric when the trainer actually
-                    # accumulated it (eval_train also gates accumulation in
-                    # NetTrainer.update — a 0 here would print all-zero
-                    # metrics)
-                    if self.eval_train:
-                        line += self.net.train_eval_line("train")
-                        round_metrics.update(
-                            self.net.train_metric.values("train"))
-                    for it, name in zip(self._eval_sources(),
-                                        self.eval_names):
-                        line += self.net.evaluate(it, name)
-                        round_metrics.update(self.net.metric.values(name))
-                    mlog.result(line)
-                if metrics.active:
-                    rec = dict(round=self.start_counter,
-                               wall_sec=round(train_wall, 3),
-                               eval_sec=round(
-                                   time.time() - round_t0 - train_wall, 3),
-                               examples=n_round,
-                               examples_per_sec=round(
-                                   n_round / max(train_wall, 1e-9), 1),
-                               iter_wait_sec=round(iter_wait, 3),
-                               dispatch_sec=round(dispatch_sec, 3),
-                               h2d_sec=round(h2d_total, 3),
-                               train_step_traces=metrics.counters.get(
-                                   "train_step_traces", 0),
-                               eval_step_traces=metrics.counters.get(
-                                   "eval_step_traces", 0),
-                               **round_metrics)
-                    if rounds_done == 1 and self.compile_sec is not None:
-                        rec["compile_sec"] = round(self.compile_sec, 3)
-                    bub = getattr(self.net, "pipe_bubble_frac", 0.0)
-                    if bub:
-                        rec["pipe_bubble_frac"] = round(bub, 4)
-                    rec.update(self.net.memory_gauges())
-                    metrics.emit("round", **rec)
-                    if bank is not None:
-                        bank.observe_round(rec)
-                self._save_model()
+                    clock.dispatch += 1  # the next unit of work
+                with clock.phase("round_boundary"):
+                    if prof.round_end():
+                        mlog.info("profile trace written to "
+                                  f"{prof.last_window_dir}")
+                        self._emit_trace_report(prof)
+                    rounds_done += 1
+                    train_wall = time.time() - round_t0
+                    if self.test_on_server:
+                        # per-round replica consistency check (the
+                        # reference's test_on_server weight check,
+                        # async_updater-inl.hpp:144-154)
+                        drift = self.net.check_weight_consistency()
+                        if drift != 0.0:
+                            raise RuntimeError(
+                                "replica weights diverged (max abs diff "
+                                f"{drift})")
+                    round_metrics = {}
+                    if self.test_io == 0:
+                        line = f"[{self.start_counter}]"
+                        # only print the train metric when the trainer
+                        # actually accumulated it (eval_train also gates
+                        # accumulation in NetTrainer.update — a 0 here
+                        # would print all-zero metrics)
+                        if self.eval_train:
+                            line += self.net.train_eval_line("train")
+                            round_metrics.update(
+                                self.net.train_metric.values("train"))
+                        for it, name in zip(self._eval_sources(),
+                                            self.eval_names):
+                            line += self.net.evaluate(it, name)
+                            round_metrics.update(
+                                self.net.metric.values(name))
+                        mlog.result(line)
+                with clock.phase("record"):
+                    # the same sums over the round: cut when the record is
+                    # built, so the round's own writing and the save that
+                    # follows are booked in the next round's
+                    cut, round_mark = clock.cut(round_mark)
+                    if metrics.active:
+                        rec = dict(round=self.start_counter,
+                                   wall_sec=round(train_wall, 3),
+                                   eval_sec=round(
+                                       time.time() - round_t0 - train_wall,
+                                       3),
+                                   examples=n_round,
+                                   examples_per_sec=round(
+                                       n_round / max(train_wall, 1e-9), 1),
+                                   **phase_fields(cut, 3, HOST_FED_FIELDS),
+                                   train_step_traces=metrics.counters.get(
+                                       "train_step_traces", 0),
+                                   eval_step_traces=metrics.counters.get(
+                                       "eval_step_traces", 0),
+                                   **round_metrics)
+                        if rounds_done == 1 and self.compile_sec is not None:
+                            rec["compile_sec"] = round(self.compile_sec, 3)
+                        bub = getattr(self.net, "pipe_bubble_frac", 0.0)
+                        if bub:
+                            rec["pipe_bubble_frac"] = round(bub, 4)
+                        rec.update(self.net.memory_gauges())
+                        metrics.emit("round", **rec)
+                        if bank is not None:
+                            bank.observe_round(rec)
+                with clock.phase("round_boundary"):
+                    self._save_model()
         except BaseException as e:
             # flight recorder: the last K step records — the run's final
             # approach into a TrainingDiverged or any mid-round failure —
@@ -1118,25 +1123,36 @@ class LearnTask:
             if src is not None:
                 src.close()
             self._close_prefetchers()
-            if prof.active:
-                # a window the run never closed: prof_num_steps past the
-                # last dispatch, test_io=1, or a mid-round raise landing
-                # inside an open window (TrainingDiverged under
-                # prof_every) — flush it so the incident window's trace
-                # + layer_profile records survive, and the profiler
-                # never runs into process exit.  Guarded: a flush
-                # failure must not mask the in-flight exception.
-                try:
-                    prof.stop()
-                    mlog.info("profile trace written to "
-                              f"{prof.last_window_dir} "
-                              "(window truncated at training end)")
-                    self._emit_trace_report(prof)
-                except Exception as pe:
-                    mlog.warn(f"profile window flush failed: {pe}")
+            self._flush_profile(prof)
         mlog.info(f"\nupdating end, {int(time.time() - start)} sec in all")
 
-    def _train_synth_device(self) -> None:
+    def _note_compile(self, seconds: float) -> None:
+        """The first dispatch traced and compiled inside its call (the
+        loop's ``compile`` phase): one ``compile`` record."""
+        self.compile_sec = seconds
+        self.net.metrics.emit("compile", compile_sec=round(seconds, 3),
+                              round=self.start_counter - 1)
+        mlog.info(f"compile: {seconds:.1f} sec (first dispatch, excluded "
+                  "from examples/sec)")
+
+    def _flush_profile(self, prof: ProfileWindow) -> None:
+        """A window the run never closed: prof_num_steps past the last
+        dispatch, test_io=1, or a mid-round raise landing inside an open
+        window (TrainingDiverged under prof_every) — flush it so the
+        incident window's trace + layer_profile records survive, and the
+        profiler never runs into process exit.  Guarded: a flush failure
+        must not mask the in-flight exception."""
+        if not prof.active:
+            return
+        try:
+            prof.stop()
+            mlog.info(f"profile trace written to {prof.last_window_dir} "
+                      "(window truncated at training end)")
+            self._emit_trace_report(prof)
+        except Exception as pe:
+            mlog.warn(f"profile window flush failed: {pe}")
+
+    def _train_synth_device(self, prof: ProfileWindow) -> None:
         """synth_device_data=1: run the REAL config-driven train loop on
         pre-staged device-resident synthetic batches — the device-side twin
         of ``test_io=1``.  Isolates the train-loop dispatch overhead from
@@ -1148,7 +1164,9 @@ class LearnTask:
         sharding: no host copy of the stack ever exists (at b1024 x 10
         steps that was 6.3 GB of float32) and no staging transform runs.
         One round = one dispatch over the same batches, so the loss a
-        round reports is comparable with the previous round's."""
+        round reports is comparable with the previous round's.  The same
+        phase clock and profile window as the host-fed loop: a round here
+        is one dispatch, so it passes ``round_boundary`` every time."""
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1167,35 +1185,52 @@ class LearnTask:
                 key, (k, shape[0], 1), 0, nclass).astype(jnp.float32),
             out_shardings=stacked)(kl)
         start = time.time()
-        while self.start_counter <= self.num_round:
-            self.net.start_round(self.start_counter)
-            t0 = time.time()
-            losses = net.update_many(datas, labels)
-            np.asarray(losses)
-            dt = time.time() - t0
-            rec = dict(round=self.start_counter - 1, step=k,
-                       global_step=net.sample_counter, synth_device=1,
-                       iter_wait_sec=0.0,
-                       loss=float(np.asarray(losses[-1])))
-            if self.compile_sec is None:
-                # jit traces + compiles synchronously inside the first
-                # dispatch: reported separately and kept out of
-                # examples/sec, as in the host-fed loop
-                self.compile_sec = dt
-                net.metrics.emit("compile", compile_sec=round(dt, 3),
-                                 round=self.start_counter - 1)
-                mlog.info(f"compile: {dt:.1f} sec (first dispatch, "
-                          "excluded from examples/sec)")
-                rec["dispatch_sec"] = 0.0
-            else:
-                rate = shape[0] * k / dt
-                mlog.info(f"round {self.start_counter - 1:8d}: "
-                          f"synth-device {k} steps, {rate:.1f} "
-                          "examples/sec")
-                rec.update(examples_per_sec=round(rate, 1),
-                           dispatch_sec=round(dt, 4))
-            net.metrics.emit("step", **rec)
-            self._save_model()
+        clock = PhaseClock()
+        step_mark = clock.read()
+        try:
+            while self.start_counter <= self.num_round:
+                first_dispatch = self.compile_sec is None
+                with clock.phase("round_boundary"):
+                    prof.maybe_start_round(clock.dispatch, 1)
+                    self.net.start_round(self.start_counter)
+                    prof.maybe_start_step(clock.dispatch)
+                with clock.phase("compile" if first_dispatch
+                                 else "enqueue") as enqueued:
+                    losses = net.update_many(datas, labels)
+                if first_dispatch:
+                    # jit traces + compiles synchronously inside the first
+                    # dispatch: reported separately and kept out of
+                    # examples/sec and of the step marks, as in the
+                    # host-fed loop
+                    self._note_compile(enqueued.seconds)
+                    step_mark = clock.read()
+                with clock.phase("device_wait") as waited:
+                    np.asarray(losses)
+                with clock.phase("record"):
+                    if prof.after_step() or prof.round_end():
+                        mlog.info("profile trace written to "
+                                  f"{prof.last_window_dir}")
+                        self._emit_trace_report(prof)
+                    cut, step_mark = clock.cut(step_mark)
+                    rec = dict(round=self.start_counter - 1, step=k,
+                               global_step=net.sample_counter,
+                               synth_device=1,
+                               wall_sec=round(cut["wall"], 6),
+                               **phase_fields(cut, 6),
+                               loss=float(np.asarray(losses[-1])))
+                    if not first_dispatch:
+                        rate = shape[0] * k \
+                            / (enqueued.seconds + waited.seconds)
+                        mlog.info(f"round {self.start_counter - 1:8d}: "
+                                  f"synth-device {k} steps, {rate:.1f} "
+                                  "examples/sec")
+                        rec["examples_per_sec"] = round(rate, 1)
+                    net.metrics.emit("step", **rec)
+                with clock.phase("round_boundary"):
+                    self._save_model()
+                clock.dispatch += 1  # the next unit of work
+        finally:
+            self._flush_profile(prof)
         mlog.info(f"\nupdating end, {int(time.time() - start)} sec in all")
 
     def _update_group(self, staged: StagedGroup) -> None:

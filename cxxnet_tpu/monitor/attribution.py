@@ -20,7 +20,9 @@ tools/obsv.py and CI):
   (innermost) match wins, and transform wrappers
   (``transpose(jvp(03-conv))``) match by substring — scope strings are
   pairwise non-substring by construction (layers/base.conn_scope_name).
-* :func:`layer_table` walks already-parsed planes and buckets per-op
+* :func:`layer_table` walks ONE chip's ``XLA Ops`` line of
+  already-parsed planes (every line of a CPU runtime trace, which has no
+  such line) and buckets per-op
   device time by layer, with collectives split into their own bucket
   (shared classifier with trace.comm_summary_in — the substring-trap
   rule applies here too), joined against the analytic per-layer
@@ -34,7 +36,8 @@ from __future__ import annotations
 import re
 from typing import Dict, List, Optional, Sequence
 
-from .trace import XPlane, collective_kind, total_ms_in
+from .trace import (XPlane, collective_kind, matching_lines,
+                    total_ms_in)
 
 #: pseudo-rows for time the scope join can't (or shouldn't) name
 COMM_ROW = "(collectives)"
@@ -122,7 +125,7 @@ def layer_table(planes: List[XPlane], scopes: Sequence[str],
     spans) match none of those and are skipped, so the table's total is
     op time, not wall clock.
 
-    Returns the ``layer_profile`` record payload: per-step
+    Returns the ``layer_profile`` record payload, of one chip: per-step
     ``device_total_ms`` (XLA-Modules total when the trace has one, else
     the counted-op sum), ``attributed_ms``, ``coverage``
     (attributed/total), and ``rows`` sorted by device time — each row
@@ -137,37 +140,42 @@ def layer_table(planes: List[XPlane], scopes: Sequence[str],
     steps = max(int(steps), 1)
     buckets: Dict[str, List[float]] = {}  # scope -> [ms, count, comm_ms]
     ops_ms = 0.0
-    for plane in planes:
-        for line in plane.lines:
-            if line.name == "python":
+    # a TPU trace: ONE chip's ``XLA Ops`` line (summed over planes the
+    # table is four times the step on four chips; ``Async XLA Ops`` holds
+    # in-flight spans beside the ops, not more ops).  The CPU thunk
+    # runtime has no such line: there every line but ``python`` is walked
+    # and the op_scopes membership below picks the program's ops out
+    lines = list(matching_lines(planes, "TPU", "XLA Ops")) or [
+        (plane, line) for plane in planes for line in plane.lines
+        if line.name != "python"]
+    for plane, line in lines:
+        for ev in line.events:
+            name = plane.event_names.get(ev.metadata_id, "")
+            scope = scope_of_path(
+                plane.event_display.get(ev.metadata_id, ""), sre)
+            known = name in op_scopes
+            if scope is None and known:
+                scope = op_scopes[name]
+            comm = collective_kind(name) is not None
+            if scope is None and not known and not comm and (
+                    op_scopes or not plane.event_display.get(
+                        ev.metadata_id)):
+                # not an op of the profiled program.  With an
+                # op_scopes map, membership is the oracle; without
+                # one (degraded trainer paths, obsv --trace) any
+                # event carrying a framework path still counts, in
+                # (unattributed) — scope-less program ops must not
+                # vanish and read as coverage ~1.0
                 continue
-            for ev in line.events:
-                name = plane.event_names.get(ev.metadata_id, "")
-                scope = scope_of_path(
-                    plane.event_display.get(ev.metadata_id, ""), sre)
-                known = name in op_scopes
-                if scope is None and known:
-                    scope = op_scopes[name]
-                comm = collective_kind(name) is not None
-                if scope is None and not known and not comm and (
-                        op_scopes or not plane.event_display.get(
-                            ev.metadata_id)):
-                    # not an op of the profiled program.  With an
-                    # op_scopes map, membership is the oracle; without
-                    # one (degraded trainer paths, obsv --trace) any
-                    # event carrying a framework path still counts, in
-                    # (unattributed) — scope-less program ops must not
-                    # vanish and read as coverage ~1.0
-                    continue
-                ms = ev.duration_ps / 1e9
-                ops_ms += ms
-                row = scope if scope is not None else (
-                    COMM_ROW if comm else OTHER_ROW)
-                cur = buckets.setdefault(row, [0.0, 0, 0.0])
-                cur[0] += ms
-                cur[1] += 1
-                if comm:
-                    cur[2] += ms
+            ms = ev.duration_ps / 1e9
+            ops_ms += ms
+            row = scope if scope is not None else (
+                COMM_ROW if comm else OTHER_ROW)
+            cur = buckets.setdefault(row, [0.0, 0, 0.0])
+            cur[0] += ms
+            cur[1] += 1
+            if comm:
+                cur[2] += ms
     device_ms = total_ms_in(planes) or ops_ms
     costs = costs or {}
     rows = []

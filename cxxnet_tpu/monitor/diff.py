@@ -78,9 +78,9 @@ def compare(metric: str, a, b, rel: float = 0.10,
 
 # ------------------------------------------------- metric extraction
 #: ledger shares whose growth is unambiguous badput — the JUDGED rows.
-#: compile/eval/other shift with run shape, and the dispatch share
-#: (goodput) rises when the device merely slows down; those ride as
-#: context rows (direction None) instead
+#: compile/eval/other shift with run shape, and the dispatch and
+#: device_wait shares (goodput) rise when the device merely slows down;
+#: those ride as context rows (direction None) instead
 _JUDGED_SHARES = ("pipe_bubble", "input_wait", "h2d_staging",
                   "ckpt_blocked", "rollback_lost")
 
@@ -108,8 +108,8 @@ def run_metrics(recs: List[dict]
         out["goodput_pct"] = (led.get("goodput_pct"), None, 0.0)
         shares = led.get("shares") or {}
         for cat in CATEGORIES:
-            if cat not in shares or cat == "dispatch":
-                continue  # dispatch share == goodput_pct, one row
+            if cat not in shares:
+                continue
             if cat in _JUDGED_SHARES:
                 # floor 0.02: a two-points-of-wall move is the smallest
                 # share shift worth a verdict on CI-sized runs

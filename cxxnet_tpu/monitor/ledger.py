@@ -10,11 +10,21 @@ record attributing the measured wall into categories:
 
 ========================  ====================================================
 ``compile``               first-dispatch jit trace + XLA compile wall
-``dispatch``              host wall spent dispatching train steps — the
-                          useful-work category goodput is computed from
+``dispatch``              host wall inside the train step's call
+                          (``dispatch_sec``, the loop's ``enqueue`` phase).
+                          Where dispatch is synchronous (the CPU backend)
+                          this is the step's whole wall; on a chip the call
+                          returns once the step is enqueued and this is the
+                          enqueue alone
+``device_wait``           host wall blocked on the device's results
+                          (``device_wait_sec``, the loop's blocking read of
+                          the loss): on a chip, where the steps' time goes.
+                          With ``dispatch`` it is the useful work goodput is
+                          computed from; absent field → 0
 ``pipe_bubble``           pipeline fill/drain idle inside the dispatched
-                          step: ``dispatch × pipe_bubble_frac`` carved out
-                          of the useful-work category.  Producers stamp
+                          step: ``(dispatch + device_wait) ×
+                          pipe_bubble_frac`` carved out of the two
+                          useful-work categories.  Producers stamp
                           ``pipe_bubble_frac`` (analytic ``(S-1)/(M+S-1)``
                           from the trainer) on step/round records of
                           pipelined runs; absent field → 0 carve
@@ -35,13 +45,16 @@ record attributing the measured wall into categories:
                           past the restored snapshot, plus the dying round's
                           partial step accounting
 ``other``                 the residual — init, iterator construction, metric
-                          math, logging, the untimed tail of the dying round
+                          math, logging (the loop's ``record_sec`` and
+                          ``boundary_sec`` beyond eval and snapshots), the
+                          untimed tail of the dying round
 ========================  ====================================================
 
 The categories tile the wall by construction (``other`` is the
 residual), so ``sum(categories) == wall_sec`` up to rounding — asserted
 within 5% on the CPU MNIST e2e (tests/test_ledger.py).  ``goodput_pct``
-is ``dispatch / wall``.
+is ``(dispatch + device_wait) / wall``: the same on a chip, where the
+steps' wall is the wait, and on the CPU, where it is the call.
 
 Two producers share this one fold: the task ``finally`` in main.py
 re-reads its own sink file and emits the record even when the run died
@@ -59,9 +72,9 @@ from typing import Dict, List, Optional
 from . import log as mlog
 
 #: ledger categories, in render order; they tile ``wall_sec``
-CATEGORIES = ("compile", "dispatch", "pipe_bubble", "input_wait",
-              "h2d_staging", "eval", "ckpt_blocked", "rollback_lost",
-              "other")
+CATEGORIES = ("compile", "dispatch", "device_wait", "pipe_bubble",
+              "input_wait", "h2d_staging", "eval", "ckpt_blocked",
+              "rollback_lost", "other")
 
 
 def parse_record_line(line: str):
@@ -149,6 +162,28 @@ def _f(rec: dict, key: str) -> float:
     return float(v) if v is not None else 0.0
 
 
+#: what a step or round record adds to which sum (``bubble`` is carved
+#: out of the first two by :func:`_book`)
+_NO_MARKS = {"dispatch": 0.0, "device_wait": 0.0, "bubble": 0.0,
+             "input_wait": 0.0, "h2d": 0.0}
+
+
+def _book(sums: Dict[str, float], rec: dict) -> None:
+    """Add one step or round record's host-wall split to ``sums``.
+    Pipelined steps spend a known fill/drain fraction of their wall idle
+    (``pipe_bubble_frac``, stamped by main.py): it is carved out of the
+    useful-work categories, whichever of the two the host saw the step's
+    wall as."""
+    frac = _f(rec, "pipe_bubble_frac")
+    for cat, field in (("dispatch", "dispatch_sec"),
+                       ("device_wait", "device_wait_sec")):
+        sec = _f(rec, field)
+        sums[cat] += sec * (1.0 - frac)
+        sums["bubble"] += sec * frac
+    sums["input_wait"] += _f(rec, "iter_wait_sec")
+    sums["h2d"] += _f(rec, "h2d_sec")
+
+
 def build_ledger(recs: List[dict],
                  wall_sec: Optional[float] = None,
                  source: str = "run") -> Optional[dict]:
@@ -171,15 +206,14 @@ def build_ledger(recs: List[dict],
         if recs[i].get("kind") == "ledger":
             recs = recs[i + 1:]
             break
-    compile_sec = dispatch = bubble = input_wait = eval_sec = 0.0
-    h2d_raw = ckpt_blocked = lost = 0.0
+    compile_sec = eval_sec = ckpt_blocked = lost = 0.0
     kept: List[dict] = []       # completed rounds still standing
     rounds_lost = 0
     # step records carry per-print-window marks; a round record, emitted
     # at round end, carries the SAME round's full sums — so pending step
     # marks are superseded (discarded) when their round record lands,
     # and only the dying round's partial accounting survives the stream
-    pend = {"dispatch": 0.0, "bubble": 0.0, "input_wait": 0.0, "h2d": 0.0}
+    pend = dict(_NO_MARKS)
     # compile happens INSIDE its round's wall (the first dispatch), so
     # a rolled-back round's lost wall must shed the compile portion the
     # `compile` category already booked — the compile record's round is
@@ -199,19 +233,10 @@ def build_ledger(recs: List[dict],
             if r.get("round") is not None:
                 compile_by_round[int(r["round"])] = _f(r, "compile_sec")
         elif k == "step":
-            # pipelined steps spend a known fill/drain fraction of their
-            # dispatch wall idle (pipe_bubble_frac, stamped by main.py):
-            # carve it out of the useful-work category
-            d = _f(r, "dispatch_sec")
-            bub = d * _f(r, "pipe_bubble_frac")
-            pend["dispatch"] += d - bub
-            pend["bubble"] += bub
-            pend["input_wait"] += _f(r, "iter_wait_sec")
-            pend["h2d"] += _f(r, "h2d_sec")
+            _book(pend, r)
         elif k == "round":
             kept.append(r)
-            pend = {"dispatch": 0.0, "bubble": 0.0,
-                    "input_wait": 0.0, "h2d": 0.0}
+            pend = dict(_NO_MARKS)
         elif k == "ckpt":
             ckpt_blocked += _f(r, "blocked_sec")
         elif k == "rollback":
@@ -233,36 +258,28 @@ def build_ledger(recs: List[dict],
                         int(q.get("round") or 0) - 1, 0.0)
                     lost += max(_f(q, "wall_sec") - nested, 0.0) \
                         + _f(q, "eval_sec")
-            lost += pend["dispatch"] + pend["bubble"] \
-                + pend["input_wait"] + pend["h2d"]
-            pend = {"dispatch": 0.0, "bubble": 0.0,
-                    "input_wait": 0.0, "h2d": 0.0}
+            lost += sum(pend.values())
+            pend = dict(_NO_MARKS)
         elif k == "anomaly":
             n_anom += 1
         elif k == "nan":
             n_nan += 1
-    for r in kept:
-        d = _f(r, "dispatch_sec")
-        bub = d * _f(r, "pipe_bubble_frac")
-        dispatch += d - bub
-        bubble += bub
-        input_wait += _f(r, "iter_wait_sec")
-        eval_sec += _f(r, "eval_sec")
-        h2d_raw += _f(r, "h2d_sec")
     # a run that died mid-round (TrainingDiverged with no rollback left)
     # leaves its last round as step marks only — book them where the
     # time actually went instead of letting the whole round read "other"
-    dispatch += pend["dispatch"]
-    bubble += pend["bubble"]
-    input_wait += pend["input_wait"]
-    h2d_raw += pend["h2d"]
+    sums = pend
+    for r in kept:
+        _book(sums, r)
+        eval_sec += _f(r, "eval_sec")
+    dispatch, device_wait, bubble, input_wait, h2d_raw = (
+        sums[k] for k in _NO_MARKS)
     if wall_sec is None:
         if first_ts is None:
             return None
         wall_sec = max(last_ts - first_ts, 0.0)
     wall_sec = float(wall_sec)
-    base = (compile_sec + dispatch + bubble + input_wait + eval_sec
-            + ckpt_blocked + lost)
+    base = (compile_sec + dispatch + device_wait + bubble + input_wait
+            + eval_sec + ckpt_blocked + lost)
     residual = wall_sec - base
     # h2d that ran on the prefetch producer thread overlapped compute
     # and cost no wall: only the part that fits the residual is a
@@ -271,7 +288,8 @@ def build_ledger(recs: List[dict],
     h2d_staging = min(h2d_raw, max(residual, 0.0))
     other = max(wall_sec - base - h2d_staging, 0.0)
     cats = {"compile": compile_sec, "dispatch": dispatch,
-            "pipe_bubble": bubble, "input_wait": input_wait,
+            "device_wait": device_wait, "pipe_bubble": bubble,
+            "input_wait": input_wait,
             "h2d_staging": h2d_staging, "eval": eval_sec,
             "ckpt_blocked": ckpt_blocked, "rollback_lost": lost,
             "other": other}
@@ -281,7 +299,7 @@ def build_ledger(recs: List[dict],
         "wall_sec": round(wall_sec, 4),
         "categories": cats,
         "shares": {k: round(v / denom, 4) for k, v in cats.items()},
-        "goodput_pct": round(dispatch / denom * 100.0, 2),
+        "goodput_pct": round((dispatch + device_wait) / denom * 100.0, 2),
         "h2d_overlapped_sec": round(max(h2d_raw - h2d_staging, 0.0), 4),
         "rounds": len(kept),
         "rounds_lost": rounds_lost,

@@ -57,7 +57,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 #: request-path stage names in path order (doc/monitor.md "Reading a
 #: p99 breakdown").  ``pad``/``device``/``unpad`` nest INSIDE
@@ -297,6 +297,122 @@ class NullTracer:
 
 
 NULL = NullTracer()
+
+
+# ------------------------------------------------------------ phase clock
+
+#: the train loop's main-thread phases, flat (siblings: none encloses a
+#: whole iteration) and in loop order, and the ``step`` / ``round`` record
+#: field each one's seconds reach (doc/monitor.md).  ``compile`` is the
+#: first ``enqueue``, which traces and compiles inside the call: the
+#: ``compile`` record carries it and the step marks restart behind it.
+LOOP_PHASES = {"input_wait": "iter_wait_sec", "enqueue": "dispatch_sec",
+               "device_wait": "device_wait_sec", "record": "record_sec",
+               "round_boundary": "boundary_sec"}
+#: seconds that were measured where the work ran (the prefetcher's
+#: ``host_next`` and ``stage`` phases, on the producer thread when there
+#: is one) and travel with the staged item; the loop books them when the
+#: item arrives
+ITEM_SECONDS = {"host_next": "host_next_sec", "h2d": "h2d_sec"}
+#: the phase fields of a host-fed loop's record (the synthetic loop stages
+#: nothing and carries :data:`LOOP_PHASES` alone)
+HOST_FED_FIELDS = {**LOOP_PHASES, **ITEM_SECONDS}
+
+
+class Phase:
+    """One stretch of host time under a name: a context manager that
+    stamps ``time.perf_counter()`` on entry and exit (``t0``, ``t1``) and
+    holds a ``jax.profiler.TraceAnnotation("cxxnet:<name>",
+    dispatch=<n>)`` open in between, so whoever takes a profiler trace
+    finds the span on the trace's own clock, on the thread that ran it,
+    beside the device planes.  The annotation is a no-op of about half a
+    microsecond while no profiler runs.  ``enqueue`` is written as a
+    ``StepTraceAnnotation`` so that xprof groups by dispatch.
+
+    The exit runs in the ``with`` statement's implicit ``finally``: a
+    ``KeyboardInterrupt`` raised inside the phase still closes the
+    annotation and books the seconds (with the :class:`PhaseClock` the
+    phase came from; a bare phase books nowhere: its seconds travel with
+    the item it staged)."""
+
+    __slots__ = ("name", "t0", "t1", "_clock", "_ann")
+
+    def __init__(self, name: str, dispatch: int,
+                 clock: Optional["PhaseClock"] = None):
+        # imported here: the read side of this module (tools/obsv.py)
+        # runs without jax
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+        self.name = name
+        self.t0 = self.t1 = 0.0
+        self._clock = clock
+        if name == "enqueue":
+            self._ann = StepTraceAnnotation(
+                "cxxnet:enqueue", step_num=dispatch, dispatch=dispatch)
+        else:
+            self._ann = TraceAnnotation("cxxnet:" + name, dispatch=dispatch)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self):
+        self.t0 = self.t1 = time.perf_counter()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self.t1 = time.perf_counter()
+        if self._clock is not None:
+            self._clock.book(self.name, self.t1 - self.t0)
+        return False
+
+
+class PhaseClock:
+    """The train loop's phase clock: every stretch of the loop's host
+    time is entered as a :class:`Phase` and its seconds added to that
+    phase's running sum.  It always runs (nothing switches it): the sums
+    feed the ``step`` and ``round`` records of both loops of ``main.py``,
+    the annotations feed any profiler trace taken meanwhile.
+
+    ``dispatch`` is the loop's running dispatch number, the identifier
+    the spans of one unit of work share (the prefetcher numbers the items
+    it stages the same way).  A record is cut from the sums as they stand
+    when it is built: ``mark = clock.read()`` then, later,
+    ``clock.cut(mark)`` gives the seconds by phase in between and
+    ``wall``, the ``perf_counter`` distance, so that ``wall`` less the
+    phases is what the loop left uninstrumented."""
+
+    def __init__(self) -> None:
+        self.sums: Dict[str, float] = {}
+        self.dispatch = 0
+
+    def phase(self, name: str) -> Phase:
+        return Phase(name, self.dispatch, self)
+
+    def book(self, name: str, seconds: float) -> None:
+        """Add seconds to ``name``'s sum: a phase's as it closes, or
+        seconds that were measured elsewhere and travelled with a staged
+        item."""
+        self.sums[name] = self.sums.get(name, 0.0) + seconds
+
+    def read(self) -> Dict[str, float]:
+        """The sums as they stand, with the clock's own reading under
+        ``wall``."""
+        return {**self.sums, "wall": time.perf_counter()}
+
+    def cut(self, mark: Dict[str, float]
+            ) -> Tuple[Dict[str, float], Dict[str, float]]:
+        """``(seconds by phase since mark, the new mark)``."""
+        now = self.read()
+        return {k: v - mark.get(k, 0.0) for k, v in now.items()}, now
+
+
+def phase_fields(cut: Dict[str, float], ndigits: int,
+                 names: Dict[str, str] = LOOP_PHASES) -> Dict[str, float]:
+    """One cut's seconds under their record field names."""
+    return {field: round(cut.get(phase, 0.0), ndigits)
+            for phase, field in names.items()}
 
 
 # --------------------------------------------------------------- analysis

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import glob
 import os
+import re
 from typing import Dict, Iterator, List, Optional, Tuple
 
 # --------------------------------------------------------------- wire format
@@ -194,16 +195,40 @@ def find_xplane(path: str) -> str:
 
 # --------------------------------------------------------------- summaries
 
+_TRAILING_NUMBER = re.compile(r"(\d+)$")
+
+
+def one_plane(planes: List[XPlane], plane_filter: str,
+              line_filter: str) -> Optional[XPlane]:
+    """ONE chip's plane: of the planes whose name holds ``plane_filter``
+    and that have a line named ``line_filter``, the lowest-numbered
+    (``/device:TPU:0``).  Every total below reduces this plane alone: a
+    sum over planes is four times the step on four chips."""
+    found = [p for p in planes if plane_filter in p.name
+             and any(ln.name == line_filter for ln in p.lines)]
+    return min(found, key=lambda p: int(
+        (_TRAILING_NUMBER.search(p.name) or [0, 0])[1]), default=None)
+
+
+def matching_lines(planes: List[XPlane], plane_filter: str,
+                   line_filter: str) -> Iterator[Tuple[XPlane, XLine]]:
+    """The lines named ``line_filter`` on :func:`one_plane`.  A line is
+    matched by its whole name: ``Async XLA Ops`` holds the spans during
+    which asynchronous copies and collectives were in flight, beside the
+    operations on ``XLA Ops`` and not instead of them, and added to them
+    it made 446 ms of a 112 ms step (PERF.md, PR 21)."""
+    plane = one_plane(planes, plane_filter, line_filter)
+    if plane is not None:
+        for line in plane.lines:
+            if line.name == line_filter:
+                yield plane, line
+
+
 def _matching_events(planes: List[XPlane], plane_filter: str,
                      line_filter: str) -> Iterator[Tuple[XPlane, XEvent]]:
-    for plane in planes:
-        if plane_filter not in plane.name:
-            continue
-        for line in plane.lines:
-            if line_filter not in line.name:
-                continue
-            for ev in line.events:
-                yield plane, ev
+    for plane, line in matching_lines(planes, plane_filter, line_filter):
+        for ev in line.events:
+            yield plane, ev
 
 
 def total_ms_in(planes: List[XPlane], plane_filter: str = "TPU",
@@ -227,7 +252,7 @@ def op_totals_in(planes: List[XPlane], plane_filter: str = "TPU",
 
 def device_total_ms(path: str, plane_filter: str = "TPU",
                     line_filter: str = "XLA Modules") -> float:
-    """Total on-chip XLA-module time (ms) across matching device planes
+    """Total XLA-module time (ms) on one chip's plane (:func:`one_plane`)
     — the bench.py "device step" numerator."""
     return total_ms_in(parse_xspace(find_xplane(path)),
                        plane_filter, line_filter)
@@ -295,48 +320,43 @@ def comm_summary_in(planes: List[XPlane], plane_filter: str = "TPU",
     comm_ms = exposed_ms = 0.0
     by_kind: Dict[str, List[float]] = {}
     unpaired = 0
-    for plane in planes:
-        if plane_filter not in plane.name:
-            continue
-        for line in plane.lines:
-            if line_filter not in line.name:
+    for plane, line in matching_lines(planes, plane_filter, line_filter):
+        open_starts: Dict[str, List[XEvent]] = {}
+        events = sorted(line.events, key=lambda e: e.offset_ps)
+        for ev in events:
+            name = plane.event_names.get(ev.metadata_id, "")
+            ck = collective_kind(name)
+            if ck is None:
                 continue
-            open_starts: Dict[str, List[XEvent]] = {}
-            events = sorted(line.events, key=lambda e: e.offset_ps)
-            for ev in events:
-                name = plane.event_names.get(ev.metadata_id, "")
-                ck = collective_kind(name)
-                if ck is None:
-                    continue
-                kind, phase = ck
-                if phase == "start":
-                    open_starts.setdefault(kind, []).append(ev)
-                    continue
-                if phase == "done" and open_starts.get(kind):
-                    start = open_starts[kind].pop(0)
-                    flight = (ev.offset_ps + ev.duration_ps
-                              - start.offset_ps) / 1e9
-                    exposed = ev.duration_ps / 1e9
-                else:
-                    # sync op, or a done whose start fell outside the
-                    # trace window: fully exposed
-                    flight = exposed = ev.duration_ps / 1e9
-                    if phase == "done":
-                        unpaired += 1
-                comm_ms += flight
-                exposed_ms += exposed
-                cur = by_kind.setdefault(kind, [0.0, 0])
-                cur[0] += flight
-                cur[1] += 1
-            for kind, starts in open_starts.items():
-                for ev in starts:  # start with no done in the window
+            kind, phase = ck
+            if phase == "start":
+                open_starts.setdefault(kind, []).append(ev)
+                continue
+            if phase == "done" and open_starts.get(kind):
+                start = open_starts[kind].pop(0)
+                flight = (ev.offset_ps + ev.duration_ps
+                          - start.offset_ps) / 1e9
+                exposed = ev.duration_ps / 1e9
+            else:
+                # sync op, or a done whose start fell outside the
+                # trace window: fully exposed
+                flight = exposed = ev.duration_ps / 1e9
+                if phase == "done":
                     unpaired += 1
-                    dur = ev.duration_ps / 1e9
-                    comm_ms += dur
-                    exposed_ms += dur
-                    cur = by_kind.setdefault(kind, [0.0, 0])
-                    cur[0] += dur
-                    cur[1] += 1
+            comm_ms += flight
+            exposed_ms += exposed
+            cur = by_kind.setdefault(kind, [0.0, 0])
+            cur[0] += flight
+            cur[1] += 1
+        for kind, starts in open_starts.items():
+            for ev in starts:  # start with no done in the window
+                unpaired += 1
+                dur = ev.duration_ps / 1e9
+                comm_ms += dur
+                exposed_ms += dur
+                cur = by_kind.setdefault(kind, [0.0, 0])
+                cur[0] += dur
+                cur[1] += 1
     frac = 0.0
     if comm_ms > 0:
         frac = min(max(1.0 - exposed_ms / comm_ms, 0.0), 1.0)

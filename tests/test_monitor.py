@@ -195,14 +195,17 @@ def test_eval_step_trace_counter():
 # ------------------------------------------------------------ JSONL schema
 
 STEP_KEYS = {"ts", "kind", "round", "step", "global_step", "elapsed_sec",
-             "examples_per_sec", "iter_wait_sec", "dispatch_sec",
-             "h2d_sec", "staging_depth", "loss"}
+             "examples_per_sec", "wall_sec", "iter_wait_sec",
+             "dispatch_sec", "device_wait_sec", "record_sec",
+             "boundary_sec", "host_next_sec", "h2d_sec", "staging_depth",
+             "loss"}
 MONITOR_KEYS = {"ts", "kind", "round", "step", "layer",
                 "w_norm", "g_norm", "u_norm", "u_ratio"}
 ROUND_KEYS = {"ts", "kind", "round", "wall_sec", "eval_sec", "examples",
               "examples_per_sec", "iter_wait_sec", "dispatch_sec",
-              "h2d_sec", "train_step_traces", "eval_step_traces",
-              "train-error", "val-error"}
+              "device_wait_sec", "record_sec", "boundary_sec",
+              "host_next_sec", "h2d_sec", "train_step_traces",
+              "eval_step_traces", "train-error", "val-error"}
 LEDGER_KEYS = {"ts", "kind", "wall_sec", "categories", "shares",
                "goodput_pct", "h2d_overlapped_sec", "rounds",
                "rounds_lost", "rollbacks", "anomalies",

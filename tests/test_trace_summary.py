@@ -200,3 +200,71 @@ def test_bench_emits_sink_record(tmp_path):
     # no spec -> no write
     bench.emit_bench_record(payload, argv=[])
     assert len(open(sink).readlines()) == 1
+
+
+# ------------------------------------- one chip's plane, lines by whole name
+
+RECORDED = os.path.join(REPO, "benchmark", "fixtures",
+                        "recorded_alexnet_dp4.xplane.pb")
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """A trace recorded on four chips (``alexnet_b8192_dp4``, PR 22), read
+    by the program's parser and by the benchmark's own reduction."""
+    from benchmark.lib import xplane
+    return parse_xspace(RECORDED), xplane.load(RECORDED)
+
+
+def test_totals_reduce_one_chip_not_the_sum_over_planes(recorded):
+    """The program's device ms over the step module's runs on chip 0 is
+    the benchmark's sum over the same runs (summed over the four planes it
+    read four times the step), and the comm report is that chip's too."""
+    from cxxnet_tpu.monitor.trace import (comm_report_in, one_plane,
+                                          total_ms_in)
+    planes, trace = recorded
+    assert one_plane(planes, "TPU", "XLA Modules").name == "/device:TPU:0"
+    chip0 = trace.devices[0].lines
+    modules_ms = sum(e.dur for e in chip0["XLA Modules"]) / 1e6
+    assert total_ms_in(planes) == pytest.approx(modules_ms, rel=0.01)
+    all_planes_ms = sum(e.dur for p in trace.devices
+                        for e in p.lines["XLA Modules"]) / 1e6
+    assert all_planes_ms > 2.5 * modules_ms   # what the sum used to read
+    rep = comm_report_in(planes, steps=1)
+    assert rep["device_sec"] == pytest.approx(modules_ms / 1e3, rel=0.01)
+    assert 0.0 < rep["comm_share"] < 0.5
+
+
+def test_async_line_is_never_added_to_the_ops_line(recorded):
+    """``Async XLA Ops`` is not ``XLA Ops``: ``op_totals_in`` holds the
+    events of chip 0's ``XLA Ops`` line and no other."""
+    from cxxnet_tpu.monitor.trace import op_totals_in
+    planes, trace = recorded
+    ops = trace.devices[0].lines["XLA Ops"]
+    totals = op_totals_in(planes)
+    assert sum(n for _, n in totals.values()) == len(ops)
+    assert sum(ms for ms, _ in totals.values()) == pytest.approx(
+        sum(e.dur for e in ops) / 1e6, rel=0.01)
+    tpu0 = next(p for p in planes if p.name == "/device:TPU:0")
+    n_async = sum(len(ln.events) for ln in tpu0.lines
+                  if ln.name == "Async XLA Ops")
+    assert n_async > 0, "the fixture has the line that used to be added"
+    # the same holds line by line: asking for the async line gives it alone
+    asyncs = op_totals_in(planes, line_filter="Async XLA Ops")
+    assert sum(n for _, n in asyncs.values()) == n_async
+
+
+def test_layer_table_walks_one_chips_ops_line(recorded):
+    from cxxnet_tpu.monitor import attribution
+    from cxxnet_tpu.monitor.trace import total_ms_in
+    planes, trace = recorded
+    ops = trace.devices[0].lines["XLA Ops"]
+    tpu0 = next(p for p in planes if p.name == "/device:TPU:0")
+    # every op of chip 0 is an op of the program, under no known scope
+    op_scopes = {name: None for name in tpu0.event_names.values()}
+    table = attribution.layer_table(planes, [], op_scopes)
+    assert table["ops_total_ms"] == pytest.approx(
+        sum(e.dur for e in ops) / 1e6, rel=0.01)
+    assert sum(r["count"] for r in table["rows"]) == len(ops)
+    assert table["device_total_ms"] == pytest.approx(total_ms_in(planes),
+                                                     rel=1e-6)

@@ -93,23 +93,29 @@ def build_report(recs: List[dict], top: int = 10) -> dict:
         rep["rounds"] = [
             {k: r.get(k) for k in
              ("round", "examples_per_sec", "wall_sec", "eval_sec",
-              "iter_wait_sec", "dispatch_sec", "h2d_sec",
-              "hbm_peak_bytes", "train_step_traces") if k in r}
+              "iter_wait_sec", "dispatch_sec", "device_wait_sec",
+              "h2d_sec", "hbm_peak_bytes", "train_step_traces")
+             if k in r}
             for r in rounds]
         wall = sum(r.get("wall_sec", 0.0) for r in rounds)
         disp = sum(r.get("dispatch_sec", 0.0) for r in rounds)
+        dev = sum(r.get("device_wait_sec", 0.0) for r in rounds)
         wait = sum(r.get("iter_wait_sec", 0.0) for r in rounds)
         rep["breakdown"] = {
             "train_wall_sec": round(wall, 3),
             "dispatch_sec": round(disp, 3),
+            # blocked on the device's results: on a chip, where the
+            # steps' wall goes (dispatch_sec is the enqueue there)
+            "device_wait_sec": round(dev, 3),
             "iter_wait_sec": round(wait, 3),
             "h2d_sec": round(sum(r.get("h2d_sec", 0.0)
                                  for r in rounds), 3),
             "eval_sec": round(sum(r.get("eval_sec", 0.0)
                                   for r in rounds), 3),
             # loop wall the host spent neither dispatching nor blocked
-            # on input: metric math, logging, staging bookkeeping
-            "other_sec": round(max(wall - disp - wait, 0.0), 3),
+            # on the device or on input: metric math, logging, staging
+            # bookkeeping
+            "other_sec": round(max(wall - disp - dev - wait, 0.0), 3),
             "compile_sec": rep.get("compile_sec"),
         }
 
@@ -320,6 +326,7 @@ def render(rep: dict) -> str:
         out.append("breakdown (train wall "
                    f"{_fmt(bd['train_wall_sec'])} s): "
                    f"dispatch {_fmt(bd['dispatch_sec'])} s, "
+                   f"device wait {_fmt(bd.get('device_wait_sec'))} s, "
                    f"input wait {_fmt(bd['iter_wait_sec'])} s, "
                    f"other {_fmt(bd['other_sec'])} s; "
                    f"h2d {_fmt(bd['h2d_sec'])} s, "
@@ -350,7 +357,8 @@ def render(rep: dict) -> str:
              for c in CATEGORIES if cats.get(c) is not None]))
         if cats.get("pipe_bubble"):
             in_step = cats["pipe_bubble"] / max(
-                cats["pipe_bubble"] + (cats.get("dispatch") or 0.0), 1e-9)
+                cats["pipe_bubble"] + (cats.get("dispatch") or 0.0)
+                + (cats.get("device_wait") or 0.0), 1e-9)
             out.append(f"pipe bubble: {in_step:.1%} of the dispatched "
                        "step wall is fill/drain idle (analytic "
                        "(S-1)/(M+S-1) — raise pipe_microbatch to shrink "
@@ -359,9 +367,12 @@ def render(rep: dict) -> str:
     if rounds:
         out.append("")
         out.append(_table(
-            ["round", "ex/s", "wall_s", "eval_s", "wait_s", "hbm_peak"],
+            ["round", "ex/s", "wall_s", "eval_s", "dispatch_s",
+             "dev_wait_s", "wait_s", "hbm_peak"],
             [[_fmt(r.get("round")), _fmt(r.get("examples_per_sec"), 1),
               _fmt(r.get("wall_sec")), _fmt(r.get("eval_sec")),
+              _fmt(r.get("dispatch_sec")),
+              _fmt(r.get("device_wait_sec")),
               _fmt(r.get("iter_wait_sec")),
               _fmt(r.get("hbm_peak_bytes"))] for r in rounds]))
     comm = rep.get("comm")

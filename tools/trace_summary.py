@@ -7,7 +7,7 @@ round-6 BASELINE work hand-rolled twice.
 
     python tools/trace_summary.py /tmp/prof                 # newest trace
     python tools/trace_summary.py trace.xplane.pb --top 30
-    python tools/trace_summary.py /tmp/prof --plane CPU --line XLA
+    python tools/trace_summary.py /tmp/prof --plane CPU --line 'XLA Ops'
     python tools/trace_summary.py /tmp/prof --json          # machine-readable
 
 Typical triage: run training with ``prof = /tmp/prof`` (optionally
@@ -86,9 +86,11 @@ def main(argv=None) -> int:
     ap.add_argument("--top", type=int, default=20, help="rows to print")
     ap.add_argument("--plane", default="TPU",
                     help="substring filter on plane names (default TPU; "
-                    "use CPU for host-emulated traces)")
+                    "use CPU for host-emulated traces); ONE plane is "
+                    "reduced, the lowest-numbered that matches")
     ap.add_argument("--line", default="XLA Ops",
-                    help="substring filter on line names")
+                    help="the line's whole name (Async XLA Ops is not "
+                    "XLA Ops)")
     ap.add_argument("--json", action="store_true",
                     help="print one JSON object instead of the table")
     args = ap.parse_args(argv)
