@@ -30,7 +30,9 @@ measured win region (``CXXNET_PALLAS_LRN``: "hwcn" (default) / "1" legacy
 
 from __future__ import annotations
 
+import collections
 import functools
+import itertools
 from typing import Tuple
 
 import jax
@@ -947,6 +949,22 @@ NEG_INF = -1e30
 # ship unannotated.  Do not add PARALLEL to the q-block grid dim of the
 # forward kernel without restructuring lse: its (1, 1, s) output block is
 # shared across q-block programs, which a megacore split would corrupt.
+#
+# Two levels of blocking in the causal kernels.  The block that is FETCHED
+# is large (_fa_blocks): it amortises the per-program cost and the k/v
+# fetches.  The triangular grid (_fa_tri_pairs) holds only the live pairs
+# of such blocks: 3 of 4 at s2048, 10 of 16 at s4096.  Of those, a pair
+# whose last key does not follow its first query is INTERIOR: computed
+# whole, with no causal mask (there is nothing for one to remove).  The
+# others CROSS the diagonal, and there the unit that is COMPUTED is a
+# strip of height _fa_strip: query strip r meets the block's keys up to
+# its own diagonal tile (forward, dq), key strip c the queries from its
+# diagonal tile down (dkv), and only that bs x bs tile is masked.  A
+# square crossing block of n = bq / bs strips computes (n + 1) / (2 n) of
+# its area instead of all of it.  _fa_plan counts what follows from the
+# shapes alone: which programs take which path, and computed over live
+# area (s2048, d128: 1.5 at n = 1, the whole block masked as before
+# strips; 1.125 at n = 4).
 
 
 def _fa_blocks(s_len, d=64):
@@ -965,23 +983,142 @@ def _fa_blocks(s_len, d=64):
     return bq, bk
 
 
-def _causal_mask(s, i, j, bq, bk):
-    qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(qpos >= kpos, s, NEG_INF)
+_FA_STRIPS = {"fwd": 4, "dq": 4, "dkv": 2}
 
 
-def _segment_mask(s, i, j, bq, bk, segq, segk):
-    """Document-packing segment mask on an already-causal-masked score
-    block: keep (same segment & segment != 0) | diagonal.  The diagonal
-    stays unconditionally allowed so padding rows (segment 0) attend
-    themselves and the online softmax never renormalizes a fully-masked
-    row — the SAME rule as the lax fallback (parallel/ring.py module
-    docstring), which the pairtests hold this kernel to."""
-    qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    same = (segq[:, None] == segk[None, :]) & (segq[:, None] != 0)
-    return jnp.where(same | (qpos == kpos), s, NEG_INF)
+def _fa_strip(s_len, d, bq, bk, kernel):
+    """Strip height inside a diagonal-crossing block, for the forward, dq
+    or dkv kernel: a multiple of 128 (strips slice the lane axis of the
+    segment row) that divides both block sides, at most 8 strips a side
+    (the kernel bodies are unrolled; Mosaic's compile time is set-up time).
+    Strips a side from the v5e sweep at b8 h16 s2048 d128 and s4096 b4
+    (experiments/fa_tune.py, PERF.md section 6, PR 25)."""
+    return max(min(bq, bk) // _FA_STRIPS[kernel], 128)
+
+
+FaPlan = collections.namedtuple(
+    "FaPlan", "bq bk bs offsets interior crossing area_ratio")
+
+
+def _fa_live_pairs(nq, nk, bq, bk):
+    return [(i, j) for i in range(nq) for j in range(nk)
+            if i * bq + bq - 1 >= j * bk]
+
+
+def _fa_crosses(i, j, bq, bk):
+    """A live pair crosses the diagonal when its last key follows its
+    first query (by positions: blocks need not be square)."""
+    return j * bk + bk - 1 > i * bq
+
+
+def _fa_plan(s_len, d=64, kernel="fwd"):
+    """Everything one causal kernel's work follows from, from the shapes
+    alone: block and strip sizes, the offsets ``i*bq - j*bk`` at which a
+    block crosses the diagonal (one static case each in the kernel bodies),
+    the number of interior and diagonal-crossing programs per (batch, head)
+    and the computed-over-live area ratio (live = s^2 / 2)."""
+    bq, bk = _fa_blocks(s_len, d)
+    bs = _fa_strip(s_len, d, bq, bk, kernel)
+    assert bq % bs == 0 and bk % bs == 0, (bq, bk, bs)
+    pairs = _fa_live_pairs(s_len // bq, s_len // bk, bq, bk)
+    offs = [i * bq - j * bk for i, j in pairs if _fa_crosses(i, j, bq, bk)]
+    area = (len(pairs) - len(offs)) * bq * bk + sum(
+        (r.stop - r.start) * (c.stop - c.start)
+        for off in offs for g in _fa_rects(bq, bk, bs, off, "q")
+        for r, c, _ in g)
+    return FaPlan(bq, bk, bs, tuple(sorted(set(offs))),
+                  len(pairs) - len(offs), len(offs),
+                  area / (s_len * s_len / 2))
+
+
+def _fa_rects(bq, bk, bs, off, by):
+    """The live part of a diagonal-crossing block whose first query lies
+    ``off`` positions past its first key, as static rectangles ``(rows,
+    cols, diag)`` of the block in groups of one strip.  ``by="q"``: a group
+    is a query strip against the keys before its diagonal tile, then that
+    tile.  ``by="k"``: a key strip against its diagonal tile, then the
+    queries below it.  ``diag`` marks the bs x bs tile on the diagonal, the
+    only place a causal mask can bite; a strip causality leaves nothing of
+    has no group."""
+    nqt, nkt, ot = bq // bs, bk // bs, off // bs
+    groups = []
+    for t in range(nqt if by == "q" else nkt):
+        if by == "q":
+            rows, c = slice(t * bs, (t + 1) * bs), t + ot
+            g = [(rows, slice(0, min(c, nkt) * bs), False)] * (c > 0)
+            g += [(rows, slice(c * bs, (c + 1) * bs), True)] * (0 <= c < nkt)
+        else:
+            cols, r = slice(t * bs, (t + 1) * bs), t - ot
+            g = [(slice(r * bs, (r + 1) * bs), cols, True)] * (0 <= r < nqt)
+            g += [(slice(max(r + 1, 0) * bs, bq), cols, False)] * (
+                r + 1 < nqt)
+        if g:
+            groups.append(g)
+    return groups
+
+
+def _fa_full(bq, bk):
+    """The group list of a block computed whole and unmasked."""
+    return [[(slice(0, bq), slice(0, bk), False)]]
+
+
+def _fa_block_cases(i, j, plan, by, body):
+    """Run ``body(groups)`` under the one static case the live pair (i, j)
+    falls in: interior (the whole block, one rectangle, unmasked) or
+    crossing the diagonal at one of the plan's offsets."""
+    bq, bk = plan.bq, plan.bk
+    if plan.interior:
+        pl.when(jnp.logical_not(_fa_crosses(i, j, bq, bk)))(
+            functools.partial(body, _fa_full(bq, bk)))
+    for off in plan.offsets:
+        pl.when(i * bq - j * bk == off)(
+            functools.partial(body, _fa_rects(bq, bk, plan.bs, off, by)))
+
+
+def _fa_col(ref, q0, q_ref):
+    """The query block's entries of a (1, 1, s) row block (lse, delta,
+    segment ids) as a (bq, 1) column.  Turning lanes into sublanes costs
+    about a cycle an element on the v5e (PR 25's sweep), so it is done once
+    a program and the rectangles slice the column."""
+    return ref[0, 0, pl.ds(q0, q_ref.shape[1])][:, None]
+
+
+def _fa_seg(seg_ref, q0, k0, q_ref):
+    """What _fa_scores needs of the segment row for the block at (q0, k0):
+    the queries' ids as a column, and where the keys' lie in the row (a
+    rectangle reads its own: a lane slice of a loaded row does not
+    broadcast).  None without a segment row."""
+    if seg_ref is None:
+        return None
+    return _fa_col(seg_ref, q0, q_ref), seg_ref, k0
+
+
+def _fa_scores(q_ref, k_ref, rect, scale, seg):
+    """Scaled, masked scores of one rectangle of a block.  The mask is the
+    rule of parallel/ring.py's module docstring, causal & ((same segment &
+    segment != 0) | diagonal), cut down to what the rectangle's position
+    leaves open: below the diagonal tile causality holds and ``qpos ==
+    kpos`` never does, on it both are a fixed lower-triangular pattern.
+    The diagonal stays unconditionally allowed so padding rows (segment 0)
+    attend themselves and the online softmax never renormalizes a
+    fully-masked row."""
+    rows, cols, diag = rect
+    # keep matmul operands in the input dtype (bf16 hits the MXU's fast
+    # path); accumulate in f32 via preferred_element_type
+    qb, kb = q_ref[0, rows], k_ref[0, cols]
+    s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    keep = None
+    if seg is not None:
+        segq, seg_ref, k0 = seg
+        segq = segq[rows]
+        segk = seg_ref[0, 0, pl.ds(k0 + cols.start, cols.stop - cols.start)]
+        keep = (segq == segk[None, :]) & (segq != 0)
+    if diag:
+        r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        keep = r >= c if keep is None else (keep & (r > c)) | (r == c)
+    return (s if keep is None else jnp.where(keep, s, NEG_INF)), qb, kb
 
 
 def _fa_fwd_init(acc, m, l):
@@ -990,28 +1127,36 @@ def _fa_fwd_init(acc, m, l):
     l[...] = jnp.zeros_like(l)
 
 
-def _fa_fwd_step(i, j, q_ref, k_ref, v_ref, acc, m, l, *, scale, causal,
-                 bq, bk, segq=None, segk=None):
-    """One online-softmax block update — the SINGLE copy of the forward
-    math, shared by the dense, triangular-grid, and segmented kernels."""
-    # keep matmul operands in the input dtype (bf16 hits the MXU's fast
-    # path); accumulate in f32 via preferred_element_type
-    qb, kb, vb = q_ref[0], k_ref[0], v_ref[0]
-    s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if causal:
-        s = _causal_mask(s, i, j, bq, bk)
-    if segq is not None:
-        s = _segment_mask(s, i, j, bq, bk, segq, segk)
-    m_prev = m[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l[...] = l[...] * corr + p.sum(axis=-1, keepdims=True)
-    acc[...] = acc[...] * corr + jax.lax.dot_general(
-        p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m[...] = m_new
+def _fa_fwd_step(q0, k0, q_ref, k_ref, v_ref, acc, m, l, groups, *, scale,
+                 seg_ref=None):
+    """One online-softmax update a group (the group's rectangles share
+    their query rows) — the SINGLE copy of the forward math, shared by the
+    dense, triangular-grid, and segmented kernels.  In three passes over
+    the groups, every score matmul, then every softmax, then every p @ v:
+    a strip's own chain of the three leaves the units waiting on each
+    other, and group after group cost 0.17-0.31 ms a layer more at 4
+    strips (PERF.md section 6, PR 25)."""
+    seg = _fa_seg(seg_ref, q0, k0, q_ref)
+    scores = [[_fa_scores(q_ref, k_ref, rect, scale, seg)[0] for rect in g]
+              for g in groups]
+    softmaxed = []
+    for g, ss in zip(groups, scores):
+        rows = g[0][0]
+        m_prev = m[rows]
+        m_new = functools.reduce(
+            jnp.maximum, [s.max(axis=-1, keepdims=True) for s in ss], m_prev)
+        corr = jnp.exp(m_prev - m_new)
+        ps = [jnp.exp(s - m_new) for s in ss]
+        l[rows] = l[rows] * corr + sum(
+            p.sum(axis=-1, keepdims=True) for p in ps)
+        m[rows] = m_new
+        softmaxed.append((corr, [p.astype(v_ref.dtype) for p in ps]))
+    for g, (corr, ps) in zip(groups, softmaxed):
+        rows = g[0][0]
+        acc[rows] = acc[rows] * corr + sum(
+            jax.lax.dot_general(p, v_ref[0, cols], (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for p, (_, cols, _) in zip(ps, g))
 
 
 def _fa_fwd_emit(i, o_ref, lse_ref, acc, m, l, bq):
@@ -1020,7 +1165,7 @@ def _fa_fwd_emit(i, o_ref, lse_ref, acc, m, l, bq):
 
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l,
-                   *, scale, causal, bq, bk):
+                   *, scale, bq, bk):
     i, j = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -1028,51 +1173,50 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l,
     def _():
         _fa_fwd_init(acc, m, l)
 
-    # causal: blocks strictly above the diagonal contribute nothing
-    live = (i * bq + bq - 1 >= j * bk) if causal else (j >= 0)
-
-    @pl.when(live)
-    def _():
-        _fa_fwd_step(i, j, q_ref, k_ref, v_ref, acc, m, l, scale=scale,
-                     causal=causal, bq=bq, bk=bk)
+    _fa_fwd_step(i * bq, j * bk, q_ref, k_ref, v_ref, acc, m, l,
+                 _fa_full(bq, bk), scale=scale)
 
     @pl.when(j == nk - 1)
     def _():
         _fa_fwd_emit(i, o_ref, lse_ref, acc, m, l, bq)
 
 
-def _fa_p_ds(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
-             scale, causal, bq, bk, segq=None, segk=None):
-    """Recompute p and ds for one block pair — the SINGLE copy of the
-    backward score math, shared by dq/dkv in both grid forms."""
-    qb, kb = q_ref[0], k_ref[0]
-    s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if causal:
-        s = _causal_mask(s, i, j, bq, bk)
-    if segq is not None:
-        s = _segment_mask(s, i, j, bq, bk, segq, segk)
-    p = jnp.exp(s - lse_ref[0, 0, pl.ds(i * bq, bq)][:, None])
-    dob = do_ref[0]
-    dp = jax.lax.dot_general(dob, v_ref[0], (((1,), (1,)), ((), ())),
+def _fa_p_ds(q_ref, k_ref, v_ref, do_ref, rect, lse, delta, seg, *, scale):
+    """Recompute p and ds for one rectangle of a block pair — the SINGLE
+    copy of the backward score math, shared by dq/dkv in both grid forms.
+    ``lse`` and ``delta`` are the query block's columns (_fa_col)."""
+    rows, cols, _ = rect
+    s, qb, kb = _fa_scores(q_ref, k_ref, rect, scale, seg)
+    p = jnp.exp(s - lse[rows])
+    dob = do_ref[0, rows]
+    dp = jax.lax.dot_general(dob, v_ref[0, cols], (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0, 0, pl.ds(i * bq, bq)][:, None]) * scale
+    ds = p * (dp - delta[rows]) * scale
     return p, ds, dob, qb, kb
 
 
-def _fa_dq_step(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_acc, *, scale, causal, bq, bk, segq=None, segk=None):
-    _, ds, _, _, kb = _fa_p_ds(i, j, q_ref, k_ref, v_ref, do_ref,
-                               lse_ref, delta_ref, scale=scale,
-                               causal=causal, bq=bq, bk=bk,
-                               segq=segq, segk=segk)
-    dq_acc[...] += jax.lax.dot_general(
-        ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+def _fa_bwd_rects(q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                  groups, *, scale, seg_ref):
+    """p and ds of every rectangle of a block, one after the other."""
+    lse, delta = _fa_col(lse_ref, q0, q_ref), _fa_col(delta_ref, q0, q_ref)
+    seg = _fa_seg(seg_ref, q0, k0, q_ref)
+    for rect in itertools.chain.from_iterable(groups):
+        yield rect, _fa_p_ds(q_ref, k_ref, v_ref, do_ref, rect, lse, delta,
+                             seg, scale=scale)
+
+
+def _fa_dq_step(q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_acc, groups, *, scale, seg_ref=None):
+    for rect, (_, ds, _, _, kb) in _fa_bwd_rects(
+            q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, groups,
+            scale=scale, seg_ref=seg_ref):
+        dq_acc[rect[0]] += jax.lax.dot_general(
+            ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
 def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                  dq_acc, *, scale, causal, bq, bk):
+                  dq_acc, *, scale, bq, bk):
     i, j = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -1080,36 +1224,29 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    live = (i * bq + bq - 1 >= j * bk) if causal else (j >= 0)
-
-    @pl.when(live)
-    def _():
-        _fa_dq_step(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dq_acc, scale=scale, causal=causal,
-                    bq=bq, bk=bk)
+    _fa_dq_step(i * bq, j * bk, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, dq_acc, _fa_full(bq, bk), scale=scale)
 
     @pl.when(j == nk - 1)
     def _():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _fa_dkv_step(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_acc, dv_acc, *, scale, causal, bq, bk,
-                 segq=None, segk=None):
-    p, ds, dob, qb, _ = _fa_p_ds(i, j, q_ref, k_ref, v_ref, do_ref,
-                                 lse_ref, delta_ref, scale=scale,
-                                 causal=causal, bq=bq, bk=bk,
-                                 segq=segq, segk=segk)
-    dv_acc[...] += jax.lax.dot_general(
-        p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dk_acc[...] += jax.lax.dot_general(
-        ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+def _fa_dkv_step(q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                 dk_acc, dv_acc, groups, *, scale, seg_ref=None):
+    for rect, (p, ds, dob, qb, _) in _fa_bwd_rects(
+            q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, groups,
+            scale=scale, seg_ref=seg_ref):
+        dv_acc[rect[1]] += jax.lax.dot_general(
+            p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_acc[rect[1]] += jax.lax.dot_general(
+            ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, bq, bk):
+                   dk_ref, dv_ref, dk_acc, dv_acc, *, scale, bq, bk):
     j, i = pl.program_id(1), pl.program_id(2)  # note: k-block is grid dim 1
     nq = pl.num_programs(2)
 
@@ -1118,13 +1255,8 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    live = (i * bq + bq - 1 >= j * bk) if causal else (i >= 0)
-
-    @pl.when(live)
-    def _():
-        _fa_dkv_step(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                     delta_ref, dk_acc, dv_acc, scale=scale,
-                     causal=causal, bq=bq, bk=bk)
+    _fa_dkv_step(i * bq, j * bk, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                 delta_ref, dk_acc, dv_acc, _fa_full(bq, bk), scale=scale)
 
     @pl.when(i == nq - 1)
     def _():
@@ -1146,10 +1278,9 @@ def _fa_tri_pairs(nq, nk, bq, bk, order):
     order="ji": j-major (dkv: i accumulates within a column).  Dead
     blocks (i*bq+bq-1 < j*bk) are EXCLUDED from the grid entirely, so
     neither their DMA nor their program overhead is paid — with equal
-    1024-blocks at s4096 that is 6 of 16 programs."""
+    1024-blocks that is 1 of 4 programs at s2048 and 6 of 16 at s4096."""
     import numpy as _np
-    pairs = [(i, j) for i in range(nq) for j in range(nk)
-             if i * bq + bq - 1 >= j * bk]
+    pairs = _fa_live_pairs(nq, nk, bq, bk)
     if order == "ji":
         pairs.sort(key=lambda ij: (ij[1], ij[0]))
     ii = _np.asarray([p[0] for p in pairs], _np.int32)
@@ -1157,58 +1288,77 @@ def _fa_tri_pairs(nq, nk, bq, bk, order):
     return jnp.asarray(ii), jnp.asarray(jj)
 
 
-def _fa_fwd_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, o_ref,
-                       lse_ref, acc, m, l, *, scale, bq, bk):
+# The triangular-grid kernels serve the plain causal and the segment-masked
+# (document packing, io/text.py) attention alike: segment masking only
+# REMOVES scores inside live blocks, so the grid, block specs, strips and
+# online-softmax state are the same, and every strip causality leaves live
+# is computed whatever the documents are.  With ``seg`` the per-position
+# segment-id row rides as one more (1, 1, s) int32 input block, exactly
+# like lse/delta, as the last input.  The mask rule is shared with the lax
+# fallback (_fa_scores / parallel/ring.py), and the interpret-mode
+# pairtests hold the two paths together (tests/test_text.py).
+
+
+def _fa_fwd_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, *refs, scale,
+                       plan, seg):
+    seg_ref = refs[0] if seg else None
+    o_ref, lse_ref, acc, m, l = refs[seg:]
     t = pl.program_id(1)
     i, j = ii_ref[t], jj_ref[t]
-    jlast = (i * bq + bq - 1) // bk
+    jlast = (i * plan.bq + plan.bq - 1) // plan.bk
 
     @pl.when(j == 0)
     def _():
         _fa_fwd_init(acc, m, l)
 
-    _fa_fwd_step(i, j, q_ref, k_ref, v_ref, acc, m, l, scale=scale,
-                 causal=True, bq=bq, bk=bk)
+    _fa_block_cases(i, j, plan, "q", functools.partial(
+        _fa_fwd_step, i * plan.bq, j * plan.bk, q_ref, k_ref, v_ref, acc, m,
+        l, scale=scale, seg_ref=seg_ref))
 
     @pl.when(j == jlast)
     def _():
-        _fa_fwd_emit(i, o_ref, lse_ref, acc, m, l, bq)
+        _fa_fwd_emit(i, o_ref, lse_ref, acc, m, l, plan.bq)
 
 
-def _fa_dq_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref,
-                      lse_ref, delta_ref, dq_ref, dq_acc, *, scale, bq, bk):
+def _fa_dq_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                      delta_ref, *refs, scale, plan, seg):
+    seg_ref = refs[0] if seg else None
+    dq_ref, dq_acc = refs[seg:]
     t = pl.program_id(1)
     i, j = ii_ref[t], jj_ref[t]
-    jlast = (i * bq + bq - 1) // bk
+    jlast = (i * plan.bq + plan.bq - 1) // plan.bk
 
     @pl.when(j == 0)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    _fa_dq_step(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_acc, scale=scale, causal=True, bq=bq, bk=bk)
+    _fa_block_cases(i, j, plan, "q", functools.partial(
+        _fa_dq_step, i * plan.bq, j * plan.bk, q_ref, k_ref, v_ref, do_ref,
+        lse_ref, delta_ref, dq_acc, scale=scale, seg_ref=seg_ref))
 
     @pl.when(j == jlast)
     def _():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _fa_dkv_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref,
-                       lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                       *, scale, bq, bk, nq):
+def _fa_dkv_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                       delta_ref, *refs, scale, plan, seg):
+    seg_ref = refs[0] if seg else None
+    dk_ref, dv_ref, dk_acc, dv_acc = refs[seg:]
     t = pl.program_id(1)
     i, j = ii_ref[t], jj_ref[t]
-    ifirst = (j * bk) // bq
+    ifirst = (j * plan.bk) // plan.bq
 
     @pl.when(i == ifirst)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    _fa_dkv_step(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_acc, dv_acc, scale=scale, causal=True, bq=bq, bk=bk)
+    _fa_block_cases(i, j, plan, "k", functools.partial(
+        _fa_dkv_step, i * plan.bq, j * plan.bk, q_ref, k_ref, v_ref, do_ref,
+        lse_ref, delta_ref, dk_acc, dv_acc, scale=scale, seg_ref=seg_ref))
 
-    @pl.when(i == nq - 1)
+    @pl.when(i == lse_ref.shape[2] // plan.bq - 1)  # the last query block
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -1233,31 +1383,58 @@ def _fa_specs(nbh, s_len, d, bq, bk):
     return q_spec, k_spec, row_spec
 
 
-def _fa_fwd(q3, k3, v3, scale, causal, interpret):
+@functools.lru_cache(maxsize=None)
+def _fa_tri_call(kernel, nbh, s_len, d, dtype, scale, seg, interpret, plan):
+    """The pallas_call of one triangular-grid kernel ("fwd", "dq", "dkv").
+    Cached because pallas_call's wrapper is a jit: the attention layers of
+    a model make the same call, and through the one wrapper they trace its
+    kernel body once.  Unrolled strips make a body some 50 ms to trace, and
+    a trace a layer added 2.3 s to the language-model cells' set-up from a
+    warm compile cache (PERF.md section 6, PR 25).  Dead above-diagonal
+    blocks are not in the grid, so neither their k/v DMA nor their program
+    overhead is paid; also runs under interpret, so the CPU parity tests
+    cover this path."""
+    bq, bk = plan.bq, plan.bk
+    q_spec, k_spec, row_spec = _fa_tri_specs(s_len, d, bq, bk)
+    x = jax.ShapeDtypeStruct((nbh, s_len, d), dtype)
+    row = jax.ShapeDtypeStruct((nbh, 1, s_len), jnp.float32)
+    body, n_in, out_specs, out_shape, scratch = {
+        "fwd": (_fa_fwd_kernel_tri, 0, [q_spec, row_spec], [x, row],
+                ((bq, d), (bq, 1), (bq, 1))),
+        "dq": (_fa_dq_kernel_tri, 3, q_spec, x, ((bq, d),)),
+        "dkv": (_fa_dkv_kernel_tri, 3, [k_spec, k_spec], [x, x],
+                ((bk, d), (bk, d))),
+    }[kernel]
+    in_specs = [q_spec, k_spec, k_spec] + [q_spec, row_spec, row_spec][:n_in]
+    gs = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(nbh, len(_fa_live_pairs(s_len // bq, s_len // bk, bq, bk))),
+        in_specs=in_specs + [row_spec] * seg, out_specs=out_specs,
+        scratch_shapes=_scratch(*scratch))
+    return pl.pallas_call(
+        functools.partial(body, scale=scale, plan=plan, seg=seg),
+        grid_spec=gs, interpret=interpret, out_shape=out_shape)
+
+
+def _fa_tri(kernel, args, scale, interpret, seg3):
+    """Run one triangular-grid kernel on (nbh, s, d) operands ``args`` (the
+    first is q) and, for the segmented form, the (nbh, 1, s) segment row."""
+    nbh, s_len, d = args[0].shape
+    plan = _fa_plan(s_len, d, kernel)
+    segs = [] if seg3 is None else [seg3]
+    ii, jj = _fa_tri_pairs(s_len // plan.bq, s_len // plan.bk, plan.bq,
+                           plan.bk, "ji" if kernel == "dkv" else "ij")
+    return _fa_tri_call(kernel, nbh, s_len, d, args[0].dtype, scale,
+                        len(segs), interpret, plan)(ii, jj, *args, *segs)
+
+
+def _fa_fwd(q3, k3, v3, scale, causal, interpret, seg3=None):
+    if causal:
+        return _fa_tri("fwd", (q3, k3, v3), scale, interpret, seg3)
     nbh, s_len, d = q3.shape
     bq, bk = _fa_blocks(s_len, d)
-    if causal:
-        # triangular grid: dead above-diagonal blocks are excluded from
-        # the grid, so neither their k/v DMA nor program overhead is paid
-        # (with equal 1024-blocks at s4096: 6 of 16 programs).  Also runs
-        # under interpret so the CPU parity tests cover this path.
-        ii, jj = _fa_tri_pairs(s_len // bq, s_len // bk, bq, bk, "ij")
-        q_spec, k_spec, row_spec = _fa_tri_specs(s_len, d, bq, bk)
-        gs = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(nbh, ii.shape[0]),
-            in_specs=[q_spec, k_spec, k_spec],
-            out_specs=[q_spec, row_spec],
-            scratch_shapes=_scratch((bq, d), (bq, 1), (bq, 1)))
-        kern = functools.partial(_fa_fwd_kernel_tri, scale=scale,
-                                 bq=bq, bk=bk)
-        return pl.pallas_call(
-            kern, grid_spec=gs, interpret=interpret,
-            out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-                       jax.ShapeDtypeStruct((nbh, 1, s_len), jnp.float32)],
-        )(ii, jj, q3, k3, v3)
     q_spec, k_spec, row_spec = _fa_specs(nbh, s_len, d, bq, bk)
-    kern = functools.partial(_fa_fwd_kernel, scale=scale, causal=causal,
-                             bq=bq, bk=bk)
+    kern = functools.partial(_fa_fwd_kernel, scale=scale, bq=bq, bk=bk)
     o, lse = pl.pallas_call(
         kern,
         grid=(nbh, s_len // bq, s_len // bk),
@@ -1271,44 +1448,19 @@ def _fa_fwd(q3, k3, v3, scale, causal, interpret):
     return o, lse
 
 
-def _fa_bwd(q3, k3, v3, o3, lse, g3, scale, causal, interpret):
+def _fa_bwd(q3, k3, v3, o3, lse, g3, scale, causal, interpret, seg3=None):
     nbh, s_len, d = q3.shape
     delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)[:, None, :]  # (nbh, 1, s)
-    bq, bk = _fa_blocks(s_len, d)
     if causal:
-        nq, nk = s_len // bq, s_len // bk
-        q_spec, k_spec, row_spec = _fa_tri_specs(s_len, d, bq, bk)
-        ii, jj = _fa_tri_pairs(nq, nk, bq, bk, "ij")
-        gs = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(nbh, ii.shape[0]),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-            out_specs=q_spec,
-            scratch_shapes=_scratch((bq, d)))
-        dq = pl.pallas_call(
-            functools.partial(_fa_dq_kernel_tri, scale=scale, bq=bq,
-                              bk=bk),
-            grid_spec=gs, interpret=interpret,
-            out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-        )(ii, jj, q3, k3, v3, g3, lse, delta)
-        ii2, jj2 = _fa_tri_pairs(nq, nk, bq, bk, "ji")
-        gs2 = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(nbh, ii2.shape[0]),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-            out_specs=[k_spec, k_spec],
-            scratch_shapes=_scratch((bk, d), (bk, d)))
-        dk, dv = pl.pallas_call(
-            functools.partial(_fa_dkv_kernel_tri, scale=scale, bq=bq,
-                              bk=bk, nq=nq),
-            grid_spec=gs2, interpret=interpret,
-            out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
-                       jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
-        )(ii2, jj2, q3, k3, v3, g3, lse, delta)
+        args = (q3, k3, v3, g3, lse, delta)
+        dq = _fa_tri("dq", args, scale, interpret, seg3)
+        dk, dv = _fa_tri("dkv", args, scale, interpret, seg3)
         return dq, dk, dv
+    bq, bk = _fa_blocks(s_len, d)
     q_spec, k_spec, row_spec = _fa_specs(nbh, s_len, d, bq, bk)
     dq = pl.pallas_call(
-        functools.partial(_fa_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk),
+        functools.partial(_fa_dq_kernel, scale=scale, bq=bq, bk=bk),
         grid=(nbh, s_len // bq, s_len // bk),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
@@ -1321,8 +1473,7 @@ def _fa_bwd(q3, k3, v3, o3, lse, g3, scale, causal, interpret):
     kq_k_spec = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
     kq_row_spec = pl.BlockSpec((1, 1, s_len), lambda b, j, i: (b, 0, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk),
+        functools.partial(_fa_dkv_kernel, scale=scale, bq=bq, bk=bk),
         grid=(nbh, s_len // bk, s_len // bq),
         in_specs=[kq_q_spec, kq_k_spec, kq_k_spec, kq_q_spec,
                   kq_row_spec, kq_row_spec],
@@ -1377,140 +1528,6 @@ def _flash_bwd_res(causal, scale, interpret, res, g):
 flash_attention.defvjp(_flash_fwd_res, _flash_bwd_res)
 
 
-# --------------------------------------------------------------------------
-# Segment-masked causal flash attention (document packing, io/text.py).
-# Same triangular live-pair grid as the causal kernels — segment masking
-# only REMOVES scores inside live blocks, so the grid, block specs, and
-# online-softmax state are unchanged; the per-position segment-id row
-# rides as one (1, 1, s) int32 block exactly like lse/delta.  The mask
-# rule is shared verbatim with the lax fallback (_segment_mask /
-# parallel/ring.py), and the interpret-mode pairtests hold the two paths
-# together (tests/test_text.py).
-
-
-def _fa_seg_slices(seg_ref, i, j, bq, bk):
-    return (seg_ref[0, 0, pl.ds(i * bq, bq)],
-            seg_ref[0, 0, pl.ds(j * bk, bk)])
-
-
-def _fa_fwd_kernel_tri_seg(ii_ref, jj_ref, q_ref, k_ref, v_ref, seg_ref,
-                           o_ref, lse_ref, acc, m, l, *, scale, bq, bk):
-    t = pl.program_id(1)
-    i, j = ii_ref[t], jj_ref[t]
-    jlast = (i * bq + bq - 1) // bk
-
-    @pl.when(j == 0)
-    def _():
-        _fa_fwd_init(acc, m, l)
-
-    segq, segk = _fa_seg_slices(seg_ref, i, j, bq, bk)
-    _fa_fwd_step(i, j, q_ref, k_ref, v_ref, acc, m, l, scale=scale,
-                 causal=True, bq=bq, bk=bk, segq=segq, segk=segk)
-
-    @pl.when(j == jlast)
-    def _():
-        _fa_fwd_emit(i, o_ref, lse_ref, acc, m, l, bq)
-
-
-def _fa_dq_kernel_tri_seg(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref,
-                          lse_ref, delta_ref, seg_ref, dq_ref, dq_acc,
-                          *, scale, bq, bk):
-    t = pl.program_id(1)
-    i, j = ii_ref[t], jj_ref[t]
-    jlast = (i * bq + bq - 1) // bk
-
-    @pl.when(j == 0)
-    def _():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    segq, segk = _fa_seg_slices(seg_ref, i, j, bq, bk)
-    _fa_dq_step(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_acc, scale=scale, causal=True, bq=bq, bk=bk,
-                segq=segq, segk=segk)
-
-    @pl.when(j == jlast)
-    def _():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
-
-
-def _fa_dkv_kernel_tri_seg(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref,
-                           lse_ref, delta_ref, seg_ref, dk_ref, dv_ref,
-                           dk_acc, dv_acc, *, scale, bq, bk, nq):
-    t = pl.program_id(1)
-    i, j = ii_ref[t], jj_ref[t]
-    ifirst = (j * bk) // bq
-
-    @pl.when(i == ifirst)
-    def _():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    segq, segk = _fa_seg_slices(seg_ref, i, j, bq, bk)
-    _fa_dkv_step(i, j, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_acc, dv_acc, scale=scale, causal=True, bq=bq, bk=bk,
-                 segq=segq, segk=segk)
-
-    @pl.when(i == nq - 1)
-    def _():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _fa_seg_fwd(q3, k3, v3, seg3, scale, interpret):
-    nbh, s_len, d = q3.shape
-    bq, bk = _fa_blocks(s_len, d)
-    ii, jj = _fa_tri_pairs(s_len // bq, s_len // bk, bq, bk, "ij")
-    q_spec, k_spec, row_spec = _fa_tri_specs(s_len, d, bq, bk)
-    gs = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(nbh, ii.shape[0]),
-        in_specs=[q_spec, k_spec, k_spec, row_spec],
-        out_specs=[q_spec, row_spec],
-        scratch_shapes=_scratch((bq, d), (bq, 1), (bq, 1)))
-    kern = functools.partial(_fa_fwd_kernel_tri_seg, scale=scale,
-                             bq=bq, bk=bk)
-    return pl.pallas_call(
-        kern, grid_spec=gs, interpret=interpret,
-        out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-                   jax.ShapeDtypeStruct((nbh, 1, s_len), jnp.float32)],
-    )(ii, jj, q3, k3, v3, seg3)
-
-
-def _fa_seg_bwd(q3, k3, v3, seg3, o3, lse, g3, scale, interpret):
-    nbh, s_len, d = q3.shape
-    delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1)[:, None, :]
-    bq, bk = _fa_blocks(s_len, d)
-    nq, nk = s_len // bq, s_len // bk
-    q_spec, k_spec, row_spec = _fa_tri_specs(s_len, d, bq, bk)
-    ii, jj = _fa_tri_pairs(nq, nk, bq, bk, "ij")
-    gs = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(nbh, ii.shape[0]),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec,
-                  row_spec],
-        out_specs=q_spec,
-        scratch_shapes=_scratch((bq, d)))
-    dq = pl.pallas_call(
-        functools.partial(_fa_dq_kernel_tri_seg, scale=scale, bq=bq, bk=bk),
-        grid_spec=gs, interpret=interpret,
-        out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-    )(ii, jj, q3, k3, v3, g3, lse, delta, seg3)
-    ii2, jj2 = _fa_tri_pairs(nq, nk, bq, bk, "ji")
-    gs2 = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(nbh, ii2.shape[0]),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec,
-                  row_spec],
-        out_specs=[k_spec, k_spec],
-        scratch_shapes=_scratch((bk, d), (bk, d)))
-    dk, dv = pl.pallas_call(
-        functools.partial(_fa_dkv_kernel_tri_seg, scale=scale, bq=bq,
-                          bk=bk, nq=nq),
-        grid_spec=gs2, interpret=interpret,
-        out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
-                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
-    )(ii2, jj2, q3, k3, v3, g3, lse, delta, seg3)
-    return dq, dk, dv
-
-
 def _seg_tile(seg, h):
     """(b, s) segment ids -> the kernels' (b*h, 1, s) int32 layout
     (b-major, matching ``q.reshape(b*h, s, d)``)."""
@@ -1528,8 +1545,8 @@ def _flash_seg_fwd_res(q, k, v, seg, scale, interpret):
     b, h, s_len, d = q.shape
     sh3 = (b * h, s_len, d)
     seg3 = _seg_tile(seg, h)
-    o3, lse = _fa_seg_fwd(q.reshape(sh3), k.reshape(sh3), v.reshape(sh3),
-                          seg3, scale, interpret)
+    o3, lse = _fa_fwd(q.reshape(sh3), k.reshape(sh3), v.reshape(sh3),
+                      scale, True, interpret, seg3)
     return o3.reshape(q.shape), (q, k, v, seg, o3, lse)
 
 
@@ -1538,9 +1555,9 @@ def _flash_seg_bwd_res(scale, interpret, res, g):
     scale, interpret = _norm_args(q, True, scale, interpret)
     b, h, s_len, d = q.shape
     sh3 = (b * h, s_len, d)
-    dq, dk, dv = _fa_seg_bwd(q.reshape(sh3), k.reshape(sh3),
-                             v.reshape(sh3), _seg_tile(seg, h), o3, lse,
-                             g.reshape(sh3), scale, interpret)
+    dq, dk, dv = _fa_bwd(q.reshape(sh3), k.reshape(sh3), v.reshape(sh3),
+                         o3, lse, g.reshape(sh3), scale, True, interpret,
+                         _seg_tile(seg, h))
     import numpy as _np
     dseg = _np.zeros(seg.shape, jax.dtypes.float0)  # int input: no tangent
     return (dq.reshape(q.shape), dk.reshape(k.shape),
@@ -1555,7 +1572,7 @@ def flash_attention_segmented(q, k, v, seg, scale=None, interpret=None):
     segment ids -> (b, h, s, d).
 
     Block-diagonal causal masking for packed documents (segment 0 =
-    padding; the diagonal is always allowed — see ``_segment_mask``).
+    padding; the diagonal is always allowed — see ``_fa_scores``).
     Same availability gate as :func:`flash_attention`
     (``flash_attention_available``); ``interpret`` defaults to off-TPU
     detection so the CPU pairtests run this exact code."""

@@ -1,23 +1,31 @@
-"""Flash-attention kernel autotune on TPU (VERDICT r5 #4).
+"""Flash-attention kernel autotune on TPU (VERDICT r5 #4; strips: PR 25).
 
-The d2048 flagship profile shows the flash kernels at ~20% of peak-MAC
-efficiency (fwd 7.1 ms/layer vs 1.4 ms ideal at dh=64): the kernel is
-DMA-bound (k/v blocks re-fetched per q-block) and VPU-bound (softmax work
-scales with h*s^2, so 32 small heads double it vs 16 MXU-wide ones).
+Two sweeps of the causal kernels, plain and segment-masked, each timing the
+forward, dq and dkv kernels apart (device time of their Mosaic calls, read
+from a profiler trace):
 
-Sweeps (bq, bk) block sizes and grid dimension_semantics for both head
-geometries of d2048 (h32/dh64 and h16/dh128), printing measured ms and
-efficiency vs the causal-MAC ideal.  Winners become the defaults in
-ops/pallas_kernels.py (_fa_blocks).
+``strips`` (the default): the strip height inside a diagonal-crossing block
+(``pk._fa_strip``), at the benchmark cells' shape (b8 h16 s2048 d128) and at
+the s4096 b4 shape the block sizes were tuned at, with the planner's
+computed-over-live area ratio beside each time.  The winner is the rule in
+``ops/pallas_kernels.py`` (``_fa_strip``).  On a tree without ``_fa_strip``
+(the parent of PR 25) it times the kernels as they are, once.
 
-Usage: python experiments/fa_tune.py [s_len] [batch]
+``blocks``: the (bq, bk) block sizes for both head geometries of d2048
+(h32/dh64 and h16/dh128) at one shape, as in round 5 (``_fa_blocks``).
+
+Usage: python experiments/fa_tune.py [strips|blocks] [s_len,batch ...]
 """
+import glob
+import os
+import shutil
 import sys
+import tempfile
 import time
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -25,26 +33,32 @@ import jax.numpy as jnp  # noqa: E402
 from cxxnet_tpu.ops import pallas_kernels as pk  # noqa: E402
 
 PEAK_MACS = 197e12 / 2
-
-
-def ideal_ms(b, h, s, d, causal=True, bwd=False):
-    macs = 2 * b * h * s * s * d * (0.5 if causal else 1.0)
-    if bwd:
-        macs *= 2.5  # dq (2 mm) + dkdv (3 mm) vs fwd's 2, causal-halved
-    return macs / PEAK_MACS * 1e3
-
-
 ITERS = 10
+KERNELS = ("fwd", "dq", "dkv")
+
+
+def ideal_ms(b, h, s, d, mms):
+    """Least MXU time of ``mms`` block matmuls' worth of causal attention
+    (forward 2, dq 2, dkv 3 without the recomputed scores: 4 and 5 with)."""
+    return mms * b * h * s * s * d * 0.5 / PEAK_MACS * 1e3
+
+
+def _kernel_of(event_name):
+    """Which flash kernel a Mosaic call's instruction line belongs to, by
+    what it returns: the forward (o, f32 lse), dkv (dk, dv) or dq."""
+    if 'custom_call_target="tpu_custom_call"' not in event_name:
+        return None
+    result = event_name.split(" = ", 1)[-1].split(" custom-call(", 1)[0]
+    if "f32[" in result:
+        return "fwd"
+    return "dkv" if result.startswith("(") else "dq"
 
 
 def measure(fn, *args):
-    """Device time per iteration from a profiler trace: the per-dispatch
-    host round trip swamps wall timings of ms-scale kernels,
-    so fn runs ITERS sequential iterations in ONE dispatch and the
-    on-chip XLA-module time is read from the trace."""
-    import shutil
-    import tempfile
-    from bench import _trace_device_ms
+    """Device ms per iteration of each flash kernel: fn runs ITERS
+    sequential iterations in ONE dispatch (the per-dispatch host round trip
+    swamps wall timings of ms-scale kernels) and the Mosaic calls' device
+    durations are read from the profiler's trace of the first chip."""
     np.asarray(fn(*args))  # compile + warm
     tdir = tempfile.mkdtemp(prefix="fa_tune_prof")
     try:
@@ -53,9 +67,90 @@ def measure(fn, *args):
             np.asarray(fn(*args))
         finally:
             jax.profiler.stop_trace()
-        return _trace_device_ms(tdir) / ITERS
+        path, = glob.glob(os.path.join(tdir, "plugins/profile/*/*.xplane.pb"))
+        plane = next(p for p in jax.profiler.ProfileData.from_file(path).planes
+                     if p.name.startswith("/device:TPU:0"))
+        ns = dict.fromkeys(KERNELS, 0.0)
+        for line in plane.lines:
+            if line.name == "XLA Ops":
+                for ev in line.events:
+                    kern = _kernel_of(ev.name)
+                    if kern:
+                        ns[kern] += ev.duration_ns
+        return {k: v / 1e6 / ITERS for k, v in ns.items()}
     finally:
         shutil.rmtree(tdir, ignore_errors=True)
+
+
+def make_inputs(b, h, s_len, d):
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, g = (jax.random.normal(kk, (b, h, s_len, d), jnp.bfloat16)
+                  for kk in keys)
+    # documents of 300 tokens and a padding tail: the segmented kernels
+    # skip nothing on the strength of segment ids, so any layout times alike
+    seg = np.arange(s_len) // 300 + 1
+    seg[-100:] = 0
+    return q, k, v, g, jnp.asarray(np.tile(seg, (b, 1)), jnp.int32)
+
+
+def make_train(attn):
+    """ITERS sequential forward+backward passes per dispatch (the output
+    feeds the next q, so XLA can neither CSE nor parallelize them)."""
+    def train(q, k, v, g):
+        def body(_, qc):
+            out, vjp = jax.vjp(attn, qc, k, v)
+            dq, dk, dv = vjp(g)
+            # consume ALL cotangents: an unused dk/dv would let XLA
+            # dead-code-eliminate the dkv kernel entirely
+            return (dq + out * 0.5 + dk * 0.25 + dv * 0.125).astype(qc.dtype)
+        return jax.lax.fori_loop(0, ITERS, body, q).sum().astype(jnp.float32)
+    return jax.jit(train)
+
+
+def time_kinds(label, b, h, s_len, d, area):
+    q, k, v, g, seg = make_inputs(b, h, s_len, d)
+    kinds = (("causal", lambda q, k, v: pk.flash_attention(q, k, v, True)),
+             ("segmented",
+              lambda q, k, v: pk.flash_attention_segmented(q, k, v, seg)))
+    ideal = [ideal_ms(b, h, s_len, d, mms) for mms in (2, 2, 3)]
+    for kind, attn in kinds:
+        try:
+            jax.clear_caches()
+            t0 = time.time()
+            ms = measure(make_train(attn), q, k, v, g)
+            cols = "  ".join(f"{kn} {ms[kn]:6.3f} ({i / ms[kn] * 100:4.1f}%)"
+                             for kn, i in zip(KERNELS, ideal))
+            print(f"b{b} h{h} s{s_len} d{d} {label} area {area} "
+                  f"{kind:9s}: {cols}  fwd+bwd {sum(ms.values()):6.3f} ms  "
+                  f"[{time.time() - t0:.0f} s]", flush=True)
+        except Exception as e:
+            print(f"b{b} h{h} s{s_len} d{d} {label} {kind}: FAILED "
+                  f"{str(e).splitlines()[0][:120]}", flush=True)
+
+
+def sweep_strips(shapes):
+    h, d = 16, 128
+    base = getattr(pk, "_fa_strip", None)
+    for s_len, b in shapes:
+        if base is None:
+            time_kinds("as-is", b, h, s_len, d, "-")
+            continue
+        bq, bk = pk._fa_blocks(s_len, d)
+        print(f"s{s_len}: the rule's strip heights "
+              + ", ".join(f"{kn} {base(s_len, d, bq, bk, kn)}"
+                          for kn in KERNELS), flush=True)
+        heights = [bs for bs in (1024, 512, 256, 128)
+                   if bq % bs == 0 and bk % bs == 0]
+        for bs in heights:
+            pk._fa_strip = lambda *a, _bs=bs: _bs
+            plan = pk._fa_plan(s_len, d)
+            time_kinds(f"bq{bq} bk{bk} bs{bs:4d} int {plan.interior} cross "
+                       f"{plan.crossing}", b, h, s_len, d,
+                       f"{plan.area_ratio:.4f}")
+        pk._fa_strip = base
+        areas = "/".join(f"{pk._fa_plan(s_len, d, kn).area_ratio:.4f}"
+                         for kn in KERNELS)
+        time_kinds("the rule", b, h, s_len, d, areas)
 
 
 def vmem_est(bq, bk, d):
@@ -66,74 +161,40 @@ def vmem_est(bq, bk, d):
     return scores + blocks + acc
 
 
-def main():
-    s_len = int(sys.argv[1]) if len(sys.argv) > 1 else 4096
-    b = int(sys.argv[2]) if len(sys.argv) > 2 else 4
-    assert jax.default_backend() == "tpu", "run on TPU"
-
-    geoms = [(32, 64), (16, 128)]
+def sweep_blocks(shapes):
     blockset = [(512, 1024), (1024, 512), (1024, 1024), (512, 512),
                 (2048, 512), (256, 2048), (1024, 2048), (2048, 1024)]
     # dimension_semantics (parallel,parallel,arbitrary) was swept here and
     # measured identical times to unannotated on v5e; the annotation was
     # dropped from the kernels (a PARALLEL q-block dim would corrupt the
     # fwd kernel's shared lse block under a megacore split)
-
     base_blocks = pk._fa_blocks
-    for h, d in geoms:
-        key = jax.random.PRNGKey(0)
-        kq, kk, kv, kg = jax.random.split(key, 4)
-        q = jax.random.normal(kq, (b, h, s_len, d), jnp.bfloat16)
-        k = jax.random.normal(kk, (b, h, s_len, d), jnp.bfloat16)
-        v = jax.random.normal(kv, (b, h, s_len, d), jnp.bfloat16)
-        g = jax.random.normal(kg, (b, h, s_len, d), jnp.bfloat16)
-        i_f = ideal_ms(b, h, s_len, d)
-        i_b = ideal_ms(b, h, s_len, d, bwd=True)
+    for s_len, b in shapes:
+        for h, d in [(32, 64), (16, 128)]:
+            for bq, bk in blockset:
+                if bq > s_len or bk > s_len:
+                    continue
+                if vmem_est(bq, bk, d) > 14 * 2 ** 20:
+                    print(f"h{h} d{d} bq{bq} bk{bk}: skip (vmem est "
+                          f"{vmem_est(bq, bk, d) / 2**20:.1f} MB)")
+                    continue
+                pk._fa_blocks = lambda s, d=64, _b=(bq, bk): _b
+                time_kinds(f"bq{bq} bk{bk}", b, h, s_len, d, "-")
+            pk._fa_blocks = base_blocks
 
-        # ITERS sequential kernel invocations per dispatch (output feeds
-        # the next q, so XLA cannot CSE or parallelize them)
-        def fwd(q, k, v):
-            def body(_, qc):
-                return pk.flash_attention(qc, k, v, True)
-            return jax.lax.fori_loop(0, ITERS, body, q).sum() \
-                .astype(jnp.float32)
-        fwd = jax.jit(fwd)
 
-        def train(q, k, v, g):
-            def body(_, qc):
-                out, vjp = jax.vjp(
-                    lambda q, k, v: pk.flash_attention(q, k, v, True),
-                    qc, k, v)
-                dq, dk, dv = vjp(g)
-                # consume ALL cotangents: an unused dk/dv would let XLA
-                # dead-code-eliminate the dkv kernel entirely
-                return (dq + out * 0.5 + dk * 0.25
-                        + dv * 0.125).astype(qc.dtype)
-            return jax.lax.fori_loop(0, ITERS, body, q).sum() \
-                .astype(jnp.float32)
-        trainf = jax.jit(train)
-
-        for bq, bk in blockset:
-            if bq > s_len or bk > s_len:
-                continue
-            if vmem_est(bq, bk, d) > 14 * 2 ** 20:
-                print(f"h{h} d{d} bq{bq} bk{bk}: skip (vmem est "
-                      f"{vmem_est(bq, bk, d) / 2**20:.1f} MB)")
-                continue
-            if True:
-                pk._fa_blocks = lambda s, d=64, _bq=bq, _bk=bk: (_bq, _bk)
-                try:
-                    jax.clear_caches()
-                    t_f = measure(fwd, q, k, v)
-                    t_t = measure(trainf, q, k, v, g) - t_f
-                    print(f"h{h} d{d} bq{bq:5d} bk{bk:5d}: "
-                          f"fwd {t_f:7.2f} ms (eff {i_f / t_f * 100:4.1f}%)"
-                          f"  bwd {t_t:7.2f} ms (eff {i_b / t_t * 100:4.1f}%)",
-                          flush=True)
-                except Exception as e:
-                    print(f"h{h} d{d} bq{bq} bk{bk}: FAILED "
-                          f"{str(e).splitlines()[0][:90]}", flush=True)
-        pk._fa_blocks = base_blocks
+def main():
+    args = sys.argv[1:]
+    mode = args.pop(0) if args and args[0] in ("strips", "blocks") else "strips"
+    shapes = [tuple(int(t) for t in a.split(",")) for a in args] or (
+        [(2048, 8), (4096, 4)] if mode == "strips" else [(4096, 4)])
+    assert jax.default_backend() == "tpu", "run on TPU"
+    dev = jax.devices()[0]
+    print(f"fa_tune {mode}: {time.strftime('%Y-%m-%d')} device "
+          f"{dev.platform} {dev.device_kind} x{jax.device_count()} jax "
+          f"{jax.__version__}; ms a call of b x h heads, device time, "
+          f"(share of the MXU-bound least time)", flush=True)
+    (sweep_strips if mode == "strips" else sweep_blocks)(shapes)
 
 
 if __name__ == "__main__":

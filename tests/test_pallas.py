@@ -4,6 +4,8 @@ PairTest-style differential check: the Pallas LRN kernel against the plain
 XLA path (``nn.lrn``'s shifted-adds formulation), forward and backward.
 """
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -680,6 +682,89 @@ def test_flash_attention_multiblock_causal_grads():
                     err_msg=f"{nm} blocks={blocks}")
     finally:
         pk._fa_blocks = old_blocks
+
+
+def _force_fa(monkeypatch, blocks, bs):
+    """Force the causal kernels' block and strip sizes, as fa_tune.py's
+    sweeps do (both are read when the kernel is traced)."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "_fa_blocks", lambda s, d=64: blocks)
+    monkeypatch.setattr(pk, "_fa_strip", lambda *a: bs)
+    return pk
+
+
+@pytest.mark.parametrize("s,blocks,bs", [
+    (256, (256, 256), 128),    # one block, all of it diagonal, n = 2
+    (256, (256, 256), 64),     # n = 4
+    (256, (256, 256), 256),    # n = 1: the whole block one masked tile
+    (512, (256, 256), 128),    # several blocks: interior ones unmasked
+    (512, (256, 256), 64),
+    (1024, (256, 512), 128),   # asymmetric: two offsets a key block
+    (1024, (256, 512), 64),
+    (512, (256, 128), 64),     # bq > bk: strips that causality empties
+])
+def test_flash_attention_strips_match_dense(monkeypatch, s, blocks, bs):
+    """Forward and all three gradients against dense_attention with the
+    strip path of the diagonal-crossing blocks forced at small shapes."""
+    from cxxnet_tpu.parallel.ring import dense_attention
+    pk = _force_fa(monkeypatch, blocks, bs)
+    plan = pk._fa_plan(s, 32)
+    assert (plan.bq, plan.bk, plan.bs) == blocks + (bs,)
+    assert plan.interior + plan.crossing == len(
+        pk._fa_live_pairs(s // blocks[0], s // blocks[1], *blocks))
+    rnd = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rnd.randn(1, 2, s, 32).astype(np.float32) * 0.5)
+               for _ in range(3))
+    np.testing.assert_allclose(
+        np.asarray(pk.flash_attention(q, k, v, True)),
+        np.asarray(dense_attention(q, k, v, causal=True)), atol=1e-5)
+    gf = jax.grad(lambda *a: jnp.sum(pk.flash_attention(*a, True) ** 2),
+                  argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(dense_attention(*a, causal=True) ** 2),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b, nm in zip(gf, gr, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   err_msg=nm)
+
+
+@pytest.mark.parametrize("s,d,bs,interior,crossing,ratio", [
+    (2048, 128, 1024, 1, 2, 1.5),       # n = 1: the parent's 3 blocks for 2
+    (2048, 128, 256, 1, 2, 1.125),      # n = 4
+    (2048, 128, 128, 1, 2, 1.0625),     # n = 8
+    (4096, 128, 1024, 6, 4, 1.25),      # 10 blocks for 8
+    (4096, 128, 256, 6, 4, 1.0625),
+    (1024, 128, 256, 0, 1, 1.25),       # one block, all of it diagonal
+    (2048, 256, 512, 2, 4, 1.25),       # (512, 1024) blocks: offsets 0, 512
+])
+def test_fa_plan_counts(monkeypatch, s, d, bs, interior, crossing, ratio):
+    """The planner is the kernels' engagement counter: which programs take
+    which path, and computed over live area, follow from the shapes."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "_fa_strip", lambda *a: bs)
+    plan = pk._fa_plan(s, d)
+    assert (plan.bq, plan.bk) == pk._fa_blocks(s, d)
+    assert (plan.interior, plan.crossing) == (interior, crossing)
+    assert plan.area_ratio == pytest.approx(ratio)
+    # both walks of a crossing block cover the same area
+    for off in plan.offsets:
+        areas = [sum((r.stop - r.start) * (c.stop - c.start)
+                     for g in pk._fa_rects(plan.bq, plan.bk, bs, off, by)
+                     for r, c, _ in g) for by in "qk"]
+        assert areas[0] == areas[1]
+
+
+def test_fa_strip_rule():
+    """The shipped strip heights: multiples of 128 that divide both block
+    sides, at most 8 strips a side."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    for s, d, kernel in itertools.product(
+            (128, 256, 512, 1024, 1536, 2048, 4096, 8192), (64, 128, 256),
+            ("fwd", "dq", "dkv")):
+        plan = pk._fa_plan(s, d, kernel)
+        assert plan.bs % 128 == 0
+        assert plan.bq % plan.bs == 0 and plan.bk % plan.bs == 0
+        assert max(plan.bq, plan.bk) // plan.bs <= 8
+        assert 1.0 < plan.area_ratio <= 2.0
 
 
 def test_layernorm_pallas_matches_xla():

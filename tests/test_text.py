@@ -404,6 +404,46 @@ def test_flash_segment_pairtest_interpret(d1):
                                    rtol=2e-3, atol=2e-4)
 
 
+@pytest.mark.parametrize("s,blocks,bs,bounds,pad", [
+    (256, (256, 256), 64, (100,), 0),       # a boundary inside a strip
+    (256, (256, 256), 64, (128,), 0),       # one exactly on a strip edge
+    (256, (256, 256), 64, (40, 128), 80),   # padding spans a whole strip
+    (512, (256, 256), 128, (100, 256, 300), 40),  # interior blocks too
+    (1024, (256, 512), 128, (200, 512, 700), 0),  # asymmetric blocks
+])
+def test_flash_segment_strips_interpret(monkeypatch, s, blocks, bs, bounds,
+                                        pad):
+    """The segmented kernels with the strip path forced: forward and all
+    three gradients against the lax fallback."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    from cxxnet_tpu.parallel import ring
+    monkeypatch.setattr(pk, "_fa_blocks", lambda s, d=64: blocks)
+    monkeypatch.setattr(pk, "_fa_strip", lambda *a: bs)
+    rnd = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rnd.randn(2, 2, s, 16).astype(np.float32))
+               for _ in range(3))
+    seg = np.ones((2, s), np.int64)
+    for at in bounds:
+        seg[:, at:] += 1
+    if pad:
+        seg[1, -pad:] = 0  # padding tail on row 1 (diagonal-only attention)
+    seg = jnp.asarray(seg)
+    np.testing.assert_allclose(
+        np.asarray(pk.flash_attention_segmented(q, k, v, seg,
+                                                interpret=True)),
+        np.asarray(ring.dense_attention(q, k, v, causal=True, seg=seg)),
+        rtol=2e-4, atol=2e-5)
+    g_ref = jax.grad(lambda *a: jnp.sum(
+        ring.dense_attention(*a, causal=True, seg=seg) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    g_out = jax.grad(lambda *a: jnp.sum(
+        pk.flash_attention_segmented(*a, seg, interpret=True) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b_, nm in zip(g_ref, g_out, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-3, atol=2e-4, err_msg=nm)
+
+
 def test_ring_segment_matches_dense():
     """Segment ids rotate around the ring with their K/V blocks; the
     sharded result must match the single-device oracle."""
