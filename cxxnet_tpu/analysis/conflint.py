@@ -85,6 +85,13 @@ def lint_pairs(pairs: ConfigPairs, path: str = "") -> List[Finding]:
             netcfg_mode = 1 if val == "start" else 0
             cur_layer = None
             continue
+        if name == "loop" or name.startswith("loop["):
+            # opens or closes a loop body (NetConfig._parse_loop_line, which
+            # _structural_findings runs): the keys that follow belong to no
+            # layer
+            cur_layer, netcfg_mode = None, 1
+            _lint_global_key(name, val, findings)
+            continue
         if name.startswith("layer["):
             cur_layer = _lint_layer_line(name, val, findings)
             if cur_layer is not None:

@@ -87,6 +87,11 @@ def _netcfg_keys() -> Tuple[KeySpec, ...]:
         K("extra_data_num", "int", lo=0),
         K("extra_data_shape[*]", "str", help="c,y,x"),
         K("label_vec[*]", "str", help="label field name for columns [a,b)"),
+        K("loop[*]", "int", lo=1,
+          help="loop[read->write] = T: the layers up to 'loop = end' run T "
+               "times, each pass reading at `read` what the last wrote to "
+               "`write`"),
+        K("loop", "enum", choices=("end",)),
     )
 
 

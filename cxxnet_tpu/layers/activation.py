@@ -121,6 +121,16 @@ class GeluLayer(_UnaryLayer):
         return jax.nn.gelu(x)
 
 
+class SiluLayer(_UnaryLayer):
+    """``x * sigmoid(x)``: the activation of gated feed-forward blocks
+    (with ``eltmul``: ``silu(W_g u) * (W_u u)``)."""
+
+    type_names = ("silu",)
+
+    def _fn(self, x, ctx):
+        return jax.nn.silu(x)
+
+
 class XeluLayer(_UnaryLayer):
     """Leaky relu with divisor b: x>0 ? x : x/b (op.h:51-61; default b=5)."""
 

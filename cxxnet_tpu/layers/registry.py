@@ -11,8 +11,8 @@ from __future__ import annotations
 from typing import Dict, Type
 
 from .activation import (BiasLayer, GeluLayer, InsanityLayer, PReluLayer,
-                         ReluLayer, SigmoidLayer, SoftplusLayer, TanhLayer,
-                         XeluLayer)
+                         ReluLayer, SigmoidLayer, SiluLayer, SoftplusLayer,
+                         TanhLayer, XeluLayer)
 from .base import Layer
 from .conv import (AvgPoolingLayer, ConvolutionLayer, InsanityPoolingLayer,
                    LRNLayer, MaxPoolingLayer, ReluMaxPoolingLayer,
@@ -22,10 +22,11 @@ from .loss import L2LossLayer, MultiLogisticLayer, SoftmaxLayer
 from .moe import MoELayer
 from .norm import BatchNormLayer, DropoutLayer
 from .pairtest import PairTestLayer
-from .sequence import (AttentionLayer, EmbeddingLayer, LayerNormLayer,
-                       SeqFullcLayer, SoftmaxSeqLayer)
-from .shape_ops import (ChConcatLayer, ConcatLayer, EltSumLayer, FlattenLayer,
-                        MaxoutLayer, SplitLayer)
+from .sequence import (AttentionLayer, EmbeddingLayer, ExitLossLayer,
+                       LayerNormLayer, RMSNormLayer, SeqFullcLayer,
+                       SeqXentLayer, SoftmaxSeqLayer)
+from .shape_ops import (ChConcatLayer, ConcatLayer, EltMulLayer, EltSumLayer,
+                        FlattenLayer, MaxoutLayer, SplitLayer)
 
 _REGISTRY: Dict[str, Type[Layer]] = {}
 
@@ -43,7 +44,9 @@ for _cls in (ReluLayer, SigmoidLayer, TanhLayer, SoftplusLayer, XeluLayer,
              FlattenLayer, SplitLayer, ConcatLayer, ChConcatLayer,
              MaxoutLayer, EltSumLayer, SoftmaxLayer, L2LossLayer,
              MultiLogisticLayer, GeluLayer, EmbeddingLayer, LayerNormLayer,
-             SeqFullcLayer, AttentionLayer, SoftmaxSeqLayer, MoELayer):
+             SeqFullcLayer, AttentionLayer, SoftmaxSeqLayer, MoELayer,
+             SiluLayer, EltMulLayer, RMSNormLayer, SeqXentLayer,
+             ExitLossLayer):
     register(_cls)
 
 

@@ -1,6 +1,7 @@
-"""Sequence-model layers: embedding, layernorm, per-position linear, gelu,
-multi-head attention (dense or ring/sequence-parallel), and the LM softmax
-loss.
+"""Sequence-model layers: embedding, layernorm, rmsnorm, per-position linear,
+multi-head attention (dense or ring/sequence-parallel, optionally rotary), the
+LM softmax loss, and the per-pass cross-entropy and exit loss of looped
+models.
 
 The reference framework predates attention entirely (SURVEY.md §5.7: data is
 fixed (N,C,H,W) images), so these layers have no file:line counterparts —
@@ -68,6 +69,23 @@ def _single_device_attention(q, k, v, causal: bool, seg=None):
         else:
             return pk.flash_attention(q, k, v, causal)
     return ring.dense_attention(q, k, v, causal=causal, seg=seg)
+
+
+def _rotary(q, k, pos, theta: float):
+    """Rotary position embedding in the rotate-half form on ``q`` and ``k``
+    ``(b, h, s, hd)`` at positions ``pos`` ``(b or 1, s)``: the pair
+    ``(x[i], x[i + hd/2])`` turns by ``pos * theta^(-2i/hd)``.  Angles and
+    the rotation are float32; the result has the inputs' dtype."""
+    hd = q.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    angle = pos.astype(jnp.float32)[:, None, :, None] * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)  # (b or 1, 1, s, hd/2)
+
+    def turn(x):
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
+    return turn(q), turn(k)
 
 
 def seq_constraint(x: jnp.ndarray, ctx: ForwardContext) -> jnp.ndarray:
@@ -243,6 +261,29 @@ class LayerNormLayer(Layer):
         return [y.astype(x.dtype)], buffers
 
 
+class RMSNormLayer(LayerNormLayer):
+    """Root-mean-square normalization over the feature axis of (b,1,s,d):
+    ``x / sqrt(mean(x^2) + eps) * g``, computed in float32.  The learned
+    gain ``g`` starts at one under the "wmat" tag; there is no bias."""
+
+    type_names = ("rmsnorm",)
+
+    def __init__(self):
+        super().__init__()
+        self.eps = 1e-6
+
+    def init_params(self, key, in_shapes, dtype=jnp.float32):
+        return {"wmat": jnp.ones((in_shapes[0][3],), dtype)}
+
+    def forward(self, params, buffers, inputs, ctx):
+        self.check_n_inputs(inputs, 1)
+        x32 = inputs[0].astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.square(x32).mean(axis=-1, keepdims=True) + self.eps)
+        y = y * params["wmat"].astype(jnp.float32)
+        return [y.astype(inputs[0].dtype)], buffers
+
+
 class SeqFullcLayer(Layer):
     """Per-position linear on the last axis: (b,1,s,d) -> (b,1,s,nhidden).
 
@@ -299,6 +340,13 @@ class AttentionLayer(Layer):
           help="label field with per-position segment ids (packed "
                "documents, io/text.py): attention is block-diagonal — "
                "cross-segment scores masked, segment 0 = padding"),
+        K("rope", "int", lo=0, hi=1,
+          help="rotary position embedding (rotate-half) on q and k, at the "
+               "positions of pos_key or 0..s-1"),
+        K("rope_theta", "float", lo=1.0, help="rotary base frequency"),
+        K("pos_key", "str",
+          help="label field with per-position ids for rope (packed "
+               "documents restart at 0); empty or absent = 0..s-1"),
     )
 
     def __init__(self):
@@ -306,6 +354,9 @@ class AttentionLayer(Layer):
         self.nhead = 0
         self.causal = 0
         self.segment_key = ""
+        self.rope = 0
+        self.rope_theta = 10000.0
+        self.pos_key = ""
 
     def set_param(self, name, val):
         if name == "nhead":
@@ -314,6 +365,12 @@ class AttentionLayer(Layer):
             self.causal = int(val)
         elif name == "segment_key":
             self.segment_key = val
+        elif name == "rope":
+            self.rope = int(val)
+        elif name == "rope_theta":
+            self.rope_theta = float(val)
+        elif name == "pos_key":
+            self.pos_key = val
         else:
             super().set_param(name, val)
 
@@ -349,6 +406,11 @@ class AttentionLayer(Layer):
         qkv = qkv.reshape(b, s, 3, h, hd).transpose(2, 0, 3, 1, 4)
         q, k, v = qkv[0], qkv[1], qkv[2]  # (b, h, s, hd)
         dec = getattr(ctx, "decode", None)
+        if self.rope:
+            assert dec is None, "attention: rope = 1 has no decode cache path"
+            pos = _label_field(ctx, self.pos_key)
+            q, k = _rotary(q, k, jnp.arange(s)[None] if pos is None else pos,
+                           self.rope_theta)
         if dec is not None:
             att = self._decode_attention(dec, q, k, v)
             att = att.transpose(0, 2, 1, 3).reshape(b, 1, s, d)
@@ -511,3 +573,141 @@ class SoftmaxSeqLayer(LossLayerBase):
                 per_inst = per_inst * ctx.labels.mask.astype(per_inst.dtype)
             ctx.losses.append(per_inst.sum() * (self.grad_scale * ctx.loss_scale))
         return [out], buffers
+
+
+def _valid_targets(ctx: ForwardContext, target: str, packed: int):
+    """The ``(b, s)`` target ids as int32 and, under ``packed``, the float32
+    mask of positions that are scored (ids >= 0); ``None`` for all."""
+    y = ctx.labels.get(target)
+    return y.astype(jnp.int32), \
+        (y >= 0).astype(jnp.float32) if packed else None
+
+
+class SeqXentLayer(Layer):
+    """Next-token cross-entropy a position, as a node: (b,1,s,V) logits ->
+    (b,1,s,1) float32 nats, 0 where ``packed = 1`` masks the target.
+
+    What ``softmax_seq`` adds to ``ctx.losses`` this layer leaves in the
+    graph, for a loss that is combined further (``exit_loss``) and for loop
+    bodies, which no loss term can leave.  It builds no probabilities:
+    ``logsumexp(x) - x[target]`` in float32.  Without labels (eval
+    forwards) it gives zeros."""
+
+    type_names = ("seq_xent",)
+    extra_config_keys = (
+        K("target", "str", help="label field with the (b, s) target ids"),
+        K("packed", "int", lo=0, hi=1,
+          help="target ids < 0 (packed-document boundaries, padding) give 0"),
+    )
+
+    def __init__(self):
+        super().__init__()
+        self.target = "label"
+        self.packed = 0
+
+    def set_param(self, name, val):
+        if name == "target":
+            self.target = val
+        elif name == "packed":
+            self.packed = int(val)
+        else:
+            super().set_param(name, val)
+
+    def infer_shapes(self, in_shapes: List[Shape4]) -> List[Shape4]:
+        assert len(in_shapes) == 1, "seq_xent: 1-1 connection only"
+        n, c, s, _ = in_shapes[0]
+        assert c == 1, "seq_xent: input must be (b,1,s,V) logits"
+        return [(n, 1, s, 1)]
+
+    def forward(self, params, buffers, inputs, ctx):
+        self.check_n_inputs(inputs, 1)
+        x = inputs[0][:, 0].astype(jnp.float32)  # (b, s, V)
+        if ctx.labels is None:
+            return [jnp.zeros(x.shape[:2] + (1,), jnp.float32)[:, None]], \
+                buffers
+        yi, valid = _valid_targets(ctx, self.target, self.packed)
+        picked = jnp.take_along_axis(
+            x, jnp.maximum(yi, 0)[:, :, None], axis=2)[:, :, 0]
+        nats = jax.nn.logsumexp(x, axis=-1) - picked
+        if valid is not None:
+            nats = nats * valid
+        return [nats[:, None, :, None]], buffers
+
+
+class ExitLossLayer(LossLayerBase):
+    """The loss of a looped model with an exit gate a pass.
+
+    Inputs, both (b,T,s,1) as they leave a ``loop`` of T passes: the
+    cross-entropy a pass and position (``seq_xent``) and the exit gate's
+    logit.  With ``lam_t = sigmoid(gate_t)`` the exit distribution of a
+    position is ``p_1 = lam_1``, ``p_t = lam_t prod_{j<t} (1 - lam_j)`` and
+    ``p_T = prod_{j<T} (1 - lam_j)``: it sums to one.  The loss of a
+    position is ``sum_t p_t l_t - beta H(p)`` with ``H(p) = -sum_t p_t log
+    p_t`` (Zhu et al. 2025, arXiv:2510.25741, the stage I objective); the
+    mean over a row's scored positions, then ``softmax_seq``'s scaling over
+    rows.  All in float32 and in log space, so a saturated gate gives mass
+    0 or 1 and no NaN.  The output node is the exit distribution.
+
+    Step diagnostics (``ctx.diagnostics``): ``exit_loss`` and ``exit_mass``,
+    the batch means of ``l_t`` and ``p_t`` as (T,) vectors, and
+    ``exit_entropy``, the batch mean of ``H(p)``.
+    """
+
+    type_names = ("exit_loss",)
+    extra_config_keys = (
+        K("beta", "float", lo=0.0, help="weight of the exit entropy bonus"),
+        K("packed", "int", lo=0, hi=1,
+          help="positions with target ids < 0 are left out of every mean"),
+    )
+
+    def __init__(self):
+        super().__init__()
+        self.beta = 0.1
+        self.packed = 0
+
+    def set_param(self, name, val):
+        if name == "beta":
+            self.beta = float(val)
+        elif name == "packed":
+            self.packed = int(val)
+        else:
+            super().set_param(name, val)
+
+    def infer_shapes(self, in_shapes: List[Shape4]) -> List[Shape4]:
+        assert len(in_shapes) == 2 and in_shapes[0] == in_shapes[1] \
+            and in_shapes[0][3] == 1, (
+            "exit_loss: inputs are the per-pass cross-entropy and gate "
+            f"logit, both (b,T,s,1); got {in_shapes}")
+        return [in_shapes[0]]
+
+    def forward(self, params, buffers, inputs, ctx):
+        self.check_n_inputs(inputs, 2)
+        nats, gate = (x[..., 0].astype(jnp.float32) for x in inputs)
+        log_go = jax.nn.log_sigmoid(-gate)       # log(1 - lam_t), (b, T, s)
+        went_on = jnp.cumsum(log_go, axis=1) - log_go  # sum over j < t
+        logp = jnp.concatenate(
+            [(jax.nn.log_sigmoid(gate) + went_on)[:, :-1], went_on[:, -1:]],
+            axis=1)
+        p = jnp.exp(logp)
+        if ctx.labels is not None and ctx.train:
+            entropy = -(p * logp).sum(axis=1, keepdims=True)     # (b, 1, s)
+            per_pos = (p * nats).sum(axis=1, keepdims=True) \
+                - self.beta * entropy
+            _, valid = _valid_targets(ctx, self.target, self.packed)
+            if valid is None:
+                valid = jnp.ones_like(per_pos[:, 0])
+            scored = jnp.maximum(valid.sum(axis=1), 1.0)[:, None]
+
+            def row_mean(v):  # (b, k, s) -> (b, k), over scored positions
+                return (v * valid[:, None]).sum(axis=-1) / scored
+
+            rows = 1.0 if ctx.labels.mask is None \
+                else ctx.labels.mask.astype(jnp.float32)[:, None]
+            # tail-batch padding is masked out as in LossLayerBase
+            ctx.losses.append((row_mean(per_pos) * rows).sum()
+                              * (self.grad_scale * ctx.loss_scale))
+            ctx.diagnostics.update(
+                exit_loss=(row_mean(nats) * rows).mean(axis=0),
+                exit_mass=(row_mean(p) * rows).mean(axis=0),
+                exit_entropy=(row_mean(entropy) * rows).mean())
+        return [p[..., None]], buffers

@@ -8,6 +8,8 @@ but no factory case; implemented here for real (channel-group max).
 
 from __future__ import annotations
 
+import functools
+import operator
 from typing import List
 
 import jax.numpy as jnp
@@ -113,17 +115,25 @@ class EltSumLayer(Layer):
     """
 
     type_names = ("eltsum",)
+    _combine = staticmethod(operator.add)
 
     def infer_shapes(self, in_shapes: List[Shape4]) -> List[Shape4]:
-        assert len(in_shapes) >= 2, "eltsum: needs at least 2 inputs"
+        kind = self.type_names[0]
+        assert len(in_shapes) >= 2, f"{kind}: needs at least 2 inputs"
         for s in in_shapes[1:]:
             assert s == in_shapes[0], \
-                f"eltsum: input shapes differ: {s} vs {in_shapes[0]}"
+                f"{kind}: input shapes differ: {s} vs {in_shapes[0]}"
         return [in_shapes[0]]
 
     def forward(self, params, buffers, inputs, ctx):
-        assert len(inputs) >= 2, "eltsum: needs at least 2 inputs"
-        out = inputs[0]
-        for x in inputs[1:]:
-            out = out + x
-        return [out], buffers
+        assert len(inputs) >= 2, \
+            f"{self.type_names[0]}: needs at least 2 inputs"
+        return [functools.reduce(self._combine, inputs)], buffers
+
+
+class EltMulLayer(EltSumLayer):
+    """N -> 1 elementwise product of same-shape nodes: the gate of a gated
+    feed-forward, ``silu(W_g u) * (W_u u)``."""
+
+    type_names = ("eltmul",)
+    _combine = staticmethod(operator.mul)

@@ -1013,6 +1013,7 @@ class LearnTask:
                             with clock.phase("device_wait"):
                                 loss = float(np.asarray(loss))
                         with clock.phase("record"):
+                            diags = self.net.last_diagnostics()
                             if recording:
                                 cut, step_mark = clock.cut(step_mark)
                                 rec = dict(
@@ -1034,6 +1035,7 @@ class LearnTask:
                                     # pipelined step: ledger carves the
                                     # fill/drain share out of dispatch
                                     rec["pipe_bubble_frac"] = round(bub, 4)
+                                rec.update(diags)
                                 metrics.emit("step", **rec)
                                 if bank is not None:
                                     bank.observe_step(rec)
@@ -1043,7 +1045,7 @@ class LearnTask:
                                 f"round {self.start_counter - 1:8d}:"
                                 f"[{sample_counter:8d}] {int(now - start)} "
                                 f"sec elapsed, {rate:.1f} examples/sec")
-                            self._report_diagnostics()
+                            self._report_diagnostics(diags)
                     clock.dispatch += 1  # the next unit of work
                 with clock.phase("round_boundary"):
                     if prof.round_end():
@@ -1225,6 +1227,7 @@ class LearnTask:
                                   f"synth-device {k} steps, {rate:.1f} "
                                   "examples/sec")
                         rec["examples_per_sec"] = round(rate, 1)
+                    rec.update(net.last_diagnostics())
                     net.metrics.emit("step", **rec)
                 with clock.phase("round_boundary"):
                     self._save_model()
@@ -1279,18 +1282,20 @@ class LearnTask:
                 for_eval=True)
         return self._pred_prefetcher
 
-    def _report_diagnostics(self) -> None:
+    def _report_diagnostics(self, diags) -> None:
         """Print step diagnostics (pairtest fwd/bwd/weight relative errors),
         flagging values over the reference's 1e-5 threshold the way the
         reference prints exceedances to stderr
         (pairtest_layer-inl.hpp:190-196)."""
-        diags = getattr(self.net, "_last_diags", None)
         if not diags:
             return
         from .layers.pairtest import PAIRTEST_RTOL
         parts, bad = [], []
         for k in sorted(diags):
-            v = float(np.asarray(diags[k]))
+            v = diags[k]
+            if isinstance(v, list):  # a value a pass (exit_loss)
+                parts.append(f"{k}=[" + " ".join(f"{x:.3g}" for x in v) + "]")
+                continue
             parts.append(f"{k}={v:.3g}")
             if k.endswith("_rel_err") and not v <= PAIRTEST_RTOL:
                 bad.append(f"{k}: err={v:g} exceeds {PAIRTEST_RTOL:g}")

@@ -345,6 +345,91 @@ def transformer(vocab: int, seq: int, dim: int, nlayer: int,
     return "\n".join(lines) + "\n"
 
 
+def looped_lm(vocab: int, seq: int, dim: int, nlayer: int, nhead: int,
+              ffn: int, passes: int = 4, packed: bool = False,
+              rope_theta: float = 1e6, eps: float = 1e-6,
+              beta: float = 0.1) -> str:
+    """Looped decoder-only LM (Ouro; Zhu et al. 2025, arXiv:2510.25741): ONE
+    stack of ``nlayer`` blocks that runs ``passes`` times on its own output,
+    with the output head, a cross-entropy and an exit gate at every pass.
+
+    ``h0 = E[tokens]``; a pass is the ``nlayer`` blocks and a final rmsnorm;
+    a block is ``a = x + rmsnorm(attn(rmsnorm(x)))``, ``y = a +
+    rmsnorm(W_d(silu(W_g u) * (W_u u)))`` with ``u = rmsnorm(a)`` (sandwich
+    norms), attention without biases and with rotary positions.  The loop
+    leaves the per-pass cross-entropy ``ce`` and gate logit ``gate`` as
+    (b, passes, s, 1) nodes, and ``exit_loss`` weighs the passes by the
+    gate's exit distribution.  ``packed`` as in :func:`transformer`:
+    segment-masked attention, rotary positions from the ``position`` field,
+    boundary targets left out of the loss.
+    """
+    packed_att = ["  segment_key = segment", "  pos_key = position"] \
+        if packed else []
+    packed_loss = ["  packed = 1"] if packed else []
+    lines = ["netconfig=start",
+             "layer[0->x0] = embedding:embed",
+             f"  vocab_size = {vocab}",
+             f"  nhidden = {dim}",
+             "  init_sigma = 0.02",
+             f"loop[x0->h] = {passes}"]
+    for i in range(nlayer):
+        a, m = f"b{i}a", f"b{i}m"
+        lines += [
+            f"layer[x{i}->{a}_r,{a}_in] = split",
+            f"layer[{a}_in->{a}_n] = rmsnorm:l{i}_norm1",
+            f"  eps = {eps}",
+            f"layer[{a}_n->{a}_o] = attention:l{i}_att",
+            f"  nhead = {nhead}",
+            "  causal = 1",
+            "  no_bias = 1",
+            "  rope = 1",
+            f"  rope_theta = {rope_theta}",
+            *packed_att,
+            f"layer[+0] = rmsnorm:l{i}_norm2",
+            f"  eps = {eps}",
+            f"layer[{a}_r,{a}_o->{m}] = eltsum",
+            f"layer[{m}->{m}_r,{m}_in] = split",
+            f"layer[{m}_in->{m}_n] = rmsnorm:l{i}_norm3",
+            f"  eps = {eps}",
+            f"layer[{m}_n->{m}_n1,{m}_n2] = split",
+            f"layer[{m}_n1->{m}_g] = seq_fullc:l{i}_ffn_gate",
+            f"  nhidden = {ffn}",
+            "  no_bias = 1",
+            "layer[+0] = silu",
+            f"layer[{m}_n2->{m}_u] = seq_fullc:l{i}_ffn_up",
+            f"  nhidden = {ffn}",
+            "  no_bias = 1",
+            f"layer[{m}_g,{m}_u->{m}_h] = eltmul",
+            f"layer[{m}_h->{m}_o] = seq_fullc:l{i}_ffn_down",
+            f"  nhidden = {dim}",
+            "  no_bias = 1",
+            f"layer[+0] = rmsnorm:l{i}_norm4",
+            f"  eps = {eps}",
+            f"layer[{m}_r,{m}_o->x{i + 1}] = eltsum",
+        ]
+    lines += [f"layer[x{nlayer}->fin] = rmsnorm:final_norm",
+              f"  eps = {eps}",
+              "layer[fin->h,fin_h,fin_g] = split",
+              "layer[fin_h->logits] = seq_fullc:head",
+              f"  nhidden = {vocab}",
+              "  no_bias = 1",
+              "layer[logits->ce] = seq_xent",
+              *packed_loss,
+              "layer[fin_g->gate] = seq_fullc:exit_gate",
+              "  nhidden = 1",
+              "loop = end",
+              "layer[ce,gate->exit] = exit_loss",
+              f"  beta = {beta}",
+              *packed_loss,
+              "netconfig=end",
+              f"input_shape = 1,1,{seq}",
+              f"label_vec[0,{seq}) = label"]
+    if packed:
+        lines += [f"label_vec[{seq},{2 * seq}) = segment",
+                  f"label_vec[{2 * seq},{3 * seq}) = position"]
+    return "\n".join(lines) + "\n"
+
+
 def _res_block(lines: List[str], name: str, bottom: str, w: int,
                stride: int, project: bool) -> str:
     """Basic residual block: two 3x3 conv+bn with an identity (or 1x1

@@ -10,6 +10,12 @@ neural_net-inl.hpp:148).
 
 Layer sharing (``share[tag]``) reuses the primary connection's layer instance
 and parameter group, reproducing kSharedLayer (neural_net-inl.hpp:238-244).
+
+A ``loop[a->b] = T`` body (``netconfig.LoopInfo``) runs as ONE ``lax.scan``
+over the passes with the weights closed over, so the body is traced and
+compiled once whatever ``T``, and each pass is a ``jax.checkpoint``: the
+backward pass keeps only what a pass read and recomputes the rest, one pass
+at a time (:meth:`Network._forward_loop`).
 """
 
 from __future__ import annotations
@@ -50,6 +56,10 @@ class Network:
         self.connections: List[Connection] = []
         self.node_shapes: List[Optional[Shape4]] = [None] * cfg.num_nodes
         self._build()
+        # per loop, the body's nodes that layers after it read: they leave
+        # the loop as every pass's value, concatenated over channels
+        self.loop_outs: Dict[int, List[int]] = {
+            loop.start: self._loop_outs(loop) for loop in cfg.loops}
         self._infer_shapes()
 
     # -- construction -----------------------------------------------------
@@ -84,6 +94,14 @@ class Network:
                 nindex_out=list(info.nindex_out),
                 param_key=self._layer_key(i, info), owns_params=True))
 
+    def _loop_outs(self, loop) -> List[int]:
+        written = dict.fromkeys(
+            n for c in self.connections[loop.start:loop.end]
+            for n in c.nindex_out if n != loop.write)
+        read_after = {n for c in self.connections[loop.end:]
+                      for n in c.nindex_in}
+        return [n for n in written if n in read_after]
+
     def _infer_shapes(self) -> None:
         cfg = self.cfg
         assert cfg.input_shape is not None, "input_shape must be configured"
@@ -92,7 +110,8 @@ class Network:
         for i in range(cfg.extra_data_num):
             ec, ey, ex = cfg.extra_shape[3 * i: 3 * i + 3]
             self.node_shapes[1 + i] = (self.batch_size, ec, ey, ex)
-        for conn in self.connections:
+        last_of = {loop.end - 1: loop for loop in cfg.loops}
+        for i, conn in enumerate(self.connections):
             in_shapes = []
             for nid in conn.nindex_in:
                 assert self.node_shapes[nid] is not None, (
@@ -104,6 +123,19 @@ class Network:
                 f"outputs for {len(conn.nindex_out)} output nodes")
             for nid, s in zip(conn.nindex_out, out_shapes):
                 self.node_shapes[nid] = s
+            if i in last_of:
+                self._close_loop_shapes(last_of[i])
+
+    def _close_loop_shapes(self, loop) -> None:
+        names = self.cfg.node_names
+        assert self.node_shapes[loop.write] == self.node_shapes[loop.read], (
+            f"loop[{names[loop.read]}->{names[loop.write]}]: the node the "
+            f"body writes has shape {self.node_shapes[loop.write]}, the node "
+            f"it reads {self.node_shapes[loop.read]}; the next pass could "
+            "not read it")
+        for nid in self.loop_outs[loop.start]:
+            n, c, y, x = self.node_shapes[nid]
+            self.node_shapes[nid] = (n, c * loop.count, y, x)
 
     # -- state ------------------------------------------------------------
     def init_params(self, key: jax.Array) -> Params:
@@ -156,18 +188,78 @@ class Network:
         engine uses it to read raw LM-head logits without running the
         softmax_seq self-loop that would rebind the logits node.
         """
-        from .. import engine
-        from ..layers.base import conn_scope_name, materialize
         nodes: List[Optional[jnp.ndarray]] = [None] * self.cfg.num_nodes
         for nid, v in inputs.items():
             nodes[nid] = self.cast_input(nid, v)
         new_buffers = dict(buffers)
+        end = len(self.connections) if until is None else until
+        at = 0
+        for loop in self.cfg.loops:
+            if loop.start >= end:
+                break
+            assert loop.end <= end, "forward(until=) cannot stop inside a loop"
+            self._forward_span(at, loop.start, params, new_buffers, nodes, ctx)
+            self._forward_loop(loop, params, new_buffers, nodes, ctx)
+            at = loop.end
+        self._forward_span(at, end, params, new_buffers, nodes, ctx)
+        return nodes, new_buffers
+
+    def _forward_loop(self, loop, params, buffers, nodes, ctx) -> None:
+        """Run the loop's body ``loop.count`` times as one ``lax.scan``.
+
+        The carry is the value of ``loop.read``; params, labels and the
+        nodes computed before the loop are closed over, so the scan holds
+        the body once and autodiff sums a weight's gradient over its uses.
+        Each pass is a ``jax.checkpoint``: differentiated, the forward scan
+        saves the carries and the backward scan recomputes one pass (with
+        whatever head and loss layers the body holds) before it transposes
+        it, so one pass's activations live at a time.  The per-pass values of
+        ``loop_outs`` leave as scan outputs; a body layer may not write to
+        ``ctx.losses`` or ``ctx.diagnostics``, whose entries could not leave
+        the traced body."""
+        outs = self.loop_outs[loop.start]
+
+        def one_pass(carry, t):
+            with jax.named_scope("pass"):
+                local = list(nodes)
+                local[loop.read] = carry
+                body_ctx = dataclasses.replace(
+                    ctx, losses=[], diagnostics={},
+                    rng=None if ctx.rng is None
+                    else jax.random.fold_in(ctx.rng, t))
+                body_buffers = dict(buffers)
+                self._forward_span(loop.start, loop.end, params,
+                                   body_buffers, local, body_ctx)
+                assert not body_ctx.losses and not body_ctx.diagnostics \
+                    and all(body_buffers[k] is buffers.get(k)
+                            for k in body_buffers), (
+                    "a layer inside a loop body left a loss term, a "
+                    "diagnostic or a buffer update; inside a loop a loss "
+                    "layer leaves its value as a node (seq_xent) and "
+                    "layers with buffers are not supported")
+                return local[loop.write], tuple(local[n] for n in outs)
+
+        last, stacked = jax.lax.scan(jax.checkpoint(one_pass),
+                                     nodes[loop.read],
+                                     jnp.arange(loop.count))
+        nodes[loop.write] = last
+        for n, v in zip(outs, stacked):
+            # (T, b, c, y, x) -> (b, T * c, y, x), pass-major
+            nodes[n] = jnp.moveaxis(v, 0, 1).reshape(
+                v.shape[1], -1, *v.shape[3:])
+
+    def _forward_span(self, start: int, end: int, params: Params,
+                      new_buffers: Params, nodes, ctx) -> None:
+        """Run connections ``[start, end)`` in declaration order, binding
+        their outputs in ``nodes`` and their buffer updates in
+        ``new_buffers``."""
+        from .. import engine
+        from ..layers.base import conn_scope_name, materialize
         fuse = getattr(self, "fuse_groups", None)
         fuse_skip = getattr(self, "fuse_skip", frozenset())
         virtual = engine.opts.concat_virtual == "1"
-        for i, conn in enumerate(self.connections):
-            if until is not None and i >= until:
-                break
+        for i in range(start, end):
+            conn = self.connections[i]
             if i in fuse_skip:
                 continue
             # layer-attribution stamp: HLO op metadata (and so the
@@ -193,7 +285,6 @@ class Network:
                     new_buffers[conn.param_key] = nb
                 for n, v in zip(conn.nindex_out, outs):
                     nodes[n] = v
-        return nodes, new_buffers
 
     def _virtual_forward(self, conn, params, nodes) -> bool:
         """``concat_virtual = 1``: execute ``conn`` on virtual channel

@@ -13,7 +13,10 @@ Reference: ``src/nnet/nnet_config.h`` (Configure :207-289, GetLayerInfo
   layer until the next ``layer[..]``/``netconfig=end``);
 * ``label_vec[a,b)`` multi-label field ranges and ``extra_data_num`` /
   ``extra_data_shape[i]`` side inputs;
-* ``input_shape = c,y,x``.
+* ``input_shape = c,y,x``;
+* ``loop[a->b] = T`` ... ``loop = end``: the layers declared between the two
+  lines are a body that runs ``T`` times on its own output (no reference
+  counterpart; :class:`LoopInfo`).
 """
 
 from __future__ import annotations
@@ -39,11 +42,29 @@ class LayerInfo:
         return self.primary_layer_index >= 0
 
 
+@dataclasses.dataclass
+class LoopInfo:
+    """A run of layers ``[start, end)`` that executes ``count`` times.
+
+    The first pass reads node ``read`` as the layers before the loop left
+    it; every later pass reads there what the pass before wrote to node
+    ``write``.  After the loop ``write`` holds the last pass's value.  A
+    body layer is one layer with one parameter group, whatever ``count``.
+    """
+
+    start: int
+    end: int
+    count: int
+    read: int
+    write: int
+
+
 _LAYER_PLUS = re.compile(r"^layer\[\+(\d+)(?::([^\]]+))?\]$")
 _LAYER_ARROW = re.compile(r"^layer\[([^\]>]+)->([^\]]+)\]$")
 _LABEL_VEC = re.compile(r"^label_vec\[(\d+),(\d+)\)$")
 _EXTRA_SHAPE = re.compile(r"^extra_data_shape\[(\d+)\]$")
 _SHARE = re.compile(r"^share\[([^\]]+)\]$")
+_LOOP = re.compile(r"^loop\[([^\],>]+)->([^\],]+)\]$")
 
 
 class NetConfig:
@@ -64,6 +85,7 @@ class NetConfig:
         self.label_name_map: Dict[str, int] = {}
         self.extra_data_num: int = 0
         self.extra_shape: List[int] = []
+        self.loops: List[LoopInfo] = []
 
     # -- label field helpers ---------------------------------------------
     def label_fields(self) -> List[Tuple[str, int, int]]:
@@ -154,6 +176,35 @@ class NetConfig:
             info.name = lname
         return info
 
+    def _parse_loop_line(self, key: str, val: str, layer_index: int) -> None:
+        open_ = bool(self.loops) and self.loops[-1].end < 0
+        if key == "loop":
+            if val != "end" or not open_:
+                raise ConfigError(
+                    f"loop = {val!r}: expected 'loop = end' after an open "
+                    "loop[a->b] = T")
+            loop = self.loops[-1]
+            loop.end = layer_index
+            written = {n for info in self.layers[loop.start:]
+                       for n in info.nindex_out}
+            if loop.write not in written:
+                raise ConfigError(
+                    f"no layer of the loop's body writes its output node "
+                    f"{self.node_names[loop.write]!r}")
+            return
+        m = _LOOP.match(key)
+        if m is None or not val.isdigit() or int(val) < 1:
+            raise ConfigError(
+                f"invalid loop declaration {key} = {val}: expected "
+                "loop[read->write] = T with T >= 1")
+        if open_:
+            raise ConfigError("loops do not nest: close the open loop with "
+                              "loop = end first")
+        self.loops.append(LoopInfo(
+            start=layer_index, end=-1, count=int(val),
+            read=self._get_node_index(m.group(1), False),
+            write=self._get_node_index(m.group(2), True)))
+
     def configure(self, cfg: ConfigPairs) -> None:
         netcfg_mode = 0
         cfg_top_node = 0
@@ -195,6 +246,11 @@ class NetConfig:
             if name == "netconfig" and val == "end":
                 netcfg_mode = 0
                 continue
+            if name == "loop" or name.startswith("loop["):
+                self._parse_loop_line(name, val, cfg_layer_index)
+                # keys that follow belong to no layer
+                netcfg_mode = 1
+                continue
             if name.startswith("layer["):
                 info = self._parse_layer_line(name, val, cfg_top_node,
                                               cfg_layer_index)
@@ -216,6 +272,8 @@ class NetConfig:
                 self.layercfg[cfg_layer_index - 1].append((name, val))
             else:
                 self.defcfg.append((name, val))
+        if self.loops and self.loops[-1].end < 0:
+            raise ConfigError("loop[...] is never closed with loop = end")
         self.num_nodes = 0
         for info in self.layers:
             for j in info.nindex_in + info.nindex_out:
@@ -237,6 +295,7 @@ class NetConfig:
             "label_name_map": self.label_name_map,
             "extra_data_num": self.extra_data_num,
             "extra_shape": self.extra_shape,
+            "loops": [dataclasses.asdict(l) for l in self.loops],
         }
 
     @classmethod
@@ -255,5 +314,6 @@ class NetConfig:
         nc.label_name_map = dict(d["label_name_map"])
         nc.extra_data_num = d["extra_data_num"]
         nc.extra_shape = list(d["extra_shape"])
+        nc.loops = [LoopInfo(**l) for l in d.get("loops", [])]
         nc.num_nodes = len(nc.node_names)
         return nc

@@ -43,6 +43,7 @@ from ..monitor.metrics import MetricsRegistry, device_memory_gauges
 from ..parallel import mesh as meshlib
 from ..updater import UpdaterHyper, create_updater
 from ..utils import serializer
+from ..utils.config import ConfigError
 from ..utils.metric import MetricSet
 from .net import Network
 from .netconfig import NetConfig
@@ -363,6 +364,12 @@ class NetTrainer:
         """Everything derivable from (net, params): updaters, hypers,
         shardings, step functions, metric bindings."""
         net = self.net
+        if self.netcfg.loops and (self.remat or self._pipelined
+                                  or engine.opts.dp_overlap == "1"):
+            raise ConfigError(
+                "remat, mesh = pipe and dp_overlap cut the flat list of "
+                "layers and know no loop[...]: a loop's own unit of "
+                "recomputation is the pass, so leave them off")
         self.updater = create_updater(self.netcfg.updater_type)
         # hyper groups: per (param_key, tag); global cfg then the layer's own
         # section (reference NeuralNet::InitUpdaters ordering)
@@ -1536,19 +1543,21 @@ class NetTrainer:
             # epoch here == sample_counter-1 of the equivalent update() call,
             # which folds AFTER incrementing — hence epoch + 1
             rng = jax.random.fold_in(rng_base, epoch + 1)
-            (loss, (new_buffers, outs, _)), grads = self._loss_and_grads(
+            (loss, (new_buffers, outs, diags)), grads = self._loss_and_grads(
                 params, buffers, data, label_vec, (), epoch, rng, eval_ids)
             new_p, new_s = self._apply_update(params, opt_state, grads, epoch)
             return ((new_p, new_s, new_buffers, epoch + 1, rng_base),
-                    (loss, outs))
+                    (loss, outs, diags))
 
         def run(params, opt_state, buffers, epoch, rng_base, datas, labels):
             self.metrics.counter_inc("train_step_traces")
             carry = (params, opt_state, buffers, epoch, rng_base)
-            carry, (losses, outs) = jax.lax.scan(
+            carry, (losses, outs, diags) = jax.lax.scan(
                 body, carry, (datas, labels))
             params, opt_state, buffers, epoch, _ = carry
-            return params, opt_state, buffers, losses, outs
+            # the last step's diagnostics, as update() leaves them
+            return params, opt_state, buffers, losses, outs, \
+                jax.tree.map(lambda d: d[-1], diags)
 
         stacked = NamedSharding(self.mesh, P(None, *self.batch_shard.spec))
         fn = self.jit(
@@ -1557,7 +1566,8 @@ class NetTrainer:
                           self.buffer_shardings, self.repl, self.repl,
                           stacked, stacked),
             out_shardings=(self.param_shardings, self.opt_shardings,
-                           self.buffer_shardings, self.repl, self.repl),
+                           self.buffer_shardings, self.repl, self.repl,
+                           self.repl),
             donate_argnums=(0, 1, 2))
         self._multi_step_cache[key] = fn
         return fn
@@ -1585,14 +1595,14 @@ class NetTrainer:
         labels = self._device_stacked(labels, jnp.float32)
         k = datas.shape[0]
         fn = self._build_multi_step(k, with_outs)
-        (self.params, self.opt_state, self.buffers, losses, outs) = fn(
+        (self.params, self.opt_state, self.buffers, losses, outs,
+         self._last_diags) = fn(
             self.params, self.opt_state, self.buffers,
             jnp.int32(self.epoch_counter), self._rng_base, datas, labels)
         self.sample_counter += k
         self.epoch_counter += k
         self._last_loss = losses[-1]
         self._last_outs = None
-        self._last_diags = None
         if with_outs:
             return losses, outs
         return losses
@@ -2094,10 +2104,21 @@ class NetTrainer:
                   for name, a, b in self._label_fields}
         self.train_metric.add_eval(preds, labels)
 
+    def last_diagnostics(self) -> Dict[str, Any]:
+        """The newest step's diagnostics on the host, a float or a list of
+        floats each (``exit_loss``, ``exit_mass``, ``exit_entropy`` of an
+        ``exit_loss`` layer; a pairtest's relative errors); empty for a
+        net whose layers leave none.  Waits for that step."""
+        diags = getattr(self, "_last_diags", None) or {}
+        return {k: np.asarray(v, np.float64).tolist()
+                for k, v in diags.items()}
+
     @property
     def has_diagnostics(self) -> bool:
-        """True when any layer emits step diagnostics (pairtest); such nets
-        need the per-batch update path so _last_diags stays populated."""
+        """True when a layer's diagnostics are wanted from EVERY step
+        (pairtest); such nets need the per-batch update path.  The step
+        counters of ``exit_loss`` are read when a record is written, from
+        whichever path ran the step."""
         from ..layers.pairtest import PairTestLayer
         return any(isinstance(c.layer, PairTestLayer)
                    for c in self.net.connections)
