@@ -238,20 +238,26 @@ def mosaic_calls(net, hlo: str) -> dict:
 
 
 def check_mosaic(net, hlo: str, what: str) -> None:
-    from cxxnet_tpu.layers.sequence import AttentionLayer, LayerNormLayer
+    from cxxnet_tpu.layers.sequence import (AttentionLayer, LayerNormLayer,
+                                            RMSNormLayer)
     counts = mosaic_calls(net, hlo)
-    n_att = sum(isinstance(c.layer, AttentionLayer)
-                for c in net.net.connections)
-    n_ln = sum(isinstance(c.layer, LayerNormLayer)
-               for c in net.net.connections)
+    # by the layer's own type, as ``mosaic_calls`` names it: an rmsnorm is
+    # a LayerNormLayer by inheritance and has kernels of its own
+    n_att, n_ln, n_rms = (sum(type(c.layer) is kind
+                              for c in net.net.connections)
+                          for kind in (AttentionLayer, LayerNormLayer,
+                                       RMSNormLayer))
     say(f"{what}: {sum(counts.values())} tpu_custom_call ops: " + ", ".join(
         f"{k[0]}/{k[1]}={v}" for k, v in sorted(counts.items())))
     # per attention layer: one forward kernel, two backward (dq, dk/dv);
-    # per LayerNorm: one forward, one backward
+    # per LayerNorm or RMSNorm: one forward, one backward (outside a loop:
+    # a loop's backward scan holds the recomputed forward too)
     want = {("AttentionLayer", "fwd"): n_att,
             ("AttentionLayer", "bwd"): 2 * n_att,
             ("LayerNormLayer", "fwd"): n_ln,
-            ("LayerNormLayer", "bwd"): n_ln}
+            ("LayerNormLayer", "bwd"): n_ln,
+            ("RMSNormLayer", "fwd"): n_rms,
+            ("RMSNormLayer", "bwd"): n_rms}
     for key, n in want.items():
         check(counts.get(key, 0) == n,
               f"{what}: {counts.get(key, 0)} Mosaic calls for {key}, "
@@ -262,9 +268,10 @@ def check_mosaic(net, hlo: str, what: str) -> None:
 def check_kernel_parity(dry: bool) -> None:
     """Each default-on Pallas kernel against its reference lowering, on
     this device, forward and gradients, at the flagship's block geometry
-    (s4096, head 128; LayerNorm rows of 2048).  bfloat16 in and out, so
-    the bound is a few bfloat16 roundings of the largest reference value;
-    a kernel computing in a narrower type, or masking wrongly, exceeds it."""
+    (s4096, head 128; LayerNorm and RMSNorm rows of 2048).  bfloat16 in and
+    out, so the bound is a few bfloat16 roundings of the largest reference
+    value; a kernel computing in a narrower type, or masking wrongly,
+    exceeds it."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -289,6 +296,23 @@ def check_kernel_parity(dry: bool) -> None:
         return (y * gamma.astype(jnp.float32)
                 + beta.astype(jnp.float32)).astype(x.dtype)
 
+    def rms_ref(x, gain):
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(jnp.square(x32).mean(-1, keepdims=True)
+                                + 1e-6)
+        return (y * gain.astype(jnp.float32)).astype(x.dtype)
+
+    def looped(norm):
+        # the looped net's form (nnet/net.py::_forward_loop): the gain closed
+        # over by a scan of checkpointed passes, dg the sum of the passes'
+        def run(x, gain):
+            one_pass = jax.checkpoint(lambda h, _: (h + norm(h, gain), None))
+            return jax.lax.scan(one_pass, x, None, length=4)[0]
+        return run
+
+    def rms(x, gain):
+        return pk.rmsnorm_pallas(x, gain, 1e-6)
+
     cases = [
         ("flash causal", (q, k, v), g,
          lambda q, k, v: pk.flash_attention(q, k, v, True),
@@ -299,6 +323,10 @@ def check_kernel_parity(dry: bool) -> None:
                                               seg=seg)),
         ("layernorm", (x, gamma, beta), dy,
          lambda x, a, b: pk.layernorm_pallas(x, a, b, 1e-5), ln_ref),
+        # the looped cell's norm: bfloat16 rows under a float32 gain
+        ("rmsnorm", (x, gamma.astype(jnp.float32)), dy, rms, rms_ref),
+        ("rmsnorm in 4 checkpointed passes",
+         (x, gamma.astype(jnp.float32)), dy, looped(rms), looped(rms_ref)),
     ]
     def forward_and_grads(f):
         # operands are jit arguments, not closed-over constants (those

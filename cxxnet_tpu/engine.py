@@ -70,7 +70,9 @@ one mid-run does not retrace already-compiled steps.
 |             |                            | d2048 flagship by 0.8G).       |
 |             |                            | "x" = input-saving backward    |
 |             |                            | (precision escape hatch, pins  |
-|             |                            | x).  See doc/pallas_ln.md      |
+|             |                            | x).  rmsnorm takes kernels of  |
+|             |                            | its own that save x, for 1 and |
+|             |                            | x alike.  See doc/pallas_ln.md |
 | fused_update| 0 (default), 1             | one-sweep Pallas adam step for |
 |             |                            | big bf16-master tensors: folds |
 |             |                            | the bf16->f32 grad convert and |

@@ -371,10 +371,18 @@ class Layer:
     # the declared-key registry (analysis/registry.py) harvests these;
     # keep them in sync with the set_param branches
     extra_config_keys: Tuple[KeySpec, ...] = ()
+    # whether a training forward of this layer took a Pallas kernel: a note
+    # of the trace, not configuration (NetTrainer.pallas_sites counts them)
+    pallas_site: bool = False
 
     def __init__(self) -> None:
         self.param = LayerParam()
         self.name: str = ""
+
+    def note_pallas(self, ctx: "ForwardContext") -> None:
+        """Called at trace time by a forward that takes a Pallas kernel."""
+        if ctx.train:
+            self.pallas_site = True
 
     # -- configuration ----------------------------------------------------
     def set_param(self, name: str, val: str) -> None:

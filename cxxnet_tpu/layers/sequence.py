@@ -49,7 +49,7 @@ def _label_field(ctx: ForwardContext, name: str):
     return ctx.labels.fields[name]
 
 
-def _single_device_attention(q, k, v, causal: bool, seg=None):
+def _single_device_attention(q, k, v, causal: bool, seg=None, on_flash=None):
     """Single-device attention dispatch: the Pallas flash kernel on TPU
     (VMEM-resident scores; measured 3.2x the XLA chunked path forward at
     s=8192 on v5e, and the only path whose backward fits at that length),
@@ -57,17 +57,18 @@ def _single_device_attention(q, k, v, causal: bool, seg=None):
     CXXNET_NO_FLASH_ATTN=1) opts out.  ``seg`` (b, s) segment ids select
     the segment-masked variants (packed documents): the triangular-flash
     segment kernel where the grid allows, the lax fallback elsewhere —
-    the two are pairtested in interpret mode (tests/test_text.py)."""
+    the two are pairtested in interpret mode (tests/test_text.py).
+    ``on_flash`` is called where a flash kernel is taken."""
     from ..engine import on_tpu, opts
     from ..ops import pallas_kernels as pk
     s, hd = q.shape[2], q.shape[3]
     if (on_tpu() and pk.flash_attention_available(s, hd)
-            and opts.flash_attn == "1"):
+            and opts.flash_attn == "1" and (seg is None or causal)):
+        if on_flash is not None:
+            on_flash()
         if seg is not None:
-            if causal:
-                return pk.flash_attention_segmented(q, k, v, seg)
-        else:
-            return pk.flash_attention(q, k, v, causal)
+            return pk.flash_attention_segmented(q, k, v, seg)
+        return pk.flash_attention(q, k, v, causal)
     return ring.dense_attention(q, k, v, causal=causal, seg=seg)
 
 
@@ -227,6 +228,7 @@ class LayerNormLayer(Layer):
         from ..ops import pallas_kernels as pk
         if (on_tpu() and opts.pallas_ln in ("1", "x")  # default-on (r6)
                 and pk.layernorm_pallas_supported(rows, d)):
+            self.note_pallas(ctx)
             # single-sweep Pallas kernel: the XLA lowering left
             # ~1.9 ms/site convert_reduce fusions in the d2048 step
             # (47.9 ms over 25 sites vs 0.094 ms standalone — the fusion
@@ -277,11 +279,24 @@ class RMSNormLayer(LayerNormLayer):
 
     def forward(self, params, buffers, inputs, ctx):
         self.check_n_inputs(inputs, 1)
-        x32 = inputs[0].astype(jnp.float32)
+        x = inputs[0]
+        rows, d = x.size // x.shape[-1], x.shape[-1]
+        from ..engine import on_tpu, opts
+        from ..ops import pallas_kernels as pk
+        if (on_tpu() and opts.pallas_ln != "0"
+                and pk.rmsnorm_pallas_supported(rows, d)):
+            # single-sweep Pallas kernels that save the input, as autodiff
+            # of the lines below does (pallas_ln = 1 and x mean the same
+            # here; 0 restores the lines): doc/pallas_ln.md
+            self.note_pallas(ctx)
+            y = pk.rmsnorm_pallas(x.reshape(rows, d), params["wmat"],
+                                  self.eps)
+            return [y.reshape(x.shape)], buffers
+        x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(
             jnp.square(x32).mean(axis=-1, keepdims=True) + self.eps)
         y = y * params["wmat"].astype(jnp.float32)
-        return [y.astype(inputs[0].dtype)], buffers
+        return [y.astype(x.dtype)], buffers
 
 
 class SeqFullcLayer(Layer):
@@ -433,8 +448,9 @@ class AttentionLayer(Layer):
                     f"seq mesh axis ({mesh.shape['seq']}); falling back to "
                     "dense attention, which gathers the full sequence on "
                     "one device", stacklevel=2)
-            att = _single_device_attention(q, k, v, bool(self.causal),
-                                           seg=seg)
+            att = _single_device_attention(
+                q, k, v, bool(self.causal), seg=seg,
+                on_flash=lambda: self.note_pallas(ctx))
         att = att.transpose(0, 2, 1, 3).reshape(b, 1, s, d)
         out = jnp.einsum("bcsd,nd->bcsn", att, params["wout"].astype(x.dtype))
         if "bout" in params:

@@ -1133,7 +1133,8 @@ class LearnTask:
         loop's ``compile`` phase): one ``compile`` record."""
         self.compile_sec = seconds
         self.net.metrics.emit("compile", compile_sec=round(seconds, 3),
-                              round=self.start_counter - 1)
+                              round=self.start_counter - 1,
+                              pallas_sites=self.net.pallas_sites())
         mlog.info(f"compile: {seconds:.1f} sec (first dispatch, excluded "
                   "from examples/sec)")
 

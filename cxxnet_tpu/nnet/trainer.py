@@ -23,6 +23,7 @@ Capability mapping:
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from functools import partial, wraps
@@ -1924,6 +1925,17 @@ class NetTrainer:
         from ..layers.base import conn_scope_name
         return [conn_scope_name(i, c)
                 for i, c in enumerate(self.net.connections)]
+
+    def pallas_sites(self) -> Dict[str, int]:
+        """Distinct layers whose training forward took a Pallas kernel in
+        the traces so far, counted by layer type (``{"rmsnorm": 33,
+        "attention": 8}``): names, not calls, because a looped,
+        checkpointed body is traced more than once.  The sequence stack's
+        default-on kernels report (attention, layernorm, rmsnorm); the
+        kernels ``ops/nn.py`` takes by engine option do not."""
+        kinds = {c.param_key: c.layer.type_names[0]
+                 for c in self.net.connections if c.layer.pallas_site}
+        return dict(sorted(collections.Counter(kinds.values()).items()))
 
     def step_hlo_text(self) -> Optional[str]:
         """Optimized-HLO text of the compiled train step (AOT-lowered

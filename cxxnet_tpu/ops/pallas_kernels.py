@@ -1795,6 +1795,125 @@ layernorm_pallas.defvjp(_ln_fwd_res, _ln_bwd_res)
 
 
 # --------------------------------------------------------------------------
+# RMSNorm over the minor axis: ``y = x * rsqrt(mean(x^2) + eps) * g``, the
+# same single row-block sweeps as LayerNorm above, with kernel bodies of its
+# own: there is no mean and no bias, and the residual contract is the other
+# one.  XLA makes of the layer's four jnp lines the epilogue (row statistic)
+# of the matmul before a norm and the prologue (x * rstd * g) of the matmul
+# after it; with the norms as calls of their own (396 a step in the looped
+# model, under 10 ms of 553) those matmuls run plain and the step is 18 ms
+# shorter (PERF.md section 6, PR 29).  Row blocks are LayerNorm's
+# (``_ln_rows``): a rule of its own bought nothing in the step and did not
+# compile for float32 rows (doc/pallas_ln.md).
+#
+# Residuals are ``(x, gain, rstd)``: the INPUT, never the output.  Autodiff
+# of the jnp lines keeps exactly ``x`` for the backward, so the kernel pins
+# the bytes the XLA path pins, and the backward is the XLA path's float32
+# mathematics in another summation order:
+#
+#     xhat = x * rstd;  dyg = dy * g;  c = mean_d(dyg * xhat)
+#     dx = rstd * (dyg - xhat * c);    dg = sum_rows(dy * xhat)
+#
+# LayerNorm's output-derived rebuild (xhat from the stored-dtype y) is a
+# trade for a 24-layer stack's memory and has no place here.  ``dg``
+# accumulates over the sequential grid in a float32 scratch that program 0
+# of every call zeroes; summing a shared gain's gradient over the passes of
+# a loop is autodiff's business, outside the kernel.
+
+
+def _rms_fwd_kernel(x_ref, g_ref, y_ref, r_ref, *, eps):
+    x = x_ref[...].astype(jnp.float32)
+    rstd = jax.lax.rsqrt(jnp.square(x).mean(axis=1, keepdims=True) + eps)
+    y_ref[...] = (x * rstd * g_ref[...].astype(jnp.float32)
+                  ).astype(y_ref.dtype)
+    r_ref[...] = rstd
+
+
+def _rms_bwd_kernel(x_ref, g_ref, r_ref, dy_ref, dx_ref, dg_ref, dg_acc):
+    i = pl.program_id(0)
+    rstd = r_ref[...]
+    xhat = x_ref[...].astype(jnp.float32) * rstd
+    dy = dy_ref[...].astype(jnp.float32)
+    dyg = dy * g_ref[...].astype(jnp.float32)
+    c = (dyg * xhat).mean(axis=1, keepdims=True)
+    dx_ref[...] = (rstd * (dyg - xhat * c)).astype(dx_ref.dtype)
+
+    @pl.when(i == 0)
+    def _():
+        dg_acc[...] = jnp.zeros_like(dg_acc)
+    dg_acc[...] += jnp.sum(dy * xhat, axis=0, keepdims=True)
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        dg_ref[...] = dg_acc[...]
+
+
+def rmsnorm_pallas_supported(rows: int, d: int) -> bool:
+    # geometry only, and LayerNorm's: the same row blocks (``_ln_rows``)
+    # under the same budget, whatever x's dtype
+    return layernorm_pallas_supported(rows, d)
+
+
+@functools.lru_cache(maxsize=None)
+def _rms_call(kernel, rows, d, dtype, eps, interpret):
+    """The pallas_call of one RMSNorm kernel ("fwd", or "bwd", which has no
+    use for ``eps``), cached by shape as ``_fa_tri_call`` is: the 33 norm
+    sites of a looped model, each traced for the forward, the recomputation
+    and the backward, go through two wrappers and trace each kernel body
+    once."""
+    rb = _ln_rows(rows, d)
+    row_spec, vec_spec, stat_spec = _ln_specs(rows, d, rb)
+    x = jax.ShapeDtypeStruct((rows, d), dtype)
+    stat = jax.ShapeDtypeStruct((rows, 1), jnp.float32)
+    if kernel == "fwd":
+        return pl.pallas_call(
+            functools.partial(_rms_fwd_kernel, eps=eps),
+            grid=(rows // rb,), in_specs=[row_spec, vec_spec],
+            out_specs=[row_spec, stat_spec], out_shape=[x, stat],
+            interpret=interpret)
+    return pl.pallas_call(
+        _rms_bwd_kernel, grid=(rows // rb,),
+        in_specs=[row_spec, vec_spec, stat_spec, row_spec],
+        out_specs=[row_spec, vec_spec],
+        out_shape=[x, jax.ShapeDtypeStruct((1, d), jnp.float32)],
+        scratch_shapes=_scratch((1, d)), interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def rmsnorm_pallas(x, gain, eps: float = 1e-6, interpret: bool = None):
+    """(rows, d) RMSNorm over axis 1; gain (d,), of any float dtype.  All
+    arithmetic is float32; ``y`` and ``dx`` have ``x``'s dtype, ``dg`` the
+    gain's.  Gate with :func:`rmsnorm_pallas_supported`."""
+    return _rms_fwd_res(x, gain, eps, interpret)[0]
+
+
+def _rms_fwd_res(x, gain, eps, interpret):
+    if interpret is None:
+        interpret = not on_tpu()
+    rows, d = x.shape
+    assert rmsnorm_pallas_supported(rows, d), (
+        f"rmsnorm_pallas: ({rows}, {d}) does not divide into row blocks "
+        "(tail rows would be left unwritten); gate with "
+        "rmsnorm_pallas_supported()")
+    y, rstd = _rms_call("fwd", rows, d, x.dtype, float(eps), interpret)(
+        x, gain.reshape(1, d))
+    return y, (x, gain, rstd)
+
+
+def _rms_bwd_res(eps, interpret, res, dy):
+    x, gain, rstd = res
+    if interpret is None:
+        interpret = not on_tpu()
+    rows, d = x.shape
+    dx, dg = _rms_call("bwd", rows, d, x.dtype, None, interpret)(
+        x, gain.reshape(1, d), rstd, dy)
+    return dx, dg.reshape(d).astype(gain.dtype)
+
+
+rmsnorm_pallas.defvjp(_rms_fwd_res, _rms_bwd_res)
+
+
+# --------------------------------------------------------------------------
 # Fused master-weight adam update.  The round-5 transformer per-op table
 # charges ~47.5 ms/step to convert_reduce fusions: XLA materializes the
 # f32 cast of each bf16 weight-grad to HBM before the adam fusion reads

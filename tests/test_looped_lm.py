@@ -289,6 +289,88 @@ def test_the_body_is_traced_once_whatever_the_count():
     assert matmuls(2) == matmuls(4) == matmuls(8)
 
 
+# ------------------------------------------------------ the rmsnorm kernels
+
+KERNEL_SIZES = dict(SIZES, dim=128, ffn=160)   # d on the lane width; 48 rows
+
+
+def on_an_emulated_tpu(monkeypatch):
+    """The layers believe the step runs on a TPU (``engine.on_tpu``, read at
+    trace time); the kernels still see the CPU and run interpreted.  At
+    s 24 attention has no flash kernel, so rmsnorm alone changes path.
+    Returns the list of the shapes ``rmsnorm_pallas`` was called with."""
+    import cxxnet_tpu.engine as engine
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(engine, "on_tpu", lambda: True)
+    calls, real = [], pk.rmsnorm_pallas
+
+    def spy(x, g, eps, interpret=None):
+        calls.append(x.shape)
+        return real(x, g, eps, interpret)
+    monkeypatch.setattr(pk, "rmsnorm_pallas", spy)
+    return calls
+
+
+def test_the_looped_net_with_the_rmsnorm_kernels_is_the_net_without(
+        monkeypatch):
+    """Loss, per-pass losses, exit masses and every gradient (the norms'
+    gains are shared by four passes) with the Pallas kernels against the
+    same net under ``pallas_ln = 0``, float32, to the 2e-4 shared-weight
+    gradients are held to here; and which layers took a kernel."""
+    import cxxnet_tpu.engine as engine
+    calls = on_an_emulated_tpu(monkeypatch)
+    data, label = packed_batch(seed=7)
+    text = looped_lm(**KERNEL_SIZES, passes=4, packed=True)
+    t = make_trainer(text)
+    assert t.pallas_sites() == {}
+    loss, diags, grads = system_loss_and_grads(t, data, label)
+    sites = 4 * LAYERS + 1
+    assert calls and set(calls) == {(B * S, 128)} and len(calls) >= sites
+    assert t.pallas_sites() == {"rmsnorm": sites}
+    del calls[:]
+    monkeypatch.setattr(engine.opts, "pallas_ln", "0")
+    plain = make_trainer(text)
+    want, want_diags, want_grads = system_loss_and_grads(plain, data, label)
+    assert calls == [] and plain.pallas_sites() == {}
+    assert loss == pytest.approx(want, abs=2e-5)
+    np.testing.assert_allclose(diags["exit_loss"], want_diags["exit_loss"],
+                               atol=2e-5)
+    np.testing.assert_allclose(diags["exit_mass"], want_diags["exit_mass"],
+                               atol=2e-6)
+    assert_grads_close(grads, want_grads)
+
+
+def test_compile_record_counts_the_layers_that_took_a_kernel(
+        tmp_path, monkeypatch):
+    """``pallas_sites`` on the ``compile`` record: layers by type, not calls
+    (the body is traced for the forward scan, the recomputation and the
+    transpose); empty where no kernel is taken."""
+    from benchmark.lib import corpus
+    from cxxnet_tpu.main import LearnTask
+    prefix = str(tmp_path / "train_%d.tok")
+    corpus.make(0, V, dict(law="zipf_markov", docs=60, mean_len=12,
+                           max_len=S, shards=2), prefix)
+    conf = str(tmp_path / "net.conf")
+    with open(conf, "w") as f:
+        f.write(f"data = train\niter = text\n  path_tok = {prefix}\n"
+                f"  tok_count = 2\niter = packseq\n  seqlen = {S}\n"
+                "iter = end\n"
+                + looped_lm(**KERNEL_SIZES, passes=4, packed=True)
+                + f"\nbatch_size = {B}\ndev = cpu\nupdater = adam\n"
+                "eta = 0.001\nnum_round = 1\nmax_round = 1\n"
+                "save_model = 0\neval_train = 0\nsilent = 1\n")
+    want = {}
+    for name in ("plain", "kernels"):
+        sink = str(tmp_path / f"{name}.jsonl")
+        task = LearnTask()
+        assert task.run([conf, f"metrics_sink=jsonl:{sink}"]) == 0
+        with open(sink) as f:
+            rec, = [r for r in map(json.loads, f) if r["kind"] == "compile"]
+        assert rec["pallas_sites"] == want == task.net.pallas_sites()
+        on_an_emulated_tpu(monkeypatch)
+        want = {"rmsnorm": 4 * LAYERS + 1}
+
+
 # ------------------------------------------------------- the exit distribution
 
 @pytest.mark.parametrize("gate,where", [(0.0, None), (1e4, 0), (-1e4, 3)])

@@ -790,3 +790,170 @@ def test_layernorm_pallas_matches_xla():
     for a, bb, nm in zip(g1, g2, ("dx", "dgamma", "dbeta")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
                                    rtol=1e-4, atol=1e-5, err_msg=nm)
+
+
+# ------------------------------------------------------------------ rmsnorm
+
+def _rms_lines(x, g, eps=1e-6):
+    """``RMSNormLayer.forward``'s XLA lines, the reference lowering."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.square(x32).mean(axis=-1, keepdims=True)
+                            + eps)
+    return (y * g.astype(jnp.float32)).astype(x.dtype)
+
+
+_RMS_CASES = [
+    # rows, d, x dtype, gain dtype
+    (64, 128, jnp.float32, jnp.float32),
+    (384, 640, jnp.float32, jnp.float32),    # non-square, 128-row blocks
+    (384, 640, jnp.bfloat16, jnp.float32),   # the looped cell's dtypes
+    (384, 640, jnp.bfloat16, jnp.bfloat16),
+    (4096, 256, jnp.bfloat16, jnp.float32),  # the cell's rows: dg over 8 blocks
+    (4096, 256, jnp.float32, jnp.bfloat16),
+]
+_rms_done = {}
+
+
+def _rms_case(case):
+    """Kernel and lines, forward and both gradients, once a case."""
+    if case not in _rms_done:
+        rows, d, xdt, gdt = case
+        rnd = np.random.RandomState(29)
+        x = jnp.asarray(rnd.randn(rows, d) * 3 + 1, xdt)
+        g = jnp.asarray(1 + 0.1 * rnd.randn(d), gdt)
+        dy = jnp.asarray(rnd.randn(rows, d), xdt)
+        from cxxnet_tpu.ops.pallas_kernels import rmsnorm_pallas
+        both = []
+        for f in (lambda x, g: rmsnorm_pallas(x, g, 1e-6, True), _rms_lines):
+            y, vjp = jax.vjp(f, x, g)
+            both.append(dict(zip(("y", "dx", "dg"), (y,) + vjp(dy))))
+        _rms_done[case] = both
+    return _rms_done[case]
+
+
+@pytest.mark.parametrize("what", ["y", "dx", "dg"])
+@pytest.mark.parametrize("case", _RMS_CASES,
+                         ids=lambda c: f"{c[0]}x{c[1]}-{c[2].__name__}"
+                                       f"-gain_{c[3].__name__}")
+def test_rmsnorm_pallas_matches_the_layers_xla_lines(case, what):
+    """Interpret mode against the four jnp lines: float32 to rounding,
+    bfloat16 results within a few bfloat16 roundings of the lines' largest
+    value (``chip_smoke.py``'s measure), the gain's gradient within 1e-3 of
+    its own length whatever the dtypes."""
+    from cxxnet_tpu.ops.pallas_kernels import rmsnorm_pallas_supported
+    assert rmsnorm_pallas_supported(case[0], case[1])
+    got, want = (np.asarray(r[what], np.float32) for r in _rms_case(case))
+    assert _rms_case(case)[0][what].dtype == _rms_case(case)[1][what].dtype
+    assert np.isfinite(got).all()
+    narrow = (case[3] if what == "dg" else case[2]) == jnp.bfloat16
+    assert _ln_rel_err(got, want) <= (0.02 if narrow else 2e-5)
+    if what == "dg":
+        assert np.linalg.norm(got - want) <= 1e-3 * np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("gdt", [jnp.float32, jnp.bfloat16])
+def test_rmsnorm_pallas_in_the_loops_form(gdt):
+    """``lax.scan`` of four ``jax.checkpoint`` passes with the gain closed
+    over, as ``Network._forward_loop`` runs a norm: ``dg`` is the sum of the
+    passes' gradients (each call zeroes its own accumulator), ``dx`` reaches
+    the first pass's input."""
+    from cxxnet_tpu.ops.pallas_kernels import rmsnorm_pallas
+    rnd = np.random.RandomState(3)
+    x = jnp.asarray(rnd.randn(64, 128), jnp.float32)
+    g = jnp.asarray(1 + 0.1 * rnd.randn(128), gdt)
+    w = jnp.asarray(rnd.randn(64, 128), jnp.float32)
+
+    def loss(norm):
+        def run(x, g):
+            def one_pass(carry, _):
+                out = carry + norm(carry, g)
+                return out, (out * w).sum()
+            last, sums = jax.lax.scan(jax.checkpoint(one_pass), x,
+                                      jnp.arange(4))
+            return (last * w).sum() + 0.5 * sums.sum()
+        return jax.jit(jax.value_and_grad(run, argnums=(0, 1)))
+
+    (v1, (dx1, dg1)) = loss(lambda x, g: rmsnorm_pallas(x, g, 1e-6, True))(x, g)
+    (v2, (dx2, dg2)) = loss(_rms_lines)(x, g)
+    assert dg1.dtype == dg2.dtype == gdt
+    assert float(v1) == pytest.approx(float(v2), rel=1e-5)
+    assert _ln_rel_err(dx1, dx2) <= 2e-5
+    assert _ln_rel_err(dg1, dg2) <= (0.02 if gdt == jnp.bfloat16 else 2e-5)
+    # one pass alone is not the sum: the passes' gradients were added
+    dg_one = jax.grad(lambda g: (rmsnorm_pallas(x, g, 1e-6, True) * w).sum())(g)
+    assert _ln_rel_err(dg_one, dg2) > 0.1
+
+
+def test_rmsnorm_pallas_residuals_are_the_input_never_the_output():
+    """The vjp keeps ``(x, gain, rstd)``: what autodiff of the jnp lines
+    keeps.  No leaf is the output or a copy of it (layernorm's
+    output-derived backward is the opposite contract)."""
+    from cxxnet_tpu.ops.pallas_kernels import _rms_fwd_res, rmsnorm_pallas
+    rnd = np.random.RandomState(0)
+    rows, d = 512, 256
+    x = jnp.asarray(rnd.randn(rows, d), jnp.bfloat16)
+    g = jnp.asarray(rnd.rand(d) + 0.5, jnp.float32)
+    y, res = _rms_fwd_res(x, g, 1e-6, True)
+    assert len(res) == 3 and res[0] is x and res[1] is g
+    assert res[2].shape == (rows, 1) and res[2].dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(res[2])[:, 0],
+        1 / np.sqrt(np.square(np.asarray(x, np.float32)).mean(-1) + 1e-6),
+        rtol=1e-5)
+    yv, vjp = jax.vjp(lambda x, g: rmsnorm_pallas(x, g, 1e-6, True), x, g)
+    big = [l for l in jax.tree_util.tree_leaves(vjp)
+           if hasattr(l, "size") and l.size >= rows * d]
+    assert {l.unsafe_buffer_pointer() for l in big} \
+        == {x.unsafe_buffer_pointer()}
+    assert yv.unsafe_buffer_pointer() != x.unsafe_buffer_pointer()
+
+
+@pytest.mark.parametrize("pallas_ln,shape,kernel", [
+    ("1", (2, 1, 8, 128), True),
+    ("x", (2, 1, 8, 128), True),     # the same kernel: it saves x anyway
+    ("0", (2, 1, 8, 128), False),    # the A/B switch takes the XLA lines
+    ("1", (1, 1, 5, 128), False),    # 5 rows divide into no block
+    ("1", (2, 1, 8, 96), False),     # d off the lane width
+])
+def test_rmsnorm_layer_route(monkeypatch, pallas_ln, shape, kernel):
+    """On an (emulated) TPU the rmsnorm layer takes ``rmsnorm_pallas``
+    wherever ``rmsnorm_pallas_supported`` holds and ``pallas_ln`` is not 0,
+    notes the site, and gives the lines' values either way."""
+    import cxxnet_tpu.engine as engine
+    from cxxnet_tpu.layers.base import ForwardContext
+    from cxxnet_tpu.layers.sequence import RMSNormLayer
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(engine.opts, "pallas_ln", pallas_ln)
+    calls = []
+    real = pk.rmsnorm_pallas
+
+    def spy(x, g, eps, interpret=None):
+        calls.append((x.shape, eps))
+        return real(x, g, eps, True)  # interpret: still on the CPU
+    monkeypatch.setattr(pk, "rmsnorm_pallas", spy)
+    layer = RMSNormLayer()
+    x = jnp.asarray(np.random.RandomState(0).randn(*shape), jnp.float32)
+    params = layer.init_params(jax.random.PRNGKey(0), [x.shape])
+    params["wmat"] = params["wmat"] * 1.5
+    with engine.placed_on("tpu"):  # the layer believes it runs on a TPU
+        (y,), _ = layer.forward(params, {}, [x], ForwardContext(train=True))
+    rows = x.size // shape[-1]
+    assert calls == ([((rows, shape[-1]), 1e-6)] if kernel else [])
+    assert layer.pallas_site is kernel
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(_rms_lines(x, params["wmat"])),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_rmsnorm_gate_is_layernorms():
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    assert pk._ln_rows(4096, 2048) == pk._ln_rows(16384, 2048) == 128
+    for rows, d in ((4096, 2048), (8, 2048), (12, 2048), (4096, 100),
+                    (512, 4096)):
+        assert pk.rmsnorm_pallas_supported(rows, d) \
+            == pk.layernorm_pallas_supported(rows, d)
+    assert pk.rmsnorm_pallas_supported(4096, 2048)
+    assert not pk.rmsnorm_pallas_supported(12, 2048)   # no block divides it
+    assert not pk.rmsnorm_pallas_supported(4096, 100)  # off the lane width
+    with pytest.raises(AssertionError, match="rmsnorm_pallas_supported"):
+        pk.rmsnorm_pallas(jnp.zeros((5, 128)), jnp.ones((128,)), 1e-6, True)

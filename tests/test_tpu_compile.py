@@ -1,4 +1,4 @@
-"""The causal flash kernels compile for the v5e at real widths.
+"""The causal flash and the RMSNorm kernels compile for the v5e at real widths.
 
 Mosaic compiles for a chip that is described and not attached, so what the
 chip's compiler would refuse (a slice off the tiling, too much VMEM) fails
@@ -45,3 +45,31 @@ def test_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, seg):
 
     text = jax.jit(fwd_bwd).lower(x, x, x, x, ids).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("rows,d,x_dtype,gain", [
+    # ouro26_s4096_loop4_docmask's norm call
+    (4096, 2048, jnp.bfloat16, jnp.float32),
+    (4096, 2048, jnp.bfloat16, jnp.bfloat16),
+    # float32 rows, the trainer's default dtype: twice the pipelined bytes
+    (4096, 2048, jnp.float32, jnp.float32),
+    (512, 4096, jnp.float32, jnp.float32),
+    (16384, 1024, jnp.float32, jnp.float32),
+    (16384, 128, jnp.bfloat16, jnp.float32),    # 512-row blocks
+    (520, 2048, jnp.bfloat16, jnp.float32),     # 8-row blocks
+])
+def test_rmsnorm_kernels_compile_for_v5e(one_chip, rows, d, x_dtype, gain):
+    """The row blocks ``_ln_rows`` chooses fit the default scoped VMEM under
+    the RMSNorm kernels too, for rows of either width."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    assert pk.rmsnorm_pallas_supported(rows, d)
+    x = jax.ShapeDtypeStruct((rows, d), x_dtype, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((d,), gain, sharding=one_chip)
+
+    def fwd_bwd(x, g, dy):
+        y, vjp = jax.vjp(lambda x, g: pk.rmsnorm_pallas(x, g, 1e-6, False),
+                         x, g)
+        return (y,) + vjp(dy)
+
+    text = jax.jit(fwd_bwd).lower(x, g, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
