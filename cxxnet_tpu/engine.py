@@ -15,42 +15,23 @@ one mid-run does not retrace already-compiled steps.
 
 | key         | values                     | meaning                        |
 |-------------|----------------------------|--------------------------------|
-| pool_bwd    | sas (default), eq, gather, | max-pool backward: XLA select- |
-|             | auto                       | and-scatter (one argmax per    |
+| pool_bwd    | sas (default), eq          | max-pool backward: XLA select- |
+|             |                            | and-scatter (one argmax per    |
 |             |                            | window) vs exact mshadow all-  |
-|             |                            | ties unpool (eq == gather);    |
-|             |                            | auto = all-ties Pallas where   |
-|             |                            | the kernel takes the shape,    |
-|             |                            | SAS elsewhere (measured ~equal |
-|             |                            | to sas on GoogLeNet; semantics |
-|             |                            | vary per pool at ties)         |
-| pool_layout | nchw (default), chwn, hwcn | pool compute layout; hwcn =    |
-|             |                            | native-layout Pallas kernels   |
-|             |                            | (implies all-ties backward)    |
-| fast_wgrad  | s2d (default), hwcn,       | wgrad lowering for small-cin   |
-|             | pallas, off                | strided convs (AlexNet conv1)  |
+|             |                            | ties unpool (XLA dilate-and-   |
+|             |                            | add, the reference semantics)  |
+| fast_wgrad  | s2d (default), off         | wgrad lowering for small-cin   |
+|             |                            | strided convs (AlexNet conv1): |
+|             |                            | space-to-depth vs XLA dilated  |
 | group_conv  | fgc (default), split       | grouped-conv lowering          |
-| conv1_fwd   | conv (default), s2d        | forward lowering for the fast- |
-|             |                            | wgrad conv class               |
-| pallas_lrn  | band (default), hwcn, 1, 0 | LRN lowering (band = MXU      |
-|             |                            | banded matmul, round 4)        |
+| pallas_lrn  | band (default), bandconv,  | LRN lowering: channel-window   |
+|             | 0                          | sum as an MXU banded matmul,   |
+|             |                            | the same as a 1x1 conv, or the |
+|             |                            | shifted-add chpool (reference) |
 | relu_vjp    | out (default), xla         | relu backward formulation      |
 | pool_relu_reorder | 1 (default), 0       | move relu after max pool (and  |
 |             |                            | defer conv bias through it) —  |
 |             |                            | gradient-equivalent a.e.       |
-| pool_relu_fuse | 0 (default), 1          | fuse the deferred relu's       |
-|             |                            | backward into the multi-row    |
-|             |                            | Pallas pool-backward kernel    |
-|             |                            | (mask epilogue on the shared   |
-|             |                            | _mp_mr_plan tile plan) where   |
-|             |                            | the hwcn kernel takes the      |
-|             |                            | shape — implies the all-ties   |
-|             |                            | backward for those pools, like |
-|             |                            | pool_bwd = auto.  Attacks the  |
-|             |                            | GoogLeNet SAS+relu cluster     |
-|             |                            | (~15 ms measured vs ~5 modeled |
-|             |                            | , BASELINE.md round 5); opt-in |
-|             |                            | until a TPU session A/Bs it    |
 | conv_sibling_fuse | 0 (default), 1       | run same-input same-geometry   |
 |             |                            | convs (inception 1x1 reduces)  |
 |             |                            | as one fused conv + slices     |
@@ -77,10 +58,8 @@ one mid-run does not retrace already-compiled steps.
 |             |                            | big bf16-master tensors: folds |
 |             |                            | the bf16->f32 grad convert and |
 |             |                            | master->bf16 cast into the     |
-|             |                            | update kernel (attacks the     |
-|             |                            | ~47.5 ms convert_reduce line). |
-|             |                            | Opt-in until a TPU session     |
-|             |                            | A/Bs it                        |
+|             |                            | update kernel.  Opt-in until a |
+|             |                            | TPU session A/Bs it            |
 | dp_overlap  | 0 (default), 1             | explicit shard_map DP step:    |
 |             |                            | gradients reduced in size-     |
 |             |                            | targeted buckets, each psum    |
@@ -163,17 +142,12 @@ _DEFS = {
     # name: (env var, default, valid values — a tuple of spellings or a
     # predicate for free-form numerics); flash_attn's env var is an
     # inverted bool, special-cased in _Options.__init__
-    "pool_bwd": ("CXXNET_POOL_BWD", "sas", ("sas", "eq", "gather", "auto")),
-    "pool_layout": ("CXXNET_POOL_LAYOUT", "nchw", ("nchw", "chwn", "hwcn")),
-    "fast_wgrad": ("CXXNET_FAST_WGRAD", "s2d",
-                   ("s2d", "hwcn", "pallas", "off")),
+    "pool_bwd": ("CXXNET_POOL_BWD", "sas", ("sas", "eq")),
+    "fast_wgrad": ("CXXNET_FAST_WGRAD", "s2d", ("s2d", "off")),
     "group_conv": ("CXXNET_GROUP_CONV", "fgc", ("fgc", "split")),
-    "conv1_fwd": ("CXXNET_CONV1_FWD", "conv", ("conv", "s2d")),
-    "pallas_lrn": ("CXXNET_PALLAS_LRN", "band",
-                   ("band", "bandconv", "hwcn", "1", "0")),
+    "pallas_lrn": ("CXXNET_PALLAS_LRN", "band", ("band", "bandconv", "0")),
     "relu_vjp": ("CXXNET_RELU_VJP", "out", ("out", "xla")),
     "pool_relu_reorder": ("CXXNET_POOL_RELU_REORDER", "1", ("1", "0")),
-    "pool_relu_fuse": ("CXXNET_POOL_RELU_FUSE", "0", ("1", "0")),
     "conv_sibling_fuse": ("CXXNET_CONV_SIBLING_FUSE", "0", ("1", "0")),
     "concat_virtual": ("CXXNET_CONCAT_VIRTUAL", "0", ("1", "0")),
     "flash_attn": ("CXXNET_NO_FLASH_ATTN", "1", ("1", "0")),

@@ -149,15 +149,6 @@ class MaxPoolingLayer(_PoolingBase):
 
     def forward(self, params, buffers, inputs, ctx):
         p = self.param
-        if self.relu_after and "deferred_bias" not in params:
-            # deferred relu with no bias riding along: the fusable form
-            # (pool_relu_fuse folds the relu mask into the Pallas unpool;
-            # a deferred bias would sit between pool and relu, so that
-            # combination keeps the unfused pair below)
-            out = N.max_pool2d_relu(inputs[0], p.kernel_height,
-                                    p.kernel_width, p.stride,
-                                    p.pad_y, p.pad_x)
-            return [out], buffers
         out = N.max_pool2d(inputs[0], p.kernel_height, p.kernel_width,
                            p.stride, p.pad_y, p.pad_x)
         if "deferred_bias" in params:
@@ -185,9 +176,9 @@ class ReluMaxPoolingLayer(_PoolingBase):
             x = apply_relu(inputs[0])
             return [N.max_pool2d(x, p.kernel_height, p.kernel_width,
                                  p.stride, p.pad_y, p.pad_x)], buffers
-        return [N.max_pool2d_relu(inputs[0], p.kernel_height,
-                                  p.kernel_width, p.stride,
-                                  p.pad_y, p.pad_x)], buffers
+        return [apply_relu(N.max_pool2d(inputs[0], p.kernel_height,
+                                        p.kernel_width, p.stride,
+                                        p.pad_y, p.pad_x))], buffers
 
 
 class SumPoolingLayer(_PoolingBase):

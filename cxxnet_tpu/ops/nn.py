@@ -20,31 +20,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from ..engine import on_tpu, opts
-
-# LRN dispatch (config key pallas_lrn / env CXXNET_PALLAS_LRN).  Default
-# "band" (round 4): the channel-window sum as a (C, C) banded matmul on
-# the otherwise-idle MXU — beats the round-3 "hwcn" Pallas kernel by
-# 1.7 ms/step on AlexNet b1024 (40.10 -> 38.37 device) and needs no
-# shape gate.  "hwcn" = the native-layout Pallas kernel (its win region
-# below), "1" = legacy (N, C, HW) kernel, "0" = pure XLA chpool.
-
-
-def _lrn_hwcn_fits(shape) -> bool:
-    # empirical win region (v5e): AlexNet's 27x27/13x13 planes win
-    # -2.5 ms/step, GoogLeNet's 56x56 planes -4 ms/step (the halo-free
-    # untiled kernel; the earlier halo-assembly variant OOM'd VMEM there).
-    # Batches must fill the 128-lane tile: Mosaic pads the minor dim to
-    # 128 regardless of n, so a small-batch block would be 128/n times
-    # larger than the estimate (measured VMEM OOM at n=2) — and the
-    # layout-match argument only holds for lane-full batches anyway.
-    n, c, h, w = shape
-    return (on_tpu() and n % 128 == 0
-            and w <= 64 and w * c * 128 * 4 <= (3 << 20))
-
 
 def pool_out_size(in_size: int, ksize: int, stride: int) -> int:
     """Reference pooling output-size rule (pooling_layer-inl.hpp:103-106).
@@ -158,8 +136,8 @@ def s2d_staged_shape(c: int, stride: int, kh: int, kw: int,
 
 def s2d_input(x: jnp.ndarray, stride: int, kh: int, kw: int,
               oh: int, ow: int, pad_y: int, pad_x: int):
-    """The x-side space-to-depth rearrangement shared by conv2d_s2d and the
-    Pallas wgrad kernel: (n, c, h, w) -> (n, c*s*s, hb, wb) with channel
+    """The x-side space-to-depth rearrangement of conv2d_s2d and the input
+    staging path: (n, c, h, w) -> (n, c*s*s, hb, wb) with channel
     order (c, sy, sx), matching the weight-side layout above.  Returns
     ``(xb, kb_y, kb_x)``."""
     s = stride
@@ -182,9 +160,7 @@ def s2d_input(x: jnp.ndarray, stride: int, kh: int, kw: int,
 # (AlexNet conv1), where XLA's dilated-dy wgrad starves the MXU (~26%
 # efficiency, BASELINE.md): "s2d" (default) computes dW through the
 # space-to-depth identity (dense stride-1 inner wgrad, pure XLA);
-# "pallas" uses the in-VMEM im2col Pallas kernel (CPU interpret mode only:
-# on a TPU Mosaic refuses its minor-dim reshape, a compile error); "off" keeps
-# XLA's dilated formulation.
+# "off" keeps XLA's dilated formulation.
 # (config key fast_wgrad / env CXXNET_FAST_WGRAD -> engine.opts)
 
 
@@ -200,26 +176,17 @@ def use_fast_wgrad(cin: int, stride: int, num_group: int) -> bool:
 # (config key group_conv / env CXXNET_GROUP_CONV -> engine.opts)
 
 
-# forward lowering for the fast-wgrad conv class: "conv" (default) XLA
-# strided conv; "s2d" routes the forward through the space-to-depth
-# identity too (A/B probe; round-2 measured it slower on v5e)
-# (config key conv1_fwd / env CXXNET_CONV1_FWD -> engine.opts)
-
-
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def conv_bias_fast(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                    stride: int, pad_y: int, pad_x: int) -> jnp.ndarray:
-    """conv2d + bias with a Pallas weight/bias-grad backward.
+    """conv2d + bias with the space-to-depth weight-grad backward.
 
     Forward is the ordinary XLA conv (already fast).  Backward computes
-    dW+db in one Pallas kernel (ops.pallas_kernels.conv_wgrad_s2d_pallas)
-    and dx through XLA's transposed conv — which XLA dead-code-eliminates
-    when the conv sits on the data layer, the AlexNet conv1 case.
+    dW through the dense stride-1 conv of ``conv2d_s2d`` and dx through
+    XLA's transposed conv — which XLA dead-code-eliminates when the conv
+    sits on the data layer, the AlexNet conv1 case.
     """
-    if opts.conv1_fwd == "s2d":
-        out = conv2d_s2d(x, w, stride=stride, pad_y=pad_y, pad_x=pad_x)
-    else:
-        out = conv2d(x, w, stride=stride, pad_y=pad_y, pad_x=pad_x)
+    out = conv2d(x, w, stride=stride, pad_y=pad_y, pad_x=pad_x)
     return out + b.astype(out.dtype).reshape(1, -1, 1, 1)
 
 
@@ -229,27 +196,12 @@ def _conv_bias_fast_fwd(x, w, b, stride, pad_y, pad_x):
 
 def _conv_bias_fast_bwd(stride, pad_y, pad_x, res, dy):
     x, w = res
-    co, ci, kh, kw = w.shape
-    if opts.fast_wgrad == "hwcn":
-        # native-layout Pallas kernel (lane-contraction dots; bias grad
-        # rides along) — the round-3 formulation that compiles on real TPU
-        from .pallas_kernels import conv_wgrad_hwcn_pallas
-        dw, db = conv_wgrad_hwcn_pallas(x, dy, kh=kh, kw=kw, stride=stride,
-                                        pad_y=pad_y, pad_x=pad_x)
-        dw = dw.astype(w.dtype)
-        db = db.astype(w.dtype)
-    elif opts.fast_wgrad == "pallas":
-        from .pallas_kernels import conv_wgrad_s2d_pallas
-        dw, db = conv_wgrad_s2d_pallas(x, dy, kh=kh, kw=kw, stride=stride,
-                                       pad_y=pad_y, pad_x=pad_x)
-        dw = dw.astype(w.dtype)
-        db = db.astype(w.dtype)
-    else:  # "s2d": dense stride-1 inner wgrad via the s2d identity
-        _, vjp_w = jax.vjp(
-            lambda wv: conv2d_s2d(x, wv, stride=stride,
-                                  pad_y=pad_y, pad_x=pad_x), w)
-        (dw,) = vjp_w(dy)
-        db = jnp.sum(dy, axis=(0, 2, 3)).astype(w.dtype)
+    # dense stride-1 inner wgrad via the s2d identity
+    _, vjp_w = jax.vjp(
+        lambda wv: conv2d_s2d(x, wv, stride=stride,
+                              pad_y=pad_y, pad_x=pad_x), w)
+    (dw,) = vjp_w(dy)
+    db = jnp.sum(dy, axis=(0, 2, 3)).astype(w.dtype)
     _, vjp_x = jax.vjp(
         lambda xv: conv2d(xv, w, stride=stride, pad_y=pad_y, pad_x=pad_x), x)
     (dx,) = vjp_x(dy)
@@ -286,9 +238,8 @@ def _pool_padding(h: int, w: int, kh: int, kw: int, stride: int,
 # max-pool backward dispatch: "sas" (default) uses XLA's select-and-scatter
 # (the lax.reduce_window VJP) — gradient goes to ONE maximum per window.
 # "eq" opts into the equality-mask VJP below: exact mshadow unpool
-# semantics (ties get gradient at EVERY maximum), but ~1.8x slower on v5e
-# (95.6ms vs 53.3ms AlexNet b1024 step) because the kx*ky dilate-and-add
-# passes materialize instead of fusing.
+# semantics (ties get gradient at EVERY maximum), as kx*ky dilate-and-add
+# passes — the reference-literal lowering, slower than SAS.
 # (config key pool_bwd / env CXXNET_POOL_BWD -> engine.opts)
 
 
@@ -314,56 +265,11 @@ def _max_pool_eq_fwd(x, ksize_y, ksize_x, stride, pad_y, pad_x):
     return y, (x, y)
 
 
-def _cand_indices(in_size: int, k: int, s: int, pad: int, out_size: int):
-    """For each input position a, the candidate window indices covering it:
-    w in [ceil((a+pad-k+1)/s), floor((a+pad)/s)] ∩ [0, out_size).  Returns
-    (ncand, in_size) index + validity arrays, ncand = ceil(k/s) or fewer."""
-    a = np.arange(in_size) + pad
-    lo = -(-(a - k + 1) // s)
-    hi = np.minimum(a // s, out_size - 1)
-    ncand = int(np.max(hi - lo + 1)) if in_size else 0
-    idx = np.stack([lo + t for t in range(ncand)])
-    valid = (idx >= 0) & (idx <= hi)
-    return np.clip(idx, 0, out_size - 1), valid
-
-
-def _max_pool_eq_bwd_gather(ksize_y, ksize_x, stride, pad_y, pad_x, res, dy):
-    """Candidate-gather unpool: same all-ties semantics as _max_pool_eq_bwd,
-    but formulated as <= ceil(k/s)^2 static row/column gathers of (y, dy)
-    back to the input grid instead of kx*ky dilated pads — each input
-    position is covered by at most ceil(k/s)^2 windows, so this reads far
-    less than the per-offset formulation when stride < kernel."""
-    x, y = res
-    n, c, h, w = x.shape
-    oh, ow = y.shape[2], y.shape[3]
-    iy, vy = _cand_indices(h, ksize_y, stride, pad_y, oh)
-    ix, vx = _cand_indices(w, ksize_x, stride, pad_x, ow)
-    dx = None
-    zero = jnp.zeros((), dy.dtype)
-    for t in range(iy.shape[0]):
-        y_r = jnp.take(y, jnp.asarray(iy[t]), axis=2)
-        dy_r = jnp.take(dy, jnp.asarray(iy[t]), axis=2)
-        my = jnp.asarray(vy[t])[None, None, :, None]
-        for u in range(ix.shape[0]):
-            y_c = jnp.take(y_r, jnp.asarray(ix[u]), axis=3)
-            dy_c = jnp.take(dy_r, jnp.asarray(ix[u]), axis=3)
-            m = my & jnp.asarray(vx[u])[None, None, None, :]
-            contrib = jnp.where(m & (x == y_c), dy_c, zero)
-            dx = contrib if dx is None else dx + contrib
-    return (dx,)
-
-
 def _max_pool_eq_bwd(ksize_y, ksize_x, stride, pad_y, pad_x, res, dy):
     """Equality-mask max-pool backward (mshadow ``unpool<red::maximum>``
     semantics: every input equal to its window's max receives the window's
     gradient — ties propagate to ALL maxima, unlike XLA select-and-scatter
-    which picks one).  Two formulations, picked by CXXNET_POOL_BWD:
-    "eq" = kx*ky dilate-and-add passes (measured ~1.8x slower than SAS in
-    a full AlexNet step on v5e: the pads materialize); "gather" =
-    candidate-window gathers (_max_pool_eq_bwd_gather)."""
-    if opts.pool_bwd == "gather":
-        return _max_pool_eq_bwd_gather(ksize_y, ksize_x, stride,
-                                       pad_y, pad_x, res, dy)
+    which picks one), as kx*ky dilate-and-add passes."""
     x, y = res
     n, c, h, w = x.shape
     oh, ow = y.shape[2], y.shape[3]
@@ -394,98 +300,10 @@ def _max_pool_eq_bwd(ksize_y, ksize_x, stride, pad_y, pad_x, res, dy):
 _max_pool_eq.defvjp(_max_pool_eq_fwd, _max_pool_eq_bwd)
 
 
-# pool layout: "chwn" transposes NCHW -> (C, H, W, N) around the
-# reduce_window / select-and-scatter pair.  Measured standalone on v5e
-# (AlexNet pool1, b1024): fwd 0.99ms vs 2.93 NCHW, SAS bwd 5.06 vs 8.47 —
-# XLA tiles the windowed ops far better with batch minor; whether the
-# transposes get absorbed in a full step is measured via fb.py.
-# (config key pool_layout / env CXXNET_POOL_LAYOUT -> engine.opts)
-
-
-def _max_pool_dispatch(x, ksize_y, ksize_x, stride, pad_y, pad_x):
-    if opts.pool_bwd in ("eq", "gather"):
-        return _max_pool_eq(x, ksize_y, ksize_x, stride, pad_y, pad_x)
-    return _max_pool_raw(x, ksize_y, ksize_x, stride, pad_y, pad_x)
-
-
-def _hwcn_pool_ok(x, ksize_y: int, ksize_x: int, stride: int,
-                  pad_y: int, pad_x: int) -> bool:
-    """Shapes the native-layout (H, W, C, N) Pallas pool kernels serve
-    on TPU — the ONE eligibility gate shared by ``max_pool2d`` and the
-    relu-fused ``max_pool2d_relu``, so the two entry points can never
-    accept different shapes (which would flip a pool between all-ties
-    and SAS gradient semantics depending on the call site)."""
-    from .pallas_kernels import max_pool_hwcn_supported
-    return (pad_y == 0 and pad_x == 0 and ksize_y == ksize_x
-            and on_tpu()
-            and x.shape[0] % 128 == 0
-            and max_pool_hwcn_supported(x.shape, stride))
-
-
 def max_pool2d(x: jnp.ndarray, ksize_y: int, ksize_x: int, stride: int,
                pad_y: int = 0, pad_x: int = 0) -> jnp.ndarray:
-    hwcn_ok = _hwcn_pool_ok(x, ksize_y, ksize_x, stride, pad_y, pad_x)
-    # "auto": Pallas all-ties where the hwcn kernel takes the shape, SAS
-    # elsewhere (measured ~equal to pure SAS on the GoogLeNet stage pools,
-    # BASELINE.md round 5).  Gradient SEMANTICS then vary per pool
-    # (all-ties vs one-winner at ties) — an explicit opt-in, never the
-    # default.
-    want_allties = (opts.pool_layout == "hwcn"
-                    or opts.pool_bwd in ("eq", "gather", "auto"))
-    if want_allties and hwcn_ok:
-        # Pallas kernels in XLA's native (H, W, C, N) activation layout:
-        # exact mshadow all-ties backward, ~15x faster than the XLA
-        # dilate-and-add eq formulation (6 vs 96 ms standalone on AlexNet
-        # pool1 b1024; still slower than SAS, so an exactness opt-in)
-        from .pallas_kernels import max_pool_hwcn
-        return max_pool_hwcn(x, ksize_y, stride)
-    if opts.pool_layout == "hwcn" and not hwcn_ok:
-        # keep all-ties semantics for the shapes the kernel can't take
-        # (padded pools, partial batches, CPU) — gradient semantics must
-        # not flip with batch divisibility mid-run
-        return _max_pool_eq(x, ksize_y, ksize_x, stride, pad_y, pad_x)
-    # ("auto" reaching this line means the Pallas kernel declined the
-    # shape, so the lowering IS SAS — honor the chwn layout choice)
-    if opts.pool_layout == "chwn" and opts.pool_bwd in ("sas", "auto"):
-        xt = jnp.transpose(x, (1, 2, 3, 0))
-        # reuse the NCHW padding/window logic by viewing (C, H, W, N) as
-        # (N', C', H, W) with batch'=C and channel'=H: reduce_window only
-        # cares about which axes carry windows
-        yt = _pool_nchw_as_chwn(xt, ksize_y, ksize_x, stride, pad_y, pad_x)
-        return jnp.transpose(yt, (3, 0, 1, 2))
-    return _max_pool_dispatch(x, ksize_y, ksize_x, stride, pad_y, pad_x)
-
-
-def max_pool2d_relu(x: jnp.ndarray, ksize_y: int, ksize_x: int,
-                    stride: int, pad_y: int = 0, pad_x: int = 0
-                    ) -> jnp.ndarray:
-    """``relu(max_pool2d(x))`` — the deferred-relu pool (the
-    ``pool_relu_reorder`` peephole's execution form).  With
-    ``pool_relu_fuse = 1`` and a shape the hwcn Pallas kernel takes,
-    the relu backward fuses into the multi-row all-ties unpool kernel
-    (``pallas_kernels.max_pool_relu_hwcn``) — the separate relu-bwd
-    pass over the pooled tensor disappears.  Fusing implies the
-    all-ties backward for that pool (like ``pool_bwd = auto``); the
-    unfused fallback keeps today's exact pair: the configured pool
-    backward followed by the ``relu_vjp``-configured relu."""
-    if opts.pool_relu_fuse == "1" \
-            and _hwcn_pool_ok(x, ksize_y, ksize_x, stride, pad_y, pad_x):
-        from .pallas_kernels import max_pool_relu_hwcn
-        return max_pool_relu_hwcn(x, ksize_y, stride)
-    from ..layers.activation import apply_relu
-    return apply_relu(max_pool2d(x, ksize_y, ksize_x, stride, pad_y, pad_x))
-
-
-def _pool_nchw_as_chwn(xt, ksize_y, ksize_x, stride, pad_y, pad_x):
-    """Max pool over dims (1, 2) of a (C, H, W, N) array with the
-    reference tail-window rule."""
-    pad_h, pad_w = _pool_padding(xt.shape[1], xt.shape[2], ksize_y,
-                                 ksize_x, stride, pad_y, pad_x)
-    return lax.reduce_window(
-        xt, -jnp.inf, lax.max,
-        window_dimensions=(1, ksize_y, ksize_x, 1),
-        window_strides=(1, stride, stride, 1),
-        padding=((0, 0), pad_h, pad_w, (0, 0)))
+    pool = _max_pool_eq if opts.pool_bwd == "eq" else _max_pool_raw
+    return pool(x, ksize_y, ksize_x, stride, pad_y, pad_x)
 
 
 def sum_pool2d(x: jnp.ndarray, ksize_y: int, ksize_x: int, stride: int,
@@ -567,15 +385,6 @@ def lrn(x: jnp.ndarray, nsize: int, alpha: float, beta: float, knorm: float
         ) -> jnp.ndarray:
     """Local response normalization across channels
     (reference lrn_layer-inl.hpp:53-56): out = x * (k + a/n * sum x^2)^-b."""
-    if opts.pallas_lrn == "1":
-        from .pallas_kernels import lrn_pallas
-        return lrn_pallas(x, nsize, alpha, beta, knorm)
-    if opts.pallas_lrn == "hwcn" and _lrn_hwcn_fits(x.shape):
-        # round-3 kernel in XLA's native (H, W, C, N) activation layout —
-        # superseded as default by the banded-matmul form (round 4:
-        # 40.10 -> 38.37 ms/step on AlexNet b1024)
-        from .pallas_kernels import lrn_pallas_hwcn
-        return lrn_pallas_hwcn(x, nsize, alpha, beta, knorm)
     if opts.pallas_lrn == "band":
         # default: the channel-window sum as a (C, C) banded matmul on
         # the (otherwise idle) MXU; autodiff gives the transposed-band

@@ -1,31 +1,19 @@
-"""Hand-written Pallas TPU kernels for ops XLA tiles poorly.
+"""Hand-written Pallas TPU kernels for the ops XLA leaves time on.
 
-The reference proves its op set is user-extensible at the expression level
-(``insanity_pooling_layer-inl.hpp:13-49`` defines custom mshadow expressions
-in-tree); the TPU analogue is this module: custom Pallas kernels slotted in
-behind the same op signatures as the XLA path.
+What is here is what a benchmark cell runs, plus one opt-in:
 
-First resident: **LRN** (``lrn_layer-inl.hpp:53-76``).  The cross-channel
-windowed reduction sits on a non-minor axis, so the XLA path materialises a
-``chpool`` intermediate between two elementwise passes over HBM.  The Pallas
-kernel does square → windowed channel sum → normalise in one VMEM-resident
-pass per batch row (forward), and the full hand-derived backward
+- flash attention, causal and document-segmented, forward and the dq / dkv
+  backward kernels (``flash_attention``, ``flash_attention_segmented``);
+- LayerNorm with an output-derived backward (``layernorm_pallas``);
+- RMSNorm, a row sweep that saves its input (``rmsnorm_pallas``);
+- the one-sweep adam step for bf16-master tensors (``fused_adam_pallas``:
+  ``fused_update = 1``, off by default, no chip A/B yet).
 
-    dx = g·norm^{-β} − 2βα/n · x · chpool(g · x · norm^{-β-1})
+The convnet path runs no Pallas kernel: conv, pool and LRN lower through
+XLA (``ops/nn.py``).
 
-in a second single-pass kernel via ``jax.custom_vjp``.
-
-Kernels run in interpreter mode off-TPU so the same code path is unit-tested
-on the CPU mesh (pallas_guide: ``interpret=True``).
-
-Round-2 measured the (N, C, HW)-layout kernel losing in-step to XLA: a
-pallas_call on a logical-NCHW activation forces a relayout (XLA keeps conv
-activations physically (H, W, C-sublane, N-lane), batch minor).  Round 3's
-``lrn_pallas_hwcn`` transposes to the MATCHING logical order first — the
-boundary becomes a bitcast — and wins ~2 ms/step on the AlexNet b1024
-config, so it is the default dispatch for lane-full batches in its
-measured win region (``CXXNET_PALLAS_LRN``: "hwcn" (default) / "1" legacy
-(N, C, HW) kernel / "0" pure XLA; see ``nn.lrn``).
+Off a TPU the kernels run in interpret mode, so the same code is tested on
+the CPU (pallas_guide: ``interpret=True``).
 """
 
 from __future__ import annotations
@@ -33,7 +21,6 @@ from __future__ import annotations
 import collections
 import functools
 import itertools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,900 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ..engine import on_tpu
 
 _VMEM = pltpu.VMEM
-
-
-def _block_spec(nb: int, c: int, hw: int):
-    """(NB, C, HW) batch-tile per grid step, resident in VMEM.  NB > 1
-    matters: one-row blocks ran 1024 programs per call on AlexNet shapes and
-    the per-program overhead swamped the kernel."""
-    return pl.BlockSpec((nb, c, hw), lambda i: (i, 0, 0), memory_space=_VMEM)
-
-
-def _chwin_sum(sq: jnp.ndarray, nsize: int,
-               transpose: bool = False) -> jnp.ndarray:
-    """Windowed sum over axis 1 (channels) of an (NB, C, HW) block: element
-    j sums sq[j-lo .. j+hi] with lo = nsize//2, hi = nsize-1-lo —
-    ``chpool_sum``'s window placement.  ``transpose=True`` swaps lo/hi,
-    giving the adjoint window needed by the backward pass for even nsize."""
-    c = sq.shape[1]
-    lo = nsize // 2
-    hi = nsize - 1 - lo
-    if transpose:
-        lo, hi = hi, lo
-    zshape = list(sq.shape)
-    acc = sq
-    for off in range(1, hi + 1):  # channels above j
-        zshape[1] = off
-        acc = acc + jnp.concatenate(
-            [sq[:, off:], jnp.zeros(zshape, sq.dtype)], axis=1)
-    for off in range(1, lo + 1):  # channels below j
-        zshape[1] = off
-        acc = acc + jnp.concatenate(
-            [jnp.zeros(zshape, sq.dtype), sq[:, :c - off]], axis=1)
-    return acc
-
-
-def _norm_pow(norm: jnp.ndarray, beta: float) -> jnp.ndarray:
-    """norm^-beta; rsqrt-family fast path for the canonical beta=0.75."""
-    if beta == 0.75:
-        return jax.lax.rsqrt(norm * jax.lax.sqrt(norm))
-    return jnp.power(norm, -beta)
-
-
-def _lrn_fwd_kernel(x_ref, o_ref, *, nsize, salpha, beta, knorm):
-    x = x_ref[...].astype(jnp.float32)
-    norm = _chwin_sum(x * x, nsize) * salpha + knorm
-    o_ref[...] = (x * _norm_pow(norm, beta)).astype(o_ref.dtype)
-
-
-def _lrn_bwd_kernel(x_ref, g_ref, dx_ref, *, nsize, salpha, beta, knorm):
-    x = x_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    norm = _chwin_sum(x * x, nsize) * salpha + knorm
-    npow = _norm_pow(norm, beta)              # norm^-b
-    inner = g * x * (npow / norm)             # g x norm^{-b-1}
-    dx = g * npow - (2.0 * beta * salpha) * x * _chwin_sum(
-        inner, nsize, transpose=True)
-    dx_ref[...] = dx.astype(dx_ref.dtype)
-
-
-def _lrn_batch_tile(n: int, c: int, hw: int, itemsize: int) -> int:
-    """Largest batch tile dividing n with a ~1MB input block: the backward
-    kernel holds ~6 f32 block-sized temporaries plus the in/out blocks, so
-    a bigger block blows the 16MB scoped-vmem limit."""
-    nb = max(1, (1 << 20) // max(c * hw * itemsize, 1))
-    while n % nb != 0:
-        nb -= 1
-    return nb
-
-
-def _call_per_batch(kernel, out_dtype, nsize, salpha, beta, knorm, *args3d,
-                    interpret):
-    n, c, hw = args3d[0].shape
-    nb = _lrn_batch_tile(n, c, hw, args3d[0].dtype.itemsize)
-    kern = functools.partial(kernel, nsize=nsize, salpha=salpha, beta=beta,
-                             knorm=knorm)
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((n, c, hw), out_dtype),
-        grid=(n // nb,),
-        in_specs=[_block_spec(nb, c, hw) for _ in args3d],
-        out_specs=_block_spec(nb, c, hw),
-        interpret=interpret,
-    )(*args3d)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def lrn_pallas(x: jnp.ndarray, nsize: int, alpha: float, beta: float,
-               knorm: float) -> jnp.ndarray:
-    """LRN over NCHW via the Pallas kernel (same semantics as ``nn.lrn``)."""
-    out, _ = _lrn_fwd_res(x, nsize, alpha, beta, knorm)
-    return out
-
-
-def _lrn_fwd_res(x, nsize, alpha, beta, knorm):
-    n, c, h, w = x.shape
-    x3 = x.reshape(n, c, h * w)
-    out = _call_per_batch(_lrn_fwd_kernel, x.dtype, nsize, alpha / nsize,
-                          beta, knorm, x3, interpret=not on_tpu())
-    return out.reshape(n, c, h, w), x
-
-
-def _lrn_bwd_res(nsize, alpha, beta, knorm, res, g):
-    x = res
-    n, c, h, w = x.shape
-    dx = _call_per_batch(_lrn_bwd_kernel, x.dtype, nsize, alpha / nsize,
-                         beta, knorm, x.reshape(n, c, h * w),
-                         g.reshape(n, c, h * w), interpret=not on_tpu())
-    return (dx.reshape(n, c, h, w),)
-
-
-lrn_pallas.defvjp(_lrn_fwd_res, _lrn_bwd_res)
-
-
-# --------------------------------------------------------------------------
-# LRN in XLA's native activation layout.  Profiling the AlexNet step shows
-# XLA lays conv activations out as {0,1,3,2:T(8,128)} — physically
-# (H, W, C-sublane, N-lane), batch minor.  A pallas_call on the logical
-# NCHW array therefore forces a relayout (the round-2 kernel's measured
-# boundary toll); transposing to the MATCHING logical order (H, W, C, N)
-# first makes the transpose a layout-change XLA can satisfy with a bitcast,
-# and inside the kernel the channel window sits on the sublane axis where
-# shifted slices are natively supported (experiments/mosaic_probe2.py).
-
-
-def _halo_concat(center, lo_v, hi_v, bc, nblk, halo):
-    """Assemble the C-extended block: ``halo`` channels from each
-    neighbouring C-block, zero-masked at the array edges (LRN zero-pads).
-    The halo refs are 8-wide (sublane tile minimum); only the adjacent
-    ``halo`` channels are used."""
-    if not halo:
-        return center
-    parts = [jnp.where(bc > 0, lo_v[:, :, lo_v.shape[2] - halo:], 0.0),
-             center,
-             jnp.where(bc < nblk - 1, hi_v[:, :, :halo], 0.0)]
-    return jnp.concatenate(parts, axis=2)
-
-
-def _cshift(v, i):
-    """v shifted by i channels (axis 2), zero-filled (concat form —
-    Mosaic-safe)."""
-    if i == 0:
-        return v
-    z = jnp.zeros(v.shape[:2] + (abs(i),) + v.shape[3:], v.dtype)
-    if i > 0:
-        return jnp.concatenate([v[:, :, i:], z], axis=2)
-    return jnp.concatenate([z, v[:, :, :i]], axis=2)
-
-
-def _lrn_hwcn_fwd_kernel(x_ref, xlo_ref, xhi_ref, o_ref, *, nsize, salpha,
-                         beta, knorm, halo):
-    bc = pl.program_id(1)
-    nblk = pl.num_programs(1)
-    lo = nsize // 2
-    hi = nsize - 1 - lo
-    x = x_ref[...].astype(jnp.float32)        # (HB, W, CB, NB)
-    cb = x.shape[2]
-    xe = _halo_concat(x, xlo_ref[...].astype(jnp.float32),
-                      xhi_ref[...].astype(jnp.float32), bc, nblk, halo)
-    sq = xe * xe
-    # center channel j = extended channel halo + j; window [j-lo, j+hi]
-    acc = None
-    for i in range(nsize):
-        if halo:
-            sl = sq[:, :, halo - lo + i:halo - lo + i + cb]
-        else:  # untiled: zero-fill shifts instead of halo slices
-            sl = _cshift(sq, i - lo)
-        acc = sl if acc is None else acc + sl
-    norm = acc * salpha + knorm
-    o_ref[...] = (x * _norm_pow(norm, beta)).astype(o_ref.dtype)
-
-
-def _lrn_hwcn_bwd_kernel(x_ref, xlo_ref, xhi_ref, g_ref, glo_ref, ghi_ref,
-                         dx_ref, *, nsize, salpha, beta, knorm, halo):
-    bc = pl.program_id(1)
-    nblk = pl.num_programs(1)
-    lo = nsize // 2
-    hi = nsize - 1 - lo
-    x = x_ref[...].astype(jnp.float32)
-    cb = x.shape[2]
-    xe = _halo_concat(x, xlo_ref[...].astype(jnp.float32),
-                      xhi_ref[...].astype(jnp.float32), bc, nblk, halo)
-    ge = _halo_concat(g_ref[...].astype(jnp.float32),
-                      glo_ref[...].astype(jnp.float32),
-                      ghi_ref[...].astype(jnp.float32), bc, nblk, halo)
-    # norm on the extended block: valid wherever the window stays inside
-    # it — true for all channels the adjoint sum below touches, because
-    # halo >= lo + hi (edge zero-fill is the correct array-edge padding)
-    sq = xe * xe
-    norm_e = None
-    for i in range(-lo, hi + 1):
-        sl = _cshift(sq, i)
-        norm_e = sl if norm_e is None else norm_e + sl
-    norm_e = norm_e * salpha + knorm
-    npow_e = _norm_pow(norm_e, beta)
-    inner_e = ge * xe * (npow_e / norm_e)
-    x_c = xe[:, :, halo:halo + cb]
-    g_c = ge[:, :, halo:halo + cb]
-    npow_c = npow_e[:, :, halo:halo + cb]
-    # adjoint window swaps lo/hi: dx[j] -= 2ba x[j] sum_{i in [-hi, lo]}
-    # inner[j+i]
-    wsum = None
-    for i in range(-hi, lo + 1):
-        if halo:
-            sl = inner_e[:, :, halo + i:halo + i + cb]
-        else:
-            sl = _cshift(inner_e, i)
-        wsum = sl if wsum is None else wsum + sl
-    dx = g_c * npow_c - (2.0 * beta * salpha) * x_c * wsum
-    dx_ref[...] = dx.astype(dx_ref.dtype)
-
-
-
-
-def _lrn_hwcn_fwd_kernel_u(x_ref, o_ref, *, nsize, salpha, beta, knorm):
-    lo = nsize // 2
-    hi = nsize - 1 - lo
-    x = x_ref[...].astype(jnp.float32)        # (HB, W, C, NB)
-    sq = x * x
-    acc = None
-    for i in range(-lo, hi + 1):
-        sl = _cshift(sq, i)
-        acc = sl if acc is None else acc + sl
-    norm = acc * salpha + knorm
-    o_ref[...] = (x * _norm_pow(norm, beta)).astype(o_ref.dtype)
-
-
-def _lrn_hwcn_bwd_kernel_u(x_ref, g_ref, dx_ref, *, nsize, salpha, beta,
-                           knorm):
-    lo = nsize // 2
-    hi = nsize - 1 - lo
-    x = x_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    sq = x * x
-    norm = None
-    for i in range(-lo, hi + 1):
-        sl = _cshift(sq, i)
-        norm = sl if norm is None else norm + sl
-    norm = norm * salpha + knorm
-    npow = _norm_pow(norm, beta)
-    inner = g * x * (npow / norm)
-    wsum = None
-    for i in range(-hi, lo + 1):
-        sl = _cshift(inner, i)
-        wsum = sl if wsum is None else wsum + sl
-    dx = g * npow - (2.0 * beta * salpha) * x * wsum
-    dx_ref[...] = dx.astype(dx_ref.dtype)
-
-# per-program VMEM budget for the LRN block planner: the round-3
-# "measured-working" 3 MB leaves AlexNet's odd 27-row planes at hb=1
-# (216 tiny programs); raced values recorded in BASELINE.md
-_LRN_BUDGET = 3 << 20
-
-
-def _lrn_hwcn_call(kernel, out_dtype, nsize, salpha, beta, knorm, args,
-                   interpret):
-    h, w, c, n = args[0].shape
-    lo = nsize // 2
-    hi = nsize - 1 - lo
-    # bwd recomputes norms for halo channels, whose windows reach another
-    # lo+hi channels out — one halo width serves both kernels
-    halo = max(lo + hi, 1)
-    nb = 128 if n % 128 == 0 else n
-    # C-tile (halo channels from neighbour-block refs, zero-masked at the
-    # edges) only when the untiled per-block working set is too large;
-    # the untiled path skips the halo assembly entirely (fewer VMEM
-    # temporaries — measured: the AlexNet shapes prefer 2-row untiled
-    # blocks, GoogLeNet's 56x56 shapes need the C-tiling)
-    cb = c
-    while cb > 2 * halo and w * cb * nb * 4 > _LRN_BUDGET:
-        cb //= 2
-    while c % cb:
-        cb -= 1
-    hblk = 8  # halo refs are one sublane tile wide (>= any lo+hi here)
-    assert halo <= hblk, f"lrn nsize {nsize} halo {halo} exceeds tile"
-    if cb % hblk or cb < hblk:
-        cb = c  # halo-block indexing needs 8 | cb; fall back to whole C
-    nblk = c // cb
-    untiled = nblk == 1
-    if untiled:
-        halo = 0  # no neighbours: no halo refs, no extended temps
-        kernel = {_lrn_hwcn_fwd_kernel: _lrn_hwcn_fwd_kernel_u,
-                  _lrn_hwcn_bwd_kernel: _lrn_hwcn_bwd_kernel_u}[kernel]
-    plane = w * (cb + 2 * halo) * nb * 4
-    hb = max(1, _LRN_BUDGET // max(plane, 1))
-    while h % hb:
-        hb -= 1
-    kern = functools.partial(kernel, nsize=nsize, salpha=salpha, beta=beta,
-                             knorm=knorm,
-                             **({} if untiled else {"halo": halo}))
-    spec = pl.BlockSpec((hb, w, cb, nb),
-                        lambda i, j, k: (i, 0, j, k), memory_space=_VMEM)
-    lo_spec = pl.BlockSpec(
-        (hb, w, hblk, nb),
-        lambda i, j, k: (i, 0, jnp.maximum(j * (cb // hblk) - 1, 0), k),
-        memory_space=_VMEM)
-    hi_spec = pl.BlockSpec(
-        (hb, w, hblk, nb),
-        lambda i, j, k: (i, 0, jnp.minimum((j + 1) * (cb // hblk),
-                                           c // hblk - 1), k),
-        memory_space=_VMEM)
-    per_arg = [spec] if untiled else [spec, lo_spec, hi_spec]
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((h, w, c, n), out_dtype),
-        grid=(h // hb, nblk, n // nb),
-        in_specs=per_arg * len(args),
-        out_specs=spec,
-        interpret=interpret,
-    )(*[a for a in args for _ in range(len(per_arg))])
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def lrn_pallas_hwcn(x: jnp.ndarray, nsize: int, alpha: float, beta: float,
-                    knorm: float) -> jnp.ndarray:
-    """LRN over logical NCHW via an (H, W, C, N)-layout Pallas kernel.
-
-    The wrapping transposes match XLA's physical activation layout, so
-    they lower to bitcasts rather than data movement (see module note).
-    """
-    out, _ = _lrn_hwcn_fwd_res(x, nsize, alpha, beta, knorm)
-    return out
-
-
-def _lrn_hwcn_fwd_res(x, nsize, alpha, beta, knorm):
-    xt = jnp.transpose(x, (2, 3, 1, 0))       # (H, W, C, N)
-    out = _lrn_hwcn_call(_lrn_hwcn_fwd_kernel, x.dtype, nsize,
-                         alpha / nsize, beta, knorm, (xt,),
-                         interpret=not on_tpu())
-    return jnp.transpose(out, (3, 2, 0, 1)), x
-
-
-def _lrn_hwcn_bwd_res(nsize, alpha, beta, knorm, res, g):
-    x = res
-    xt = jnp.transpose(x, (2, 3, 1, 0))
-    gt = jnp.transpose(g, (2, 3, 1, 0))
-    dx = _lrn_hwcn_call(_lrn_hwcn_bwd_kernel, x.dtype, nsize,
-                        alpha / nsize, beta, knorm, (xt, gt),
-                        interpret=not on_tpu())
-    return (jnp.transpose(dx, (3, 2, 0, 1)),)
-
-
-lrn_pallas_hwcn.defvjp(_lrn_hwcn_fwd_res, _lrn_hwcn_bwd_res)
-
-
-# VMEM budget for the multi-row backward's channel tile: estimates over
-# ~13.4 MB crashed the Mosaic compile (GoogLeNet c832/w14, c480/w32)
-_MR_BWD_VMEM_CAP = 12 << 20
-
-
-def _pick_cb(c: int, per_cb_bytes: int, cap: int) -> int:
-    """Largest channel tile dividing c that fits the VMEM budget, else the
-    smallest legal tile.  Mosaic requires a block dim be a multiple of 8
-    OR the full array dim — the old halving loop could land on e.g. 60
-    for c=480 (GoogLeNet stage-3 pool), which is neither, and failed TPU
-    compilation."""
-    legal = [cb for cb in range(1, c + 1)
-             if c % cb == 0 and (cb == c or cb % 8 == 0)]
-    return next((cb for cb in reversed(legal)
-                 if cb * per_cb_bytes <= cap), legal[0])
-
-
-def _mp_mr_plan(c: int, w: int, nb: int, s: int, hb: int = None):
-    """Tile plan for the MULTI-ROW pool backward, shared by the shape gate
-    (:func:`max_pool_hwcn_supported`) and the kernel launcher
-    (:func:`_mp_hwcn_bwd`) so the two can't silently diverge: returns
-    ``(hb, cb, per_cb_bytes)``.
-
-    * ``hb`` — input rows per program; default 3*s (amortizes per-program
-      overhead), rounded down to a multiple of s (static candidate-row
-      offsets require s | hb).
-    * ``per_cb_bytes`` — dominant VMEM per (w, cb, nb) plane and row:
-      in/out blocks + the f32 row accumulators and their stack come to
-      ~12 block-planes per row.
-    * ``cb`` — largest legal channel tile fitting ``_MR_BWD_VMEM_CAP``
-      (via :func:`_pick_cb`); callers must still check
-      ``cb * per_cb_bytes <= _MR_BWD_VMEM_CAP`` — when no tile fits,
-      _pick_cb falls back to the smallest legal one, which over-allocates
-      and crashes Mosaic.
-    """
-    if hb is None:
-        hb = 3 * s
-    hb = max(hb - hb % s, s)
-    per = w * nb * 12 * hb
-    return hb, _pick_cb(c, per, _MR_BWD_VMEM_CAP), per
-
-
-def max_pool_hwcn_supported(shape, s: int) -> bool:
-    """Shapes the hwcn pool kernel compiles for on TPU: the lane dim must
-    be full tiles for the bitcast boundary, and the tile the shared plan
-    picks for the multi-row backward must actually fit its budget
-    (measured: c64/w224 k2s2 fails, c32/w147 and c64/w112 compile)."""
-    n, c, h, w = shape
-    if n % 128 != 0:
-        return False
-    _, cb, per = _mp_mr_plan(c, w, 128, s)
-    return cb * per <= _MR_BWD_VMEM_CAP
-
-
-# --------------------------------------------------------------------------
-# Max pooling in the native (H, W, C, N) layout.  Same bitcast-boundary
-# trick as lrn_pallas_hwcn.  Forward: grid (C, N, OH) with k one-row input
-# refs per output row (index maps s*r+i — rows are blocks, so any stride
-# is plain indexing); the stride-s window along W uses the pad +
-# reshape-split phase form (mosaic_probe).  Backward implements mshadow's
-# exact all-ties unpool (``unpool<red::maximum>``: EVERY input equal to
-# its window max receives the window's gradient), which XLA's
-# select-and-scatter only approximates (one winner) — so this kernel is
-# both faster and closer to reference semantics.
-
-
-def _pool_phases(v, s, wpad, fill):
-    """(W, C, N) -> s phase views (wpad/s, C, N) along the major W axis."""
-    w, c, n = v.shape
-    if w < wpad:
-        pad = jnp.full((wpad - w, c, n), fill, v.dtype)
-        v = jnp.concatenate([v, pad], axis=0)
-    v2 = v.reshape(wpad // s, s, c, n)
-    return [v2[:, p] for p in range(s)]
-
-
-def _mp_hwcn_fwd_kernel(*refs, k, s, ow, wpad, h_in):
-    x_rows, o_ref = refs[:k], refs[k]
-    r = pl.program_id(2)
-    acc = None
-    for i in range(k):
-        row = x_rows[i][0].astype(jnp.float32)      # (W, C, NB)
-        # row i of the window is input row s*r+i; the index map clamps at
-        # the edge, so mask clamped reads (clipped tail windows) to -inf
-        valid = (s * r + i) < h_in
-        row = jnp.where(valid, row, NEG_INF)
-        ph = _pool_phases(row, s, wpad, NEG_INF)
-        for j in range(k):
-            v = ph[j % s][j // s:j // s + ow]
-            acc = v if acc is None else jnp.maximum(acc, v)
-    o_ref[0] = acc.astype(o_ref.dtype)
-
-
-def _mp_col_place(ph, pv, dv, k, s, ow, wq, acc):
-    """Accumulate one candidate row's column taps into the per-phase
-    accumulators (shared by the 1-row and multi-row backward kernels)."""
-    for j in range(k):
-        q = j // s
-        av = ph[j % s][q:q + ow]
-        contrib = jnp.where(av == pv, dv, 0.0)
-        parts = []
-        if q:
-            parts.append(jnp.zeros((q,) + contrib.shape[1:], jnp.float32))
-        parts.append(contrib)
-        if wq - q - ow:
-            parts.append(jnp.zeros((wq - q - ow,) + contrib.shape[1:],
-                                   jnp.float32))
-        placed = parts[0] if len(parts) == 1 \
-            else jnp.concatenate(parts, axis=0)
-        acc[j % s] = placed if acc[j % s] is None \
-            else acc[j % s] + placed
-    return acc
-
-
-def _mp_interleave(acc, a_row, wpad, wq):
-    zeros = jnp.zeros((wq,) + a_row.shape[1:], jnp.float32)
-    parts = [zeros if v is None else v for v in acc]
-    wide = jnp.stack(parts, axis=1).reshape((wpad,) + a_row.shape[1:])
-    return wide[:a_row.shape[0]]
-
-
-def _mp_hwcn_bwd_kernel(*refs, k, s, ow, wpad, oh, h_in, relu_mask=False):
-    ncand = -(-k // s)  # output rows touching one input row
-    x_ref = refs[0]
-    p_refs = refs[1:1 + ncand]
-    dp_refs = refs[1 + ncand:1 + 2 * ncand]
-    dx_ref = refs[1 + 2 * ncand]
-    h = pl.program_id(2)
-    a = x_ref[0].astype(jnp.float32)                # (W, C, NB)
-    ph = _pool_phases(a, s, wpad, NEG_INF)
-    wq = wpad // s
-    r0 = (h - (k - 1) + (s - 1)) // s               # first candidate row
-    acc = [None] * s
-    for cand in range(ncand):
-        r = r0 + cand
-        pv = p_refs[cand][0].astype(jnp.float32)    # (OW, C, NB)
-        dv = dp_refs[cand][0].astype(jnp.float32)
-        # tap index i = h - s*r must lie in [0, k) and r in [0, oh)
-        i_tap = h - s * jnp.clip(r, 0, oh - 1)
-        valid_r = (r >= 0) & (r < oh) & (i_tap >= 0) & (i_tap < k)
-        dv = jnp.where(valid_r, dv, 0.0)
-        if relu_mask:
-            # fused relu backward: pv is the PRE-relu pool output and
-            # relu(pv) > 0 iff pv > 0, so masking dv here is exactly
-            # where(out > 0, dy, 0) — no separate relu-bwd HBM pass
-            dv = jnp.where(pv > 0, dv, 0.0)
-        acc = _mp_col_place(ph, pv, dv, k, s, ow, wq, acc)
-    dx_ref[0] = _mp_interleave(acc, a, wpad, wq).astype(dx_ref.dtype)
-
-
-def _mp_hwcn_bwd_kernel_mr(*refs, k, s, ow, wpad, oh, h_in, hb, nref,
-                           relu_mask=False):
-    """Multi-row backward: hb input rows per program (hb % s == 0, so the
-    candidate-row offsets are static per in-block row), p/dp supplied as
-    ``nref`` one-row refs starting at the block's first candidate row.
-    ``relu_mask`` fuses the deferred-relu backward (pool_relu_fuse): each
-    candidate's incoming gradient is zeroed where the pre-relu pool
-    output is <= 0, in-register, on the same (hb, cb) tile plan."""
-    ncand = -(-k // s)
-    x_ref = refs[0]
-    p_refs = refs[1:1 + nref]
-    dp_refs = refs[1 + nref:1 + 2 * nref]
-    dx_ref = refs[1 + 2 * nref]
-    bh = pl.program_id(2)
-    h0 = bh * hb
-    rbase = (h0 - (k - 1) + (s - 1)) // s
-    wq = wpad // s
-    rel0 = (-(k - 1) + (s - 1)) // s  # rel_j at j=0 (s | h0)
-    rows = []
-    for j in range(hb):
-        a = x_ref[j].astype(jnp.float32)            # (W, C, NB)
-        ph = _pool_phases(a, s, wpad, NEG_INF)
-        rel_j = (j - (k - 1) + (s - 1)) // s - rel0
-        acc = [None] * s
-        for cand in range(ncand):
-            # absolute candidate row and its static tap index
-            i_tap = j - s * ((j - (k - 1) + (s - 1)) // s) - s * cand
-            if i_tap < 0 or i_tap >= k:
-                continue
-            ref_i = rel_j + cand
-            r_abs = rbase + ref_i
-            pv = p_refs[ref_i][0].astype(jnp.float32)
-            dv = dp_refs[ref_i][0].astype(jnp.float32)
-            valid = (r_abs >= 0) & (r_abs < oh) & (h0 + j < h_in)
-            dv = jnp.where(valid, dv, 0.0)
-            if relu_mask:
-                # see _mp_hwcn_bwd_kernel: relu'(pool) folded in-register
-                dv = jnp.where(pv > 0, dv, 0.0)
-            acc = _mp_col_place(ph, pv, dv, k, s, ow, wq, acc)
-        rows.append(_mp_interleave(acc, a, wpad, wq))
-    dx_ref[...] = jnp.stack(rows, axis=0).astype(dx_ref.dtype)
-
-
-def _mp_hwcn_fwd(xt, k, s, interpret):
-    h, w, c, n = xt.shape
-    oh = min(h - k + s - 1, h - 1) // s + 1
-    ow = min(w - k + s - 1, w - 1) // s + 1
-    # phases must hold the deepest column tap: slice [j//s : j//s + ow]
-    # with j up to k-1 needs (k-1)//s + ow entries per phase, which on
-    # clipped tail windows (even w, k=3, s=2) exceeds ceil(w/s)
-    wpad = max(-(-w // s), (k - 1) // s + ow) * s
-    nb = 128 if n % 128 == 0 else n
-    cb = _pick_cb(c, (w * nb * 4) * (k + 2), 10 << 20)
-
-    x_specs = [
-        pl.BlockSpec((1, w, cb, nb),
-                     lambda bc, bn, r, i=i: (jnp.minimum(s * r + i, h - 1),
-                                             0, bc, bn), memory_space=_VMEM)
-        for i in range(k)]
-    o_spec = pl.BlockSpec((1, ow, cb, nb),
-                          lambda bc, bn, r: (r, 0, bc, bn), memory_space=_VMEM)
-    kern = functools.partial(_mp_hwcn_fwd_kernel, k=k, s=s, ow=ow,
-                             wpad=wpad, h_in=h)
-    return pl.pallas_call(
-        kern,
-        grid=(c // cb, n // nb, oh),
-        in_specs=x_specs,
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((oh, ow, c, n), xt.dtype),
-        interpret=interpret,
-    )(*([xt] * k))
-
-
-def _mp_hwcn_bwd(xt, pt, dpt, k, s, interpret, hb=None, relu_mask=False):
-    h, w, c, n = xt.shape
-    oh, ow = pt.shape[0], pt.shape[1]
-    wpad = max(-(-w // s), (k - 1) // s + ow) * s  # see _mp_hwcn_fwd
-    ncand = -(-k // s)
-    nb = 128 if n % 128 == 0 else n
-    if hb is None or hb > 1:
-        # tile plan shared with max_pool_hwcn_supported (_mp_mr_plan).
-        # Under _MR_BWD_VMEM_CAP every proven AlexNet shape picks the same
-        # tile as the original 14 MB halving loop did
-        hb, cb, _ = _mp_mr_plan(c, w, nb, s, hb)
-        rel0 = (-(k - 1) + (s - 1)) // s
-        rel_last = (hb - 1 - (k - 1) + (s - 1)) // s - rel0
-        nref = rel_last + ncand
-
-        def p_imap(i):
-            def imap(bc, bn, bh):
-                rbase = (bh * hb - (k - 1) + (s - 1)) // s
-                return (jnp.clip(rbase + i, 0, oh - 1), 0, bc, bn)
-            return imap
-
-        x_spec = pl.BlockSpec((hb, w, cb, nb),
-                              lambda bc, bn, bh: (bh, 0, bc, bn),
-                              memory_space=_VMEM)
-        p_specs = [pl.BlockSpec((1, ow, cb, nb), p_imap(i), memory_space=_VMEM)
-                   for i in range(nref)]
-        kern = functools.partial(_mp_hwcn_bwd_kernel_mr, k=k, s=s, ow=ow,
-                                 wpad=wpad, oh=oh, h_in=h, hb=hb,
-                                 nref=nref, relu_mask=relu_mask)
-        return pl.pallas_call(
-            kern,
-            grid=(c // cb, n // nb, -(-h // hb)),
-            in_specs=[x_spec] + p_specs + p_specs,
-            out_specs=x_spec,
-            out_shape=jax.ShapeDtypeStruct(xt.shape, xt.dtype),
-            interpret=interpret,
-        )(xt, *([pt] * nref), *([dpt] * nref))
-
-    cb = _pick_cb(c, (w * nb * 4) * (2 * ncand + 4), 10 << 20)
-
-    def cand_imap(cand):
-        def imap(bc, bn, hrow):
-            r0 = (hrow - (k - 1) + (s - 1)) // s
-            return (jnp.clip(r0 + cand, 0, oh - 1), 0, bc, bn)
-        return imap
-
-    x_spec = pl.BlockSpec((1, w, cb, nb),
-                          lambda bc, bn, hrow: (hrow, 0, bc, bn),
-                          memory_space=_VMEM)
-    p_specs = [pl.BlockSpec((1, ow, cb, nb), cand_imap(i), memory_space=_VMEM)
-               for i in range(ncand)]
-    kern = functools.partial(_mp_hwcn_bwd_kernel, k=k, s=s, ow=ow,
-                             wpad=wpad, oh=oh, h_in=h,
-                             relu_mask=relu_mask)
-    return pl.pallas_call(
-        kern,
-        grid=(c // cb, n // nb, h),
-        in_specs=[x_spec] + p_specs + p_specs,
-        out_specs=x_spec,
-        out_shape=jax.ShapeDtypeStruct(xt.shape, xt.dtype),
-        interpret=interpret,
-    )(xt, *([pt] * ncand), *([dpt] * ncand))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def max_pool_hwcn(x: jnp.ndarray, k: int, s: int) -> jnp.ndarray:
-    """Max pool over logical NCHW via (H, W, C, N)-layout Pallas kernels
-    (no padding; reference tail-window rule).  Backward = exact mshadow
-    all-ties unpool."""
-    out, _ = _mp_fwd_res(x, k, s)
-    return out
-
-
-def _mp_fwd_res(x, k, s):
-    xt = jnp.transpose(x, (2, 3, 1, 0))
-    pt = _mp_hwcn_fwd(xt, k, s, interpret=not on_tpu())
-    return jnp.transpose(pt, (3, 2, 0, 1)), (xt, pt)
-
-
-def _mp_bwd_res(k, s, res, g):
-    xt, pt = res
-    dpt = jnp.transpose(g, (2, 3, 1, 0))
-    dxt = _mp_hwcn_bwd(xt, pt, dpt, k, s, interpret=not on_tpu())
-    return (jnp.transpose(dxt, (3, 2, 0, 1)),)
-
-
-max_pool_hwcn.defvjp(_mp_fwd_res, _mp_bwd_res)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def max_pool_relu_hwcn(x: jnp.ndarray, k: int, s: int) -> jnp.ndarray:
-    """``relu(max_pool(x))`` with the relu backward FUSED into the
-    multi-row all-ties unpool kernel (engine option ``pool_relu_fuse``):
-    the deferred-relu mask ``pool_out > 0`` zeroes each candidate's
-    incoming gradient in-register on the shared :func:`_mp_mr_plan`
-    tile plan, so the stride^2-sized relu-bwd read-modify-write pass
-    over the pooled tensor — the SAS+relu cluster's second half —
-    disappears.  Residuals are identical to :func:`max_pool_hwcn`
-    (``(xt, pt)`` with ``pt`` the PRE-relu pool output; the relu needs
-    no extra buffer because ``relu'(pt) = pt > 0``)."""
-    out, _ = _mpr_fwd_res(x, k, s)
-    return out
-
-
-def _mpr_fwd_res(x, k, s):
-    xt = jnp.transpose(x, (2, 3, 1, 0))
-    pt = _mp_hwcn_fwd(xt, k, s, interpret=not on_tpu())
-    y = jnp.maximum(jnp.transpose(pt, (3, 2, 0, 1)), 0)
-    return y, (xt, pt)
-
-
-def _mpr_bwd_res(k, s, res, g):
-    xt, pt = res
-    dpt = jnp.transpose(g, (2, 3, 1, 0))
-    dxt = _mp_hwcn_bwd(xt, pt, dpt, k, s, interpret=not on_tpu(),
-                       relu_mask=True)
-    return (jnp.transpose(dxt, (3, 2, 0, 1)),)
-
-
-max_pool_relu_hwcn.defvjp(_mpr_fwd_res, _mpr_bwd_res)
-
-
-# --------------------------------------------------------------------------
-# Strided-conv weight (+bias) gradient in the native layout.  The round-2
-# attempt im2col'd in VMEM per image and died on Mosaic's minor-dim
-# reshape limits; this formulation never reshapes: with activations
-# transposed to (H, W, C, N) (bitcast, see above), each (row, col)
-# position yields a lane-contraction dot
-#     acc[o, (tap, ci)] += dy[r, t, o, :] . xs2d[r+dh, t+dw, ci, :]
-# — (96, NB) x (448, NB) MXU calls accumulated across the whole grid
-# (rows innermost, so the single output block accumulates legally).
-# The bias gradient rides along as a lane-preserving row sum.
-
-
-def _cw_hwcn_kernel(dy_ref, x0_ref, x1_ref, x2_ref, dw_ref, db_ref, acc,
-                    accb, *, co, cin_b, kb, ow, taps_pad):
-    bn, r = pl.program_id(0), pl.program_id(1)
-
-    @pl.when((bn == 0) & (r == 0))
-    def _():
-        acc[...] = jnp.zeros_like(acc)
-
-    @pl.when(r == 0)
-    def _():
-        accb[...] = jnp.zeros_like(accb)
-
-    dy_row = dy_ref[0]                       # (OW, co, NB) bf16
-    xs_rows = [x0_ref[0], x1_ref[0], x2_ref[0]][:kb]  # (WB, cin_b, NB)
-    a = acc[...]
-    for t in range(ow):
-        dy_rt = dy_row[t]                    # (co, NB)
-        cols = jnp.concatenate(
-            [xs_rows[dh][t + dw] for dh in range(kb) for dw in range(kb)]
-            + [jnp.zeros((taps_pad - kb * kb * cin_b, dy_rt.shape[1]),
-                         xs_rows[0].dtype)] * (taps_pad > kb * kb * cin_b),
-            axis=0)                          # (taps_pad, NB)
-        a = a + jax.lax.dot_general(
-            dy_rt, cols, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    acc[...] = a
-    accb[...] += jnp.sum(dy_row.astype(jnp.float32), axis=0)
-
-    @pl.when((bn == pl.num_programs(0) - 1) & (r == pl.num_programs(1) - 1))
-    def _():
-        dw_ref[...] = acc[...]
-
-    @pl.when(r == pl.num_programs(1) - 1)
-    def _():
-        db_ref[0] = accb[...]
-
-
-def conv_wgrad_hwcn_pallas(x: jnp.ndarray, dy: jnp.ndarray, *, kh: int,
-                           kw: int, stride: int, pad_y: int = 0,
-                           pad_x: int = 0, nb: int = 128,
-                           interpret: bool = None):
-    """Weight + bias gradient of a stride-s conv (no groups), logical
-    NCHW/OIHW, computed via the s2d identity in (H, W, C, N) layout.
-
-    Returns (dW (co, ci, kh, kw) f32, db (co,) f32).  For the
-    small-cin / large-stride geometry class (AlexNet conv1) where XLA's
-    dilated-dy wgrad starves the MXU.
-    """
-    if interpret is None:
-        interpret = not on_tpu()
-    from .nn import s2d_input
-    n, c, h, w = x.shape
-    _, co, oh, ow = dy.shape
-    s = stride
-    xs2d, kb_y, kb_x = s2d_input(x, s, kh, kw, oh, ow, pad_y, pad_x)
-    assert kb_y == kb_x, "square kernels only"
-    kb = kb_y
-    assert kb <= 3, "kernel blocks up to 3 wired (extend x refs for more)"
-    cin_b = c * s * s
-    taps = kb * kb * cin_b
-    taps_pad = taps  # keep exact; MXU pads internally
-    xs_t = jnp.transpose(xs2d, (2, 3, 1, 0))     # (HB, WB, cin_b, N)
-    dy_t = jnp.transpose(dy, (2, 3, 1, 0))       # (OH, OW, co, N)
-    while n % nb:
-        nb //= 2
-    dy_spec = pl.BlockSpec((1, ow, co, nb),
-                           lambda bn, r: (r, 0, 0, bn), memory_space=_VMEM)
-    # rows r+i for i >= kb are never read; clamp their index maps
-    hb = xs_t.shape[0]
-    x_specs = [pl.BlockSpec((1, xs_t.shape[1], cin_b, nb),
-                            lambda bn, r, i=i: (jnp.minimum(r + i, hb - 1),
-                                                0, 0, bn), memory_space=_VMEM)
-               for i in range(3)]
-    dw_spec = pl.BlockSpec((co, taps_pad), lambda bn, r: (0, 0),
-                           memory_space=_VMEM)
-    db_spec = pl.BlockSpec((1, co, nb), lambda bn, r: (bn, 0, 0),
-                           memory_space=_VMEM)
-    kern = functools.partial(_cw_hwcn_kernel, co=co, cin_b=cin_b, kb=kb,
-                             ow=ow, taps_pad=taps_pad)
-    dw_inner, db_part = pl.pallas_call(
-        kern,
-        grid=(n // nb, oh),
-        in_specs=[dy_spec] + x_specs,
-        out_specs=[dw_spec, db_spec],
-        out_shape=[jax.ShapeDtypeStruct((co, taps_pad), jnp.float32),
-                   jax.ShapeDtypeStruct((n // nb, co, nb), jnp.float32)],
-        scratch_shapes=_scratch((co, taps_pad), (co, nb)),
-        interpret=interpret,
-    )(dy_t, xs_t, xs_t, xs_t)
-    db = jnp.sum(db_part, axis=(0, 2))
-    # column order is (dh, dw) x (c, sy, sx) — invert to OIHW
-    dw6 = dw_inner.reshape(co, kb, kb, c, s, s)
-    dw6 = dw6.transpose(0, 3, 1, 4, 2, 5)        # (co, c, kb, sy, kb, sx)
-    dwp = dw6.reshape(co, c, kb * s, kb * s)
-    return dwp[:, :, :kh, :kw], db
-
-
-# --------------------------------------------------------------------------
-# Flash attention: the sequence stack's hot op.  One VMEM-resident pass per
-# (batch*head, q-block), online softmax over k-blocks carried in scratch —
-# never materialises the (s, s) score matrix.  Backward recomputes scores
-# from the saved logsumexp (two kernels: dq over k-blocks, dk/dv over
-# q-blocks).  Same math as parallel/ring.dense_attention's chunked path.
-#
-# Measured on TPU v5e (b4 h8 s8192 d128 bf16, causal): forward 16.5ms vs
-# 53ms for the XLA chunked path (3.2x); fwd+bwd 38.5ms, where the XLA
-# path's scan-autodiff residuals (per-chunk f32 scores) exceed HBM
-# entirely.  Matmul operands stay bf16 (MXU fast path) with f32
-# accumulation; block sizes 512x1024 amortise per-program overhead (the
-# first cut at 128x128 ran 131k programs and was slower than XLA).
-
-# --------------------------------------------------------------------------
-# Strided-conv weight gradient.  XLA computes the wgrad of a strided conv by
-# dilating dy with (stride-1) zeros, so for AlexNet conv1 (11x11 / stride 4 /
-# cin 3) ~15/16 of the MXU contraction is zeros (~26% efficiency, BASELINE.md
-# profile).  This kernel removes the dilation with the space-to-depth
-# identity: the stride-s conv equals a stride-1 conv over s2d-rearranged
-# input (ops.nn.conv2d_s2d), whose wgrad is a DENSE contraction
-#
-#     dW_inner[o, (c*s*s)*(kb*kb)] = sum_{n,oh,ow} dy[n,o,oh,ow] *
-#                                    x_s2d[n, c*s*s, oh+dh, ow+dw]
-#
-# evaluated as one (96 x K) @ (K x 432)-shaped MXU matmul per image, with
-# the im2col block built tile-wise in VMEM (never materialised to HBM).
-# The (co, ci*s*s, kb, kb) result maps back to OIHW outside the kernel.
-
-
-def _conv_wgrad_kernel(x_ref, dy_ref, o_ref, ob_ref, acc, accb, *, nb, co,
-                       cin_b, oh, ow, kb_y, kb_x):
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        acc[...] = jnp.zeros_like(acc)
-        accb[...] = jnp.zeros_like(accb)
-
-    for i in range(nb):
-        dy2 = dy_ref[i].reshape(co, oh * ow)
-        cols = jnp.concatenate(
-            [x_ref[i, :, dh:dh + oh, dw:dw + ow].reshape(cin_b, oh * ow)
-             for dh in range(kb_y) for dw in range(kb_x)], axis=0)
-        acc[...] += jax.lax.dot_general(
-            dy2, cols, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # bias grad rides along: dy is already in VMEM, so the row-sum is
-        # free compared to the separate full-activation reduce XLA emits
-        accb[...] += jnp.sum(dy2.astype(jnp.float32), axis=1)[None, :]
-
-    @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
-    def _():
-        o_ref[...] = acc[...]
-        ob_ref[...] = accb[...]
-
-
-def conv_wgrad_s2d_pallas(x: jnp.ndarray, dy: jnp.ndarray, *, kh: int,
-                          kw: int, stride: int, pad_y: int = 0,
-                          pad_x: int = 0, nb: int = 8,
-                          interpret: bool = None):
-    """Weight + bias gradient of a stride-s 2D conv (no groups), NCHW/OIHW.
-
-    Returns ``(dW (co, ci, kh, kw), db (co,))`` in float32.  Intended for
-    the small-input-channel / large-stride geometry class (AlexNet conv1)
-    where XLA's dilated-dy formulation starves the MXU; see module comment.
-    """
-    if interpret is None:
-        interpret = not on_tpu()
-    from .nn import s2d_input
-    n, c, h, w = x.shape
-    _, co, oh, ow = dy.shape
-    s = stride
-    xs2d, kb_y, kb_x = s2d_input(x, s, kh, kw, oh, ow, pad_y, pad_x)
-    cin_b = c * s * s
-    while n % nb != 0:
-        nb //= 2
-    kern = functools.partial(_conv_wgrad_kernel, nb=nb, co=co, cin_b=cin_b,
-                             oh=oh, ow=ow, kb_y=kb_y, kb_x=kb_x)
-    ncols = cin_b * kb_y * kb_x
-    hb, wb = oh - 1 + kb_y, ow - 1 + kb_x
-    dw_inner, db = pl.pallas_call(
-        kern,
-        grid=(n // nb,),
-        in_specs=[pl.BlockSpec((nb, cin_b, hb, wb), lambda i: (i, 0, 0, 0)),
-                  pl.BlockSpec((nb, co, oh, ow), lambda i: (i, 0, 0, 0))],
-        out_specs=[pl.BlockSpec((co, ncols), lambda i: (0, 0)),
-                   pl.BlockSpec((1, co), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((co, ncols), jnp.float32),
-                   jax.ShapeDtypeStruct((1, co), jnp.float32)],
-        scratch_shapes=_scratch((co, ncols), (1, co)),
-        interpret=interpret,
-    )(xs2d, dy)
-    # invert conv2d_s2d's weight layout: columns are ordered
-    # (seg=(dh,dw)) x (c, sy, sx); padded taps (dh*s+sy >= kh) are zero in
-    # the contraction and sliced away here
-    dw6 = dw_inner.reshape(co, kb_y, kb_x, c, s, s)
-    dw6 = dw6.transpose(0, 3, 1, 4, 2, 5)  # (co, c, kb_y, sy, kb_x, sx)
-    dwp = dw6.reshape(co, c, kb_y * s, kb_x * s)
-    return dwp[:, :, :kh, :kw], db[0]
 
 
 NEG_INF = -1e30

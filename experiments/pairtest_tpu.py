@@ -33,14 +33,14 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 # every engine option, at its most reference-literal value
-REF = {"pool_bwd": "eq", "pool_layout": "nchw", "fast_wgrad": "off",
-       "group_conv": "split", "conv1_fwd": "conv", "pallas_lrn": "0",
+REF = {"pool_bwd": "eq", "fast_wgrad": "off",
+       "group_conv": "split", "pallas_lrn": "0",
        "relu_vjp": "xla", "pool_relu_reorder": "0",
        "conv_sibling_fuse": "0", "concat_virtual": "0", "input_s2d": "0"}
 
 # the shipping stack, as bench.py runs it
-SHIP = {"pool_bwd": "sas", "pool_layout": "nchw", "fast_wgrad": "s2d",
-        "group_conv": "fgc", "conv1_fwd": "conv", "pallas_lrn": "band",
+SHIP = {"pool_bwd": "sas", "fast_wgrad": "s2d",
+        "group_conv": "fgc", "pallas_lrn": "band",
         "relu_vjp": "out", "pool_relu_reorder": "1",
         "conv_sibling_fuse": "0", "concat_virtual": "0", "input_s2d": "1"}
 
@@ -99,7 +99,8 @@ def run_variant(model: str, batch: int, dtype: str, name: str,
         conf = getattr(zoo, model)() + \
             "metric = error\neta = 0.01\nmomentum = 0.9\nsilent = 1\n"
     t0 = time.perf_counter()
-    t = _make_trainer(conf, batch, "tpu",
+    # the chip where there is one; the CPU for tests/test_pairtest_gate.py
+    t = _make_trainer(conf, batch, jax.default_backend(),
                       extra=[("dtype", dtype), ("eval_train", "0"),
                              ("silent", "1"), ("updater", "sgd"),
                              ("eta", "0.01"), ("momentum", "0"),

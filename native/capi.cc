@@ -27,7 +27,9 @@ import numpy as np
 from cxxnet_tpu.wrapper.api import Net, DataIter
 
 def _arr(mv, shape):
-    return np.frombuffer(mv, dtype=np.float32).reshape(shape)
+    # a copy: the pointer is the caller's for the length of the call only,
+    # and JAX reads a host array it was given after update() has returned
+    return np.frombuffer(mv, dtype=np.float32).reshape(shape).copy()
 
 def _c(a):
     return np.ascontiguousarray(a, np.float32)

@@ -39,3 +39,19 @@ def run_layer(type_name, x, cfg=None, train=False, in_shapes=None, seed=0,
 
 def rand4(*shape, seed=0):
     return np.random.RandomState(seed).randn(*shape).astype(np.float32)
+
+
+def assert_f32_roundoff(got, want, what="", units=8):
+    """``got`` equals ``want`` up to float32 rounding: every element lies
+    within ``units`` x 2^-23 x max|want| of it.  For results of two
+    DIFFERENT XLA programs of the same arithmetic: the compiler fuses,
+    contracts (fma) and orders each program's float32 operations as it
+    likes, so bit equality between them is a property of one compiler
+    build on one CPU, not of the code under test (ROADMAP D4).  The worst
+    seen over ten runs of each such test here is 3.4 units (PR 30); a
+    bfloat16 step anywhere on the path (2^-8) would miss by about 2^15."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype == np.float32, (what, got.dtype)
+    tol = units * np.finfo(np.float32).eps * np.abs(want).max()
+    err = np.abs(got.astype(np.float64) - want).max()
+    assert err <= tol, f"{what}: off by {err:.3e}, allowed {tol:.3e}"

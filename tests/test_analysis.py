@@ -438,6 +438,37 @@ def test_engine_bad_value_raises_valueerror():
         engine.set_engine_option("pool_bwd", "zzz")
 
 
+@pytest.mark.parametrize("name,val", [
+    ("pool_bwd", "gather"), ("pool_bwd", "auto"),
+    ("fast_wgrad", "hwcn"), ("fast_wgrad", "pallas"),
+    ("pallas_lrn", "1"), ("pallas_lrn", "hwcn")])
+def test_removed_lowering_values_are_refused(name, val):
+    """A lowering value that went with its code (PR 30) is a bad value like
+    any other: refused by name, with the spellings that are left."""
+    before = getattr(engine.opts, name)
+    with pytest.raises(ValueError) as ei:
+        engine.set_engine_option(name, val)
+    assert all(repr(v) in str(ei.value) for v in engine._DEFS[name][2])
+    assert getattr(engine.opts, name) == before
+
+
+@pytest.mark.parametrize("name,val", [
+    ("pool_layout", "hwcn"), ("conv1_fwd", "s2d"), ("pool_relu_fuse", "1")])
+def test_removed_lowering_keys_are_unknown(name, val):
+    """A lowering key that went with its code (PR 30) is an unknown key
+    like any other: task=check reports it, no engine option answers to
+    it, and the trainer ignores it."""
+    findings, code = run_check(
+        parse_config_string(f"{name} = {val}\n"), trace=False)
+    bad = by_key(findings, name)
+    assert code == 1 and bad and bad[0].severity == "error"
+    assert "unknown config key" in bad[0].message
+    assert not engine.is_engine_option(name)
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    NetTrainer().set_param(name, val)
+    assert not hasattr(engine.opts, name)
+
+
 # ------------------------------------------------------------- jaxpr lint
 
 class _BigConstLayer(Layer):

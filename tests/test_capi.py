@@ -45,6 +45,10 @@ def capi():
     lib.CXNNetPredictBatch.argtypes = [ctypes.c_void_p, f32p, u64p,
                                        ctypes.c_int, u64p,
                                        ctypes.POINTER(ctypes.c_int)]
+    lib.CXNNetGetWeight.restype = f32p
+    lib.CXNNetGetWeight.argtypes = [ctypes.c_void_p, ctypes.c_char_p,
+                                    ctypes.c_char_p, u64p,
+                                    ctypes.POINTER(ctypes.c_int)]
     lib.CXNGetLastError.restype = ctypes.c_char_p
     lib.CXNIOCreateFromConfig.restype = ctypes.c_void_p
     lib.CXNIOCreateFromConfig.argtypes = [ctypes.c_char_p]
@@ -122,6 +126,36 @@ def test_capi_train_predict(capi):
         train_steps(80)
         acc = accuracy()
     assert acc > 0.8, acc
+    capi.CXNNetFree(net)
+
+
+def test_capi_update_copies_the_callers_buffer(capi):
+    """The pointers of CXNNetUpdateBatch are the caller's again when it
+    returns: a caller that overwrites its batch right away (every C
+    caller with one staging buffer) must train on what it passed.  JAX
+    reads a host array after update() has returned, so a view of the
+    caller's memory trained on whatever the caller wrote next — what made
+    test_capi_train_predict unsteady (ROADMAP D4, PR 30)."""
+    net = capi.CXNNetCreate(b"cpu", NET_CFG)
+    assert net, capi.CXNGetLastError()
+    assert capi.CXNNetInitModel(net) == 0, capi.CXNGetLastError()
+    rng = np.random.RandomState(1)
+    xb = np.empty((16, 1, 1, 6), np.float32)
+    yb = np.empty((16, 1), np.float32)
+    for _ in range(120):
+        xb[:] = rng.rand(16, 1, 1, 6)
+        yb[:, 0] = xb.reshape(16, 6).sum(1) > 3
+        assert capi.CXNNetUpdateBatch(net, _f32(xb), _u64(16, 1, 1, 6),
+                                      4, _f32(yb), _u64(16, 1), 2) == 0
+        xb[:] = np.nan
+        yb[:] = np.nan
+    oshape = _u64(0, 0, 0, 0)
+    ondim = ctypes.c_int(0)
+    w = capi.CXNNetGetWeight(net, b"fc1", b"wmat", oshape,
+                             ctypes.byref(ondim))
+    assert w, capi.CXNGetLastError()
+    got = np.ctypeslib.as_array(w, shape=(8, 6)).copy()
+    assert np.isfinite(got).all(), got
     capi.CXNNetFree(net)
 
 

@@ -23,6 +23,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from cxxnet_tpu.serve.batcher import ServeClosed, StepScheduler
 from cxxnet_tpu.serve.decode import DecodeEngine, sample_token
+from helpers import assert_f32_roundoff
 
 
 # ------------------------------------------------------------ engine parity
@@ -62,9 +63,12 @@ def test_prefill_matches_full_forward_bitwise(engine):
 
 def test_incremental_steps_match_full_forward_bitwise(engine):
     """Greedy decode through the cache: every step's logits row equals
-    the full forward over the grown sequence, bitwise at f32 — masked
-    cache positions softmax to exactly 0.0 and drop out of the p·V
-    reduction, so stale garbage in unwritten slots is invisible."""
+    the full forward over the grown sequence at f32 — masked cache
+    positions softmax to exactly 0.0 and drop out of the p·V reduction,
+    so stale garbage in unwritten slots is invisible.  Equal up to
+    float32 rounding, not bit for bit: the one-token step and the full
+    forward are two XLA programs (a (1, d) and an (L, d) matmul round
+    differently in the last digit: -0.09193942 for -0.09193941)."""
     p = list(_prompt(6, seed=42))
     logits = engine.prefill(1, np.asarray(p, np.int32))
     seq = list(p) + [int(np.argmax(logits))]
@@ -73,7 +77,7 @@ def test_incremental_steps_match_full_forward_bitwise(engine):
         step = engine.step(np.asarray([0, seq[-1]], np.int32),
                            np.asarray([0, pos], np.int32))
         full = engine.full_logits(np.asarray(seq, np.int32))
-        assert np.array_equal(step[1], full[pos])
+        assert_f32_roundoff(step[1], full[pos], f"position {pos}")
         seq.append(int(np.argmax(step[1])))
     assert engine.retraces == 0
 
@@ -549,8 +553,9 @@ def _spec_generate(flagship, draft, prompts, max_new, **kw):
 def test_block_matches_sequential_steps_bitwise(block_engine):
     """The multi-column cache advance: one width-4 block dispatch over
     the tokens k sequential steps would feed produces the SAME four
-    logits rows, bitwise — each block row's mask stops at its own
-    position, so its reduction is the sequential step's."""
+    logits rows — each block row's mask stops at its own position, so
+    its reduction is the sequential step's.  Up to float32 rounding: the
+    width-4 block and the width-1 step are two XLA programs."""
     eng = block_engine
     p = _prompt(9, seed=11)
     logits = eng.prefill(0, p)
@@ -566,7 +571,7 @@ def test_block_matches_sequential_steps_bitwise(block_engine):
         np.asarray([toks[:4], [0, 0, 0, 0]], np.int32),
         np.asarray([len(p), 0], np.int32))
     for i in range(4):
-        assert np.array_equal(blk[0, i], rows[i]), f"row {i}"
+        assert_f32_roundoff(blk[0, i], rows[i], f"row {i}")
     assert eng.retraces == 0
 
 

@@ -455,22 +455,118 @@ def test_diff_layers_harness():
     assert d["in_grad_rel_err"] > 1e-3
 
 
-def test_max_pool_bwd_gather_matches_dilate():
-    """The candidate-window gather unpool (CXXNET_POOL_BWD=gather) equals
-    the dilate-and-add formulation on strided/padded/tail geometries."""
+def _distinct(shape, seed, shift=0.0):
+    """Float32 values that are all different (a permutation), so that no
+    pool window holds a tie: the two backward rules then agree exactly."""
+    size = int(np.prod(shape))
+    v = np.random.RandomState(seed).permutation(size).astype(np.float32)
+    return jnp.asarray((v / size - shift).reshape(shape))
+
+
+_POOL_SHAPES = [
+    ((4, 16, 27, 27), 3, 2),   # AlexNet pool2 family
+    ((2, 8, 13, 13), 3, 2),    # clipped tail
+    ((2, 8, 12, 12), 2, 2),    # VGG/LeNet family
+    ((2, 8, 9, 9), 3, 1),      # inception same-size branch (no pad)
+    ((2, 8, 12, 12), 3, 2),    # even width + clipped tail
+    ((2, 8, 14, 14), 3, 2),
+    ((2, 8, 56, 56), 3, 2),    # GoogLeNet stage pool family
+]
+
+
+@pytest.mark.parametrize("shape,k,s", _POOL_SHAPES)
+def test_max_pool_default_matches_eq(monkeypatch, shape, k, s):
+    """max_pool2d under the default pool_bwd = sas == the all-ties
+    reference form: forward bitwise, and the gradient wherever no window
+    holds a tie (select-and-scatter picks the one maximum there is)."""
+    import jax
+    from cxxnet_tpu.engine import opts
+    from cxxnet_tpu.ops import nn as N
+    monkeypatch.setattr(opts, "pool_bwd", "sas")
+    x = _distinct(shape, 1)
+    a = N.max_pool2d(x, k, k, s)
+    b = N._max_pool_eq(x, k, k, s, 0, 0)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    g = jnp.asarray(np.random.RandomState(2).randn(*a.shape), jnp.float32)
+    da = jax.vjp(lambda v: N.max_pool2d(v, k, k, s), x)[1](g)[0]
+    db = jax.vjp(lambda v: N._max_pool_eq(v, k, k, s, 0, 0), x)[1](g)[0]
+    np.testing.assert_allclose(np.asarray(da), np.asarray(db),
+                               rtol=1e-6, atol=1e-6)
+
+
+def _unpool_all_ties_oracle(x, dy, k, s, p):
+    """The reference's pool and unpool<red::maximum> as plain loops: window
+    (oy, ox) covers rows oy*s - p .. + k - 1 clipped to the image, and every
+    input equal to the window's maximum receives the window's gradient."""
+    n, c, h, w = x.shape
+    oh, ow = dy.shape[2], dy.shape[3]
+    y = np.full((n, c, oh, ow), -np.inf, np.float32)
+    tied = 0
+    for oy in range(oh):
+        for ox in range(ow):
+            win = x[:, :, max(oy * s - p, 0):min(oy * s - p + k, h),
+                    max(ox * s - p, 0):min(ox * s - p + k, w)]
+            y[:, :, oy, ox] = m = win.max(axis=(2, 3))
+            tied += ((win == m[:, :, None, None]).sum(axis=(2, 3)) > 1).sum()
+    assert tied > y.size // 4, "the input must tie in many windows"
+    dx = np.zeros_like(x)
+    for oy in range(oh):
+        for ox in range(ow):
+            for a in range(max(oy * s - p, 0), min(oy * s - p + k, h)):
+                for b in range(max(ox * s - p, 0), min(ox * s - p + k, w)):
+                    hit = x[:, :, a, b] == y[:, :, oy, ox]
+                    dx[:, :, a, b] += np.where(hit, dy[:, :, oy, ox], 0)
+    return y, dx
+
+
+@pytest.mark.parametrize("h,w,k,s,p", [
+    (55, 55, 3, 2, 0), (13, 13, 3, 2, 0), (28, 28, 2, 2, 0),
+    (27, 27, 3, 1, 1), (9, 9, 3, 3, 0), (8, 10, 4, 3, 2)])
+def test_max_pool_eq_bwd_matches_loop_oracle(h, w, k, s, p):
+    """_max_pool_eq_bwd (dilate-and-add, the one all-ties implementation:
+    pool_bwd = eq and insanity pooling) on integer-valued input WITH ties
+    == a plain loop of the reference's unpool, on strided, padded and
+    tail geometries."""
     from cxxnet_tpu.ops import nn as N
     rnd = np.random.RandomState(0)
-    for (h, w, k, s, p) in [(55, 55, 3, 2, 0), (13, 13, 3, 2, 0),
-                            (28, 28, 2, 2, 0), (27, 27, 3, 1, 1),
-                            (9, 9, 3, 3, 0), (8, 10, 4, 3, 2)]:
-        x = jnp.asarray(rnd.randint(0, 5, (2, 3, h, w)).astype(np.float32))
-        y = N._max_pool_raw(x, k, k, s, p, p)
-        dy = jnp.asarray(rnd.rand(*y.shape).astype(np.float32))
-        d1 = N._max_pool_eq_bwd(k, k, s, p, p, (x, y), dy)[0]
-        d2 = N._max_pool_eq_bwd_gather(k, k, s, p, p, (x, y), dy)[0]
-        np.testing.assert_allclose(np.asarray(d2), np.asarray(d1),
-                                   rtol=1e-5, atol=1e-6,
-                                   err_msg=str((h, w, k, s, p)))
+    x = rnd.randint(0, 5, (2, 3, h, w)).astype(np.float32)
+    y = N._max_pool_raw(jnp.asarray(x), k, k, s, p, p)
+    dy = rnd.rand(*y.shape).astype(np.float32)
+    y_want, dx_want = _unpool_all_ties_oracle(x, dy, k, s, p)
+    np.testing.assert_array_equal(np.asarray(y), y_want)
+    dx = N._max_pool_eq_bwd(k, k, s, p, p, (jnp.asarray(x), y),
+                            jnp.asarray(dy))[0]
+    np.testing.assert_allclose(np.asarray(dx), dx_want,
+                               rtol=1e-5, atol=1e-6,
+                               err_msg=str((h, w, k, s, p)))
+
+
+@pytest.mark.parametrize("shape,k,s", [
+    _POOL_SHAPES[i] for i in (0, 1, 2, 3, 6)])
+def test_pool_relu_commute(monkeypatch, shape, k, s):
+    """relu(max_pool(x)) == max_pool(relu(x)) in value and, with no ties
+    among the positive entries, in gradient: the identity the default
+    pool_relu_reorder = 1 rests on in every AlexNet cell."""
+    import jax
+    from cxxnet_tpu.engine import opts
+    from cxxnet_tpu.layers.activation import apply_relu
+    from cxxnet_tpu.ops import nn as N
+    monkeypatch.setattr(opts, "pool_bwd", "sas")
+    monkeypatch.setattr(opts, "relu_vjp", "out")
+    # shifted so that a real share of the WINDOW MAXIMA are negative (else
+    # the relu is vacuous); the half step keeps every value off zero
+    size = int(np.prod(shape))
+    x = _distinct(shape, 1, shift=0.9 + 0.5 / size)
+    after = lambda v: apply_relu(N.max_pool2d(v, k, k, s))    # noqa: E731
+    before = lambda v: N.max_pool2d(apply_relu(v), k, k, s)   # noqa: E731
+    ya = after(x)
+    np.testing.assert_array_equal(np.asarray(ya), np.asarray(before(x)))
+    assert 0.1 < (np.asarray(ya) == 0).mean() < 0.9
+    g = jnp.asarray(np.random.RandomState(2).randn(*ya.shape), jnp.float32)
+    da = jax.vjp(after, x)[1](g)[0]
+    db = jax.vjp(before, x)[1](g)[0]
+    np.testing.assert_allclose(np.asarray(da), np.asarray(db),
+                               rtol=1e-6, atol=1e-6)
 
 
 def test_conv2d_s2d_matches_conv2d():

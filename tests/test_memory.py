@@ -390,8 +390,13 @@ metrics_sink = jsonl:{sink}
     assert fc1["opt_bytes"] == fc1["param_bytes"]
     assert fc1["model_bytes"] > 0 and fc1["model_x"] > 0
     assert mp["coverage"] > 0.5
-    assert len(mp["timeline"]) > 4 and max(mp["timeline"]) \
-        == mp["peak_live_bytes"]
+    # the timeline is 32 evenly spaced READINGS of the live-byte curve, so
+    # it reaches the peak only where a reading falls on it: this step's
+    # entry computation has 45 instructions under jax 0.9.0, the peak
+    # (20992) holds for instruction 38 alone and readings fall on 37 and
+    # 39 (18944).  The curve's exact shape is test_live_timeline_exact's
+    assert len(mp["timeline"]) > 4 \
+        and 0 < max(mp["timeline"]) <= mp["peak_live_bytes"]
     assert mp["model"]["est_peak_bytes"] > mp["model"]["param_bytes"]
     # CPU: no made-up capacity, no fake measured gauges
     assert "hbm_capacity_bytes" not in mp

@@ -21,6 +21,7 @@ from cxxnet_tpu import engine  # noqa: E402
 from cxxnet_tpu.io.data import DataBatch  # noqa: E402
 
 from __graft_entry__ import _make_trainer  # noqa: E402
+from helpers import assert_f32_roundoff  # noqa: E402
 
 CONV_NET = """
 netconfig=start
@@ -111,11 +112,11 @@ def _train(net, overlap, extra=(), *, bucket_mb="0.001",
             jax.tree.map(np.asarray, t.opt_state), t)
 
 
-def _assert_trees_equal(a, b, what):
+def _assert_trees_equal(a, b, what, equal=np.testing.assert_array_equal):
     la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
     assert len(la) == len(lb)
     for x, y in zip(la, lb):
-        np.testing.assert_array_equal(x, y, err_msg=what)
+        equal(x, y, what)
 
 
 # ------------------------------------------------------------- parity
@@ -383,7 +384,8 @@ def test_mesh_overlap_bitwise_parity(tag, extra, kw):
     """The overlapped step on a data:2,model:2 mesh with MODEL-SHARDED
     weights (fullc wmats P("model", None), gathered at segment entry,
     gradients psum'd over data at their bucket's grad-ready point) is
-    trajectory-BITWISE-identical to the implicit step with replicated
+    trajectory-BITWISE-identical (the ZeRO case: identical up to float32
+    rounding) to the implicit step with replicated
     weights at f32: per-device compute is identical (the gathered shards
     reconstruct the full weight bit-for-bit; compute replicates across
     model) and the data-axis psum groups are the same 2-member sets."""
@@ -399,9 +401,18 @@ def test_mesh_overlap_bitwise_parity(tag, extra, kw):
     off = _train(MESH_NET, False,
                  tuple(kv for kv in extra if kv[0] != "fullc_gather"),
                  mesh=MESH, **kw)
-    assert on[0] == off[0], f"{tag}: per-step losses must be bitwise equal"
-    _assert_trees_equal(off[1], on[1], f"{tag}: params diverged")
-    _assert_trees_equal(off[2], on[2], f"{tag}: optimizer state diverged")
+    equal = np.testing.assert_array_equal
+    if tag == "zero":
+        # the ZeRO step reduce-scatters the conv gradient and updates
+        # shards: its update arithmetic is another XLA program than the
+        # implicit step's, fused and contracted differently (momentum
+        # comes out 3.4 units of the last place apart here, the losses
+        # bit for bit), so equal up to float32 rounding
+        equal = assert_f32_roundoff
+    equal(np.float32(on[0]), np.float32(off[0]), f"{tag}: per-step losses")
+    _assert_trees_equal(off[1], on[1], f"{tag}: params diverged", equal)
+    _assert_trees_equal(off[2], on[2], f"{tag}: optimizer state diverged",
+                        equal)
 
 
 def test_mesh_overlap_tracks_gspmd_sharded_implicit():

@@ -1,7 +1,9 @@
-"""Pallas kernel parity tests (interpreter mode on the CPU mesh).
+"""Kernel and lowering parity tests (Pallas in interpreter mode on the CPU).
 
-PairTest-style differential check: the Pallas LRN kernel against the plain
-XLA path (``nn.lrn``'s shifted-adds formulation), forward and backward.
+PairTest-style differential checks: each Pallas kernel (flash attention,
+layernorm, rmsnorm, the adam sweep) and each alternative XLA lowering
+(banded LRN, the space-to-depth weight gradient) against the plain form,
+forward and backward.
 """
 
 import itertools
@@ -12,7 +14,6 @@ import numpy as np
 import pytest
 
 from cxxnet_tpu.ops import nn as N
-from cxxnet_tpu.ops.pallas_kernels import lrn_pallas
 
 
 def _xla_lrn(x, nsize, alpha, beta, knorm):
@@ -21,78 +22,29 @@ def _xla_lrn(x, nsize, alpha, beta, knorm):
     return x * jnp.power(norm, -beta)
 
 
-@pytest.mark.parametrize("nsize,beta", [(5, 0.75), (3, 0.5), (4, 0.75)])
-def test_lrn_pallas_forward(nsize, beta):
-    x = jnp.asarray(np.random.RandomState(0).randn(3, 16, 5, 7),
-                    jnp.float32)
-    got = lrn_pallas(x, nsize, 0.001, beta, 1.0)
-    want = _xla_lrn(x, nsize, 0.001, beta, 1.0)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.parametrize("nsize,beta", [(5, 0.75), (3, 0.5), (4, 0.75)])
-def test_lrn_pallas_grad(nsize, beta):
-    x = jnp.asarray(np.random.RandomState(1).randn(2, 16, 4, 5),
-                    jnp.float32)
-    w = jnp.asarray(np.random.RandomState(2).randn(*x.shape), jnp.float32)
-
-    g_pallas = jax.grad(
-        lambda v: (lrn_pallas(v, nsize, 0.001, beta, 1.0) * w).sum())(x)
-    g_xla = jax.grad(
-        lambda v: (_xla_lrn(v, nsize, 0.001, beta, 1.0) * w).sum())(x)
-    np.testing.assert_allclose(np.asarray(g_pallas), np.asarray(g_xla),
-                               rtol=1e-4, atol=1e-5)
-
-
-def test_lrn_dispatch_forced_pallas(monkeypatch):
-    """nn.lrn routes through the Pallas kernel when pallas_lrn = 1."""
-    from cxxnet_tpu.engine import opts
-    monkeypatch.setattr(opts, "pallas_lrn", "1")
-    x = jnp.asarray(np.random.RandomState(3).randn(2, 8, 3, 3), jnp.float32)
-    got = N.lrn(x, 5, 0.001, 0.75, 1.0)
-    want = _xla_lrn(x, 5, 0.001, 0.75, 1.0)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.parametrize("shape,conv",
-                         [((4, 3, 23, 23, 8, 11, 4, 0), "alexnet-conv1"),
-                          ((2, 3, 16, 16, 16, 5, 2, 2), "padded"),
-                          ((8, 4, 15, 15, 8, 7, 3, 1), "odd")])
-def test_conv_wgrad_pallas_matches_vjp(shape, conv):
-    """Space-to-depth Pallas weight/bias-grad == XLA's conv VJP."""
-    from cxxnet_tpu.ops.pallas_kernels import conv_wgrad_s2d_pallas
-    n, c, h, w, co, k, s, p = shape
-    rnd = np.random.RandomState(0)
-    x = jnp.asarray(rnd.rand(n, c, h, w).astype(np.float32))
-    wt = jnp.asarray((rnd.rand(co, c, k, k) - 0.5).astype(np.float32))
-    y = N.conv2d(x, wt, stride=s, pad_y=p, pad_x=p)
-    dy = jnp.asarray(rnd.rand(*y.shape).astype(np.float32))
-    dw_ref = jax.vjp(
-        lambda wv: N.conv2d(x, wv, stride=s, pad_y=p, pad_x=p), wt)[1](dy)[0]
-    dw, db = conv_wgrad_s2d_pallas(x, dy, kh=k, kw=k, stride=s,
-                                   pad_y=p, pad_x=p)
-    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_ref),
-                               rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(db),
-                               np.asarray(dy.sum(axis=(0, 2, 3))),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_conv_bias_fast_full_vjp():
-    """conv_bias_fast == conv2d+bias in value and all three gradients."""
+@pytest.mark.parametrize("geom", [
+    (4, 3, 23, 23, 8, 11, 4, 0),    # AlexNet conv1
+    (8, 3, 23, 23, 16, 11, 4, 0),   # the same, wider
+    (2, 3, 16, 16, 16, 5, 2, 2),    # padded 5x5/s2
+    (8, 4, 15, 15, 8, 7, 3, 1),     # odd 7x7/s3
+    (4, 3, 18, 18, 8, 5, 2, 0),     # 5x5/s2 at cin 3
+])
+def test_conv_bias_fast_full_vjp(geom):
+    """conv_bias_fast (the space-to-depth weight gradient, the default for
+    the small-cin strided class) == conv2d+bias in value and all three
+    gradients."""
     rnd = np.random.RandomState(1)
-    n, c, h, w, co, k, s = 2, 3, 23, 23, 8, 11, 4
+    n, c, h, w, co, k, s, p = geom
     x = jnp.asarray(rnd.rand(n, c, h, w).astype(np.float32))
     wt = jnp.asarray((rnd.rand(co, c, k, k) - 0.5).astype(np.float32))
     b = jnp.asarray(rnd.rand(co).astype(np.float32))
 
     def ref(wt, b, xv):
-        return N.conv2d(xv, wt, stride=s) + b.reshape(1, -1, 1, 1)
+        return (N.conv2d(xv, wt, stride=s, pad_y=p, pad_x=p)
+                + b.reshape(1, -1, 1, 1))
 
     def fast(wt, b, xv):
-        return N.conv_bias_fast(xv, wt, b, s, 0, 0)
+        return N.conv_bias_fast(xv, wt, b, s, p, p)
 
     y_ref, y_fast = ref(wt, b, x), fast(wt, b, x)
     np.testing.assert_allclose(np.asarray(y_fast), np.asarray(y_ref),
@@ -269,142 +221,6 @@ def test_flash_attention_asymmetric_blocks():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-def test_lrn_hwcn_matches_xla():
-    """Native-layout (H,W,C,N) LRN kernel == XLA path, fwd + grad."""
-    import jax
-    import jax.numpy as jnp
-    from cxxnet_tpu.ops import nn as N
-    from cxxnet_tpu.ops.pallas_kernels import lrn_pallas_hwcn
-    x = jnp.asarray(np.random.RandomState(0).randn(4, 96, 9, 9),
-                    jnp.float32)
-    a = lrn_pallas_hwcn(x, 5, 0.001, 0.75, 1.0)
-    b = N.lrn(x, 5, 0.001, 0.75, 1.0)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=2e-5, atol=1e-6)
-    ga = jax.grad(lambda v: (lrn_pallas_hwcn(v, 5, .001, .75, 1.) ** 2
-                             ).sum())(x)
-    gb = jax.grad(lambda v: (N.lrn(v, 5, .001, .75, 1.) ** 2).sum())(x)
-    np.testing.assert_allclose(np.asarray(ga), np.asarray(gb),
-                               rtol=2e-4, atol=1e-5)
-
-
-@pytest.mark.parametrize("shape,k,s", [
-    ((4, 16, 27, 27), 3, 2),   # AlexNet pool2 family
-    ((2, 8, 13, 13), 3, 2),    # clipped tail
-    ((2, 8, 12, 12), 2, 2),    # VGG/LeNet family
-    ((2, 8, 9, 9), 3, 1),      # inception same-size branch (no pad)
-    ((2, 8, 12, 12), 3, 2),    # even width + clipped tail: the tap slice
-    ((2, 8, 14, 14), 3, 2),    # needs (k-1)//s + ow > ceil(w/s) phase
-    ((2, 8, 56, 56), 3, 2),    # entries (GoogLeNet pool shapes 112/56/14)
-])
-def test_max_pool_hwcn_matches_eq(shape, k, s):
-    """Native-layout pool kernel == reference rule fwd; backward == exact
-    all-ties eq-mask unpool (mshadow semantics)."""
-    import jax
-    import jax.numpy as jnp
-    from cxxnet_tpu.ops import nn as N
-    from cxxnet_tpu.ops.pallas_kernels import max_pool_hwcn
-    x = jnp.asarray(np.random.RandomState(1).randn(*shape), jnp.float32)
-    a = max_pool_hwcn(x, k, s)
-    b = N._max_pool_raw(x, k, k, s, 0, 0)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
-    g = jnp.asarray(np.random.RandomState(2).randn(*a.shape), jnp.float32)
-    da = jax.vjp(lambda v: max_pool_hwcn(v, k, s), x)[1](g)[0]
-    db = jax.vjp(lambda v: N._max_pool_eq(v, k, k, s, 0, 0), x)[1](g)[0]
-    np.testing.assert_allclose(np.asarray(da), np.asarray(db), atol=1e-4)
-
-
-@pytest.mark.parametrize("shape,k,s", [
-    ((4, 16, 27, 27), 3, 2),   # AlexNet pool2 family (overlapping)
-    ((2, 8, 13, 13), 3, 2),    # clipped tail
-    ((2, 8, 12, 12), 2, 2),    # VGG/LeNet family
-    ((2, 8, 9, 9), 3, 1),      # inception same-size branch (no pad)
-    ((2, 8, 56, 56), 3, 2),    # GoogLeNet stage pool family
-])
-def test_max_pool_relu_fused_matches_unfused(shape, k, s):
-    """relu-fused multi-row pool backward (pool_relu_fuse;
-    pallas_kernels.max_pool_relu_hwcn): forward AND gradient are
-    bitwise ALL-TIES-identical to the unfused pair relu∘max_pool_hwcn
-    in interpret mode — the in-kernel ``pv > 0`` mask epilogue is
-    exactly relu's where(out > 0, dy, 0) because pv is the pre-relu
-    pool output."""
-    import jax
-    import jax.numpy as jnp
-    from cxxnet_tpu.ops.pallas_kernels import (max_pool_hwcn,
-                                               max_pool_relu_hwcn)
-    # shifted below zero so a real fraction of WINDOW MAXIMA are negative
-    # (a max of k*k unit Gaussians is almost never negative unshifted —
-    # the relu mask would be vacuously all-ones)
-    x = jnp.asarray(np.random.RandomState(1).randn(*shape) - 1.5,
-                    jnp.float32)
-    fused = max_pool_relu_hwcn(x, k, s)
-    unfused = jnp.maximum(max_pool_hwcn(x, k, s), 0)
-    np.testing.assert_array_equal(np.asarray(fused), np.asarray(unfused))
-    assert (np.asarray(fused) == 0).mean() > 0.2
-    g = jnp.asarray(np.random.RandomState(2).randn(*fused.shape),
-                    jnp.float32)
-    da = jax.vjp(lambda v: max_pool_relu_hwcn(v, k, s), x)[1](g)[0]
-    db = jax.vjp(lambda v: jnp.maximum(max_pool_hwcn(v, k, s), 0),
-                 x)[1](g)[0]
-    np.testing.assert_array_equal(np.asarray(da), np.asarray(db))
-
-
-def test_max_pool2d_relu_dispatcher_unfused_identity():
-    """ops.nn.max_pool2d_relu with pool_relu_fuse=0 (default) is exactly
-    apply_relu(max_pool2d(.)) — the pre-fusion execution form — for both
-    values and gradients; pool_relu_fuse=1 on CPU keeps the same path
-    (the fused kernel is gated to shapes the TPU hwcn kernel takes)."""
-    import jax
-    import jax.numpy as jnp
-    from cxxnet_tpu import engine
-    from cxxnet_tpu.layers.activation import apply_relu
-    from cxxnet_tpu.ops import nn as N
-    x = jnp.asarray(np.random.RandomState(3).randn(2, 4, 10, 10),
-                    jnp.float32)
-    ref_fn = lambda v: apply_relu(N.max_pool2d(v, 3, 3, 2))  # noqa: E731
-    ref = ref_fn(x)
-    g = jnp.asarray(np.random.RandomState(4).randn(*ref.shape),
-                    jnp.float32)
-    dref = jax.vjp(ref_fn, x)[1](g)[0]
-    saved = engine.opts.pool_relu_fuse
-    try:
-        for fuse in ("0", "1"):
-            engine.opts.set("pool_relu_fuse", fuse)
-            got = N.max_pool2d_relu(x, 3, 3, 2)
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-            dgot = jax.vjp(lambda v: N.max_pool2d_relu(v, 3, 3, 2),
-                           x)[1](g)[0]
-            np.testing.assert_array_equal(np.asarray(dgot),
-                                          np.asarray(dref))
-    finally:
-        engine.opts.set("pool_relu_fuse", saved)
-
-
-@pytest.mark.parametrize("geom", [
-    (8, 3, 23, 23, 16, 11, 4),   # AlexNet conv1 class (kb=3)
-    (4, 3, 18, 18, 8, 5, 2),     # 5x5/s2 class (kb=3)
-])
-def test_conv_wgrad_hwcn_matches_xla(geom):
-    import jax
-    import jax.numpy as jnp
-    from cxxnet_tpu.ops import nn as N
-    from cxxnet_tpu.ops.pallas_kernels import conv_wgrad_hwcn_pallas
-    n, c, h, w_, co, k, s = geom
-    rnd = np.random.RandomState(3)
-    x = jnp.asarray(rnd.randn(n, c, h, w_), jnp.float32)
-    wt = jnp.asarray(rnd.randn(co, c, k, k) * 0.1, jnp.float32)
-    oh = (h - k) // s + 1
-    dy = jnp.asarray(rnd.randn(n, co, oh, oh), jnp.float32)
-    _, vjp = jax.vjp(lambda wv: N.conv2d(x, wv, stride=s), wt)
-    (dw_ref,) = vjp(dy)
-    dw, db = conv_wgrad_hwcn_pallas(x, dy, kh=k, kw=k, stride=s)
-    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_ref),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(db),
-                               np.asarray(dy.sum(axis=(0, 2, 3))),
-                               rtol=1e-5, atol=1e-5)
-
-
 @pytest.mark.parametrize("nsize,beta", [(5, 0.75), (3, 0.5), (4, 0.75)])
 def test_lrn_band_matches_xla(nsize, beta):
     """Banded-matmul LRN (pallas_lrn = band) == chpool formulation,
@@ -424,28 +240,25 @@ def test_lrn_band_matches_xla(nsize, beta):
                                rtol=2e-4, atol=1e-5)
 
 
-def test_pool_channel_tile_legality():
-    """_pick_cb must return a tile that divides c and is a multiple of 8
-    (or c itself): the old halving loop landed on 60 for GoogLeNet's
-    480-channel stage-3 pool, which Mosaic rejects."""
-    from cxxnet_tpu.ops.pallas_kernels import (_pick_cb,
-                                               max_pool_hwcn_supported)
-    for c in (480, 240, 832, 96, 256, 192, 512, 64, 528):
-        for per in (28 * 128 * 4 * 8, 14 * 128 * 12 * 6, 55 * 128 * 4 * 5):
-            cb = _pick_cb(c, per, 10 << 20)
-            assert c % cb == 0
-            assert cb == c or cb % 8 == 0
-    # every GoogLeNet/AlexNet pool geometry is supported; w=224 (no legal
-    # tile fits the multi-row backward budget) is not
-    for shape, s in [((128, 64, 112, 112), 2),
-                     ((128, 192, 56, 56), 2),
-                     ((128, 480, 28, 28), 2),
-                     ((128, 832, 14, 14), 2),
-                     ((128, 96, 55, 55), 2),
-                     ((128, 256, 27, 27), 2)]:
-        assert max_pool_hwcn_supported(shape, s), shape
-    assert not max_pool_hwcn_supported((128, 64, 224, 224), 2)
-    assert not max_pool_hwcn_supported((100, 64, 28, 28), 2)  # lanes
+@pytest.mark.parametrize("value,nsize,beta", [
+    ("band", 5, 0.75), ("bandconv", 3, 0.5), ("0", 4, 0.75)])
+def test_lrn_dispatch_by_value(monkeypatch, value, nsize, beta):
+    """nn.lrn under each pallas_lrn value == the chpool formulation, fwd +
+    grad: band is every AlexNet cell's default, bandconv GoogLeNet.conf's,
+    0 the reference-literal lowering the pairtest gate compares against."""
+    from cxxnet_tpu.engine import opts
+    monkeypatch.setattr(opts, "pallas_lrn", value)
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 24, 5, 7),
+                    jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(N.lrn(x, nsize, 0.001, beta, 1.0)),
+        np.asarray(_xla_lrn(x, nsize, 0.001, beta, 1.0)),
+        rtol=2e-5, atol=1e-6)
+    ga = jax.grad(lambda v: (N.lrn(v, nsize, .001, beta, 1.) ** 2).sum())(x)
+    gb = jax.grad(
+        lambda v: (_xla_lrn(v, nsize, .001, beta, 1.) ** 2).sum())(x)
+    np.testing.assert_allclose(np.asarray(ga), np.asarray(gb),
+                               rtol=2e-4, atol=1e-5)
 
 
 def _ln_rel_err(a, b):

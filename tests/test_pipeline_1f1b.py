@@ -31,6 +31,7 @@ import jax.numpy as jnp  # noqa: E402
 from cxxnet_tpu import engine  # noqa: E402
 from cxxnet_tpu.io.data import DataBatch  # noqa: E402
 from cxxnet_tpu.models.zoo import lenet  # noqa: E402
+from helpers import assert_f32_roundoff  # noqa: E402
 from test_trainer import make_trainer  # noqa: E402
 
 EXTRA = [("eta", "0.1"), ("momentum", "0.9"), ("silent", "1"),
@@ -76,12 +77,12 @@ def _train(schedule, overlap, extra=(), tail_padd=0, n_micro=2):
     return losses, params
 
 
-def _assert_bitwise(a, b, who):
+def _assert_same(a, b, who, equal=np.testing.assert_array_equal):
     for la, lb in zip(a[0], b[0]):
-        np.testing.assert_array_equal(la, lb, err_msg=f"{who}: loss")
+        equal(la, lb, f"{who}: loss")
     fa, fb = jax.tree.leaves(a[1]), jax.tree.leaves(b[1])
     for x, y in zip(fa, fb):
-        np.testing.assert_array_equal(x, y, err_msg=f"{who}: params")
+        equal(x, y, f"{who}: params")
 
 
 @pytest.mark.parametrize("extra,tail_padd", [
@@ -90,12 +91,16 @@ def _assert_bitwise(a, b, who):
     pytest.param((("update_period", "2"),), 0, marks=pytest.mark.slow),
 ], ids=["plain", "tail_mask", "update_period"])
 def test_1f1b_bitwise_triangle(extra, tail_padd):
-    """implicit-1f1b == explicit-1f1b == gpipe, bitwise, at M = 2."""
+    """implicit-1f1b == explicit-1f1b, bitwise, and == gpipe at M = 2 up
+    to float32 rounding: the two schedules add the same two terms a key,
+    but they are two XLA programs and the compiler contracts and fuses
+    each one's update arithmetic its own way (the losses come out bit for
+    bit here, the parameters 2.5 units of the last place apart)."""
     imp = _train("1f1b", "0", extra, tail_padd)
     exp = _train("1f1b", "1", extra, tail_padd)
     gp = _train("gpipe", "0", extra, tail_padd)
-    _assert_bitwise(imp, exp, "1f1b explicit buckets vs implicit psum")
-    _assert_bitwise(imp, gp, "1f1b vs gpipe")
+    _assert_same(imp, exp, "1f1b explicit buckets vs implicit psum")
+    _assert_same(imp, gp, "1f1b vs gpipe", equal=assert_f32_roundoff)
 
 
 def test_remat_pipe_rejected():
