@@ -242,6 +242,9 @@ class ForwardContext:
     # incremental-decode cache state (serve/decode.py); None outside
     # task=serve generation
     decode: Optional[DecodeState] = None
+    # inside a loop's pass: ``(name, shape, dtype)`` of what the layers'
+    # kernels named for the pass's save set (``Network._forward_loop``)
+    saved: Optional[List[Tuple[str, Tuple[int, ...], Any]]] = None
     _rng_count: int = 0
 
     def next_rng(self) -> jax.Array:
@@ -379,10 +382,13 @@ class Layer:
         self.param = LayerParam()
         self.name: str = ""
 
-    def note_pallas(self, ctx: "ForwardContext") -> None:
-        """Called at trace time by a forward that takes a Pallas kernel."""
+    def note_pallas(self, ctx: "ForwardContext", saved=()) -> None:
+        """Called at trace time by a forward that takes a Pallas kernel;
+        ``saved`` is what the kernel names for a loop's save set."""
         if ctx.train:
             self.pallas_site = True
+        if ctx.saved is not None:
+            ctx.saved.extend(saved)
 
     # -- configuration ----------------------------------------------------
     def set_param(self, name: str, val: str) -> None:

@@ -58,14 +58,15 @@ def _single_device_attention(q, k, v, causal: bool, seg=None, on_flash=None):
     the segment-masked variants (packed documents): the triangular-flash
     segment kernel where the grid allows, the lax fallback elsewhere —
     the two are pairtested in interpret mode (tests/test_text.py).
-    ``on_flash`` is called where a flash kernel is taken."""
+    ``on_flash`` is called where a flash kernel is taken, with what the
+    kernel names for a loop's save set (``pk.flash_saved``)."""
     from ..engine import on_tpu, opts
     from ..ops import pallas_kernels as pk
     s, hd = q.shape[2], q.shape[3]
     if (on_tpu() and pk.flash_attention_available(s, hd)
             and opts.flash_attn == "1" and (seg is None or causal)):
         if on_flash is not None:
-            on_flash()
+            on_flash(pk.flash_saved(q))
         if seg is not None:
             return pk.flash_attention_segmented(q, k, v, seg)
         return pk.flash_attention(q, k, v, causal)
@@ -450,7 +451,7 @@ class AttentionLayer(Layer):
                     "one device", stacklevel=2)
             att = _single_device_attention(
                 q, k, v, bool(self.causal), seg=seg,
-                on_flash=lambda: self.note_pallas(ctx))
+                on_flash=lambda saved: self.note_pallas(ctx, saved))
         att = att.transpose(0, 2, 1, 3).reshape(b, 1, s, d)
         out = jnp.einsum("bcsd,nd->bcsn", att, params["wout"].astype(x.dtype))
         if "bout" in params:

@@ -1134,7 +1134,8 @@ class LearnTask:
         self.compile_sec = seconds
         self.net.metrics.emit("compile", compile_sec=round(seconds, 3),
                               round=self.start_counter - 1,
-                              pallas_sites=self.net.pallas_sites())
+                              pallas_sites=self.net.pallas_sites(),
+                              loop_saved=self.net.loop_saved())
         mlog.info(f"compile: {seconds:.1f} sec (first dispatch, excluded "
                   "from examples/sec)")
 

@@ -13,14 +13,16 @@ and parameter group, reproducing kSharedLayer (neural_net-inl.hpp:238-244).
 
 A ``loop[a->b] = T`` body (``netconfig.LoopInfo``) runs as ONE ``lax.scan``
 over the passes with the weights closed over, so the body is traced and
-compiled once whatever ``T``, and each pass is a ``jax.checkpoint``: the
-backward pass keeps only what a pass read and recomputes the rest, one pass
-at a time (:meth:`Network._forward_loop`).
+compiled once whatever ``T``, and each pass is a ``jax.checkpoint`` with a
+save set: the backward pass keeps what a pass read and what its flash
+attention kernels left for their backward (``o`` and ``lse``), and recomputes
+the rest, one pass at a time (:meth:`Network._forward_loop`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -60,6 +62,9 @@ class Network:
         # the loop as every pass's value, concatenated over channels
         self.loop_outs: Dict[int, List[int]] = {
             loop.start: self._loop_outs(loop) for loop in cfg.loops}
+        # per loop (``read->write``), what the last training trace of its
+        # body left for the backward pass beside the carry
+        self.loop_saved: Dict[str, dict] = {}
         self._infer_shapes()
 
     # -- construction -----------------------------------------------------
@@ -213,10 +218,21 @@ class Network:
         Each pass is a ``jax.checkpoint``: differentiated, the forward scan
         saves the carries and the backward scan recomputes one pass (with
         whatever head and loss layers the body holds) before it transposes
-        it, so one pass's activations live at a time.  The per-pass values of
+        it, so one pass's activations live at a time.  The checkpoint's save
+        set holds the two names the flash attention wrappers give what
+        their forward kernel leaves for the backward ones
+        (``ops/pallas_kernels.FLASH_SAVED``): those leave the forward scan
+        stacked over the passes, and the recomputed pass holds no forward
+        kernel.  That is unconditional: a pass kept one ``(rows, d)``
+        activation and keeps one more an attention layer that took the
+        kernel, of the fourteen or so a block holds while it is
+        differentiated; where no layer names anything (off the TPU,
+        ``flash_attn = 0``) the pass is a bare checkpoint.  What one
+        training trace kept is in ``loop_saved``.  The per-pass values of
         ``loop_outs`` leave as scan outputs; a body layer may not write to
         ``ctx.losses`` or ``ctx.diagnostics``, whose entries could not leave
         the traced body."""
+        from ..ops import pallas_kernels as pk
         outs = self.loop_outs[loop.start]
 
         def one_pass(carry, t):
@@ -224,12 +240,14 @@ class Network:
                 local = list(nodes)
                 local[loop.read] = carry
                 body_ctx = dataclasses.replace(
-                    ctx, losses=[], diagnostics={},
+                    ctx, losses=[], diagnostics={}, saved=[],
                     rng=None if ctx.rng is None
                     else jax.random.fold_in(ctx.rng, t))
                 body_buffers = dict(buffers)
                 self._forward_span(loop.start, loop.end, params,
                                    body_buffers, local, body_ctx)
+                if ctx.train:
+                    self._note_loop_saved(loop, body_ctx.saved)
                 assert not body_ctx.losses and not body_ctx.diagnostics \
                     and all(body_buffers[k] is buffers.get(k)
                             for k in body_buffers), (
@@ -239,7 +257,8 @@ class Network:
                     "layers with buffers are not supported")
                 return local[loop.write], tuple(local[n] for n in outs)
 
-        last, stacked = jax.lax.scan(jax.checkpoint(one_pass),
+        keep = jax.checkpoint_policies.save_only_these_names(*pk.FLASH_SAVED)
+        last, stacked = jax.lax.scan(jax.checkpoint(one_pass, policy=keep),
                                      nodes[loop.read],
                                      jnp.arange(loop.count))
         nodes[loop.write] = last
@@ -247,6 +266,18 @@ class Network:
             # (T, b, c, y, x) -> (b, T * c, y, x), pass-major
             nodes[n] = jnp.moveaxis(v, 0, 1).reshape(
                 v.shape[1], -1, *v.shape[3:])
+
+    def _note_loop_saved(self, loop, saved) -> None:
+        """``loop_saved``'s entry for ``loop`` from what one trace of its
+        body named: the names, how many tensors a pass keeps under them and
+        their bytes over all passes, from the shapes as traced."""
+        nbytes = sum(math.prod(shape) * jnp.dtype(dtype).itemsize
+                     for _, shape, dtype in saved)
+        names = self.cfg.node_names
+        self.loop_saved[f"{names[loop.read]}->{names[loop.write]}"] = {
+            "names": sorted({name for name, _, _ in saved}),
+            "tensors_per_pass": len(saved),
+            "bytes": nbytes * loop.count}
 
     def _forward_span(self, start: int, end: int, params: Params,
                       new_buffers: Params, nodes, ctx) -> None:

@@ -1937,6 +1937,14 @@ class NetTrainer:
                  for c in self.net.connections if c.layer.pallas_site}
         return dict(sorted(collections.Counter(kinds.values()).items()))
 
+    def loop_saved(self) -> Dict[str, dict]:
+        """Per ``loop[a->b]`` of the net (``"a->b"``), what a pass of the
+        last training trace keeps for the backward pass beside its carry:
+        the ``names`` the pass's checkpoint saves, ``tensors_per_pass`` and
+        their ``bytes`` over all passes, from the shapes as traced
+        (``Network._forward_loop``).  ``{}`` for a net without a loop."""
+        return dict(self.net.loop_saved)
+
     def step_hlo_text(self) -> Optional[str]:
         """Optimized-HLO text of the compiled train step (AOT-lowered
         from abstract args matching :meth:`update`'s operands), or None

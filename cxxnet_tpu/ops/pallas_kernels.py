@@ -24,6 +24,7 @@ import itertools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -599,12 +600,35 @@ def _norm_args(q, causal, scale, interpret):
     return scale, interpret
 
 
+# What the forward kernel leaves for the backward ones, by name, for a
+# ``jax.checkpoint`` whose policy saves them (``nnet/net.py``, a loop's
+# pass): the backward then reads ``o`` and ``lse`` and does not run the
+# forward kernel again.  The names sit inside the forward rule, on the
+# residuals themselves: a name on the layer's output would save a copy and
+# leave the kernel in the recomputed body.  Under no policy, or a bare
+# ``jax.checkpoint``, a name is the identity and lowers to nothing.
+FLASH_O, FLASH_LSE = "flash_o", "flash_lse"
+FLASH_SAVED = (FLASH_O, FLASH_LSE)
+
+
+def _fa_saved(o3, lse):
+    return checkpoint_name(o3, FLASH_O), checkpoint_name(lse, FLASH_LSE)
+
+
+def flash_saved(q):
+    """``(name, shape, dtype)`` of the two tensors a flash forward on ``q``
+    ``(b, h, s, d)`` names, for a loop's account of what a pass keeps."""
+    b, h, s_len, d = q.shape
+    return [(FLASH_O, (b * h, s_len, d), q.dtype),
+            (FLASH_LSE, (b * h, 1, s_len), jnp.float32)]
+
+
 def _flash_fwd_res(q, k, v, causal, scale, interpret):
     scale, interpret = _norm_args(q, causal, scale, interpret)
     b, h, s_len, d = q.shape
     sh3 = (b * h, s_len, d)
-    o3, lse = _fa_fwd(q.reshape(sh3), k.reshape(sh3), v.reshape(sh3),
-                      scale, causal, interpret)
+    o3, lse = _fa_saved(*_fa_fwd(q.reshape(sh3), k.reshape(sh3),
+                                 v.reshape(sh3), scale, causal, interpret))
     return o3.reshape(q.shape), (q, k, v, o3, lse)
 
 
@@ -638,8 +662,9 @@ def _flash_seg_fwd_res(q, k, v, seg, scale, interpret):
     b, h, s_len, d = q.shape
     sh3 = (b * h, s_len, d)
     seg3 = _seg_tile(seg, h)
-    o3, lse = _fa_fwd(q.reshape(sh3), k.reshape(sh3), v.reshape(sh3),
-                      scale, True, interpret, seg3)
+    o3, lse = _fa_saved(*_fa_fwd(q.reshape(sh3), k.reshape(sh3),
+                                 v.reshape(sh3), scale, True, interpret,
+                                 seg3))
     return o3.reshape(q.shape), (q, k, v, seg, o3, lse)
 
 

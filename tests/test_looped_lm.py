@@ -48,20 +48,20 @@ def make_trainer(text, extra=()):
     return t
 
 
-def packed_batch(seed=0):
+def packed_batch(seed=0, s=S):
     """``B`` rows of three documents each, in the ``packseq`` layout: data
     (b,1,1,s) and label (b, 3s) = targets (-1 across a boundary) | segment
     ids 1..3 | positions that restart at each document."""
     rng = np.random.default_rng(seed)
-    data = rng.integers(0, V, (B, 1, 1, S)).astype(np.float32)
-    label = np.zeros((B, 3 * S), np.float32)
+    data = rng.integers(0, V, (B, 1, 1, s)).astype(np.float32)
+    label = np.zeros((B, 3 * s), np.float32)
     for r in range(B):
-        cuts = np.sort(rng.choice(np.arange(2, S - 1), 2, replace=False))
-        lens = np.diff(np.concatenate([[0], cuts, [S]]))
+        cuts = np.sort(rng.choice(np.arange(2, s - 1), 2, replace=False))
+        lens = np.diff(np.concatenate([[0], cuts, [s]]))
         seg = np.repeat(np.arange(1, 4), lens)
         pos = np.concatenate([np.arange(n) for n in lens])
-        tgt = np.roll(data[r].reshape(S), -1)
-        tgt[np.concatenate([cuts - 1, [S - 1]])] = -1
+        tgt = np.roll(data[r].reshape(s), -1)
+        tgt[np.concatenate([cuts - 1, [s - 1]])] = -1
         label[r] = np.concatenate([tgt, seg, pos])
     return data, label
 
@@ -246,8 +246,7 @@ def test_loop_of_four_equals_the_blocks_written_out_with_share():
 def test_loop_of_one_is_the_plain_stack():
     data, label = packed_batch(seed=4)
     text = looped_lm(**SIZES, passes=1, packed=True)
-    plain = "\n".join(ln for ln in text.split("\n")
-                      if not ln.startswith("loop"))
+    plain = without_loop(text)
     assert not parse_net(plain).loops and parse_net(text).loops
     loss, diags, grads = system_loss_and_grads(make_trainer(text), data,
                                                label)
@@ -258,17 +257,32 @@ def test_loop_of_one_is_the_plain_stack():
     assert_grads_close(grads, want_grads, rtol=2e-5)
 
 
+def without_loop(text):
+    return "\n".join(ln for ln in text.split("\n")
+                     if not ln.startswith("loop"))
+
+
 def parse_net(text):
     cfg = NetConfig()
     cfg.configure(list(parse_config_string(text)))
     return cfg
 
 
-def test_recomputation_at_the_pass_boundary_leaves_the_gradient(monkeypatch):
-    data, label = packed_batch(seed=5)
-    t = make_trainer(looped_lm(**SIZES, passes=4, packed=True))
+@pytest.mark.parametrize("flash", [False, True])
+def test_recomputation_at_the_pass_boundary_leaves_the_gradient(monkeypatch,
+                                                                flash):
+    """The pass's checkpoint against no checkpoint at all: on the ``lax``
+    attention path, where the save set names nothing, and with the flash
+    kernels interpreted at s 128, where a pass keeps their ``o`` and
+    ``lse``."""
+    if flash:
+        on_an_emulated_tpu(monkeypatch)
+    sizes, s = (FLASH_SIZES, FLASH_S) if flash else (SIZES, S)
+    data, label = packed_batch(seed=5, s=s)
+    t = make_trainer(looped_lm(**sizes, passes=4, packed=True))
     loss, _, grads = system_loss_and_grads(t, data, label)
-    monkeypatch.setattr(jax, "checkpoint", lambda fn: fn)
+    assert t.net.loop_saved["x0->h"]["tensors_per_pass"] == 2 * LAYERS * flash
+    monkeypatch.setattr(jax, "checkpoint", lambda fn, **kw: fn)
     plain_loss, _, plain_grads = system_loss_and_grads(t, data, label)
     assert loss == pytest.approx(plain_loss, abs=1e-6)
     assert_grads_close(grads, plain_grads, rtol=2e-5)
@@ -292,12 +306,15 @@ def test_the_body_is_traced_once_whatever_the_count():
 # ------------------------------------------------------ the rmsnorm kernels
 
 KERNEL_SIZES = dict(SIZES, dim=128, ffn=160)   # d on the lane width; 48 rows
+FLASH_S = 128                       # the shortest row a flash kernel takes
+FLASH_SIZES = dict(KERNEL_SIZES, seq=FLASH_S)
 
 
 def on_an_emulated_tpu(monkeypatch):
     """The layers believe the step runs on a TPU (``engine.on_tpu``, read at
     trace time); the kernels still see the CPU and run interpreted.  At
-    s 24 attention has no flash kernel, so rmsnorm alone changes path.
+    s 24 attention has no flash kernel, so rmsnorm alone changes path; at
+    s 128 (``FLASH_SIZES``) attention takes it too.
     Returns the list of the shapes ``rmsnorm_pallas`` was called with."""
     import cxxnet_tpu.engine as engine
     from cxxnet_tpu.ops import pallas_kernels as pk
@@ -340,35 +357,125 @@ def test_the_looped_net_with_the_rmsnorm_kernels_is_the_net_without(
     assert_grads_close(grads, want_grads)
 
 
+def compile_record(tmp_path, net_text, s, name="run"):
+    """One round of ``net_text`` on packed rows of ``s`` tokens through
+    ``LearnTask.run``: the task and its ``compile`` record."""
+    from benchmark.lib import corpus
+    from cxxnet_tpu.main import LearnTask
+    prefix = str(tmp_path / "train_%d.tok")
+    corpus.make(0, V, dict(law="zipf_markov", docs=60, mean_len=s // 2,
+                           max_len=s, shards=2), prefix)
+    conf = str(tmp_path / "net.conf")
+    with open(conf, "w") as f:
+        f.write(f"data = train\niter = text\n  path_tok = {prefix}\n"
+                f"  tok_count = 2\niter = packseq\n  seqlen = {s}\n"
+                "iter = end\n" + net_text
+                + f"\nbatch_size = {B}\ndev = cpu\nupdater = adam\n"
+                "eta = 0.001\nnum_round = 1\nmax_round = 1\n"
+                "save_model = 0\neval_train = 0\nsilent = 1\n")
+    sink = str(tmp_path / f"{name}.jsonl")
+    task = LearnTask()
+    assert task.run([conf, f"metrics_sink=jsonl:{sink}"]) == 0
+    with open(sink) as f:
+        rec, = [r for r in map(json.loads, f) if r["kind"] == "compile"]
+    return task, rec
+
+
 def test_compile_record_counts_the_layers_that_took_a_kernel(
         tmp_path, monkeypatch):
     """``pallas_sites`` on the ``compile`` record: layers by type, not calls
     (the body is traced for the forward scan, the recomputation and the
     transpose); empty where no kernel is taken."""
-    from benchmark.lib import corpus
-    from cxxnet_tpu.main import LearnTask
-    prefix = str(tmp_path / "train_%d.tok")
-    corpus.make(0, V, dict(law="zipf_markov", docs=60, mean_len=12,
-                           max_len=S, shards=2), prefix)
-    conf = str(tmp_path / "net.conf")
-    with open(conf, "w") as f:
-        f.write(f"data = train\niter = text\n  path_tok = {prefix}\n"
-                f"  tok_count = 2\niter = packseq\n  seqlen = {S}\n"
-                "iter = end\n"
-                + looped_lm(**KERNEL_SIZES, passes=4, packed=True)
-                + f"\nbatch_size = {B}\ndev = cpu\nupdater = adam\n"
-                "eta = 0.001\nnum_round = 1\nmax_round = 1\n"
-                "save_model = 0\neval_train = 0\nsilent = 1\n")
+    text = looped_lm(**KERNEL_SIZES, passes=4, packed=True)
     want = {}
     for name in ("plain", "kernels"):
-        sink = str(tmp_path / f"{name}.jsonl")
-        task = LearnTask()
-        assert task.run([conf, f"metrics_sink=jsonl:{sink}"]) == 0
-        with open(sink) as f:
-            rec, = [r for r in map(json.loads, f) if r["kind"] == "compile"]
+        task, rec = compile_record(tmp_path, text, S, name)
         assert rec["pallas_sites"] == want == task.net.pallas_sites()
         on_an_emulated_tpu(monkeypatch)
         want = {"rmsnorm": 4 * LAYERS + 1}
+
+
+# -------------------------------------------- what a pass keeps: o and lse
+
+def flash_forward_calls(jaxpr, nbh, s):
+    """The ``pallas_call`` equations of ``jaxpr`` and of every jaxpr inside
+    it that are a flash FORWARD kernel: the one with two outputs, the second
+    the ``(b*h, 1, s)`` float32 ``lse``."""
+    n = 0
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            avals = e.params["out_avals"]
+            n += len(avals) == 2 and avals[1].shape == (nbh, 1, s)
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += flash_forward_calls(sub, nbh, s)
+    return n
+
+
+@pytest.mark.parametrize("segmented", [False, True])
+def test_the_save_set_takes_the_flash_forward_out_of_the_recomputed_body(
+        segmented):
+    """A scan of checkpointed bodies around one flash call, interpreted: under
+    the loop's save set the gradient holds ONE forward kernel (the forward
+    scan's; the backward scan reads ``o`` and ``lse``), under a bare
+    ``jax.checkpoint`` two, and the two gradients are equal bit for bit."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    b, h, s, d = 1, 2, FLASH_S, 64
+    rng = np.random.default_rng(3)
+    x, w, k, v = (jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
+                  for _ in range(4))
+    seg = jnp.asarray(np.repeat([1, 2, 3], [40, 50, 38])[None], jnp.int32)
+
+    def grad_fn(**policy):
+        def loss(w, x):   # as in a loop: the weight closed over, x carried
+            def body(carry, _):
+                q = carry * w
+                o = pk.flash_attention_segmented(
+                    q, k, v, seg, interpret=True) if segmented \
+                    else pk.flash_attention(q, k, v, True, None, True)
+                return carry + o, None
+            last, _ = jax.lax.scan(jax.checkpoint(body, **policy), x,
+                                   jnp.arange(3))
+            return jnp.sum(last ** 2)
+        return jax.grad(loss, argnums=(0, 1))
+
+    saved = grad_fn(policy=jax.checkpoint_policies.save_only_these_names(
+        *pk.FLASH_SAVED))
+    bare = grad_fn()
+    calls = [flash_forward_calls(jax.make_jaxpr(f)(w, x).jaxpr, b * h, s)
+             for f in (saved, bare)]
+    assert calls == [1, 2]
+    for got, want in zip(jax.jit(saved)(w, x), jax.jit(bare)(w, x)):
+        assert np.isfinite(np.asarray(want)).all() and np.abs(want).max() > 0
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("net", ["no_loop", "lax", "flash"])
+def test_compile_record_says_what_a_pass_keeps(tmp_path, monkeypatch, net):
+    """``loop_saved`` on the ``compile`` record: ``{}`` for a net without a
+    loop; for the looped net on the ``lax`` attention path an entry that
+    names nothing; with the flash kernels taken both names, two tensors an
+    attention layer and their bytes over the four passes."""
+    flash = net == "flash"
+    sizes, s = (FLASH_SIZES, FLASH_S) if flash else (SIZES, S)
+    if flash:
+        on_an_emulated_tpu(monkeypatch)
+    text = looped_lm(**sizes, passes=1 if net == "no_loop" else 4,
+                     packed=True)
+    want = {"x0->h": {"names": [], "tensors_per_pass": 0, "bytes": 0}}
+    if net == "no_loop":
+        text, want = without_loop(text), {}
+    if flash:
+        hd = sizes["dim"] // HEADS
+        want = {"x0->h": {
+            "names": ["flash_lse", "flash_o"],
+            "tensors_per_pass": 2 * LAYERS,
+            "bytes": 4 * LAYERS * 4 * (B * HEADS * s * hd + B * HEADS * s)}}
+    task, rec = compile_record(tmp_path, text, s)
+    assert rec["loop_saved"] == want == task.net.loop_saved()
+    assert bool(task.net.pallas_sites().get("attention")) == flash
 
 
 # ------------------------------------------------------- the exit distribution
