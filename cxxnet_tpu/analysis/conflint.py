@@ -620,8 +620,9 @@ def _text_rules(pairs: ConfigPairs, last: Dict[str, str],
       surfaced here before any compile);
     * a ``packseq`` data section requires segment-aware consumers:
       ``softmax_seq`` without ``packed = 1`` trains on cross-document
-      targets and ``attention`` without ``segment_key`` leaks
-      cross-document scores — both errors;
+      targets, ``attention`` without ``segment_key`` leaks
+      cross-document scores and ``mamba2`` without it carries its state
+      and its conv taps across documents — all errors;
     * the packer's ``seqlen`` must equal the netconfig input width.
     """
     from ..parallel.mesh import MeshSpec
@@ -646,8 +647,8 @@ def _text_rules(pairs: ConfigPairs, last: Dict[str, str],
     # the runtime will actually use
     global_seqlen: Optional[str] = None
     cur_layer = ""
-    n_attention = 0
-    n_att_seg = 0
+    # layers that must read the segment ids of packed rows: [count, with key]
+    seg_layers = {"attention": [0, 0], "mamba2": [0, 0]}
     softmax_seq_packed = False
     for name, val in pairs:
         if name in _SECTION_HEADS:
@@ -670,11 +671,11 @@ def _text_rules(pairs: ConfigPairs, last: Dict[str, str],
             continue
         if name.startswith("layer["):
             cur_layer = val.split(":", 1)[0]
-            if cur_layer == "attention":
-                n_attention += 1
+            if cur_layer in seg_layers:
+                seg_layers[cur_layer][0] += 1
             continue
-        if cur_layer == "attention" and name == "segment_key" and val:
-            n_att_seg += 1
+        if cur_layer in seg_layers and name == "segment_key" and val:
+            seg_layers[cur_layer][1] += 1
         elif cur_layer == "softmax_seq" and name == "packed" \
                 and val.strip() == "1":
             softmax_seq_packed = True
@@ -731,13 +732,15 @@ def _text_rules(pairs: ConfigPairs, last: Dict[str, str],
                     "'packed = 1': cross-document and padding targets "
                     "would train as real next-token targets; set "
                     "packed = 1 on the loss layer (doc/io.md)"))
-    if n_attention and n_att_seg < n_attention:
-        add(Finding("error", "segment_key",
-                    f"packseq data section but {n_attention - n_att_seg} "
-                    f"of {n_attention} attention layer(s) have no "
-                    "segment_key: cross-document attention leaks across "
-                    "packed rows; set segment_key = <segment field> "
-                    "(doc/io.md)"))
+    leaks = {"attention": "cross-document attention leaks across packed rows",
+             "mamba2": "the state and the conv taps carry across packed "
+                       "documents"}
+    for kind, (n, with_key) in seg_layers.items():
+        if with_key < n:
+            add(Finding("error", "segment_key",
+                        f"packseq data section but {n - with_key} of {n} "
+                        f"{kind} layer(s) have no segment_key: {leaks[kind]}; "
+                        "set segment_key = <segment field> (doc/io.md)"))
 
 
 #: keys the incremental-decode path consumes (serve/decode.py); the

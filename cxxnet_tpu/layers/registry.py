@@ -11,8 +11,8 @@ from __future__ import annotations
 from typing import Dict, Type
 
 from .activation import (BiasLayer, GeluLayer, InsanityLayer, PReluLayer,
-                         ReluLayer, SigmoidLayer, SiluLayer, SoftplusLayer,
-                         TanhLayer, XeluLayer)
+                         ReluLayer, ScaleLayer, SigmoidLayer, SiluLayer,
+                         SoftplusLayer, TanhLayer, XeluLayer)
 from .base import Layer
 from .conv import (AvgPoolingLayer, ConvolutionLayer, InsanityPoolingLayer,
                    LRNLayer, MaxPoolingLayer, ReluMaxPoolingLayer,
@@ -25,6 +25,7 @@ from .pairtest import PairTestLayer
 from .sequence import (AttentionLayer, EmbeddingLayer, ExitLossLayer,
                        LayerNormLayer, RMSNormLayer, SeqFullcLayer,
                        SeqXentLayer, SoftmaxSeqLayer)
+from .ssm import Mamba2Layer
 from .shape_ops import (ChConcatLayer, ConcatLayer, EltMulLayer, EltSumLayer,
                         FlattenLayer, MaxoutLayer, SplitLayer)
 
@@ -46,7 +47,7 @@ for _cls in (ReluLayer, SigmoidLayer, TanhLayer, SoftplusLayer, XeluLayer,
              MultiLogisticLayer, GeluLayer, EmbeddingLayer, LayerNormLayer,
              SeqFullcLayer, AttentionLayer, SoftmaxSeqLayer, MoELayer,
              SiluLayer, EltMulLayer, RMSNormLayer, SeqXentLayer,
-             ExitLossLayer):
+             ExitLossLayer, ScaleLayer, Mamba2Layer):
     register(_cls)
 
 

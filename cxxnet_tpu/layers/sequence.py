@@ -49,7 +49,8 @@ def _label_field(ctx: ForwardContext, name: str):
     return ctx.labels.fields[name]
 
 
-def _single_device_attention(q, k, v, causal: bool, seg=None, on_flash=None):
+def _single_device_attention(q, k, v, causal: bool, seg=None, on_flash=None,
+                             scale=None):
     """Single-device attention dispatch: the Pallas flash kernel on TPU
     (VMEM-resident scores; measured 3.2x the XLA chunked path forward at
     s=8192 on v5e, and the only path whose backward fits at that length),
@@ -59,7 +60,8 @@ def _single_device_attention(q, k, v, causal: bool, seg=None, on_flash=None):
     segment kernel where the grid allows, the lax fallback elsewhere —
     the two are pairtested in interpret mode (tests/test_text.py).
     ``on_flash`` is called where a flash kernel is taken, with what the
-    kernel names for a loop's save set (``pk.flash_saved``)."""
+    kernel names for a loop's save set (``pk.flash_saved``).  ``scale``
+    multiplies the scores (None: ``1/sqrt(hd)``)."""
     from ..engine import on_tpu, opts
     from ..ops import pallas_kernels as pk
     s, hd = q.shape[2], q.shape[3]
@@ -68,9 +70,9 @@ def _single_device_attention(q, k, v, causal: bool, seg=None, on_flash=None):
         if on_flash is not None:
             on_flash(pk.flash_saved(q))
         if seg is not None:
-            return pk.flash_attention_segmented(q, k, v, seg)
-        return pk.flash_attention(q, k, v, causal)
-    return ring.dense_attention(q, k, v, causal=causal, seg=seg)
+            return pk.flash_attention_segmented(q, k, v, seg, scale)
+        return pk.flash_attention(q, k, v, causal, scale)
+    return ring.dense_attention(q, k, v, causal=causal, scale=scale, seg=seg)
 
 
 def _rotary(q, k, pos, theta: float):
@@ -306,9 +308,31 @@ class SeqFullcLayer(Layer):
     Unlike ``fullc`` (which flattens the node to (b, c*h*w) — correct for
     image heads, wrong for sequences), this is position-wise.  Weight "wmat"
     (nhidden, d), bias "bias" (nhidden,) — same tags/layout as fullc.
+
+    ``tie = <layer name>`` makes it a tied output head: the layer owns no
+    parameters and reads the named ``embedding`` layer's ``(vocab, d)``
+    table as its "wmat" (``Network._build`` points the connection at that
+    layer's parameter group), so the table's gradient is the sum of both
+    uses and the optimizer keeps one state for it.  Needs ``no_bias = 1``
+    and ``nhidden`` equal to the table's rows.
     """
 
     type_names = ("seq_fullc",)
+    extra_config_keys = (
+        K("tie", "str",
+          help="name of the embedding layer whose table this head reads "
+               "(a tied output head); empty = a weight of its own"),
+    )
+
+    def __init__(self):
+        super().__init__()
+        self.tie = ""
+
+    def set_param(self, name, val):
+        if name == "tie":
+            self.tie = val
+        else:
+            super().set_param(name, val)
 
     def infer_shapes(self, in_shapes: List[Shape4]) -> List[Shape4]:
         assert len(in_shapes) == 1, "seq_fullc: 1-1 connection only"
@@ -340,7 +364,13 @@ class AttentionLayer(Layer):
     """Multi-head self-attention on (b,1,s,d).
 
     Params: "wqkv" (3d, d), "wout" (d, d), biases "bqkv"/"bout" unless
-    ``no_bias``.  Config: ``nhead`` (required), ``causal = 0|1``.
+    ``no_bias``.  Config: ``nhead`` (required), ``causal = 0|1``.  With
+    ``nkvhead < nhead`` groups of ``nhead / nkvhead`` query heads share a
+    key/value head (query head ``i`` reads head ``i // group``) and "wqkv"
+    is ``((nhead + 2 nkvhead) hd, d)``: q's rows, then k's, then v's.  K and
+    V are repeated to ``nhead`` heads in front of the kernels and the decode
+    cache, which know one head count.  ``score_scale`` multiplies the scores
+    in every path (0, the default: ``1/sqrt(hd)``).
 
     When the trainer mesh has a ``seq`` axis the score computation runs as
     ring attention (K/V rotating over ICI, online softmax — see
@@ -352,6 +382,11 @@ class AttentionLayer(Layer):
     type_names = ("attention",)
     extra_config_keys = (
         K("nhead", "int", lo=1), K("causal", "int", lo=0, hi=1),
+        K("nkvhead", "int", lo=0,
+          help="key/value heads, shared by groups of nhead / nkvhead query "
+               "heads; 0 = nhead"),
+        K("score_scale", "float", lo=0.0,
+          help="multiplier of the scores q k^T; 0 = 1/sqrt(head size)"),
         K("segment_key", "str",
           help="label field with per-position segment ids (packed "
                "documents, io/text.py): attention is block-diagonal — "
@@ -368,6 +403,8 @@ class AttentionLayer(Layer):
     def __init__(self):
         super().__init__()
         self.nhead = 0
+        self.nkvhead = 0
+        self.score_scale = 0.0
         self.causal = 0
         self.segment_key = ""
         self.rope = 0
@@ -377,6 +414,10 @@ class AttentionLayer(Layer):
     def set_param(self, name, val):
         if name == "nhead":
             self.nhead = int(val)
+        elif name == "nkvhead":
+            self.nkvhead = int(val)
+        elif name == "score_scale":
+            self.score_scale = float(val)
         elif name == "causal":
             self.causal = int(val)
         elif name == "segment_key":
@@ -396,17 +437,20 @@ class AttentionLayer(Layer):
         assert c == 1, "attention: input must be (b,1,s,d)"
         assert self.nhead > 0, "attention: must set nhead"
         assert d % self.nhead == 0, "attention: nhead must divide dim"
+        assert self.nhead % (self.nkvhead or self.nhead) == 0, \
+            "attention: nkvhead must divide nhead"
         return [in_shapes[0]]
 
     def init_params(self, key, in_shapes, dtype=jnp.float32):
         d = in_shapes[0][3]
+        nqkv = d + 2 * (self.nkvhead or self.nhead) * (d // self.nhead)
         kq, ko = jax.random.split(key)
         params = {
-            "wqkv": self.param.rand_init_weight(kq, (3 * d, d), d, 3 * d, dtype),
+            "wqkv": self.param.rand_init_weight(kq, (nqkv, d), d, nqkv, dtype),
             "wout": self.param.rand_init_weight(ko, (d, d), d, d, dtype),
         }
         if not self.param.no_bias:
-            params["bqkv"] = jnp.zeros((3 * d,), dtype)
+            params["bqkv"] = jnp.zeros((nqkv,), dtype)
             params["bout"] = jnp.zeros((d,), dtype)
         return params
 
@@ -419,16 +463,24 @@ class AttentionLayer(Layer):
         qkv = jnp.einsum("bcsd,nd->bcsn", x, params["wqkv"].astype(x.dtype))
         if "bqkv" in params:
             qkv = qkv + params["bqkv"].astype(x.dtype)
-        qkv = qkv.reshape(b, s, 3, h, hd).transpose(2, 0, 3, 1, 4)
-        q, k, v = qkv[0], qkv[1], qkv[2]  # (b, h, s, hd)
+        nkv = self.nkvhead or h
+        if nkv == h:
+            qkv = qkv.reshape(b, s, 3, h, hd).transpose(2, 0, 3, 1, 4)
+            q, k, v = qkv[0], qkv[1], qkv[2]  # (b, h, s, hd)
+        else:
+            q, k, v = (t.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
+                       for t in jnp.split(qkv[:, 0], [d, d + nkv * hd], -1))
         dec = getattr(ctx, "decode", None)
         if self.rope:
             assert dec is None, "attention: rope = 1 has no decode cache path"
             pos = _label_field(ctx, self.pos_key)
             q, k = _rotary(q, k, jnp.arange(s)[None] if pos is None else pos,
                            self.rope_theta)
+        if nkv != h:  # after the rotary turn: it turns K's heads, not Q's count
+            k, v = (jnp.repeat(t, h // nkv, axis=1) for t in (k, v))
+        scale = self.score_scale or 1.0 / (hd ** 0.5)
         if dec is not None:
-            att = self._decode_attention(dec, q, k, v)
+            att = self._decode_attention(dec, q, k, v, scale)
             att = att.transpose(0, 2, 1, 3).reshape(b, 1, s, d)
             out = jnp.einsum("bcsd,nd->bcsn", att,
                              params["wout"].astype(x.dtype))
@@ -441,7 +493,8 @@ class AttentionLayer(Layer):
         mesh = _seq_mesh(ctx)
         if mesh is not None and s % mesh.shape["seq"] == 0:
             att = ring.sharded_attention(q, k, v, mesh,
-                                         causal=bool(self.causal), seg=seg)
+                                         causal=bool(self.causal), seg=seg,
+                                         scale=scale)
         else:
             if mesh is not None:
                 warnings.warn(
@@ -451,14 +504,15 @@ class AttentionLayer(Layer):
                     "one device", stacklevel=2)
             att = _single_device_attention(
                 q, k, v, bool(self.causal), seg=seg,
-                on_flash=lambda saved: self.note_pallas(ctx, saved))
+                on_flash=lambda saved: self.note_pallas(ctx, saved),
+                scale=scale)
         att = att.transpose(0, 2, 1, 3).reshape(b, 1, s, d)
         out = jnp.einsum("bcsd,nd->bcsn", att, params["wout"].astype(x.dtype))
         if "bout" in params:
             out = out + params["bout"].astype(x.dtype)
         return [seq_constraint(out, ctx)], buffers
 
-    def _decode_attention(self, dec, q, k, v):
+    def _decode_attention(self, dec, q, k, v, scale):
         """Cache-aware attention for incremental decode (serve/decode.py).
 
         Prefill captures this layer's fresh (k, v) into the decode cache
@@ -483,7 +537,8 @@ class AttentionLayer(Layer):
         assert self.causal, "incremental decode requires causal = 1"
         if dec.mode not in ("step", "block"):
             dec.caches[key] = {"k": k, "v": v}
-            return _single_device_attention(q, k, v, True, seg=None)
+            return _single_device_attention(q, k, v, True, seg=None,
+                                            scale=scale)
         b, h, s, hd = q.shape
         if dec.mode == "step":
             assert s == 1, f"decode step expects seq len 1, got {s}"
@@ -519,7 +574,6 @@ class AttentionLayer(Layer):
             # position
             qoff = jnp.arange(s, dtype=jnp.int32)
         dec.caches[key] = {"k": ck, "v": cv}
-        scale = 1.0 / (hd ** 0.5)
         scores = jnp.einsum("bhqd,bhkd->bhqk", q,
                             ck.astype(q.dtype),
                             preferred_element_type=jnp.float32) * scale

@@ -1135,7 +1135,8 @@ class LearnTask:
         self.net.metrics.emit("compile", compile_sec=round(seconds, 3),
                               round=self.start_counter - 1,
                               pallas_sites=self.net.pallas_sites(),
-                              loop_saved=self.net.loop_saved())
+                              loop_saved=self.net.loop_saved(),
+                              ssm_sites=self.net.ssm_sites())
         mlog.info(f"compile: {seconds:.1f} sec (first dispatch, excluded "
                   "from examples/sec)")
 

@@ -9,8 +9,8 @@ language, so everything downstream (NetConfig, trainer, checkpointing,
 wrapper) treats zoo models identically to user-written config files.
 """
 
-from .zoo import (alexnet, googlenet, lenet, looped_lm, mlp, resnet,
-                  transformer, vgg)
+from .zoo import (alexnet, googlenet, hybrid_lm, lenet, looped_lm, mlp,
+                  resnet, transformer, vgg)
 
-__all__ = ["alexnet", "googlenet", "lenet", "looped_lm", "mlp", "resnet",
-           "transformer", "vgg"]
+__all__ = ["alexnet", "googlenet", "hybrid_lm", "lenet", "looped_lm", "mlp",
+           "resnet", "transformer", "vgg"]

@@ -430,6 +430,101 @@ def looped_lm(vocab: int, seq: int, dim: int, nlayer: int, nhead: int,
     return "\n".join(lines) + "\n"
 
 
+def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
+              nhead: int, nkvhead: int, ffn: int, ssm_heads: int,
+              ssm_head_dim: int, ssm_state: int, ssm_groups: int = 1,
+              ssm_conv: int = 4, ssm_chunk: int = 256,
+              att_scale: float = 0.0, emb_mult: float = 1.0,
+              res_mult: float = 1.0, logit_div: float = 1.0,
+              eps: float = 1e-5, packed: bool = False) -> str:
+    """Hybrid state-space / attention decoder-only LM (the Granite 4.0-H
+    family's block; Mamba-2: Dao & Gu 2024, arXiv:2405.21060).
+
+    ``h0 = emb_mult * E[tokens]``; layer ``l`` of ``layer_types`` (``mamba``
+    or ``attention``): ``a = h + res_mult * mixer(rmsnorm(h))``, ``h' = a +
+    res_mult * W_d(silu(W_g u) * (W_u u))`` with ``u = rmsnorm(a)``; the
+    mixer a ``mamba2`` layer or grouped-query attention without biases or
+    positions, its scores times ``att_scale`` (0: ``1/sqrt(hd)``); ``logits =
+    E rmsnorm(h_L) / logit_div`` over the SAME table (``tie``); the loss is
+    ``softmax_seq``'s mean over scored positions.  ``packed``: segment ids mask
+    attention, restart the recurrence and the conv taps, and boundary
+    targets are left out of the loss.
+    """
+    seg = ["  segment_key = segment"] if packed else []
+    lines = ["netconfig=start",
+             "layer[0->x0] = embedding:embed",
+             f"  vocab_size = {vocab}",
+             f"  nhidden = {dim}",
+             "  init_sigma = 0.02",
+             "layer[+0] = scale",
+             f"  factor = {emb_mult}"]
+    for i, kind in enumerate(layer_types):
+        a, m = f"b{i}a", f"b{i}m"
+        if kind == "mamba":
+            mixer = [f"layer[{a}_n->{a}_o] = mamba2:l{i}_mamba",
+                     f"  nhead = {ssm_heads}",
+                     f"  head_dim = {ssm_head_dim}",
+                     f"  d_state = {ssm_state}",
+                     f"  ngroup = {ssm_groups}",
+                     f"  kernel_size = {ssm_conv}",
+                     f"  chunk = {ssm_chunk}",
+                     f"  eps = {eps}"]
+        elif kind == "attention":
+            mixer = [f"layer[{a}_n->{a}_o] = attention:l{i}_att",
+                     f"  nhead = {nhead}",
+                     f"  nkvhead = {nkvhead}",
+                     f"  score_scale = {att_scale}",
+                     "  causal = 1",
+                     "  no_bias = 1"]
+        else:
+            raise ValueError(f"hybrid_lm: layer type {kind!r} is neither "
+                             "'mamba' nor 'attention'")
+        lines += [
+            f"layer[x{i}->{a}_r,{a}_in] = split",
+            f"layer[{a}_in->{a}_n] = rmsnorm:l{i}_norm1",
+            f"  eps = {eps}",
+            *mixer, *seg,
+            "layer[+0] = scale",
+            f"  factor = {res_mult}",
+            f"layer[{a}_r,{a}_o->{m}] = eltsum",
+            f"layer[{m}->{m}_r,{m}_in] = split",
+            f"layer[{m}_in->{m}_n] = rmsnorm:l{i}_norm2",
+            f"  eps = {eps}",
+            f"layer[{m}_n->{m}_n1,{m}_n2] = split",
+            f"layer[{m}_n1->{m}_g] = seq_fullc:l{i}_ffn_gate",
+            f"  nhidden = {ffn}",
+            "  no_bias = 1",
+            "layer[+0] = silu",
+            f"layer[{m}_n2->{m}_u] = seq_fullc:l{i}_ffn_up",
+            f"  nhidden = {ffn}",
+            "  no_bias = 1",
+            f"layer[{m}_g,{m}_u->{m}_h] = eltmul",
+            f"layer[{m}_h->{m}_o] = seq_fullc:l{i}_ffn_down",
+            f"  nhidden = {dim}",
+            "  no_bias = 1",
+            "layer[+0] = scale",
+            f"  factor = {res_mult}",
+            f"layer[{m}_r,{m}_o->x{i + 1}] = eltsum",
+        ]
+    lines += [f"layer[x{len(layer_types)}->fin] = rmsnorm:final_norm",
+              f"  eps = {eps}",
+              "layer[fin->logits] = seq_fullc:head",
+              f"  nhidden = {vocab}",
+              "  no_bias = 1",
+              "  tie = embed",
+              "layer[+0] = scale",
+              f"  factor = {1.0 / logit_div}",
+              "layer[+0] = softmax_seq",
+              *(["  packed = 1"] if packed else []),
+              "netconfig=end",
+              f"input_shape = 1,1,{seq}",
+              f"label_vec[0,{seq}) = label"]
+    if packed:
+        lines += [f"label_vec[{seq},{2 * seq}) = segment",
+                  f"label_vec[{2 * seq},{3 * seq}) = position"]
+    return "\n".join(lines) + "\n"
+
+
 def _res_block(lines: List[str], name: str, bottom: str, w: int,
                stride: int, project: bool) -> str:
     """Basic residual block: two 3x3 conv+bn with an identity (or 1x1

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from ..layers.base import ForwardContext, LabelInfo, Layer, Shape4
 from ..layers.registry import create_layer
 from ..layers.shape_ops import SplitLayer
+from ..utils.config import ConfigError
 from .netconfig import NetConfig
 
 Params = Dict[str, Dict[str, jnp.ndarray]]
@@ -94,10 +95,37 @@ class Network:
                 layer.set_param(k, v)
             for k, v in cfg.layercfg[i]:
                 layer.set_param(k, v)
+            tied = self._tied_to(layer, i)
             self.connections.append(Connection(
                 layer=layer, nindex_in=list(info.nindex_in),
                 nindex_out=list(info.nindex_out),
-                param_key=self._layer_key(i, info), owns_params=True))
+                param_key=self._layer_key(i, info) if tied is None
+                else tied.param_key, owns_params=tied is None))
+
+    def _tied_to(self, layer: Layer, index: int) -> Optional[Connection]:
+        """The connection whose parameter group a ``tie = <name>`` layer
+        reads (a tied output head over an embedding's table), or None.
+        Unlike ``share[tag]`` the two connections are different layer types
+        over one group: the group's gradient is the sum of both uses and
+        the optimizer keeps one state."""
+        name = getattr(layer, "tie", "")
+        if not name:
+            return None
+        at = self.cfg.layer_name_map.get(name, index)
+        if at >= index or self.cfg.layers[at].type_name != "embedding":
+            raise ConfigError(
+                f"tie = {name}: no embedding layer of that name is declared "
+                "before this layer")
+        if not layer.param.no_bias:
+            raise ConfigError(
+                f"tie = {name}: a tied head reads the table alone; set "
+                "no_bias = 1")
+        table = self.connections[at].layer
+        if layer.param.num_hidden != table.vocab_size:
+            raise ConfigError(
+                f"tie = {name}: the head's nhidden {layer.param.num_hidden} "
+                f"is not the table's {table.vocab_size} rows")
+        return self.connections[at]
 
     def _loop_outs(self, loop) -> List[int]:
         written = dict.fromkeys(

@@ -1937,6 +1937,18 @@ class NetTrainer:
                  for c in self.net.connections if c.layer.pallas_site}
         return dict(sorted(collections.Counter(kinds.values()).items()))
 
+    def ssm_sites(self) -> List[dict]:
+        """The ``mamba2`` layers whose training forward took the chunked
+        scan in the traces so far, in net order: the layer's name with
+        ``chunk``, ``heads``, ``head_dim``, ``state`` and the ``lowering``
+        that computed it (``layers/ssm.SSM_LOWERING``).  ``[]`` for a net
+        without such a layer."""
+        fields = ("chunk", "heads", "head_dim", "state", "lowering")
+        return [dict(zip(fields, c.layer.ssm_site),
+                     layer=c.param_key.split("-", 1)[1])
+                for c in self.net.connections
+                if getattr(c.layer, "ssm_site", None) and c.owns_params]
+
     def loop_saved(self) -> Dict[str, dict]:
         """Per ``loop[a->b]`` of the net (``"a->b"``), what a pass of the
         last training trace keeps for the backward pass beside its carry:

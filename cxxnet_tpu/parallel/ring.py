@@ -196,7 +196,8 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def sharded_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       mesh: Mesh, causal: bool = False,
                       seq_axis: str = "seq",
-                      seg: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                      seg: Optional[jnp.ndarray] = None,
+                      scale: Optional[float] = None) -> jnp.ndarray:
     """shard_map wrapper: global (b, h, s, d) arrays in, attention computed
     as a ring over ``seq_axis`` (batch stays sharded over "data" and heads
     over "model" when those axes exist).  ``seg`` (b, s) shards over
@@ -207,14 +208,14 @@ def sharded_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     spec = P(dp, hp, seq_axis, None)
     if seg is None:
         fn = functools.partial(ring_attention, axis_name=seq_axis,
-                               causal=causal)
+                               causal=causal, scale=scale)
         return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=spec, check_vma=False)(q, k, v)
     seg_spec = P(dp, seq_axis)
 
     def fn(q_, k_, v_, seg_):
         return ring_attention(q_, k_, v_, axis_name=seq_axis,
-                              causal=causal, seg=seg_)
+                              causal=causal, scale=scale, seg=seg_)
 
     return jax.shard_map(fn, mesh=mesh,
                          in_specs=(spec, spec, spec, seg_spec),
