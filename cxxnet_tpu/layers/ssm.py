@@ -22,38 +22,153 @@ decay from ``s`` to ``t`` and a tap from ``s`` to ``t`` exist only where the
 two positions carry the same segment id.  Decays, ``Delta``, the state and
 the norm's statistic are float32; the matmuls take the node's dtype.
 
-This lowering is one ``lax.scan`` over the chunks that XLA compiles
-(:func:`mamba_scan`, ``SSM_LOWERING``): in a device trace everything between
-``win`` and ``wout`` is the layer's ``while`` operations (forward, the
-forward a ``remat`` segment recomputes, backward), with the named scopes
-``conv``, ``ssd`` and ``gate_norm`` on its three parts.  The ``compile``
-record's ``ssm_sites`` names the lowering with the shapes.  There is no
-decode path: the layer carries no state between forwards.
+The layer is one ``lax.scan`` over the chunks (:func:`mamba_scan`): in a
+device trace everything between ``win`` and ``wout`` is the layer's ``while``
+operations (forward, the forward a ``remat`` segment recomputes, backward).
+A trip's convolution is XLA operations (scope ``conv``); its recurrence and
+gated norm have two lowerings, chosen per layer from what the trace can see
+(:func:`ssm_lowering`; no key): on a TPU, at shapes the kernels tile
+(:func:`ssd_head_block`: the published widths of the hybrid models; chunk a
+multiple of 128), ONE Pallas kernel forward and one backward with the decay
+tile and the scores in VMEM (``ops/pallas_ssd.py``, ``pallas_ssd_in_scan``);
+on the CPU, at toy shapes or ``chunk = 64``, XLA operations under the scopes
+``ssd`` and ``gate_norm`` (:func:`_chunk_xla`, ``xla_scan_over_chunks``), which
+is also the reference the kernels are tested against.  The ``compile``
+record's ``ssm_sites`` names what each layer took, with the shapes, and
+``pallas_sites`` counts the layers that took the kernels.  This module does
+not import Pallas: ``mamba_scan`` imports the kernels where it takes them.
+There is no decode path: the layer carries no state between forwards.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import List
 
 import jax
 import jax.numpy as jnp
 
 from ..analysis.schema import K
+from ..engine import on_tpu
 from .base import ForwardContext, Layer, Shape4
 from .sequence import _label_field, seq_constraint
 
 SSM_LOWERING = "xla_scan_over_chunks"
+SSM_PALLAS = "pallas_ssd_in_scan"
+
+
+def ssd_head_block(chunk: int, heads: int, head_dim: int, state: int,
+                   groups: int):
+    """Heads a grid step of the kernel pair of ``ops/pallas_ssd``, or None
+    at a shape the kernels do not tile: they take a chunk of whole 128-row
+    tiles, a state of whole 128-lane tiles that divides ``H P``, and heads
+    that are, or pair up to, 128-lane units; a step takes the largest
+    divisor of a group's heads with at most 1024 channels and at most 42
+    heads (three bfloat16 parts of a head's value share 128 lanes)."""
+    if chunk % 128 or state % 128 or (heads * head_dim) % state \
+            or heads % groups or (head_dim % 128 and 128 % head_dim):
+        return None
+    per_group, unit = heads // groups, max(1, 128 // head_dim)
+    fits = [hb for hb in range(unit, per_group + 1, unit)
+            if per_group % hb == 0 and hb * head_dim <= 1024
+            and 3 * hb <= 128]
+    return max(fits) if fits else None
+
+
+def ssm_lowering(chunk: int, heads: int, head_dim: int, state: int,
+                 groups: int) -> str:
+    """Which of the two lowerings of a chunk trip's recurrence and gated
+    norm :func:`mamba_scan` takes, from what it can see: the kernel pair on
+    a TPU at shapes it tiles (:func:`ssd_head_block`), XLA operations
+    (:func:`_chunk_xla`) anywhere else."""
+    if on_tpu() and ssd_head_block(chunk, heads, head_dim, state, groups):
+        return SSM_PALLAS
+    return SSM_LOWERING
+
+
+def _chunk_xla(act, z_c, dt_c, seg_c, before, left, dt_bias, a, d_skip, gain,
+               groups: int, eps: float):
+    """A chunk's recurrence and gated norm as XLA operations: the reference
+    lowering, and ``ops/pallas_ssd.ssd_chunk``'s signature.  ``act`` ``(b, l,
+    H P + 2 G N)`` the convolution's output, ``left`` ``(b, H, P, N)`` the
+    carried state, ``before`` ``(b,)`` the segment that ended the chunk
+    before.  Returns the normed ``(b, l, H P)`` in ``act``'s dtype and the
+    state the chunk leaves."""
+    b, chunk, _ = act.shape
+    _, h, hd, n = left.shape
+    g, r, inner = groups, h // groups, h * hd
+    dtype, f32 = act.dtype, jnp.float32
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    seg_end = seg_c[:, -1]
+
+    def decay(log, where):
+        return jnp.exp(jnp.where(where, log, -jnp.inf))
+
+    with jax.named_scope("ssd"):
+        x, bmat, cmat = jnp.split(act, [inner, inner + g * n], axis=-1)
+        x = x.reshape(b, chunk, h, hd)
+        bmat, cmat = (m.reshape(b, chunk, g, n) for m in (bmat, cmat))
+        delta = jax.nn.softplus(dt_c.astype(f32) + dt_bias)   # (b,l,h)
+        # log decay from the chunk's start through position l: (b,h,l)
+        cum = jnp.cumsum(delta * a, axis=1).transpose(0, 2, 1)
+        xd = (x.astype(f32) * delta[..., None]).astype(dtype).transpose(
+            0, 2, 1, 3)                                       # (b,h,l,p)
+        # inside: y_l = sum_{s<=l} exp(cum_l - cum_s) (C_l . B_s) x_s
+        live = (seg_c[:, :, None] == seg_c[:, None, :]) & lower
+        within = decay(cum[..., :, None] - cum[..., None, :],
+                       live[:, None])                         # (b,h,l,s)
+        scores = jnp.einsum("blgn,bsgn->bgls", cmat, bmat,
+                            preferred_element_type=f32)
+        mixed = (within.reshape(b, g, r, chunk, chunk)
+                 * scores[:, :, None]).reshape(b, h, chunk, chunk)
+        y = jnp.einsum("bhls,bhsp->bhlp", mixed.astype(dtype), xd,
+                       preferred_element_type=f32)
+        # what the state the chunk starts from adds, as far as the
+        # segment that ran at the end of the chunk before still runs
+        from_start = decay(cum, (seg_c == before[:, None])[:, None])
+        y = y + jnp.einsum(
+            "blgn,bgrpn->bgrlp", cmat,
+            left.astype(dtype).reshape(b, g, r, hd, n),
+            preferred_element_type=f32).reshape(b, h, chunk, hd) \
+            * from_start[..., None]
+        # the state the chunk leaves: what it started from if it is of
+        # that one document throughout, and what the positions of its
+        # LAST segment add, decayed to the chunk's end
+        to_end = decay(cum[..., -1:] - cum,
+                       (seg_c == seg_end[:, None])[:, None])  # (b,h,l)
+        added = jnp.einsum(
+            "blgn,bgrlp->bgrpn", bmat,
+            (xd.astype(f32) * to_end[..., None]).astype(dtype).reshape(
+                b, g, r, chunk, hd),
+            preferred_element_type=f32).reshape(b, h, hd, n)
+        through = jnp.where((seg_end == before)[:, None],
+                            jnp.exp(cum[..., -1]), 0.0)       # (b, h)
+        left = left * through[..., None, None] + added
+        y = y.transpose(0, 2, 1, 3) + x.astype(f32) * d_skip[:, None]
+    with jax.named_scope("gate_norm"):
+        gated = (y.reshape(b, chunk, inner)
+                 * jax.nn.silu(z_c.astype(f32))).reshape(
+                     b, chunk, g, inner // g)
+        gated = (gated * jax.lax.rsqrt(
+            jnp.square(gated).mean(axis=-1, keepdims=True)
+            + eps)).reshape(b, chunk, inner) * gain
+    return gated.astype(dtype), left
 
 
 def mamba_scan(xbc, z, dt, seg, p, *, heads: int, head_dim: int, state: int,
-               groups: int, chunk: int, eps: float):
+               groups: int, chunk: int, eps: float,
+               lowering: str = SSM_LOWERING):
     """Everything a ``mamba2`` layer does between ``win``'s output and
     ``wout``'s input, as ONE ``lax.scan`` over chunks of ``chunk`` positions:
     a trip is the convolution, the recurrence and the gated norm of one
     chunk, and carries the state ``(b, H, P, N)`` float32, the last ``K - 1``
     convolution inputs and their segment ids.  Each trip is a
     ``jax.checkpoint``: the backward scan keeps what crossed the chunk's
-    edge and recomputes the chunk.
+    edge and recomputes the chunk.  ``lowering`` (:func:`ssm_lowering`) says
+    what computes a trip's recurrence and gated norm: :func:`_chunk_xla`, or
+    one Pallas kernel forward and one backward (``ops/pallas_ssd``, imported
+    here and nowhere else, so that a process without the layer on a TPU
+    never loads Pallas); the convolution is XLA's in both.
 
     ``xbc`` ``(b, s, H P + 2 G N)``, ``z`` ``(b, s, H P)``, ``dt`` ``(b, s,
     H)`` as ``win`` left them, ``seg`` ``(b, s)`` int32 or None, ``p`` the
@@ -67,7 +182,7 @@ def mamba_scan(xbc, z, dt, seg, p, *, heads: int, head_dim: int, state: int,
     """
     b, s, conv_dim = xbc.shape
     h, hd, n, g = heads, head_dim, state, groups
-    r, inner = h // g, h * hd
+    inner = h * hd
     taps = p["conv_w"].shape[1]
     dtype, f32 = xbc.dtype, jnp.float32
     if seg is None:
@@ -81,15 +196,17 @@ def mamba_scan(xbc, z, dt, seg, p, *, heads: int, head_dim: int, state: int,
     conv_w, conv_b = p["conv_w"].astype(f32), p["conv_b"].astype(f32)
     dt_bias, a = p["dt_bias"].astype(f32), -jnp.exp(p["a_log"].astype(f32))
     d_skip, gain = p["d_skip"].astype(f32), p["norm_gain"].astype(f32)
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-
-    def decay(log, where):
-        return jnp.exp(jnp.where(where, log, -jnp.inf))
+    if lowering == SSM_PALLAS:
+        from ..ops import pallas_ssd
+        rest = functools.partial(
+            pallas_ssd.ssd_chunk, groups=g, eps=eps, interpret=not on_tpu(),
+            hb=ssd_head_block(chunk, h, hd, n, g))
+    else:
+        rest = functools.partial(_chunk_xla, groups=g, eps=eps)
 
     def one_chunk(carry, xs):
         left, tail, seg_tail = carry
         xbc_c, z_c, dt_c, seg_c = xs
-        before, seg_end = seg_tail[:, -1], seg_c[:, -1]           # (b,)
         with jax.named_scope("conv"):
             # K shifted adds; a tap from another segment reads zero
             window = jnp.concatenate([tail, xbc_c], axis=1)
@@ -100,56 +217,10 @@ def mamba_scan(xbc, z, dt, seg, p, *, heads: int, head_dim: int, state: int,
                 acc = acc + window[:, k:k + chunk].astype(f32) \
                     * conv_w[:, k] * same[..., None]
             act = jax.nn.silu(acc).astype(dtype)
-        with jax.named_scope("ssd"):
-            x, bmat, cmat = jnp.split(act, [inner, inner + g * n], axis=-1)
-            x = x.reshape(b, chunk, h, hd)
-            bmat, cmat = (m.reshape(b, chunk, g, n) for m in (bmat, cmat))
-            delta = jax.nn.softplus(dt_c.astype(f32) + dt_bias)   # (b,l,h)
-            # log decay from the chunk's start through position l: (b,h,l)
-            cum = jnp.cumsum(delta * a, axis=1).transpose(0, 2, 1)
-            xd = (x.astype(f32) * delta[..., None]).astype(dtype).transpose(
-                0, 2, 1, 3)                                       # (b,h,l,p)
-            # inside: y_l = sum_{s<=l} exp(cum_l - cum_s) (C_l . B_s) x_s
-            live = (seg_c[:, :, None] == seg_c[:, None, :]) & lower
-            within = decay(cum[..., :, None] - cum[..., None, :],
-                           live[:, None])                         # (b,h,l,s)
-            scores = jnp.einsum("blgn,bsgn->bgls", cmat, bmat,
-                                preferred_element_type=f32)
-            mixed = (within.reshape(b, g, r, chunk, chunk)
-                     * scores[:, :, None]).reshape(b, h, chunk, chunk)
-            y = jnp.einsum("bhls,bhsp->bhlp", mixed.astype(dtype), xd,
-                           preferred_element_type=f32)
-            # what the state the chunk starts from adds, as far as the
-            # segment that ran at the end of the chunk before still runs
-            from_start = decay(cum, (seg_c == before[:, None])[:, None])
-            y = y + jnp.einsum(
-                "blgn,bgrpn->bgrlp", cmat,
-                left.astype(dtype).reshape(b, g, r, hd, n),
-                preferred_element_type=f32).reshape(b, h, chunk, hd) \
-                * from_start[..., None]
-            # the state the chunk leaves: what it started from if it is of
-            # that one document throughout, and what the positions of its
-            # LAST segment add, decayed to the chunk's end
-            to_end = decay(cum[..., -1:] - cum,
-                           (seg_c == seg_end[:, None])[:, None])  # (b,h,l)
-            added = jnp.einsum(
-                "blgn,bgrlp->bgrpn", bmat,
-                (xd.astype(f32) * to_end[..., None]).astype(dtype).reshape(
-                    b, g, r, chunk, hd),
-                preferred_element_type=f32).reshape(b, h, hd, n)
-            through = jnp.where((seg_end == before)[:, None],
-                                jnp.exp(cum[..., -1]), 0.0)       # (b, h)
-            left = left * through[..., None, None] + added
-            y = y.transpose(0, 2, 1, 3) + x.astype(f32) * d_skip[:, None]
-        with jax.named_scope("gate_norm"):
-            gated = (y.reshape(b, chunk, inner)
-                     * jax.nn.silu(z_c.astype(f32))).reshape(
-                         b, chunk, g, inner // g)
-            gated = (gated * jax.lax.rsqrt(
-                jnp.square(gated).mean(axis=-1, keepdims=True)
-                + eps)).reshape(b, chunk, inner) * gain
+        gated, left = rest(act, z_c, dt_c, seg_c, seg_tail[:, -1], left,
+                           dt_bias, a, d_skip, gain)
         carry = (left, window[:, chunk:], seg_window[:, chunk:])
-        return carry, gated.astype(dtype)
+        return carry, gated
 
     def chunks(t):  # (b, nc * chunk, ...) -> (nc, b, chunk, ...)
         return jnp.moveaxis(t.reshape((b, nc, chunk) + t.shape[2:]), 1, 0)
@@ -267,13 +338,17 @@ class Mamba2Layer(Layer):
         seg = _label_field(ctx, self.segment_key)
         if seg is not None:
             seg = seg.astype(jnp.int32)
+        lowering = ssm_lowering(self.chunk, h, p, n, g)
         if ctx.train:
-            self.ssm_site = (self.chunk, h, p, n, SSM_LOWERING)
+            self.ssm_site = (self.chunk, h, p, n, lowering)
+        if lowering == SSM_PALLAS:
+            self.note_pallas(ctx)
         proj = jnp.einsum("bcsd,nd->bcsn", u,
                           params["win"].astype(u.dtype))[:, 0]
         z, xbc, dt = jnp.split(proj, [inner, inner + conv_dim], axis=-1)
         gated = mamba_scan(xbc, z, dt, seg, params, heads=h, head_dim=p,
-                           state=n, groups=g, chunk=self.chunk, eps=self.eps)
+                           state=n, groups=g, chunk=self.chunk, eps=self.eps,
+                           lowering=lowering)
         out = jnp.einsum("bsn,dn->bsd", gated,
                          params["wout"].astype(u.dtype))[:, None]
         return [seq_constraint(out, ctx)], buffers

@@ -1931,7 +1931,7 @@ class NetTrainer:
         the traces so far, counted by layer type (``{"rmsnorm": 33,
         "attention": 8}``): names, not calls, because a looped,
         checkpointed body is traced more than once.  The sequence stack's
-        default-on kernels report (attention, layernorm, rmsnorm); the
+        default-on kernels report (attention, the norms, mamba2); the
         kernels ``ops/nn.py`` takes by engine option do not."""
         kinds = {c.param_key: c.layer.type_names[0]
                  for c in self.net.connections if c.layer.pallas_site}
@@ -1941,8 +1941,8 @@ class NetTrainer:
         """The ``mamba2`` layers whose training forward took the chunked
         scan in the traces so far, in net order: the layer's name with
         ``chunk``, ``heads``, ``head_dim``, ``state`` and the ``lowering``
-        that computed it (``layers/ssm.SSM_LOWERING``).  ``[]`` for a net
-        without such a layer."""
+        that computed it (``layers/ssm.ssm_lowering``'s choice for that
+        layer).  ``[]`` for a net without such a layer."""
         fields = ("chunk", "heads", "head_dim", "state", "lowering")
         return [dict(zip(fields, c.layer.ssm_site),
                      layer=c.param_key.split("-", 1)[1])

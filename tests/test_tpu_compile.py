@@ -1,4 +1,5 @@
-"""The causal flash and the RMSNorm kernels compile for the v5e at real widths.
+"""The causal flash, the RMSNorm and the Mamba-2 chunk kernels compile for the v5e
+at real widths.
 
 Mosaic compiles for a chip that is described and not attached, so what the
 chip's compiler would refuse (a slice off the tiling, too much VMEM) fails
@@ -72,4 +73,37 @@ def test_rmsnorm_kernels_compile_for_v5e(one_chip, rows, d, x_dtype, gain):
         return (y,) + vjp(dy)
 
     text = jax.jit(fwd_bwd).lower(x, g, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+@pytest.mark.parametrize("chunk,h,p,n,g", [
+    (256, 64, 64, 128, 1),   # granite4h_docmask_b1's mamba2 layers
+    (256, 16, 128, 128, 2),  # heads of a whole unit, two groups
+])
+def test_ssd_kernel_pair_compiles_for_v5e(one_chip, chunk, h, p, n, g):
+    """A chunk trip's kernel pair (``ops/pallas_ssd``) at the head block
+    ``layers/ssm.ssd_head_block`` chooses, under its raised VMEM limit."""
+    from cxxnet_tpu.layers import ssm
+    from cxxnet_tpu.ops import pallas_ssd
+    hb = ssm.ssd_head_block(chunk, h, p, n, g)
+    assert hb
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    inner = h * p
+    args = (sds((1, chunk, inner + 2 * g * n), bf), sds((1, chunk, inner), bf),
+            sds((1, chunk, h), bf), sds((1, h, p, n), f32), sds((h,), f32),
+            sds((h,), f32), sds((h,), f32), sds((inner,), f32))
+    seg, before = sds((1, chunk), jnp.int32), sds((1,), jnp.int32)
+
+    def fwd_bwd(seg, before, act, z, dt, *rest):
+        out, vjp = jax.vjp(
+            lambda act, z, dt, *rest: pallas_ssd.ssd_chunk(
+                act, z, dt, seg, before, *rest, g, hb, 1e-5, False),
+            act, z, dt, *rest)
+        return out, vjp(out)
+
+    text = jax.jit(fwd_bwd).lower(seg, before, *args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
