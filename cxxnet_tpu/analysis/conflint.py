@@ -415,8 +415,44 @@ def _cross_key_rules(pairs: ConfigPairs, layer_types: List[str],
     _serve_rules(last, task, add)
     _ckpt_rules(last, task, monitor, add)
     _text_rules(pairs, last, layer_types, add)
+    _expert_rules(pairs, add)
     _decode_rules(pairs, last, layer_types, task, add)
     _mem_rules(last, task, add)
+
+
+def _expert_rules(pairs: ConfigPairs, add) -> None:
+    """Per ``moe_topk`` layer: the held experts ``expert_first ..
+    expert_first + expert_held - 1`` and ``top_k`` must lie inside the
+    ``num_expert`` the router scores (shape inference asserts the same;
+    here it is a finding before any trace)."""
+    sizes: Optional[Dict[str, int]] = None
+
+    def close() -> None:
+        if not sizes or "num_expert" not in sizes:
+            return
+        n, first = sizes["num_expert"], sizes.get("expert_first", 0)
+        held = sizes.get("expert_held", 0) or n
+        if first + held > n:
+            add(Finding("error", "expert_held",
+                        f"moe_topk holds experts {first}..{first + held - 1}"
+                        f" but the router scores num_expert = {n}",
+                        scope="layer:moe_topk"))
+        if sizes.get("top_k", 1) > n:
+            add(Finding("error", "top_k",
+                        f"top_k = {sizes['top_k']} of num_expert = {n}",
+                        scope="layer:moe_topk"))
+
+    for name, val in pairs:
+        if name.startswith("layer[") or name == "netconfig":
+            close()
+            sizes = {} if val.split(":", 1)[0] == "moe_topk" else None
+        elif sizes is not None and name in ("num_expert", "expert_held",
+                                            "expert_first", "top_k"):
+            try:
+                sizes[name] = int(val)
+            except ValueError:
+                pass  # the key's own value check reports it
+    close()
 
 
 def _mem_rules(last: Dict[str, str], task: str, add) -> None:

@@ -32,7 +32,7 @@ from .schema import KeySpec
 # weight-tag prefixes for tag-scoped hyper overrides (``wmat:lr``,
 # ``bias:wd`` — updater/param.h:100-105); the zoo's extra tags included
 TAG_PREFIXES = ("wmat", "bias", "gate", "wmat2", "bias2",
-                "wqkv", "wout", "bqkv", "wpos")
+                "wqkv", "wout", "bqkv", "wpos", "router", "w13", "w2")
 
 # templated key name -> full-match regex
 _TEMPLATES = {

@@ -245,6 +245,9 @@ class ForwardContext:
     # inside a loop's pass: ``(name, shape, dtype)`` of what the layers'
     # kernels named for the pass's save set (``Network._forward_loop``)
     saved: Optional[List[Tuple[str, Tuple[int, ...], Any]]] = None
+    # a check asked for what the routed layers selected
+    # (``NetTrainer.keep_expert_selection``)
+    keep_selection: bool = False
     _rng_count: int = 0
 
     def next_rng(self) -> jax.Array:

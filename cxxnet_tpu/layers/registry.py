@@ -19,15 +19,16 @@ from .conv import (AvgPoolingLayer, ConvolutionLayer, InsanityPoolingLayer,
                    SumPoolingLayer)
 from .fullc import FixConnectLayer, FullConnectLayer
 from .loss import L2LossLayer, MultiLogisticLayer, SoftmaxLayer
-from .moe import MoELayer
+from .moe import MoELayer, TopKExpertLayer
 from .norm import BatchNormLayer, DropoutLayer
 from .pairtest import PairTestLayer
 from .sequence import (AttentionLayer, EmbeddingLayer, ExitLossLayer,
                        LayerNormLayer, RMSNormLayer, SeqFullcLayer,
                        SeqXentLayer, SoftmaxSeqLayer)
-from .ssm import Mamba2Layer
 from .shape_ops import (ChConcatLayer, ConcatLayer, EltMulLayer, EltSumLayer,
                         FlattenLayer, MaxoutLayer, SplitLayer)
+from .shortconv import ShortConvLayer
+from .ssm import Mamba2Layer
 
 _REGISTRY: Dict[str, Type[Layer]] = {}
 
@@ -47,7 +48,8 @@ for _cls in (ReluLayer, SigmoidLayer, TanhLayer, SoftplusLayer, XeluLayer,
              MultiLogisticLayer, GeluLayer, EmbeddingLayer, LayerNormLayer,
              SeqFullcLayer, AttentionLayer, SoftmaxSeqLayer, MoELayer,
              SiluLayer, EltMulLayer, RMSNormLayer, SeqXentLayer,
-             ExitLossLayer, ScaleLayer, Mamba2Layer):
+             ExitLossLayer, ScaleLayer, Mamba2Layer, TopKExpertLayer,
+             ShortConvLayer):
     register(_cls)
 
 

@@ -92,6 +92,14 @@ def _rotary(q, k, pos, theta: float):
     return turn(q), turn(k)
 
 
+def _head_rms_norm(x, gain, eps: float):
+    """RMSNorm of ``x`` ``(b, h, s, hd)`` over a head's ``hd`` channels with
+    the gain ``(hd,)`` shared by the heads, in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.square(x32).mean(-1, keepdims=True) + eps)
+    return (y * gain.astype(jnp.float32)).astype(x.dtype)
+
+
 def seq_constraint(x: jnp.ndarray, ctx: ForwardContext) -> jnp.ndarray:
     """Pin a (b, 1, s, d) activation to the seq-sharded layout."""
     mesh = _seq_mesh(ctx)
@@ -370,7 +378,10 @@ class AttentionLayer(Layer):
     is ``((nhead + 2 nkvhead) hd, d)``: q's rows, then k's, then v's.  K and
     V are repeated to ``nhead`` heads in front of the kernels and the decode
     cache, which know one head count.  ``score_scale`` multiplies the scores
-    in every path (0, the default: ``1/sqrt(hd)``).
+    in every path (0, the default: ``1/sqrt(hd)``).  ``qk_norm = 1``: each
+    head's q and k pass an RMSNorm over the head's channels, with one gain
+    of ``hd`` for q and one for k shared by the heads ("q_norm", "k_norm"),
+    AHEAD of the rotary turn.
 
     When the trainer mesh has a ``seq`` axis the score computation runs as
     ring attention (K/V rotating over ICI, online softmax — see
@@ -398,6 +409,10 @@ class AttentionLayer(Layer):
         K("pos_key", "str",
           help="label field with per-position ids for rope (packed "
                "documents restart at 0); empty or absent = 0..s-1"),
+        K("qk_norm", "int", lo=0, hi=1,
+          help="RMSNorm of each head's q and k (a gain of the head size "
+               "each, shared by the heads) ahead of rope"),
+        K("qk_norm_eps", "float", lo=0.0),
     )
 
     def __init__(self):
@@ -410,6 +425,8 @@ class AttentionLayer(Layer):
         self.rope = 0
         self.rope_theta = 10000.0
         self.pos_key = ""
+        self.qk_norm = 0
+        self.qk_norm_eps = 1e-6
 
     def set_param(self, name, val):
         if name == "nhead":
@@ -428,6 +445,10 @@ class AttentionLayer(Layer):
             self.rope_theta = float(val)
         elif name == "pos_key":
             self.pos_key = val
+        elif name == "qk_norm":
+            self.qk_norm = int(val)
+        elif name == "qk_norm_eps":
+            self.qk_norm_eps = float(val)
         else:
             super().set_param(name, val)
 
@@ -452,6 +473,9 @@ class AttentionLayer(Layer):
         if not self.param.no_bias:
             params["bqkv"] = jnp.zeros((nqkv,), dtype)
             params["bout"] = jnp.zeros((d,), dtype)
+        if self.qk_norm:
+            params["q_norm"] = jnp.ones((d // self.nhead,), dtype)
+            params["k_norm"] = jnp.ones((d // self.nhead,), dtype)
         return params
 
     def forward(self, params, buffers, inputs, ctx):
@@ -471,6 +495,10 @@ class AttentionLayer(Layer):
             q, k, v = (t.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
                        for t in jnp.split(qkv[:, 0], [d, d + nkv * hd], -1))
         dec = getattr(ctx, "decode", None)
+        if self.qk_norm:
+            assert dec is None, "attention: qk_norm = 1 has no decode path"
+            q, k = (_head_rms_norm(t, params[g], self.qk_norm_eps)
+                    for t, g in ((q, "q_norm"), (k, "k_norm")))
         if self.rope:
             assert dec is None, "attention: rope = 1 has no decode cache path"
             pos = _label_field(ctx, self.pos_key)

@@ -1136,7 +1136,8 @@ class LearnTask:
                               round=self.start_counter - 1,
                               pallas_sites=self.net.pallas_sites(),
                               loop_saved=self.net.loop_saved(),
-                              ssm_sites=self.net.ssm_sites())
+                              ssm_sites=self.net.ssm_sites(),
+                              moe_sites=self.net.moe_sites())
         mlog.info(f"compile: {seconds:.1f} sec (first dispatch, excluded "
                   "from examples/sec)")
 

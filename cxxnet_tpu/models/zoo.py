@@ -9,7 +9,7 @@ section are independent (``src/nnet/nnet_config.h:255-287``).
 
 from __future__ import annotations
 
-from typing import List, Sequence
+from typing import List, Optional, Sequence
 
 
 def mlp(num_class: int = 10, input_dim: int = 784,
@@ -431,14 +431,23 @@ def looped_lm(vocab: int, seq: int, dim: int, nlayer: int, nhead: int,
 
 
 def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
-              nhead: int, nkvhead: int, ffn: int, ssm_heads: int,
-              ssm_head_dim: int, ssm_state: int, ssm_groups: int = 1,
+              nhead: int, nkvhead: int, ffn: int, ssm_heads: int = 0,
+              ssm_head_dim: int = 0, ssm_state: int = 0, ssm_groups: int = 1,
               ssm_conv: int = 4, ssm_chunk: int = 256,
               att_scale: float = 0.0, emb_mult: float = 1.0,
               res_mult: float = 1.0, logit_div: float = 1.0,
-              eps: float = 1e-5, packed: bool = False) -> str:
-    """Hybrid state-space / attention decoder-only LM (the Granite 4.0-H
-    family's block; Mamba-2: Dao & Gu 2024, arXiv:2405.21060).
+              eps: float = 1e-5, packed: bool = False, *,
+              conv_taps: int = 3, rope_theta: float = 0.0,
+              qk_norm: bool = False, dense_layers: Optional[int] = None,
+              experts: int = 0, experts_held: int = 0, expert_first: int = 0,
+              experts_per_token: int = 1, expert_ffn: int = 0,
+              score_func: str = "sigmoid", expert_bias: bool = False,
+              expert_bias_rate: float = 0.0, norm_topk: bool = True,
+              routed_scale: float = 1.0) -> str:
+    """Hybrid decoder-only LM: per layer one of three mixers and one of two
+    feed-forwards (the Granite 4.0-H family's block; Mamba-2: Dao & Gu 2024,
+    arXiv:2405.21060; and the LFM2 family's: gated short convolutions beside
+    grouped-query attention, routed experts after the leading dense layers).
 
     ``h0 = emb_mult * E[tokens]``; layer ``l`` of ``layer_types`` (``mamba``
     or ``attention``): ``a = h + res_mult * mixer(rmsnorm(h))``, ``h' = a +
@@ -449,15 +458,29 @@ def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
     ``softmax_seq``'s mean over scored positions.  ``packed``: segment ids mask
     attention, restart the recurrence and the conv taps, and boundary
     targets are left out of the loss.
+
+    A ``conv`` layer is a ``shortconv`` of ``conv_taps`` taps.  ``rope_theta >
+    0`` turns q and k by rotary positions (inside the document when
+    ``packed``), ``qk_norm`` norms each head's q and k ahead of that.  With
+    ``experts > 0`` the layers from ``dense_layers`` on replace the dense
+    feed-forward by a ``moe_topk`` layer: ``experts_per_token`` of ``experts``
+    experts of width ``expert_ffn``, of which the net holds ``experts_held``
+    from ``expert_first`` on (0: all); ``expert_bias`` gives each a bias in
+    the selection that starts at zero and moves by ``expert_bias_rate`` a step
+    toward an even load.  A multiplier of exactly 1 writes no ``scale``
+    layer.
     """
     seg = ["  segment_key = segment"] if packed else []
+
+    def scaled(factor):
+        return [] if factor == 1.0 else ["layer[+0] = scale",
+                                         f"  factor = {factor}"]
     lines = ["netconfig=start",
              "layer[0->x0] = embedding:embed",
              f"  vocab_size = {vocab}",
              f"  nhidden = {dim}",
              "  init_sigma = 0.02",
-             "layer[+0] = scale",
-             f"  factor = {emb_mult}"]
+             *scaled(emb_mult)]
     for i, kind in enumerate(layer_types):
         a, m = f"b{i}a", f"b{i}m"
         if kind == "mamba":
@@ -476,34 +499,56 @@ def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
                      f"  score_scale = {att_scale}",
                      "  causal = 1",
                      "  no_bias = 1"]
+            if rope_theta:
+                mixer += ["  rope = 1", f"  rope_theta = {rope_theta}",
+                          *(["  pos_key = position"] if packed else [])]
+            if qk_norm:
+                mixer += ["  qk_norm = 1", f"  qk_norm_eps = {eps}"]
+        elif kind == "conv":
+            mixer = [f"layer[{a}_n->{a}_o] = shortconv:l{i}_conv",
+                     f"  kernel_size = {conv_taps}"]
         else:
-            raise ValueError(f"hybrid_lm: layer type {kind!r} is neither "
-                             "'mamba' nor 'attention'")
+            raise ValueError(f"hybrid_lm: layer type {kind!r} is none of "
+                             "'mamba', 'attention' and 'conv'")
+        if experts and i >= (dense_layers or 0):
+            feed_forward = [
+                f"layer[{m}_n->{m}_o] = moe_topk:l{i}_moe",
+                f"  num_expert = {experts}",
+                f"  expert_held = {experts_held or experts}",
+                f"  expert_first = {expert_first}",
+                f"  top_k = {experts_per_token}",
+                f"  nhidden = {expert_ffn}",
+                f"  score_func = {score_func}",
+                f"  expert_bias = {int(expert_bias)}",
+                f"  expert_bias_rate = {expert_bias_rate}",
+                f"  norm_topk = {int(norm_topk)}",
+                f"  routed_scale = {routed_scale}"]
+        else:
+            feed_forward = [
+                f"layer[{m}_n->{m}_n1,{m}_n2] = split",
+                f"layer[{m}_n1->{m}_g] = seq_fullc:l{i}_ffn_gate",
+                f"  nhidden = {ffn}",
+                "  no_bias = 1",
+                "layer[+0] = silu",
+                f"layer[{m}_n2->{m}_u] = seq_fullc:l{i}_ffn_up",
+                f"  nhidden = {ffn}",
+                "  no_bias = 1",
+                f"layer[{m}_g,{m}_u->{m}_h] = eltmul",
+                f"layer[{m}_h->{m}_o] = seq_fullc:l{i}_ffn_down",
+                f"  nhidden = {dim}",
+                "  no_bias = 1"]
         lines += [
             f"layer[x{i}->{a}_r,{a}_in] = split",
             f"layer[{a}_in->{a}_n] = rmsnorm:l{i}_norm1",
             f"  eps = {eps}",
             *mixer, *seg,
-            "layer[+0] = scale",
-            f"  factor = {res_mult}",
+            *scaled(res_mult),
             f"layer[{a}_r,{a}_o->{m}] = eltsum",
             f"layer[{m}->{m}_r,{m}_in] = split",
             f"layer[{m}_in->{m}_n] = rmsnorm:l{i}_norm2",
             f"  eps = {eps}",
-            f"layer[{m}_n->{m}_n1,{m}_n2] = split",
-            f"layer[{m}_n1->{m}_g] = seq_fullc:l{i}_ffn_gate",
-            f"  nhidden = {ffn}",
-            "  no_bias = 1",
-            "layer[+0] = silu",
-            f"layer[{m}_n2->{m}_u] = seq_fullc:l{i}_ffn_up",
-            f"  nhidden = {ffn}",
-            "  no_bias = 1",
-            f"layer[{m}_g,{m}_u->{m}_h] = eltmul",
-            f"layer[{m}_h->{m}_o] = seq_fullc:l{i}_ffn_down",
-            f"  nhidden = {dim}",
-            "  no_bias = 1",
-            "layer[+0] = scale",
-            f"  factor = {res_mult}",
+            *feed_forward,
+            *scaled(res_mult),
             f"layer[{m}_r,{m}_o->x{i + 1}] = eltsum",
         ]
     lines += [f"layer[x{len(layer_types)}->fin] = rmsnorm:final_norm",
@@ -512,8 +557,7 @@ def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
               f"  nhidden = {vocab}",
               "  no_bias = 1",
               "  tie = embed",
-              "layer[+0] = scale",
-              f"  factor = {1.0 / logit_div}",
+              *scaled(1.0 / logit_div),
               "layer[+0] = softmax_seq",
               *(["  packed = 1"] if packed else []),
               "netconfig=end",

@@ -221,6 +221,7 @@ class NetTrainer:
         self.train_metric = MetricSet()
         self.net: Optional[Network] = None
         self._train_step = None
+        self._keep_selection = False  # keep_expert_selection()
         self._eval_step_cache: Dict[Tuple[int, ...], Any] = {}
         # header "extra" of the last load_model (iterator/sentinel state
         # for the task driver's exact resume); None on a fresh init
@@ -840,7 +841,8 @@ class NetTrainer:
                              labels=LabelInfo(fields=fields, mask=mask)
                              if fields else None,
                              epoch=epoch, loss_scale=self.loss_scale,
-                             mesh=self.mesh if self.mesh.size > 1 else None)
+                             mesh=self.mesh if self.mesh.size > 1 else None,
+                             keep_selection=self._keep_selection)
         inputs = {0: data}
         for i, e in enumerate(extras):
             inputs[1 + i] = e
@@ -1949,6 +1951,20 @@ class NetTrainer:
                 for c in self.net.connections
                 if getattr(c.layer, "ssm_site", None) and c.owns_params]
 
+    def moe_sites(self) -> List[dict]:
+        """The ``moe_topk`` layers a training forward has traced, in net
+        order: the layer's name with the experts ``published`` (the router's
+        width), ``held`` and the ``first`` held index, the experts a token
+        (``top_k``), the experts' ``width``, the ``score`` function and the
+        ``lowering`` of the grouped products (``layers/moe.GMM_LOWERING``).
+        ``[]`` for a net without such a layer."""
+        fields = ("published", "held", "first", "top_k", "width", "score",
+                  "lowering")
+        return [dict(zip(fields, c.layer.moe_site),
+                     layer=c.param_key.split("-", 1)[1])
+                for c in self.net.connections
+                if getattr(c.layer, "moe_site", None) and c.owns_params]
+
     def loop_saved(self) -> Dict[str, dict]:
         """Per ``loop[a->b]`` of the net (``"a->b"``), what a pass of the
         last training trace keeps for the backward pass beside its carry:
@@ -2139,11 +2155,31 @@ class NetTrainer:
     def last_diagnostics(self) -> Dict[str, Any]:
         """The newest step's diagnostics on the host, a float or a list of
         floats each (``exit_loss``, ``exit_mass``, ``exit_entropy`` of an
-        ``exit_loss`` layer; a pairtest's relative errors); empty for a
-        net whose layers leave none.  Waits for that step."""
+        ``exit_loss`` layer; the ``moe_*`` counters of ``moe_topk`` layers; a
+        pairtest's relative errors); empty for a net whose layers leave
+        none.  Names that start with ``_`` are not for records.  Waits for
+        that step."""
         diags = getattr(self, "_last_diags", None) or {}
         return {k: np.asarray(v, np.float64).tolist()
-                for k, v in diags.items()}
+                for k, v in diags.items() if not k.startswith("_")}
+
+    def keep_expert_selection(self, keep: bool = True) -> None:
+        """From the next ``update`` on, the step also returns what its
+        ``moe_topk`` layers selected (:meth:`last_expert_selection`): for a
+        check that holds the step's own routing to a reference.  The step is
+        built anew, one more compilation; no step returns it unasked."""
+        if keep != self._keep_selection:
+            self._keep_selection = keep
+            self._train_step = self._build_train_step()
+            self._train_step_masked = None
+
+    def last_expert_selection(self) -> List[np.ndarray]:
+        """The newest step's routing, a ``moe_topk`` layer in net order:
+        ``(tokens, top_k)`` int32, the experts each token selected; ``[]``
+        unless :meth:`keep_expert_selection` asked for it before the step,
+        and for a net without such a layer."""
+        diags = getattr(self, "_last_diags", None) or {}
+        return [np.asarray(s) for s in diags.get("_moe_selected", [])]
 
     @property
     def has_diagnostics(self) -> bool:

@@ -227,7 +227,8 @@ def test_no_process_without_the_layer_imports_pallas():
     """PR 34 was refused for 1.2 s of ``setup_s`` in the AlexNet cells:
     ``layers/ssm.py``, which every process imports with the layer registry,
     imported Pallas at module level.  The AlexNet example's train step,
-    built and traced, and the module itself load none of it."""
+    built and traced, and the modules of the state-space, routed-expert
+    and short-convolution layers themselves load none of it."""
     code = (
         "import sys\n"
         "from cxxnet_tpu.nnet.trainer import NetTrainer\n"
@@ -245,6 +246,8 @@ def test_no_process_without_the_layer_imports_pallas():
         "assert t._step_lowered() is not None, 'the step did not trace'\n"
         "assert not pallas(), pallas()\n"
         "import cxxnet_tpu.layers.ssm\n"
+        "import cxxnet_tpu.layers.moe\n"
+        "import cxxnet_tpu.layers.shortconv\n"
         "assert not pallas(), pallas()\n")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, cwd=REPO,

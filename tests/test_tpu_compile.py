@@ -1,5 +1,5 @@
-"""The causal flash, the RMSNorm and the Mamba-2 chunk kernels compile for the v5e
-at real widths.
+"""The causal flash, the RMSNorm and the Mamba-2 chunk kernels and the routed
+experts' grouped products compile for the v5e at real widths.
 
 Mosaic compiles for a chip that is described and not attached, so what the
 chip's compiler would refuse (a slice off the tiling, too much VMEM) fails
@@ -107,3 +107,42 @@ def test_ssd_kernel_pair_compiles_for_v5e(one_chip, chunk, h, p, n, g):
 
     text = jax.jit(fwd_bwd).lower(seg, before, *args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_routed_experts_compile_for_v5e(one_chip):
+    """``lfm2moe_s8192_docmask_b1``'s expert layer, forward and backward: one
+    row of 8,192 tokens, 4 of 32 experts a token, 8 held.  XLA lowers each
+    ragged dot to one Mosaic call: two products forward, their two input
+    gradients and two weight gradients, none a second time (the backward
+    pass keeps the first product's output and gates the rows again)."""
+    from cxxnet_tpu.layers import moe
+    t, d, f, experts, held, k = 8192, 2048, 1792, 32, 8, 4
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(x, router, bias, w13, w2):
+        sel, weights, _ = moe.route(x, router, bias, top_k=k)
+        return moe.expert_ffn(x, sel, weights, w13.reshape(held, d, 2 * f),
+                              w2.reshape(held, f, d), first=0, held=held)[0]
+
+    def fwd_bwd(x, router, bias, w13, w2, g):
+        out, vjp = jax.vjp(lambda *a: layer(a[0], a[1], bias, a[2], a[3]),
+                           x, router, w13, w2)
+        return (out,) + vjp(g)
+
+    text = jax.jit(fwd_bwd).lower(
+        sds((t, d)), sds((experts, d)), sds((experts,), jnp.float32),
+        sds((held * d, 2 * f)), sds((held * f, d)), sds((t, d))
+    ).compile().as_text()
+    products = [ln for ln in text.split("\n")
+                if ln.lstrip().startswith("%ragged-dot-none")]
+    assert len(products) == 6
+    assert all('custom_call_target="tpu_custom_call"' in ln
+               for ln in products)
+    # the pairs' rows are b s k, whatever share of them meets a held expert
+    written = [ln.split(" custom-call(")[0] for ln in products]
+    assert sum(f"bf16[{t * k},{2 * f}]" in w for w in written) == 1
+    assert sum(f"bf16[{t * k},{d}]" in w for w in written) == 2
+    assert sum(f"bf16[{held}," in w for w in written) == 2
