@@ -49,7 +49,6 @@ from typing import List
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..analysis.schema import K
@@ -301,43 +300,6 @@ def route(u, router, bias, *, top_k: int, score_func: str = "sigmoid",
     return sel.astype(jnp.int32), picked * scale, scores
 
 
-@jax.custom_vjp
-def _rows(x, index, back, keep):
-    """``x[index]`` where ``index`` ``(k t,)`` names every row of ``x`` ``(t,
-    d)`` ``k`` times and ``back`` ``(k t,)`` is the permutation that orders
-    ``index`` (``index[back] == arange(k t) % t``): the gradient is then a
-    gather and a sum of ``k`` blocks of rows, not a scatter.  ``keep`` ``(k
-    t,)`` in the order of ``back``: only these rows' gradients count,
-    whatever the others hold."""
-    del back, keep
-    return x[index]
-
-
-def _rows_fwd(x, index, back, keep):
-    return x[index], (back, keep, x.shape[0])
-
-
-def _rows_bwd(res, g):
-    back, keep, t = res
-    rows = jnp.where(keep[:, None], g[back], jnp.zeros((), g.dtype))
-    return rows.reshape(-1, t, g.shape[-1]).sum(axis=0).astype(g.dtype), \
-        None, None, None
-
-
-_rows.defvjp(_rows_fwd, _rows_bwd)
-
-
-@jax.custom_vjp
-def _permute(x, perm, inverse):
-    """``x[perm]`` for a permutation with its inverse: gathers both ways."""
-    del inverse
-    return x[perm]
-
-
-_permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
-                lambda inverse, g: (g[inverse], None, None))
-
-
 def grouped_matmul(lhs, rhs, sizes):
     """``lhs[rows of group g] @ rhs[g]`` for the groups of ``sizes`` ``(G,)``
     laid one after the other from row 0 of ``lhs`` ``(m, k)``; ``rhs`` ``(G,
@@ -362,49 +324,257 @@ def _gated(hidden, pair_weight, live):
     return jnp.where(live[:, None], act.astype(hidden.dtype), zero)
 
 
-def expert_ffn(x, sel, weights, w13, w2, *, first: int, held: int):
+# the grouped products tile their rows by at most this many: a window of the
+# pairs' rows is a multiple of it (of the tokens where a toy net has fewer)
+ROW_TILE = 512
+
+
+def window_rows(tokens: int, top_k: int, held: int, experts: int) -> int:
+    """The static row count ``m`` of the arrays of a layer's pairs' rows, from
+    the shapes alone: 1.5 times the held pairs of an even routing (``tokens
+    top_k held / experts``) rounded up to a whole tile, and at most ``tokens
+    top_k``, which is what a layer that holds all its experts gets."""
+    pairs = tokens * top_k
+    tile = min(ROW_TILE, tokens)
+    return min(-(-(pairs * held // experts * 3) // (2 * tile)) * tile, pairs)
+
+
+LANES = 128
+
+
+@jax.custom_vjp
+def _permuted(values, perm, inverse):
+    """``values[perm]`` of a vector for a permutation with its inverse, and
+    the gradient the same by the inverse.  Whole rows of 128 lanes are
+    gathered and a row's lane picked by comparison: on a TPU a gather (or a
+    scatter) of 32,768 single elements takes 0.15 to 0.3 ms (PR 37's chip
+    runs)."""
+    del inverse
+    rows = jnp.pad(values, (0, -values.shape[0] % LANES)).reshape(-1, LANES)
+    picked = rows.at[perm // LANES].get(mode="promise_in_bounds")
+    lane = jnp.arange(LANES) == (perm % LANES)[:, None]
+    return jnp.where(lane, picked, jnp.zeros((), values.dtype)).sum(axis=1)
+
+
+_permuted.defvjp(lambda values, perm, inverse: (_permuted(values, perm,
+                                                          inverse),
+                                                (perm, inverse)),
+                 lambda kept, g: (_permuted(g, kept[1], kept[0]), None, None))
+
+
+def _token_sums(rows, slot, lo, hi, top_k):
+    """Per token the float32 sum of its pairs' rows in the window ``[lo,
+    hi)`` of the order by expert: pair ``j t + i``, token ``i``'s ``j``-th,
+    has row ``slot[j t + i] - lo`` of ``rows`` ``(m, d)`` if its slot lies in
+    the window; any other pair adds nothing, whatever the rows from ``hi``
+    on hold.  The ``top_k`` blocks are added one by one: a reduction over
+    them made XLA write all ``t top_k`` rows in float32 first (PR 37's chip
+    run: 1.1 ms of a layer's 1.3 in either pass)."""
+    picked = rows.at[jnp.clip(slot - lo, 0, rows.shape[0] - 1)].get(
+        mode="promise_in_bounds").reshape(top_k, -1, rows.shape[1])
+    inside = ((slot >= lo) & (slot < hi)).reshape(top_k, -1, 1)
+    return sum(jnp.where(inside[j], picked[j].astype(jnp.float32), 0.0)
+               for j in range(top_k))
+
+
+def _window(w, m, x, pair_weight, order, sizes):
+    """Window ``w`` of the pairs in the order by expert, rows ``[w m, (w + 1)
+    m)``: its tokens' rows of ``x``, the tokens, the pairs' weights, the
+    groups (the experts' ranges cut to the window), which rows lie inside
+    them, and the window's first row and the row behind its last group."""
+    lo = w * m
+    ends = jnp.cumsum(sizes)
+    hi = jnp.minimum(ends[-1], lo + m)
+    groups = jnp.clip(jnp.minimum(ends, lo + m)
+                      - jnp.maximum(ends - sizes, lo), 0)
+    token = jax.lax.dynamic_slice(order, (lo,), (m,)) % x.shape[0]
+    return x.at[token].get(mode="promise_in_bounds"), token, \
+        jax.lax.dynamic_slice(pair_weight, (lo,), (m,)), groups, \
+        lo + jnp.arange(m) < hi, lo, hi
+
+
+def _forward_window(w, m, x, pair_weight, w13, w2, order, slot, sizes):
+    """The two grouped products over window ``w``: ``(its part of the
+    tokens' sums (t, d) float32, hidden (m, 2 f))``."""
+    xs, _, weight, groups, live, lo, hi = _window(w, m, x, pair_weight,
+                                                  order, sizes)
+    hidden = grouped_matmul(xs, w13, groups)
+    # a pair's weight goes onto its row between the two products, so that
+    # the combine is a plain sum of a token's k rows
+    y = grouped_matmul(_gated(hidden, weight, live), w2, groups)
+    return _token_sums(y, slot, lo, hi, slot.shape[0] // x.shape[0]), hidden
+
+
+def _product_grads(lhs, rhs, groups, cotangent):
+    """The gradients of :func:`grouped_matmul` by ``lhs`` and ``rhs``."""
+    return jax.vjp(lambda a, e: grouped_matmul(a, e, groups), lhs,
+                   rhs)[1](cotangent)
+
+
+def _backward_window(w, m, kept, x, pair_weight, w13, w2, order, slot, sizes,
+                     g):
+    """Window ``w``'s part of the gradients by ``x`` (float32), the window's
+    pairs' weights, ``w13`` and ``w2`` from the kept first product's output:
+    the inputs are gathered and the rows gated again, no product is repeated
+    (each product's own value is asked of ``jax.vjp`` and never used)."""
+    xs, token, weight, groups, live, lo, hi = _window(w, m, x, pair_weight,
+                                                      order, sizes)
+    hidden = jax.lax.dynamic_slice(kept, (lo, 0), (m, kept.shape[1]))
+    act, gated_vjp = jax.vjp(lambda h, p: _gated(h, p, live), hidden, weight)
+    # the cotangent of a pair's row is its token's row of the output's
+    d_y = jnp.where(live[:, None],
+                    g.at[token].get(mode="promise_in_bounds"),
+                    jnp.zeros((), g.dtype))
+    d_act, d_w2 = _product_grads(act, w2, groups, d_y)
+    d_hidden, d_weight = gated_vjp(d_act)
+    d_xs, d_w13 = _product_grads(xs, w13, groups, d_hidden)
+    # an expert with no row in the window: the layer does not count on the
+    # kernel's zeros in its block of a weight gradient (the TPU's wrote them
+    # in nine probes of nine, my chip run, PR 38)
+    met = (groups > 0)[:, None, None]
+    return _token_sums(d_xs, slot, lo, hi, slot.shape[0] // x.shape[0]), \
+        d_weight, jnp.where(met, d_w13, jnp.zeros((), d_w13.dtype)), \
+        jnp.where(met, d_w2, jnp.zeros((), d_w2.dtype))
+
+
+def _trips(m, sizes):
+    """The windows of ``m`` rows that hold the groups' rows, on the device."""
+    return (sizes.sum() + (m - 1)) // m
+
+
+def _padded(m, order, pair_weight):
+    """``order`` and ``pair_weight`` out to a whole number of windows."""
+    short = -order.shape[0] % m
+    return jnp.pad(order, (0, short)), jnp.pad(pair_weight, (0, short))
+
+
+def _products_fwd(m, x, pair_weight, w13, w2, order, slot, sizes):
+    """``(out (t, d), kept)``: the forward products over as many windows of
+    ``m`` rows as the groups' rows need, a loop of dynamic trip count whose
+    body exists once, and the first product's output at every window's rows
+    (those of a window that did not run hold nothing).  One window holds any
+    load where ``m`` is all the pairs: no loop then."""
+    args = (x, pair_weight, w13, w2, order, slot, sizes)
+    if m == order.shape[0]:
+        out, kept = _forward_window(0, m, *args)
+        return out.astype(x.dtype), (kept,) + args
+    wide_order, wide_weight = _padded(m, order, pair_weight)
+
+    def window(w, carry):
+        out, kept = carry
+        part, hidden = _forward_window(w, m, x, wide_weight, w13, w2,
+                                       wide_order, slot, sizes)
+        return (out.astype(jnp.float32) + part).astype(out.dtype), \
+            jax.lax.dynamic_update_slice(kept, hidden, (w * m, 0))
+
+    out, kept = jax.lax.fori_loop(
+        0, _trips(m, sizes), window,
+        (jnp.zeros_like(x),
+         jnp.zeros((wide_order.shape[0], w13.shape[2]), x.dtype)))
+    return out, (kept,) + args
+
+
+def _products_bwd(m, kept, g):
+    # as under jax.checkpoint: what this pass computes again from the kept
+    # (the gathered inputs, the gated rows) is not to be merged with the
+    # forward pass's and kept alive in its place
+    kept, x, pair_weight, w13, w2, order, slot, sizes = \
+        jax.lax.optimization_barrier(kept)
+    if m == order.shape[0]:
+        d_x, d_weight, d_w13, d_w2 = _backward_window(
+            0, m, kept, x, pair_weight, w13, w2, order, slot, sizes, g)
+        return d_x.astype(x.dtype), d_weight, d_w13, d_w2, None, None, None
+    wide_order, wide_weight = _padded(m, order, pair_weight)
+
+    def window(w, carry):
+        # the weight gradients are sums over the windows: the loop carries
+        # them (and writes them once more than a single product would)
+        d_x, d_weight, d_w13, d_w2 = carry
+        part = _backward_window(w, m, kept, x, wide_weight, w13, w2,
+                                wide_order, slot, sizes, g)
+        return (d_x.astype(jnp.float32) + part[0]).astype(d_x.dtype), \
+            jax.lax.dynamic_update_slice(d_weight, part[1], (w * m,)), \
+            d_w13 + part[2], d_w2 + part[3]
+
+    d_x, d_weight, d_w13, d_w2 = jax.lax.fori_loop(
+        0, _trips(m, sizes), window,
+        (jnp.zeros_like(x), jnp.zeros_like(wide_weight),
+         jnp.zeros_like(w13), jnp.zeros_like(w2)))
+    return d_x, d_weight[:order.shape[0]], d_w13, d_w2, None, None, None
+
+
+def _products(m, *args):
+    """``expert_ffn``'s products on windows of ``m`` rows; ``pair_weight``
+    ``(t k,)`` in the order by expert, ``slot`` the inverse of ``order``.
+    Forward and backward pass each carry the loop over the windows and
+    share ONE kept array, the first product's output: nothing is kept a
+    trip."""
+    return _products_fwd(m, *args)[0]
+
+
+_products = jax.custom_vjp(_products, nondiff_argnums=(0,))
+_products.defvjp(_products_fwd, _products_bwd)
+
+
+def expert_ffn(x, sel, weights, w13, w2, *, first: int, held: int,
+               experts: int = 0):
     """The held experts' part of ``sum_{e in sel} w_e W_2e (silu(W_1e u) *
     (W_3e u))`` on tokens ``x`` ``(t, d)``.  ``w13`` ``(held, d, 2 f)`` holds
-    gate and up side by side, ``w2`` ``(held, f, d)``.  The ``t k`` pairs are
-    ordered by expert, those of experts outside ``[first, first + held)``
-    last; the two grouped products run over the held groups' sizes.  No
-    capacity: every pair of a held expert is computed, at any imbalance.
-    Returns ``(out (t, d), sizes (held,), covered)``; ``covered`` counts the
-    held pairs whose row lies inside the groups the products ran over (the
-    ordering's side of ``moe_dropped``: all of them)."""
+    gate and up side by side, ``w2`` ``(held, f, d)``; ``experts`` is the
+    number the router scores (0: the held ones are all).  The ``t k`` pairs
+    are ordered by expert, those of experts outside ``[first, first + held)``
+    last.  The arrays of the pairs' rows (gathered inputs, both products'
+    rows, their cotangents) have ``m`` rows (:func:`window_rows`, from the
+    shapes), and the two grouped products run over as many windows of ``m``
+    rows of that order as the step's own count of held pairs needs, one after
+    the other inside the compiled program: window ``w`` takes rows ``[w m,
+    (w + 1) m)``, its groups are the experts' ranges cut to it, and the
+    tokens' sums and the weight gradients add up over the windows.  No
+    capacity: every pair of a held expert is computed, at any imbalance; a
+    load past ``m`` costs a window more, never a pair.  Returns ``(out (t,
+    d), sizes (held,), covered, rows)``; ``covered`` counts the held pairs
+    whose row lies inside the groups AND inside a window that ran (the
+    ordering's side of ``moe_dropped``: all of them), ``rows`` is ``m`` times
+    the windows taken."""
+    return _expert_ffn(window_rows(x.shape[0], sel.shape[1], held,
+                                   experts or held), first, held, x, sel,
+                       weights, w13, w2)
+
+
+def _expert_ffn(m, first, held, x, sel, weights, w13, w2):
     t, _ = x.shape
     k = sel.shape[1]
     # the pairs in the order (k, t): pair j t + i is token i's j-th expert,
     # so that a token's k rows are k whole blocks of the pairs' rows
     local = sel.T.reshape(-1) - first
     local = jnp.where((local >= 0) & (local < held), local, held)
-    order = jnp.argsort(local, stable=True).astype(jnp.int32)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(t * k, dtype=jnp.int32))
-    sizes = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
-    held_pair = local < held                       # in the pairs' own order
-    live = jnp.arange(t * k) < sizes.sum()         # in the order by expert
-    pair_weight = weights.T.reshape(-1)[order]
+    # the groups' sizes and every pair's slot in the order by expert from
+    # comparisons and a running count: a pair follows the pairs of earlier
+    # experts and the earlier pairs of its own.  The order itself is ONE
+    # scatter of t k single elements (0.15 ms on a TPU); a sort in its place
+    # runs in 30 us and adds 1.5 MB to the executable and 0.2 s to a cold
+    # compile, and four sorts a layer gained 0.3% (my chip runs, PR 38)
+    mine = local == jnp.arange(held + 1)[:, None]
+    count = jnp.cumsum(mine, axis=1, dtype=jnp.int32)
+    sizes = count[:held, -1]
+    slot = jnp.where(mine, count - 1 + jnp.cumsum(count[:, -1])[:, None]
+                     - count[:, -1:], 0).sum(axis=0)
+    order = jnp.zeros_like(slot).at[slot].set(
+        jnp.arange(t * k, dtype=jnp.int32), unique_indices=True,
+        mode="promise_in_bounds")
+    out = _products(m, x, _permuted(weights.T.reshape(-1), order, slot),
+                    w13.astype(x.dtype), w2.astype(x.dtype), order, slot,
+                    sizes)
+    rows = m * _trips(m, sizes) if m < t * k else jnp.int32(m)
+    covered = ((local < held)
+               & (slot < jnp.minimum(sizes.sum(), rows))).sum()
+    return out, sizes, covered, rows
 
-    def products(x, pair_weight, w13, w2):
-        xs = _rows(x, order % t, inverse, held_pair)
-        hidden = checkpoint_name(grouped_matmul(xs, w13, sizes),
-                                 "moe_hidden")
-        # a pair's weight goes onto its row between the two products, so
-        # that the combine is a plain sum of a token's k rows
-        y = grouped_matmul(_gated(hidden, pair_weight, live), w2, sizes)
-        y = _permute(y, inverse, order).reshape(k, t, -1)
-        return jnp.where(held_pair.reshape(k, t, 1), y.astype(jnp.float32),
-                         0.0).sum(axis=0)
 
-    # of the pairs' rows the backward pass keeps ``hidden`` alone: it
-    # gathers the inputs and gates the rows again (no product is repeated)
-    out = jax.checkpoint(
-        products, policy=jax.checkpoint_policies.save_only_these_names(
-            "moe_hidden"))(x, pair_weight, w13.astype(x.dtype),
-                           w2.astype(x.dtype))
-    covered = (held_pair & live[inverse]).sum()
-    return out.astype(x.dtype), sizes, covered
+# a net's layers of one shape are traced once a step, not once each: the
+# Python of five layers' hand-written passes was 1.1 s of a warm start on the
+# chip's host (my chip runs, PR 38)
+_expert_ffn = jax.jit(_expert_ffn, static_argnums=(0, 1, 2))
 
 
 class TopKExpertLayer(Layer):
@@ -415,7 +585,13 @@ class TopKExpertLayer(Layer):
     experts would add is left out, as on one rank of an expert-parallel
     group, and the weights' normaliser runs over all ``top_k`` selected.
     Gated-SiLU experts of width ``nhidden`` without biases, no residual
-    inside, no auxiliary loss, no capacity: no token is dropped.
+    inside, no auxiliary loss, no capacity: no token is dropped.  The
+    arrays of the token-expert pairs' rows have ``m`` rows
+    (:func:`window_rows`: 1.5 times an even routing's held pairs, or all ``b
+    s top_k`` where the layer holds every expert) and the products run over
+    as many windows of ``m`` rows as the step's held pairs fill, counted on
+    the device inside the compiled step: a load past ``m`` takes a window
+    more and is computed whole, never cut.
 
     Parameters: ``router`` ``(num_expert, d)``, ``w13`` ``(held d, 2
     nhidden)`` (expert ``e``'s gate and up matrices side by side in rows ``e
@@ -433,7 +609,10 @@ class TopKExpertLayer(Layer):
     held expert; ``moe_load_max_over_mean``, the fullest held expert's rows
     over the mean; ``moe_dropped``, the pairs the ROUTER gave a held expert
     less those whose row the ordering put inside the groups the products
-    ran over (0 without a capacity; the two counts are made apart).  Where
+    ran over and inside a window that ran (0 without a capacity; the two
+    counts are made apart); ``moe_rows_computed``, ``m`` times the windows
+    each layer's pairs took this step (``b s top_k`` a layer that holds all
+    its experts).  Where
     the context asks for it (``ctx.keep_selection``,
     ``NetTrainer.keep_expert_selection``), the experts each token selected
     go with them under a name no record takes, ``_moe_selected``.
@@ -531,13 +710,15 @@ class TopKExpertLayer(Layer):
             x, params["router"], buffers.get("bias"), top_k=self.top_k,
             score_func=self.score_func, norm_topk=bool(self.norm_topk),
             scale=self.routed_scale)
-        out, sizes, covered = expert_ffn(
+        out, sizes, covered, rows = expert_ffn(
             x, sel, weights, params["w13"].reshape(held, d, 2 * f),
             params["w2"].reshape(held, f, d), first=self.expert_first,
-            held=held)
+            held=held, experts=self.num_expert)
         if ctx.train:
             self.moe_site = (self.num_expert, held, self.expert_first,
-                             self.top_k, f, self.score_func, GMM_LOWERING)
+                             self.top_k, f, self.score_func, GMM_LOWERING,
+                             window_rows(b * s, self.top_k, held,
+                                         self.num_expert))
             in_range = (sel >= self.expert_first) \
                 & (sel < self.expert_first + held)
             pairs = sizes.sum()
@@ -549,14 +730,16 @@ class TopKExpertLayer(Layer):
                 load.astype(jnp.float32))
             diag["moe_dropped"] = diag.get("moe_dropped", 0) \
                 + (in_range.sum() - covered)
+            diag["moe_rows_computed"] = diag.get("moe_rows_computed", 0) \
+                + rows
             if ctx.keep_selection:
                 diag["_moe_selected"] = diag.get("_moe_selected", []) + [sel]
             if self.expert_bias and self.expert_bias_rate > 0:
                 # the auxiliary-loss-free balancing rule: an expert that
                 # more tokens selected than the mean loses, one that fewer
                 # did gains; the counts are of all experts, held or not
-                counts = jnp.zeros((self.num_expert,), jnp.int32).at[
-                    sel.reshape(-1)].add(1)
+                counts = (sel.reshape(-1) == jnp.arange(
+                    self.num_expert)[:, None]).sum(axis=1)
                 mean = sel.size / self.num_expert
                 buffers = dict(buffers, bias=buffers["bias"]
                                + self.expert_bias_rate

@@ -1956,10 +1956,10 @@ class NetTrainer:
         order: the layer's name with the experts ``published`` (the router's
         width), ``held`` and the ``first`` held index, the experts a token
         (``top_k``), the experts' ``width``, the ``score`` function and the
-        ``lowering`` of the grouped products (``layers/moe.GMM_LOWERING``).
-        ``[]`` for a net without such a layer."""
+        ``lowering`` (``layers/moe.GMM_LOWERING``) and the ``rows`` of a window
+        (``layers/moe.window_rows``).  ``[]`` for a net without such a layer."""
         fields = ("published", "held", "first", "top_k", "width", "score",
-                  "lowering")
+                  "lowering", "rows")
         return [dict(zip(fields, c.layer.moe_site),
                      layer=c.param_key.split("-", 1)[1])
                 for c in self.net.connections

@@ -301,28 +301,172 @@ def test_no_token_is_dropped_when_every_token_selects_the_same_expert():
     np.testing.assert_allclose(out, want, rtol=0, atol=1e-5)
 
 
-def test_rows_past_the_last_group_may_hold_anything(monkeypatch):
+@pytest.fixture
+def small_tile(monkeypatch):
+    """Windows at toy sizes: 216 rows for the 384 pairs of ``B S`` tokens with
+    3 of 8 experts held (144 held pairs when even), 144 with 4 of 16 held
+    (three windows hold every pair)."""
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    assert moe.window_rows(B * S, TOPK, HELD, E) == 216
+    assert moe.window_rows(B * S, TOPK, 4, 16) == M == 144
+
+
+def test_window_rows_come_from_the_shapes():
+    """The benchmark cell's layer: 8,192 tokens, 4 of 32 experts a token, 8
+    held: 1.5 times the even routing's 8,192 held pairs; a layer that holds
+    every expert, or so many that 1.5 times their share is all the pairs,
+    has ``t k``."""
+    assert moe.window_rows(8192, 4, 8, 32) == 12288
+    assert moe.window_rows(8192, 4, 32, 32) == 32768
+    assert moe.window_rows(8192, 4, 24, 32) == 32768
+    assert moe.window_rows(8192, 4, 16, 32) == 24576
+    # a whole tile of the products' rows
+    assert moe.window_rows(8192, 4, 3, 32) == 4608
+    assert moe.window_rows(8192, 4, 1, 64) == 1024
+    # a toy net's tile is its tokens
+    assert moe.window_rows(B * S, TOPK, HELD, E) == 288
+
+
+M = 144  # the window of the loads below: 96 tokens, 4 of 16 experts, 4 held
+
+
+def selection(pairs, held=4, first=FIRST, experts=16, one_expert=False,
+              seed=0):
+    """``(B S, TOPK)`` distinct experts a token of which ``pairs`` in all lie
+    in ``[first, first + held)``, spread over the tokens as evenly as a
+    count allows and over the held experts at random (``one_expert``: all on
+    the first held one), with float32 weights of the pairs."""
+    rng = np.random.default_rng(seed)
+    t = B * S
+    inside = np.arange(first, first + held)
+    outside = np.setdiff1d(np.arange(experts), inside)
+    a_token = np.full(t, pairs // t) + (np.arange(t) < pairs % t)
+    assert a_token.max() <= (1 if one_expert else held)
+    sel = np.zeros((t, TOPK), np.int32)
+    for i, n in enumerate(rng.permutation(a_token)):
+        mine = inside[:n] if one_expert else rng.choice(inside, n, False)
+        rest = rng.choice(outside, TOPK - n, False)
+        sel[i] = rng.permutation(np.concatenate([mine, rest]))
+    weights = rng.uniform(0.1, 0.6, sel.shape).astype(np.float32)
+    return jnp.asarray(sel), jnp.asarray(weights)
+
+
+def ffn_value_and_grads(sel, weights, held, experts, seed=1):
+    """A scalar of ``expert_ffn``'s output with its gradients by ``x``, the
+    pairs' weights, ``w13`` and ``w2``, and the layer's counts."""
+    x = jax.random.normal(jax.random.PRNGKey(seed), (B * S, D))
+    _, params, _ = expert_layer(held, FIRST)
+    w13 = params["w13"].reshape(held, D, 48)
+    w2 = params["w2"].reshape(held, 24, D)
+
+    def scalar(x, weights, w13, w2):
+        out, sizes, covered, rows = moe.expert_ffn(
+            x, sel, weights, w13, w2, first=FIRST, held=held,
+            experts=experts)
+        return (out * jnp.cos(out)).sum(), (out, sizes, covered, rows)
+
+    (value, aux), grads = jax.jit(jax.value_and_grad(
+        scalar, (0, 1, 2, 3), has_aux=True))(x, weights, w13, w2)
+    return value, aux, grads
+
+
+LOADS = {  # held pairs, all on one expert, windows taken, a group cut
+    "no_held_pair": (0, False, 0, False),
+    "under_a_window": (100, False, 1, False),
+    "exactly_a_window": (M, False, 1, False),
+    "a_window_and_a_pair": (M + 1, False, 2, True),
+    "exactly_two_windows": (2 * M, False, 2, True),
+    "every_pair_on_held_experts": (B * S * TOPK, False, 3, True),
+    "every_token_on_one_held_expert": (B * S, True, 1, False),
+}
+
+
+@pytest.mark.parametrize("load", list(LOADS))
+def test_any_load_equals_the_layer_on_all_the_pairs_rows(small_tile, load):
+    """Whatever number of windows a load takes, the output and the gradients
+    by ``x``, the pairs' weights, ``w13`` and ``w2`` are those of the program
+    on all ``t k`` rows (``experts = 0``: one window of all the rows, no
+    loop).  One window: the groups are the same rows in the same order and a
+    row's arithmetic does not know how many rows follow it, so every number
+    is equal to the bit.  More windows: the rows' own numbers (the gradient
+    by the pairs' weights) are still equal to the bit; a token's sum and a
+    weight gradient add the same terms window by window, in another order:
+    the furthest found at these sizes in float32 is 8e-7 of the tensor's
+    largest entry, ``w2``'s gradient over three windows (limit 2e-6)."""
+    pairs, one_expert, windows, cut = LOADS[load]
+    sel, weights = selection(pairs, one_expert=one_expert)
+    got, (out, sizes, covered, took), got_grads = ffn_value_and_grads(
+        sel, weights, 4, 16)
+    want, (want_out, want_sizes, want_covered, all_rows), want_grads = \
+        ffn_value_and_grads(sel, weights, 4, 0)
+    assert int(took) == windows * M and int(all_rows) == B * S * TOPK
+    assert int(sizes.sum()) == int(covered) == int(want_covered) == pairs
+    np.testing.assert_array_equal(sizes, want_sizes)
+    if one_expert:
+        assert int(sizes[0]) == pairs
+    # a group that straddles a window's edge
+    ends = np.cumsum(sizes)
+    edges = M * np.arange(1, 3)[:, None]
+    assert bool(((ends - np.asarray(sizes) < edges) & (edges < ends)).any()) \
+        == cut
+    for g in got_grads:
+        assert np.isfinite(np.asarray(g)).all()
+    if windows <= 1:
+        np.testing.assert_array_equal(out, want_out)
+        assert float(got) == float(want)
+        for g, w in zip(got_grads, want_grads):
+            np.testing.assert_array_equal(g, w)
+    else:
+        for g, w in zip((out,) + got_grads, (want_out,) + want_grads):
+            np.testing.assert_allclose(
+                g, w, rtol=0, atol=2e-6 * float(jnp.abs(w).max()))
+    if pairs:
+        assert all(float(jnp.abs(w).max()) > 1e-3 for w in want_grads)
+    else:
+        assert not np.asarray(out).any()
+        assert not any(np.asarray(g).any() for g in got_grads)
+
+
+@pytest.fixture
+def fresh_traces():
+    """``expert_ffn``'s core is jitted (a net's layers trace it once): what a
+    test patches under it is seen only by a trace made after the patch, and
+    must not be left to a later test."""
+    yield jax.clear_caches
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("pairs,windows,experts", [
+    (100, 1, 16), (250, 2, 16), (330, 3, 16), (100, 1, 0)])
+def test_rows_past_the_last_group_may_hold_anything(monkeypatch, small_tile,
+                                                    fresh_traces, pairs,
+                                                    windows, experts):
     """On a TPU the grouped product writes the held groups' rows only (my
-    chip run, PR 36: a first run's every loss was NaN).  With those rows
-    poisoned, in the product's result and in its gradient by the rows, the
-    layer's output and every gradient are what they were."""
+    chip run, PR 36: a first run's every loss was NaN), and this layer does
+    not count on it for the block of an expert with no row either.  With the
+    rows between a window's last group and its end poisoned in every trip, in
+    the product's result and in its gradient by the rows, and the weight
+    gradient's blocks of the experts a window does not meet, the layer's
+    output and every gradient are what they were."""
     x = jax.random.normal(jax.random.PRNGKey(1), (B * S, D))
-    _, params, buffers = expert_layer(HELD, FIRST)
-    w13 = params["w13"].reshape(HELD, D, 48)
-    w2 = params["w2"].reshape(HELD, 24, D)
+    _, params, _ = expert_layer(4, FIRST)
+    w13 = params["w13"].reshape(4, D, 48)
+    w2 = params["w2"].reshape(4, 24, D)
+    sel, weights = selection(pairs)
 
-    def layer(x, router, w13, w2):
-        sel, w, _ = moe.route(x, router, buffers["bias"], top_k=TOPK)
-        out, sizes, covered = moe.expert_ffn(x, sel, w, w13, w2, first=FIRST,
-                                             held=HELD)
-        return (out * jnp.cos(out)).sum(), (sizes, covered)
+    def layer(x, weights, w13, w2):
+        out, sizes, covered, took = moe.expert_ffn(
+            x, sel, weights, w13, w2, first=FIRST, held=4, experts=experts)
+        return (out * jnp.cos(out)).sum(), (sizes, covered, took)
 
-    args = (x, params["router"], w13, w2)
-    (want, (sizes, covered)), want_grads = jax.value_and_grad(
+    args = (x, weights, w13, w2)
+    (want, (sizes, covered, took)), want_grads = jax.value_and_grad(
         layer, (0, 1, 2, 3), has_aux=True)(*args)
-    assert int(sizes.sum()) < B * S * TOPK  # there ARE rows past the groups
-    assert int(covered) == int(sizes.sum())
+    rows = windows * M if experts else B * S * TOPK
+    assert int(sizes.sum()) == pairs < int(took) == rows  # rows past them
+    assert int(covered) == pairs
     clean = moe.grouped_matmul
+    seen = set()
 
     @jax.custom_vjp
     def poisoned(lhs, rhs, sizes):
@@ -333,23 +477,61 @@ def test_rows_past_the_last_group_may_hold_anything(monkeypatch):
         return jnp.where(past[:, None], jnp.nan, rows)
 
     def fwd(lhs, rhs, sizes):
+        seen.add(lhs.shape[0])
         return poisoned(lhs, rhs, sizes), (lhs, rhs, sizes)
 
     def bwd(res, g):
         lhs, rhs, sizes = res
         d_lhs, d_rhs = jax.vjp(lambda a, b: clean(a, b, sizes), lhs,
                                rhs)[1](jnp.where(jnp.isnan(g), 7.0, g))
-        return spoil(d_lhs, sizes), d_rhs, None
+        return spoil(d_lhs, sizes), \
+            jnp.where((sizes == 0)[:, None, None], jnp.nan, d_rhs), None
 
     poisoned.defvjp(fwd, bwd)
     monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+    fresh_traces()
     (got, _), got_grads = jax.value_and_grad(
         layer, (0, 1, 2, 3), has_aux=True)(*args)
+    assert seen == {M if experts else B * S * TOPK}
     np.testing.assert_allclose(got, want, rtol=1e-6)
     for g, w in zip(got_grads, want_grads):
         assert np.isfinite(np.asarray(g)).all()
         np.testing.assert_allclose(g, w, rtol=0,
                                    atol=1e-6 * float(jnp.abs(w).max()))
+
+
+def primitives_of(jaxpr):
+    """The names of every primitive in ``jaxpr`` and under it."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= primitives_of(sub)
+    return names
+
+
+@pytest.mark.parametrize("held,loops", [(0, False), (HELD, True)])
+def test_a_layer_that_holds_all_its_experts_has_no_loop(small_tile, held,
+                                                        loops):
+    """``expert_held = 0``: the pairs' rows are all ``t k`` and neither pass
+    of the layer carries a ``while``; a layer that holds a share carries one
+    in each, and no pass of either a ``cond``."""
+    layer, params, buffers = expert_layer(held, 0 if held == 0 else FIRST)
+    x = jax.random.normal(jax.random.PRNGKey(1), (B, 1, S, D))
+
+    def loss(params, x):
+        ctx = ForwardContext(train=True)
+        out = layer.forward(params, buffers, [x], ctx)[0][0]
+        return (out * out).sum(), ctx.diagnostics["moe_rows_computed"]
+
+    forward = primitives_of(jax.make_jaxpr(loss)(params, x).jaxpr)
+    both = primitives_of(jax.make_jaxpr(
+        jax.grad(loss, (0, 1), has_aux=True))(params, x).jaxpr)
+    assert "ragged_dot_general" in forward or "ragged_dot" in forward
+    assert ("while" in forward) == loops and ("while" in both) == loops
+    assert "cond" not in both
+    _, rows = loss(params, x)
+    assert int(rows) == (216 if loops else B * S * TOPK)
 
 
 def test_the_bias_moves_selections_and_no_weight():
@@ -581,6 +763,10 @@ def test_a_float8_reference_is_refused():
 
 # ------------------------------------------------------- counters and sites
 
+COUNTERS = {"moe_local_pairs", "moe_load_max_over_mean", "moe_dropped",
+            "moe_rows_computed"}
+
+
 def test_the_step_carries_the_expert_counters():
     data, label = packed_batch()
     t = make_trainer(hybrid_lm(**SIZES, packed=True))
@@ -588,11 +774,12 @@ def test_the_step_carries_the_expert_counters():
     t.update(DataBatch(data=data, label=label,
                        index=np.arange(B, dtype=np.uint32)))
     diags = t.last_diagnostics()
-    assert set(diags) == {"moe_local_pairs", "moe_load_max_over_mean",
-                          "moe_dropped"}
+    assert set(diags) == COUNTERS
     assert diags["moe_dropped"] == 0
     # three routed layers, B S tokens, four experts a token, 3 of 8 held
     assert 0 < diags["moe_local_pairs"] <= 3 * B * S * 3
+    # a window of 288 rows holds any load of 96 tokens on 3 held experts
+    assert diags["moe_rows_computed"] == 3 * 288
     assert 1.0 <= diags["moe_load_max_over_mean"] <= HELD
     # what the step selected is returned only to a check that asks for it
     assert t.last_expert_selection() == []
@@ -600,8 +787,7 @@ def test_the_step_carries_the_expert_counters():
     t.update(DataBatch(data=data, label=label,
                        index=np.arange(B, dtype=np.uint32)))
     diags = t.last_diagnostics()
-    assert set(diags) == {"moe_local_pairs", "moe_load_max_over_mean",
-                          "moe_dropped"}
+    assert set(diags) == COUNTERS
     selection = t.last_expert_selection()
     assert [s.shape for s in selection] == [(B * S, TOPK)] * 3
     assert all(s.dtype == np.int32 and (0 <= s).all() and (s < E).all()
@@ -615,8 +801,34 @@ def test_the_step_carries_the_expert_counters():
                        index=np.arange(B, dtype=np.uint32)))
     assert t.last_expert_selection() == []
     want = dict(published=E, held=HELD, first=FIRST, top_k=TOPK, width=24,
-                score="sigmoid", lowering=moe.GMM_LOWERING)
+                score="sigmoid", lowering=moe.GMM_LOWERING, rows=288)
     assert t.moe_sites() == [dict(want, layer=f"l{i}_moe") for i in (1, 2, 3)]
+
+
+def test_a_load_that_crosses_a_window_traces_nothing(small_tile):
+    """Two steps of one compiled program: the first near the even load (every
+    layer in one window of 216 rows), the second under a bias that sends
+    every token to all three held experts (288 pairs a layer: two windows):
+    the trips are counted on the device and the step is traced once."""
+    data, label = packed_batch()
+    batch = DataBatch(data=data, label=label,
+                      index=np.arange(B, dtype=np.uint32))
+    t = make_trainer(hybrid_lm(**SIZES, packed=True))
+    t.update(batch)
+    first = t.last_diagnostics()
+    traces = t.metrics.counters["train_step_traces"]
+    assert first["moe_local_pairs"] < 3 * 216
+    assert first["moe_rows_computed"] == 3 * 216
+    lift = np.zeros(E, np.float32)
+    lift[FIRST:FIRST + HELD] = 10.0
+    t.buffers = jax.tree.map(lambda b: b + jnp.asarray(lift), t.buffers)
+    t.update(batch)
+    second = t.last_diagnostics()
+    assert second["moe_local_pairs"] == 3 * 288
+    assert second["moe_rows_computed"] == 3 * 432
+    assert second["moe_dropped"] == first["moe_dropped"] == 0
+    assert t.metrics.counters["train_step_traces"] == traces == 1
+    assert t.moe_sites()[0]["rows"] == 216
 
 
 def test_a_step_moves_each_bias_toward_an_even_load():
@@ -739,7 +951,7 @@ def test_the_steps_own_selection_is_held_to_the_reference_router(
             and any("select another set" in p for p in problems), problems
 
 
-def test_update_many_in_a_scan_carries_the_counters():
+def test_update_many_in_a_scan_carries_the_counters(small_tile):
     data, label = packed_batch()
     one = make_trainer(hybrid_lm(**SIZES, packed=True))
     many = make_trainer(hybrid_lm(**SIZES, packed=True))
@@ -750,7 +962,8 @@ def test_update_many_in_a_scan_carries_the_counters():
     losses = many.update_many(np.stack([data] * 2), np.stack([label] * 2))
     assert np.isfinite(np.asarray(losses)).all()
     got, want = many.last_diagnostics(), one.last_diagnostics()
-    assert set(got) == set(want) and got["moe_dropped"] == 0
+    assert set(got) == set(want) == COUNTERS and got["moe_dropped"] == 0
+    assert got["moe_rows_computed"] == 3 * 216
     for k in want:
         np.testing.assert_allclose(got[k], want[k], rtol=1e-6)
 

@@ -114,9 +114,15 @@ def test_routed_experts_compile_for_v5e(one_chip):
     row of 8,192 tokens, 4 of 32 experts a token, 8 held.  XLA lowers each
     ragged dot to one Mosaic call: two products forward, their two input
     gradients and two weight gradients, none a second time (the backward
-    pass keeps the first product's output and gates the rows again)."""
+    pass keeps the first product's output and gates the rows again), each
+    ONCE in the program: the windows of 12,288 rows are the trips of one
+    ``while`` a pass, and nothing chooses between copies of a pass."""
+    import re
+
     from cxxnet_tpu.layers import moe
     t, d, f, experts, held, k = 8192, 2048, 1792, 32, 8, 4
+    m = moe.window_rows(t, k, held, experts)
+    assert m == 12288
     bf = jnp.bfloat16
 
     def sds(shape, dtype=bf):
@@ -125,7 +131,8 @@ def test_routed_experts_compile_for_v5e(one_chip):
     def layer(x, router, bias, w13, w2):
         sel, weights, _ = moe.route(x, router, bias, top_k=k)
         return moe.expert_ffn(x, sel, weights, w13.reshape(held, d, 2 * f),
-                              w2.reshape(held, f, d), first=0, held=held)[0]
+                              w2.reshape(held, f, d), first=0, held=held,
+                              experts=experts)[0]
 
     def fwd_bwd(x, router, bias, w13, w2, g):
         out, vjp = jax.vjp(lambda *a: layer(a[0], a[1], bias, a[2], a[3]),
@@ -141,8 +148,13 @@ def test_routed_experts_compile_for_v5e(one_chip):
     assert len(products) == 6
     assert all('custom_call_target="tpu_custom_call"' in ln
                for ln in products)
-    # the pairs' rows are b s k, whatever share of them meets a held expert
+    # the parent's count of Mosaic calls: the products and their metadata
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    # a window's rows are m, whatever share of the pairs is held
     written = [ln.split(" custom-call(")[0] for ln in products]
-    assert sum(f"bf16[{t * k},{2 * f}]" in w for w in written) == 1
-    assert sum(f"bf16[{t * k},{d}]" in w for w in written) == 2
+    assert sum(f"bf16[{m},{2 * f}]" in w for w in written) == 1
+    assert sum(f"bf16[{m},{d}]" in w for w in written) == 2
+    assert sum(f"bf16[{m},{f}]" in w for w in written) == 1
     assert sum(f"bf16[{held}," in w for w in written) == 2
+    assert len(re.findall(r" while\(", text)) == 2
+    assert not re.findall(r" conditional\(", text)
