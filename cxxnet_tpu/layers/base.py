@@ -151,7 +151,17 @@ def conn_scope_name(index: int, conn) -> str:
     transform-wrapped paths like ``transpose(jvp(03-conv))`` is
     unambiguous."""
     base = conn.param_key.split("-", 1)[1]
-    return f"{index:02d}-" + _SCOPE_BAD.sub("_", base)
+    return f"{index:02d}-" + scope_safe(base)
+
+
+#: the scope the trainer applies the updater under, a parameter group's
+#: ``update/<NN-name>``: the pass ``update`` of layer attribution
+UPDATE_SCOPE = "update"
+
+
+def scope_safe(name: str) -> str:
+    """``name`` with the characters ``jax.named_scope`` rejects replaced."""
+    return _SCOPE_BAD.sub("_", name)
 
 
 @dataclasses.dataclass

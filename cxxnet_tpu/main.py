@@ -178,9 +178,6 @@ class LearnTask:
         self._resume_iter_state = None
         self._resume_sentinel_state = None
         self._warned_iter_capture = False
-        # instruction->scope join, cached like trainer._step_aot_cache:
-        # recurring prof_every windows must not re-scan the HLO text
-        self._op_scopes_cache = None
         # the mem_profile table (monitor/memory.py) is the executable's
         # static truth — built once per trainer, re-emitted per window
         self._mem_profile_cache = None
@@ -536,27 +533,21 @@ class LearnTask:
             self._emit_mem_profile()
 
     def _emit_layer_profile(self, planes, steps: int) -> None:
-        """Join the window's per-op device times against the stamped
-        layer scopes (monitor/attribution.py) and the analytic cost
+        """Book the window's per-op device self times to layer scope
+        and pass (monitor/attribution.py) and join the analytic cost
         model (analysis/costmodel.py); emit one ``layer_profile`` record
-        carrying the whole table.  Runs only with an active sink, so
-        the one extra AOT compile ``step_hlo_text`` pays (cached per
-        trainer) is an explicit observability opt-in."""
+        carrying the whole table.  The instruction -> ``op_name`` map is
+        the executable the window's trace itself holds (its ``Hlo
+        Proto``): no second lowering or compile, and a scanned
+        ``update_many`` step is covered like a single one."""
         net = self.net
         metrics = net.metrics
         try:
             from .analysis import costmodel
             from .monitor import attribution
-            scopes = net.layer_scopes()
-            op_scopes = self._op_scopes_cache
-            if op_scopes is None:
-                hlo = net.step_hlo_text()
-                op_scopes = attribution.hlo_op_scopes(hlo, scopes) \
-                    if hlo else {}
-                self._op_scopes_cache = op_scopes
             kind = net.devices[0].device_kind
             table = attribution.layer_table(
-                planes, scopes, op_scopes, steps=steps,
+                planes, steps=steps,
                 costs=costmodel.layer_costs(net.net),
                 peak_flops=costmodel.peak_flops(kind),
                 peak_bw=costmodel.peak_bw(kind))

@@ -12,7 +12,15 @@ container might not, and a 600 MB dependency for four varint fields is
 the wrong trade.  Field numbers verified against the installed proto:
 XSpace.planes=1; XPlane.name=2/lines=3/event_metadata=4 (map: key=1,
 value=2); XLine.name=2/events=4; XEvent.metadata_id=1/offset_ps=2/
-duration_ps=3; XEventMetadata.id=1/name=2/display_name=3.
+duration_ps=3; XEventMetadata.id=1/name=2/stats=5; XPlane.stat_metadata=5
+(map: key=1, value=2 with XStatMetadata.name=2); XStat.metadata_id=1/
+bytes_value=6.
+
+The executables that ran ride in the trace: the ``/host:metadata`` plane
+has one event metadata per module, named as the ``XLA Modules`` line
+names it, whose ``Hlo Proto`` stat holds the serialized ``HloProto``
+(``XPlane.hlo_protos``; monitor/attribution.py reads each instruction's
+``op_name`` out of it).
 
 Collective classification: cross-chip reduction ops (all-reduce /
 reduce-scatter / all-gather / all-to-all / collective-permute, plus
@@ -93,20 +101,17 @@ class XLine:
 
 
 class XPlane:
-    __slots__ = ("name", "lines", "event_names", "event_display")
+    __slots__ = ("name", "lines", "event_names", "hlo_protos")
 
     def __init__(self, name: str, lines: List[XLine],
                  event_names: Dict[int, str],
-                 event_display: Optional[Dict[int, str]] = None):
+                 hlo_protos: Optional[Dict[str, bytes]] = None):
         self.name = name
         self.lines = lines
         self.event_names = event_names
-        # XEventMetadata.display_name (field 3): TPU op events carry the
-        # framework op path here ("jit(step)/03-conv/conv_general_..."),
-        # which is where layer attribution reads named scopes from when
-        # the trace itself has them (monitor/attribution.py)
-        self.event_display = event_display if event_display is not None \
-            else {}
+        # module name -> serialized HloProto, from the event metadata's
+        # "Hlo Proto" stats (the /host:metadata plane; empty elsewhere)
+        self.hlo_protos = hlo_protos if hlo_protos is not None else {}
 
 
 def _parse_event(buf: bytes) -> XEvent:
@@ -131,9 +136,11 @@ def _parse_line(buf: bytes) -> XLine:
     return XLine(name, events)
 
 
-def _parse_event_metadata_entry(buf: bytes) -> Tuple[int, str, str]:
-    """map<int64, XEventMetadata> entry -> (id, name, display_name)."""
-    key, name, display = 0, "", ""
+def _parse_event_metadata_entry(buf: bytes
+                                ) -> Tuple[int, str, List[Tuple[int, bytes]]]:
+    """map<int64, XEventMetadata> entry -> (id, name, [(stat metadata
+    id, bytes value)]) — only stats that carry bytes are kept."""
+    key, name, stats = 0, "", []
     for field, _, val in _fields(buf):
         if field == 1:
             key = val
@@ -141,36 +148,65 @@ def _parse_event_metadata_entry(buf: bytes) -> Tuple[int, str, str]:
             for f2, _, v2 in _fields(val):
                 if f2 == 2:
                     name = v2.decode("utf-8", "replace")
-                elif f2 == 3:
-                    display = v2.decode("utf-8", "replace")
-    return key, name, display
+                elif f2 == 5:
+                    sid, blob = 0, None
+                    for f3, _, v3 in _fields(v2):
+                        if f3 == 1:
+                            sid = v3
+                        elif f3 == 6:
+                            blob = v3
+                    if blob is not None:
+                        stats.append((sid, blob))
+    return key, name, stats
+
+
+def _parse_stat_metadata_entry(buf: bytes) -> Tuple[int, str]:
+    """map<int64, XStatMetadata> entry -> (id, name)."""
+    key, name = 0, ""
+    for field, _, val in _fields(buf):
+        if field == 1:
+            key = val
+        elif field == 2:
+            for f2, _, v2 in _fields(val):
+                if f2 == 2:
+                    name = v2.decode("utf-8", "replace")
+    return key, name
 
 
 def op_event_name(name: str) -> str:
     """The HLO instruction name of an XLA-op event.  The TPU runtime of
     jax 0.9 / libtpu 0.0.34 names an op event by its whole instruction
-    line (``%fusion.220 = (bf16[8192,2048]{...}, ...) fusion(...)``) and
-    leaves ``display_name`` empty, where earlier runtimes used the bare
-    instruction name; every consumer (the collective classifier, the
+    line (``%fusion.220 = (bf16[8192,2048]{...}, ...) fusion(...)``),
+    where earlier runtimes used the bare instruction name; every
+    consumer (the collective classifier, the
     compiled-HLO scope join, per-op totals) keys on the bare name."""
     if name.startswith("%"):
         return name[1:].split(" = ", 1)[0]
     return name
 
 
+HLO_PROTO_STAT = "Hlo Proto"
+
+
 def _parse_plane(buf: bytes) -> XPlane:
-    name, lines, event_names, event_display = "", [], {}, {}
+    name, lines, event_names = "", [], {}
+    stat_names: Dict[int, str] = {}
+    blobs: List[Tuple[str, int, bytes]] = []
     for field, _, val in _fields(buf):
         if field == 2:
             name = val.decode("utf-8", "replace")
         elif field == 3:
             lines.append(_parse_line(val))
         elif field == 4:
-            k, v, d = _parse_event_metadata_entry(val)
+            k, v, stats = _parse_event_metadata_entry(val)
             event_names[k] = op_event_name(v)
-            if d:
-                event_display[k] = d
-    return XPlane(name, lines, event_names, event_display)
+            blobs += [(v, sid, blob) for sid, blob in stats]
+        elif field == 5:
+            k, v = _parse_stat_metadata_entry(val)
+            stat_names[k] = v
+    protos = {module: blob for module, sid, blob in blobs
+              if stat_names.get(sid) == HLO_PROTO_STAT}
+    return XPlane(name, lines, event_names, protos)
 
 
 def parse_xspace(path: str) -> List[XPlane]:

@@ -38,7 +38,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import engine
 from ..analysis.schema import K
 from ..io.data import DataBatch
-from ..layers.base import ForwardContext, LabelInfo, as_mat
+from ..layers.base import (UPDATE_SCOPE, ForwardContext, LabelInfo, as_mat,
+                           scope_safe)
 from ..monitor import TrainingDiverged, log as mlog
 from ..monitor.metrics import MetricsRegistry, device_memory_gauges
 from ..parallel import mesh as meshlib
@@ -1413,6 +1414,9 @@ class NetTrainer:
         return jax.value_and_grad(loss_fn, has_aux=True)(params)
 
     def _apply_update(self, params, opt_state, grads, epoch):
+        """The updater over every parameter, under the scope
+        ``update/<NN-name>``: layer attribution (monitor/attribution.py)
+        tells the optimizer's device time by it, per layer."""
         new_p, new_s = {}, {}
         for pkey, group in params.items():
             def rec(g, gg, ss, hypers):
@@ -1425,8 +1429,10 @@ class NetTrainer:
                         np_[tag], ns_[tag] = self.updater.apply(
                             p, gg[tag], ss[tag], hypers[tag], epoch)
                 return np_, ns_
-            new_p[pkey], new_s[pkey] = rec(
-                group, grads[pkey], opt_state[pkey], self.hypers[pkey])
+            with jax.named_scope(UPDATE_SCOPE), \
+                    jax.named_scope(scope_safe(pkey)):
+                new_p[pkey], new_s[pkey] = rec(
+                    group, grads[pkey], opt_state[pkey], self.hypers[pkey])
         return new_p, new_s
 
     def _build_train_step(self, with_mask: bool = False):

@@ -3,7 +3,7 @@ sentinels, run-report CLI):
 
 * scope stamping: conn_scope_name contract, named scopes in the
   compiled step HLO, attribution joins against the checked-in fixture
-  (tests/fixtures/minimal.xplane.pb carries display_name scope paths);
+  (tests/fixtures/minimal.xplane.pb carries the step's Hlo Proto);
 * layer_profile end-to-end on a CPU MNIST run with a profiling window —
   rows sum to the traced op total and named layers appear;
 * prof_every recurring windows emit one trace + layer_profile record
@@ -55,7 +55,12 @@ def test_conn_scope_name_contract():
     # 100+-connection nets grow a third index digit; still recoverable
     C.param_key = "100-conv"
     assert conn_scope_name(100, C()) == "100-conv"
-    assert attribution.scopes_from_planes([]) == []  # (shape check)
+    # the updater's scope of a parameter group is sanitized the same way
+    from cxxnet_tpu.layers.base import UPDATE_SCOPE, scope_safe
+    assert scope_safe("00-weird name/|x") == "00-weird_name__x"
+    assert attribution.part_of(
+        f"jit(step)/{UPDATE_SCOPE}/{scope_safe('16-fc6')}/mul") \
+        == ("16-fc6", "update")
 
 
 def test_scope_of_path_innermost_and_wrapped():
@@ -72,7 +77,7 @@ def test_scope_of_path_innermost_and_wrapped():
     assert attribution.scope_of_path("", sre) is None
 
 
-def test_hlo_op_scopes_parses_optimized_text():
+def test_text_instructions_parse_optimized_text():
     hlo = """
 HloModule jit_step, entry_computation_layout={...}
 
@@ -87,81 +92,107 @@ ENTRY %main {
   ROOT %fusion.2 = f32[16,32] fusion(%dot.19), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(step)/01-relu/mul"}
 }
 """
-    m = attribution.hlo_op_scopes(hlo, ["00-fc1", "01-relu"])
-    assert m["dot.19"] == "00-fc1"
-    assert m["fusion.2"] == "01-relu"
-    assert m["mul.3"] == "01-relu"      # fused-computation body included
-    assert m["param.1"] is None         # no metadata -> known, unscoped
+    by_name, by_comp = attribution.text_instructions(hlo)
+    assert by_name["dot.19"].opcode == "dot"
+    assert by_name["fusion.2"].kind == "fusion:kLoop"
+    assert by_name["fusion.2"].calls == ["fused_computation"]
+    assert by_name["fusion.2"].is_root and by_name["mul.3"].is_root
+    assert [i.name for i in by_comp["fused_computation"]] == ["p0", "mul.3"]
+    m = attribution.bookings((by_name, by_comp))
+    assert m["dot.19"].scope == "00-fc1"
+    assert m["fusion.2"].scope == "01-relu"
+    assert m["mul.3"].scope == "01-relu"    # fused-computation body included
+    assert m["param.1"].scope == "none"     # no metadata -> known, unscoped
 
 
 # ------------------------------------------------------- fixture attribution
 
 def test_layer_table_against_fixture():
-    """The checked-in xplane fixture carries display_name scope paths
-    (tools/make_xplane_fixture.py): compute buckets to its two layers,
-    collectives to their own row, and the substring-trap fusion books
-    as the 03-fullc compute its path names — never as comm."""
+    """The checked-in xplane fixture carries the step's executable as
+    the profiler writes it (tools/make_xplane_fixture.py: an ``Hlo
+    Proto`` on ``/host:metadata``): compute buckets to its two layers
+    by the instructions' op_name, collectives to their own row, and the
+    substring-trap fusion books as the 03-fullc compute its path names
+    — never as comm.  SELF times: the all-reduce-start of 0.1 ms lies
+    inside the first fusion.1 on the line and is not counted twice."""
     planes = parse_xspace(FIXTURE)
-    t = attribution.layer_table(planes, ["00-conv", "03-fullc"])
+    t = attribution.layer_table(planes)
+    assert t["source"] == "trace_hlo_proto"
     rows = {r["layer"]: r for r in t["rows"]}
-    assert rows["00-conv"]["device_ms"] == pytest.approx(4.5)
+    assert rows["00-conv"]["device_ms"] == pytest.approx(4.4)
     assert rows["00-conv"]["count"] == 3  # fusion.1 x2 + convolution.3
+    assert rows["00-conv"]["pass"] == {"bwd": pytest.approx(4.4)}
     assert rows["03-fullc"]["device_ms"] == pytest.approx(0.8)
+    assert rows["03-fullc"]["pass"] == {"fwd": pytest.approx(0.8)}
     assert rows["03-fullc"]["comm_ms"] == 0.0  # the trap stays compute
     assert rows[attribution.COMM_ROW]["device_ms"] == pytest.approx(0.8)
     assert rows[attribution.COMM_ROW]["comm_ms"] == pytest.approx(0.8)
-    assert t["ops_total_ms"] == pytest.approx(6.1)
+    assert t["ops_total_ms"] == pytest.approx(6.0)
     assert t["device_total_ms"] == pytest.approx(5.0)  # XLA Modules line
-    assert t["attributed_ms"] == pytest.approx(5.3)
+    assert t["attributed_ms"] == pytest.approx(5.2)
+    # fusion.1 holds 00-conv's weight gradient AND its update
+    assert t["wgrad_update_ms"] == pytest.approx(1.4)
+    assert t["optimizer_ms"] == 0.0
     # rows sum exactly to the counted op total
     assert sum(r["device_ms"] for r in t["rows"]) \
         == pytest.approx(t["ops_total_ms"])
     # per-step division
-    t2 = attribution.layer_table(planes, ["00-conv"], steps=2)
+    t2 = attribution.layer_table(planes, steps=2)
     assert {r["layer"]: r for r in t2["rows"]}["00-conv"]["device_ms"] \
-        == pytest.approx(2.25)
+        == pytest.approx(2.2)
 
 
-def test_layer_table_degraded_join_keeps_unattributed(tmp_path):
-    """Without an op_scopes map (degraded trainer paths, --trace mode)
-    a scope-less op that still carries a framework path lands in
+def test_layer_table_given_map_keeps_unattributed():
+    """With a given instruction map, membership decides what is an op of
+    the profiled program: a scope-less program op lands in
     (unattributed) instead of vanishing — coverage must not read ~1.0
-    when half the program has no scope.  Pathless events (module lines,
-    host bookkeeping) stay excluded either way."""
+    when half the program has no scope — and events the map does not
+    hold (module lines, host bookkeeping, other programs) stay out."""
     from cxxnet_tpu.monitor.trace import XEvent, XLine, XPlane
     MS = 1_000_000_000
     p = XPlane("/device:TPU:0",
-               [XLine("XLA Ops", [XEvent(1, MS), XEvent(2, MS),
-                                  XEvent(3, MS)])],
-               {1: "fusion.1", 2: "fusion.2", 3: "host-loop"},
-               {1: "jit(step)/00-conv/add",
-                2: "jit(step)/jit(main)/loss/sub"})  # path, no scope
-    t = attribution.layer_table([p], ["00-conv"])
+               [XLine("XLA Ops", [XEvent(1, MS), XEvent(2, MS, MS),
+                                  XEvent(3, MS, 2 * MS)])],
+               {1: "fusion.1", 2: "fusion.2", 3: "host-loop"})
+    B = attribution.Booking
+    t = attribution.layer_table([p], ops={
+        "fusion.1": B("00-conv", "fwd", "fusion:kLoop"),
+        "fusion.2": B("none", "fwd", "fusion:kLoop")})
+    assert t["source"] == "given"
     rows = {r["layer"]: r for r in t["rows"]}
     assert rows["00-conv"]["device_ms"] == pytest.approx(1.0)
     assert rows[attribution.OTHER_ROW]["device_ms"] == pytest.approx(1.0)
-    assert "host-loop" not in rows and len(rows) == 2  # pathless: out
+    assert "host-loop" not in rows and len(rows) == 2  # not in the map
     assert t["coverage"] == pytest.approx(0.5)
-    # with an op_scopes oracle, membership decides instead (fusion.2
-    # deliberately absent -> excluded, the pre-oracle behavior)
-    t2 = attribution.layer_table([p], ["00-conv"],
-                                 op_scopes={"fusion.1": "00-conv"})
+    t2 = attribution.layer_table(
+        [p], ops={"fusion.1": B("00-conv", "fwd", "fusion:kLoop")})
     assert t2["coverage"] == pytest.approx(1.0)
     assert t2["ops_total_ms"] == pytest.approx(1.0)
+    # a trace that holds no executable books nothing, and says so
+    t3 = attribution.layer_table([p])
+    assert t3["rows"] == [] and t3["coverage"] == 0.0
 
 
-def test_scopes_recovered_from_trace_metadata():
-    assert attribution.scopes_from_planes(parse_xspace(FIXTURE)) == \
-        ["00-conv", "03-fullc"]
+def test_scopes_recovered_from_the_traces_executable():
+    ops = attribution.step_bookings(parse_xspace(FIXTURE))
+    assert {b.scope for b in ops.values()} == \
+        {"00-conv", "03-fullc", "none"}
+    assert ops["fusion.1"].with_update and not ops["fusion.1"].all_update
+    assert ops["all-reduce-start.1"].comm and ops["reduce-scatter.2"].comm
+    assert not ops["loop-all-reduce-fusion.3"].comm  # the trap
+    assert attribution.step_bookings([]) == {}
 
 
-def test_scopes_from_planes_sees_wrapped_backward_paths():
+def test_part_of_sees_wrapped_backward_paths():
     """A layer visible ONLY inside a transform wrapper (its forward ops
-    fused under a neighbor) is still discovered for --trace mode."""
-    from cxxnet_tpu.monitor.trace import XPlane
-    p = XPlane("/device:TPU:0", [], {1: "fusion.9"},
-               {1: "jit(step)/transpose(jvp(07-norm))/mul"})
-    assert attribution.scopes_from_planes([p]) == ["07-norm"]
+    fused under a neighbor) is still named, with its pass."""
+    assert attribution.part_of("jit(step)/transpose(jvp(07-norm))/mul") \
+        == ("07-norm", "bwd")
+    assert attribution.part_of("jit(step)/jvp(07-norm)/mul") \
+        == ("07-norm", "fwd")
+    assert attribution.part_of(
+        "jit(step)/transpose(jvp())/checkpoint/rematted_computation/"
+        "07-norm/mul") == ("07-norm", "recompute")
 
 
 def test_op_event_name_strips_instruction_text():
@@ -181,16 +212,23 @@ def test_op_event_name_strips_instruction_text():
         assert op_event_name(bare) == bare
 
 
-def test_event_display_parsed():
-    tpu = parse_xspace(FIXTURE)[0]
-    assert tpu.event_display[1] == "jit(step)/jit(main)/00-conv/add.1"
-    assert 4 not in tpu.event_display  # the module event carries none
+def test_hlo_protos_parsed():
+    planes = parse_xspace(FIXTURE)
+    meta = next(p for p in planes if p.name == "/host:metadata")
+    assert list(meta.hlo_protos) == ["jit_step"]
+    by_name, by_comp = attribution.proto_instructions(
+        meta.hlo_protos["jit_step"])
+    assert by_name["fusion.1"].kind == "fusion:kOutput"
+    assert [i.opcode for i in by_comp[by_name["fusion.1"].calls[0]]] \
+        == ["convolution", "multiply"]
+    assert by_name["copy.2"].op_name == "jit(step)/03-fullc/copy"
+    assert planes[0].hlo_protos == {}  # the device plane carries none
 
 
 def test_layer_table_roofline_columns():
     planes = parse_xspace(FIXTURE)
     costs = {"00-conv": {"flops": 1e9, "bytes": 1e6}}
-    t = attribution.layer_table(planes, ["00-conv"], costs=costs,
+    t = attribution.layer_table(planes, costs=costs,
                                 peak_flops=100e12, peak_bw=800e9)
     row = {r["layer"]: r for r in t["rows"]}["00-conv"]
     sec = row["device_ms"] / 1e3
@@ -201,7 +239,7 @@ def test_layer_table_roofline_columns():
     assert row["roofline_x"] == pytest.approx(
         row["device_ms"] / floor_ms, rel=1e-2)
     # unknown chip (CPU): no made-up peaks, no MFU columns
-    t2 = attribution.layer_table(planes, ["00-conv"], costs=costs)
+    t2 = attribution.layer_table(planes, costs=costs)
     row2 = {r["layer"]: r for r in t2["rows"]}["00-conv"]
     assert "mfu_pct" not in row2 and "roofline_ms" not in row2
     assert row2["flops"] == 1e9
@@ -593,9 +631,11 @@ def test_step_hlo_text_carries_scopes():
     assert txt is not None
     scopes = t.layer_scopes()
     assert scopes == ["00-fc1", "01-relu", "02-fc2", "03-softmax"]
-    op_scopes = attribution.hlo_op_scopes(txt, scopes)
-    hit = {s for s in op_scopes.values() if s}
+    ops = attribution.bookings(attribution.text_instructions(txt))
+    hit = {b.scope for b in ops.values()}
     assert "00-fc1" in hit and "02-fc2" in hit
+    assert hit - {"none"} <= set(scopes)
+    assert {"fwd", "bwd", "update"} <= {b.pass_ for b in ops.values()}
     # cached: the second call is the same object (one AOT compile total)
     assert t.step_hlo_text() is txt
 

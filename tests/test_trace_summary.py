@@ -27,7 +27,8 @@ FIXTURE = os.path.join(REPO, "tests", "fixtures", "minimal.xplane.pb")
 
 def test_parse_planes_and_metadata():
     planes = parse_xspace(FIXTURE)
-    assert [p.name for p in planes] == ["/device:TPU:0", "/host:CPU"]
+    assert [p.name for p in planes] == ["/device:TPU:0", "/host:CPU",
+                                        "/host:metadata"]
     tpu = planes[0]
     assert [l.name for l in tpu.lines] == ["XLA Modules", "XLA Ops"]
     assert tpu.event_names == {
@@ -255,16 +256,35 @@ def test_async_line_is_never_added_to_the_ops_line(recorded):
 
 
 def test_layer_table_walks_one_chips_ops_line(recorded):
+    """A map keyed by bare instruction names books the chip's whole-line
+    event names, SELF time: the scanned dispatch's ``while`` spans its
+    body's operations and is counted once, for what is its own."""
+    from benchmark.lib.xplane import self_times
     from cxxnet_tpu.monitor import attribution
     from cxxnet_tpu.monitor.trace import total_ms_in
     planes, trace = recorded
     ops = trace.devices[0].lines["XLA Ops"]
     tpu0 = next(p for p in planes if p.name == "/device:TPU:0")
     # every op of chip 0 is an op of the program, under no known scope
-    op_scopes = {name: None for name in tpu0.event_names.values()}
-    table = attribution.layer_table(planes, [], op_scopes)
-    assert table["ops_total_ms"] == pytest.approx(
-        sum(e.dur for e in ops) / 1e6, rel=0.01)
+    given = {name: attribution.Booking("none", "fwd", "")
+             for name in tpu0.event_names.values()}
+    table = attribution.layer_table(planes, ops=given)
+    self_ms = sum(ns for _, ns, _ in self_times(ops)) / 1e6
+    assert table["ops_total_ms"] == pytest.approx(self_ms, rel=0.01)
+    whiles = [e for e in ops if e.name.startswith("%while")]
+    assert whiles and self_ms < 0.8 * sum(e.dur for e in ops) / 1e6
     assert sum(r["count"] for r in table["rows"]) == len(ops)
     assert table["device_total_ms"] == pytest.approx(total_ms_in(planes),
                                                      rel=1e-6)
+    assert table["coverage"] == 0.0  # nothing is named by this map
+    # a map that names the loop's body books the whole-line event names
+    body = {name: attribution.Booking("03-conv", "fwd", "")
+            for name in tpu0.event_names.values()
+            if name.startswith("fusion")}
+    named = attribution.layer_table(planes, ops=body)
+    rows = {r["layer"]: r for r in named["rows"]}
+    # what is not the named fusions' is the data-parallel all-reduces',
+    # which count by their base opcode whatever the map holds
+    assert set(rows) == {"03-conv", attribution.COMM_ROW}
+    assert named["coverage"] > 0.9
+    assert 0 < named["ops_total_ms"] < table["ops_total_ms"]

@@ -28,9 +28,10 @@ append-mode sink, including the previous session's ledger) are
 catch-up context, never terminal.
 
 ``--trace`` re-runs layer attribution directly on a profiler trace via
-the scope paths embedded in its op metadata (TPU traces; CPU-runtime
-traces carry none — there the in-run ``layer_profile`` record, which
-joins through the compiled HLO, is the authoritative table).
+the executable it holds (the ``Hlo Proto`` of the step's module on its
+``/host:metadata`` plane — TPU and CPU-runtime traces alike); the
+in-run ``layer_profile`` record, which also knows the window's dispatch
+count and the cost model, stays the authoritative table.
 """
 # disclint: ok-file(print) — standalone CLI; stdout is the product surface
 
@@ -144,6 +145,8 @@ def build_report(recs: List[dict], top: int = 10) -> dict:
             "device_total_ms": lp.get("device_total_ms"),
             "attributed_ms": lp.get("attributed_ms"),
             "coverage": lp.get("coverage"),
+            "optimizer_ms": lp.get("optimizer_ms"),
+            "wgrad_update_ms": lp.get("wgrad_update_ms"),
             "rows": (lp.get("rows") or [])[:top],
             "dropped_rows": max(len(lp.get("rows") or []) - top, 0),
         }
@@ -393,15 +396,22 @@ def render(rep: dict) -> str:
             f"{_fmt(lp.get('attributed_ms'))} of "
             f"{_fmt(lp.get('device_total_ms'))} ms/step attributed "
             f"(coverage {_fmt(lp.get('coverage'))})")
-        rows = [[r.get("layer", "?"), _fmt(r.get("device_ms")),
-                 _fmt(r.get("share")), _fmt(r.get("comm_ms")),
-                 _fmt(r.get("mfu_pct"), 1), _fmt(r.get("roofline_ms")),
-                 _fmt(r.get("roofline_x"), 1)]
+        passes = ("fwd", "recompute", "bwd", "update")
+        rows = [[r.get("layer", "?"), _fmt(r.get("device_ms"))]
+                + [_fmt((r.get("pass") or {}).get(p)) for p in passes]
+                + [_fmt(r.get("share")), _fmt(r.get("comm_ms")),
+                   _fmt(r.get("mfu_pct"), 1), _fmt(r.get("roofline_ms")),
+                   _fmt(r.get("roofline_x"), 1)]
                 for r in lp.get("rows") or []]
         if rows:
             out.append(_table(
-                ["layer", "ms/step", "share", "comm_ms", "mfu%",
+                ["layer", "ms/step", *passes, "share", "comm_ms", "mfu%",
                  "roofline_ms", "x_roof"], rows))
+        if lp.get("optimizer_ms") or lp.get("wgrad_update_ms"):
+            out.append(
+                f"updater alone {_fmt(lp.get('optimizer_ms'))} ms/step; "
+                f"fused into weight gradients "
+                f"{_fmt(lp.get('wgrad_update_ms'))} ms/step")
         if lp.get("dropped_rows"):
             out.append(f"... {lp['dropped_rows']} more rows "
                        "(--top to widen)")
@@ -621,16 +631,17 @@ def render(rep: dict) -> str:
 
 
 def trace_report(path: str, top: int) -> dict:
-    """Standalone re-attribution of a trace by its embedded scope paths
-    (no trainer, no HLO join — see module docstring)."""
+    """Standalone re-attribution of a trace by the executable it holds
+    (its ``Hlo Proto``; no trainer — see module docstring)."""
     from cxxnet_tpu.monitor import attribution
     from cxxnet_tpu.monitor.trace import (comm_report_in, find_xplane,
                                           parse_xspace)
     xplane = find_xplane(path)
     planes = parse_xspace(xplane)
-    scopes = attribution.scopes_from_planes(planes)
-    table = attribution.layer_table(planes, scopes)
+    ops = attribution.step_bookings(planes)
+    table = attribution.layer_table(planes, ops=ops)
     table["rows"] = table["rows"][:top]
+    scopes = {b.scope for b in ops.values()} - {attribution.NONE}
     return {"trace": xplane, "scopes_found": len(scopes),
             "comm": comm_report_in(planes), "layers": table}
 
@@ -957,7 +968,7 @@ def main(argv=None) -> int:
             print(_table(["layer", "ms/window", "share", "comm_ms"],
                          rows))
         else:
-            print("  (no scope metadata in this trace — use the run's "
+            print("  (no Hlo Proto in this trace — use the run's "
                   "layer_profile record instead)")
     return 0
 
