@@ -641,6 +641,9 @@ class SoftmaxSeqLayer(LossLayerBase):
     def __init__(self):
         super().__init__()
         self.packed = 0
+        # (b, s, V, the logits' dtype, bytes kept, float32 bytes avoided) of
+        # the last training trace: a note of the trace (NetTrainer.loss_sites)
+        self.loss_site = None
 
     def set_param(self, name, val):
         if name == "packed":
@@ -653,19 +656,33 @@ class SoftmaxSeqLayer(LossLayerBase):
         x = inputs[0]  # (b, 1, s, V)
         out = jax.nn.softmax(x, axis=-1)
         if ctx.labels is not None and ctx.train:
+            # imported here: only a process that trains a language model
+            # loads the module
+            from ..ops.xent import token_xent
             y = ctx.labels.get(self.target)  # (b, s) float ids
-            logp = jax.nn.log_softmax(x[:, 0].astype(jnp.float32), axis=-1)
             yi = y.astype(jnp.int32)
+            b, _, s, v = x.shape
+            self.loss_site = (b, s, v, x.dtype.name,
+                              b * s * (v * x.dtype.itemsize + 4), 4 * b * s * v)
+            logits = x[:, 0]
+            # an id < 0 counts from the end, as indexing does (packseq marks
+            # document boundaries with -1 whatever ``packed`` says)
+            ids = jnp.maximum(yi, 0) if self.packed \
+                else jnp.where(yi < 0, yi + v, yi)
+            if _seq_mesh(ctx) is None:
+                # a row a position, as the head's products are to XLA
+                # ([b s, d] x [d, V]): handed (b, s, V) it lays the logits of
+                # V = 50257 out s-minor and relayouts their cotangent for the
+                # head's backward (4.9 ms a step in gpt13_s2048_docmask).  Not
+                # where s is sharded: b and s do not merge there.
+                logits, ids = logits.reshape(b * s, v), ids.reshape(b * s)
+            nats = token_xent(logits, ids).reshape(yi.shape)
             if self.packed:
                 valid = (y >= 0).astype(jnp.float32)
-                tok = jnp.take_along_axis(
-                    logp, jnp.maximum(yi, 0)[:, :, None], axis=2)[:, :, 0]
-                per_inst = -(tok * valid).sum(axis=1) \
+                per_inst = (nats * valid).sum(axis=1) \
                     / jnp.maximum(valid.sum(axis=1), 1.0)
             else:
-                tok = jnp.take_along_axis(
-                    logp, yi[:, :, None], axis=2)[:, :, 0]
-                per_inst = -tok.mean(axis=1)  # mean per-token nats
+                per_inst = nats.mean(axis=1)  # mean per-token nats
             if ctx.labels.mask is not None:
                 # tail-batch replica padding is masked out, same contract
                 # as LossLayerBase (DataBatch.tail_mask_padd)

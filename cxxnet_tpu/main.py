@@ -1120,15 +1120,15 @@ class LearnTask:
         mlog.info(f"\nupdating end, {int(time.time() - start)} sec in all")
 
     def _note_compile(self, seconds: float) -> None:
-        """The first dispatch traced and compiled inside its call (the
-        loop's ``compile`` phase): one ``compile`` record."""
+        """One ``compile`` record: the first dispatch's trace and compile."""
         self.compile_sec = seconds
         self.net.metrics.emit("compile", compile_sec=round(seconds, 3),
                               round=self.start_counter - 1,
                               pallas_sites=self.net.pallas_sites(),
                               loop_saved=self.net.loop_saved(),
                               ssm_sites=self.net.ssm_sites(),
-                              moe_sites=self.net.moe_sites())
+                              moe_sites=self.net.moe_sites(),
+                              loss_sites=self.net.loss_sites())
         mlog.info(f"compile: {seconds:.1f} sec (first dispatch, excluded "
                   "from examples/sec)")
 

@@ -1971,6 +1971,21 @@ class NetTrainer:
                 for c in self.net.connections
                 if getattr(c.layer, "moe_site", None) and c.owns_params]
 
+    def loss_sites(self) -> List[dict]:
+        """The loss layers whose training forward took ``ops/xent.token_xent``
+        in the traces so far, in net order: the layer's name with the logits'
+        ``b``, ``s``, ``V`` and ``dtype``, the ``residual_bytes`` its backward
+        pass keeps (the logits as they arrive and a float32 ``logsumexp`` a
+        position) and ``f32_logits_bytes_avoided``, the float32 ``[b, s, V]``
+        array a differentiated ``log_softmax`` would keep.  ``[]`` for a net
+        without such a layer."""
+        fields = ("b", "s", "V", "dtype", "residual_bytes",
+                  "f32_logits_bytes_avoided")
+        return [dict(zip(fields, c.layer.loss_site),
+                     layer=c.param_key.split("-", 1)[1])
+                for c in self.net.connections
+                if getattr(c.layer, "loss_site", None) and c.owns_params]
+
     def loop_saved(self) -> Dict[str, dict]:
         """Per ``loop[a->b]`` of the net (``"a->b"``), what a pass of the
         last training trace keeps for the backward pass beside its carry:

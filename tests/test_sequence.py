@@ -238,3 +238,212 @@ def test_ring_attention_chunked_local_blocks():
     finally:
         ring._chunk_for = old
         ring.CHUNKED_ATTN_THRESHOLD = old_thresh
+
+
+# ---------------------------- softmax_seq's cross-entropy: ops/xent.token_xent
+def log_softmax_xent(logits, target):
+    """``softmax_seq``'s lines before PR 40, differentiated by JAX: the
+    reference ``token_xent`` is held to."""
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    return -jnp.take_along_axis(logp, target[..., None], axis=-1)[..., 0]
+
+
+def xent_inputs(dtype, v, b=2, s=12, seed=0):
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    logits = (3 * jax.random.normal(k1, (b, s, v))).astype(dtype)
+    return logits, jax.random.randint(k2, (b, s), 0, v), \
+        jax.random.normal(k3, (b, s))
+
+
+def value_and_cotangent(loss, how):
+    """``(logits, target, g) -> (nats, cotangent of the logits)`` run the way
+    the trainer runs a loss: ``plain``, under ``jit``, inside a ``lax.scan``
+    of two steps (``update_many``), or under ``jax.checkpoint`` (``remat``)."""
+    def once(logits, target, g):
+        f = jax.checkpoint(loss) if how == "checkpoint" else loss
+        nats, pullback = jax.vjp(lambda x: f(x, target), logits)
+        return nats, pullback(g)[0]
+    if how == "scan":
+        def twice(logits, target, g):
+            def body(carry, scale):
+                return carry, once(logits, target, g * scale)
+            _, (nats, d) = jax.lax.scan(body, 0, jnp.array([1.0, 1.0]))
+            return nats[1], d[1]
+        return jax.jit(twice)
+    return once if how == "plain" else jax.jit(once)
+
+
+@pytest.mark.parametrize("how", ["plain", "jit", "scan", "checkpoint"])
+@pytest.mark.parametrize("v", [257, 256])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_token_xent_is_log_softmax_differentiated(dtype, v, how):
+    from cxxnet_tpu.ops.xent import token_xent
+    logits, target, g = xent_inputs(dtype, v)
+    nats, d = value_and_cotangent(token_xent, how)(logits, target, g)
+    want, want_d = value_and_cotangent(log_softmax_xent, "jit")(
+        logits, target, g)
+    assert nats.dtype == jnp.float32 and d.dtype == dtype
+    np.testing.assert_allclose(nats, want, rtol=1e-6, atol=1e-6)
+    d, want_d = (np.asarray(a, np.float32) for a in (d, want_d))
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(d, want_d, rtol=1e-6, atol=1e-6)
+    else:  # both round a float32 cotangent to bfloat16 once: a step apart
+        assert (np.abs(d - want_d) <= 2.0 ** -7 * np.abs(want_d)).all()
+
+
+def test_token_xent_keeps_no_float32_logits_and_scatters_nothing():
+    """With bfloat16 logits the backward pass is handed the logits as they
+    came and one float32 number a position, and builds the cotangent without
+    a scatter (the differentiated ``log_softmax`` keeps float32 ``[b, s, V]``
+    and scatter-adds the picked positions into another)."""
+    from cxxnet_tpu.ops.xent import token_xent
+    logits, target, g = xent_inputs(jnp.bfloat16, 257)
+
+    def kept_and_backward(loss):
+        _, pullback = jax.vjp(lambda x: loss(x, target), logits)
+        kept = [(r.dtype.name, r.shape) for r in jax.tree.leaves(pullback)
+                if hasattr(r, "shape")]
+        return kept, str(jax.make_jaxpr(pullback)(g))
+
+    kept, backward = kept_and_backward(token_xent)
+    assert sorted(kept) == [("bfloat16", (2, 12, 257)), ("float32", (2, 12)),
+                            ("int32", (2, 12))]
+    assert "scatter" not in backward and "f32[2,12,257]" in backward
+    kept, backward = kept_and_backward(log_softmax_xent)  # the test can see
+    assert ("float32", (2, 12, 257)) in kept and "scatter-add" in backward
+
+
+def softmax_seq_before(x, y, packed, mask, scale):
+    """``SoftmaxSeqLayer.forward``'s loss term before PR 40."""
+    logp = jax.nn.log_softmax(x[:, 0].astype(jnp.float32), axis=-1)
+    yi = y.astype(jnp.int32)
+    if packed:
+        valid = (y >= 0).astype(jnp.float32)
+        tok = jnp.take_along_axis(
+            logp, jnp.maximum(yi, 0)[:, :, None], axis=2)[:, :, 0]
+        per_inst = -(tok * valid).sum(axis=1) \
+            / jnp.maximum(valid.sum(axis=1), 1.0)
+    else:
+        tok = jnp.take_along_axis(logp, yi[:, :, None], axis=2)[:, :, 0]
+        per_inst = -tok.mean(axis=1)
+    if mask is not None:
+        per_inst = per_inst * mask
+    return per_inst.sum() * scale
+
+
+SOFTMAX_SEQ_CASES = {
+    # name: (packed, targets set to -1 as (row, positions), mask, scale)
+    "plain": (0, None, None, 1.0),
+    "plain_boundary_ids": (0, (1, [0, 5]), None, 1.0),
+    "packed_masked_targets": (1, (0, [2, 3, 11]), None, 1.0),
+    "packed_row_all_masked": (1, (2, list(range(12))), None, 1.0),
+    "tail_batch_mask": (1, (0, [4]), [1.0, 1.0, 0.0], 1.0),
+    "loss_scale": (1, (1, [7]), None, 0.25 / 3),
+}
+
+
+@pytest.mark.parametrize("case", list(SOFTMAX_SEQ_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_softmax_seq_loss_and_gradient_are_the_differentiated_ones(dtype,
+                                                                   case):
+    from cxxnet_tpu.layers.base import LabelInfo
+    packed, masked, mask, scale = SOFTMAX_SEQ_CASES[case]
+    logits, target, _ = xent_inputs(dtype, 257, b=3)
+    x = logits[:, None]
+    y = np.asarray(target, np.float32)
+    if masked:
+        y[masked[0], masked[1]] = -1
+    y = jnp.asarray(y)
+    mask = None if mask is None else jnp.asarray(mask)
+    layer = create_layer("softmax_seq")
+    layer.set_param("packed", str(packed))
+    layer.set_param("grad_scale", "2")
+
+    def loss(x):
+        ctx = ForwardContext(train=True, loss_scale=scale, labels=LabelInfo(
+            fields={"label": y}, mask=mask))
+        layer.forward({}, {}, [x], ctx)
+        return ctx.losses[0]
+
+    got, d = jax.jit(jax.value_and_grad(loss))(x)
+    want, want_d = jax.jit(jax.value_and_grad(
+        lambda x: softmax_seq_before(x, y, packed, mask, 2 * scale)))(x)
+    assert layer.loss_site[:4] == (3, 12, 257, jnp.dtype(dtype).name)
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    d, want_d = (np.asarray(a, np.float32) for a in (d, want_d))
+    tol = 1e-6 if dtype == jnp.float32 else 2.0 ** -7
+    assert (np.abs(d - want_d) <= tol * np.abs(want_d) + 1e-9).all()
+    if packed and masked:  # exact zeros, not small numbers
+        assert not d[masked[0], 0, masked[1]].any()
+    if mask is not None:
+        assert not d[2].any()
+
+
+def test_softmax_seq_keeps_b_and_s_apart_where_the_sequence_is_sharded():
+    """On a mesh with a ``seq`` axis the loss is handed (b, s, V) logits (b
+    and s sharded do not merge into rows); elsewhere a row a position."""
+    import cxxnet_tpu.ops.xent as xent
+    from cxxnet_tpu.layers.base import LabelInfo
+    logits, target, _ = xent_inputs(jnp.float32, 64, b=2, s=8)
+    layer = create_layer("softmax_seq")
+    seen = []
+    devs = np.array(jax.devices()[:8])
+
+    def spy(x, t, inner=xent.token_xent):
+        seen.append(x.shape)
+        return inner(x, t)
+
+    for mesh in (None, Mesh(devs.reshape(2, 4), ("data", "seq")),
+                 Mesh(devs[:2], ("data",))):
+        ctx = ForwardContext(train=True, mesh=mesh, labels=LabelInfo(
+            fields={"label": target.astype(jnp.float32)}))
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(xent, "token_xent", spy)
+            layer.forward({}, {}, [logits[:, None]], ctx)
+    assert seen == [(16, 64), (2, 8, 64), (16, 64)]
+    np.testing.assert_allclose(ctx.losses[0],
+                               log_softmax_xent(logits, target).mean(1).sum(),
+                               rtol=1e-6)
+
+
+def test_compile_record_names_the_loss_that_took_token_xent(tmp_path):
+    """``loss_sites`` on the ``compile`` record of a toy transformer run
+    through ``LearnTask.run``: the layer, the logits' shape and dtype and the
+    bytes kept and avoided; ``[]`` for the AlexNet example's net."""
+    import json
+    from benchmark.lib import corpus
+    from cxxnet_tpu.main import LearnTask
+    from cxxnet_tpu.models import alexnet, transformer
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    from cxxnet_tpu.utils.config import parse_config_string
+    vocab, s, b = 61, 16, 4
+    prefix = str(tmp_path / "train_%d.tok")
+    corpus.make(0, vocab, dict(law="zipf_markov", docs=40, mean_len=8,
+                               max_len=s, shards=2), prefix)
+    conf, sink = str(tmp_path / "net.conf"), str(tmp_path / "run.jsonl")
+    with open(conf, "w") as f:
+        f.write(f"data = train\niter = text\n  path_tok = {prefix}\n"
+                f"  tok_count = 2\niter = packseq\n  seqlen = {s}\n"
+                "iter = end\n"
+                + transformer(vocab=vocab, seq=s, dim=16, nlayer=1, nhead=2,
+                              packed=True)
+                + f"\nbatch_size = {b}\ndev = cpu\nupdater = adam\n"
+                "eta = 0.001\nnum_round = 1\nmax_round = 1\ndtype = bfloat16\n"
+                "save_model = 0\neval_train = 0\nsilent = 1\n")
+    task = LearnTask()
+    assert task.net is None or task.net.loss_sites() == []
+    assert task.run([conf, f"metrics_sink=jsonl:{sink}"]) == 0
+    with open(sink) as f:
+        rec, = [r for r in map(json.loads, f) if r["kind"] == "compile"]
+    site, = rec["loss_sites"]
+    assert site == task.net.loss_sites()[0] == dict(
+        layer=site["layer"], b=b, s=s, V=vocab, dtype="bfloat16",
+        residual_bytes=b * s * (2 * vocab + 4),
+        f32_logits_bytes_avoided=4 * b * s * vocab)
+    assert task.net.net.connections[-1].layer.type_names[0] == "softmax_seq"
+    convnet = NetTrainer()
+    for k, v in list(parse_config_string(alexnet(num_class=10))) + [
+            ("batch_size", "2"), ("dev", "cpu"), ("silent", "1")]:
+        convnet.set_param(k, v)
+    convnet.init_model()
+    assert convnet.loss_sites() == []
