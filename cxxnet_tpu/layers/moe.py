@@ -571,6 +571,15 @@ def _expert_ffn(m, first, held, x, sel, weights, w13, w2):
     return out, sizes, covered, rows
 
 
+def shared_ffn(x, w13, w2):
+    """The shared expert on every token ``x`` ``(t, d)``: ``(silu(x W_1) *
+    (x W_3)) W_2`` with ``w13`` ``(d, 2 f)`` = ``[W_1, W_3]`` and ``w2`` ``(f,
+    d)``, the products in ``x``'s dtype, the gate in float32."""
+    gate, up = jnp.split(x @ w13.astype(x.dtype), 2, axis=-1)
+    act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    return act.astype(x.dtype) @ w2.astype(x.dtype)
+
+
 # a net's layers of one shape are traced once a step, not once each: the
 # Python of five layers' hand-written passes was 1.1 s of a warm start on the
 # chip's host (my chip runs, PR 38)
@@ -595,7 +604,11 @@ class TopKExpertLayer(Layer):
 
     Parameters: ``router`` ``(num_expert, d)``, ``w13`` ``(held d, 2
     nhidden)`` (expert ``e``'s gate and up matrices side by side in rows ``e
-    d .. (e + 1) d``) and ``w2`` ``(held nhidden, d)``.  Buffer ``bias``
+    d .. (e + 1) d``) and ``w2`` ``(held nhidden, d)``; with ``shared_nhidden
+    = fs > 0`` a shared gated-SiLU expert on every token (:func:`shared_ffn`,
+    ``shared_w13`` ``(d, 2 fs)``, ``shared_w2`` ``(fs, d)``, every rank of the
+    group holds it whole), added to the routed sum unscaled, after
+    ``routed_scale``.  Buffer ``bias``
     ``(num_expert,)`` with ``expert_bias = 1``: added to the scores for the
     selection only and never trained by the gradient; it starts at zero and
     with ``expert_bias_rate = u`` every training step moves it toward an
@@ -636,6 +649,9 @@ class TopKExpertLayer(Layer):
         K("norm_topk", "int", lo=0, hi=1,
           help="weights renormalised over the selected experts"),
         K("routed_scale", "float", help="multiplier of the weights"),
+        K("shared_nhidden", "int", lo=0,
+          help="width of a shared gated-SiLU expert on every token, added "
+               "unscaled; 0 = none"),
     )
 
     def __init__(self):
@@ -649,12 +665,13 @@ class TopKExpertLayer(Layer):
         self.expert_bias_rate = 0.0
         self.norm_topk = 1
         self.routed_scale = 1.0
+        self.shared_nhidden = 0
         # a note of the last training trace (NetTrainer.moe_sites)
         self.moe_site = None
 
     def set_param(self, name, val):
         if name in ("num_expert", "expert_held", "expert_first", "top_k",
-                    "expert_bias", "norm_topk"):
+                    "expert_bias", "norm_topk", "shared_nhidden"):
             setattr(self, name, int(val))
         elif name in ("expert_bias_rate", "routed_scale"):
             setattr(self, name, float(val))
@@ -686,12 +703,20 @@ class TopKExpertLayer(Layer):
         d, f, e = in_shapes[0][3], self.param.num_hidden, self.num_expert
         kr, k1, k2 = jax.random.split(key, 3)
         p = self.param
-        return {
+        params = {
             "router": p.rand_init_weight(kr, (e, d), d, e, dtype),
             "w13": p.rand_init_weight(k1, (self.held * d, 2 * f), d, 2 * f,
                                       dtype),
             "w2": p.rand_init_weight(k2, (self.held * f, d), f, d, dtype),
         }
+        fs = self.shared_nhidden
+        if fs:
+            k3, k4 = jax.random.split(jax.random.fold_in(key, 3))
+            params["shared_w13"] = p.rand_init_weight(k3, (d, 2 * fs), d,
+                                                      2 * fs, dtype)
+            params["shared_w2"] = p.rand_init_weight(k4, (fs, d), fs, d,
+                                                     dtype)
+        return params
 
     def init_buffers(self, in_shapes: List[Shape4]):
         if not self.expert_bias:
@@ -714,11 +739,16 @@ class TopKExpertLayer(Layer):
             x, sel, weights, params["w13"].reshape(held, d, 2 * f),
             params["w2"].reshape(held, f, d), first=self.expert_first,
             held=held, experts=self.num_expert)
+        if self.shared_nhidden:
+            with jax.named_scope("shared"):
+                out = out + shared_ffn(x, params["shared_w13"],
+                                       params["shared_w2"])
         if ctx.train:
             self.moe_site = (self.num_expert, held, self.expert_first,
                              self.top_k, f, self.score_func, GMM_LOWERING,
                              window_rows(b * s, self.top_k, held,
-                                         self.num_expert))
+                                         self.num_expert),
+                             self.shared_nhidden)
             in_range = (sel >= self.expert_first) \
                 & (sel < self.expert_first + held)
             pairs = sizes.sum()

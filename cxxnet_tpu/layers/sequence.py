@@ -19,6 +19,7 @@ gathers the full sequence on one device.
 
 from __future__ import annotations
 
+import math
 import warnings
 from typing import List
 
@@ -49,41 +50,90 @@ def _label_field(ctx: ForwardContext, name: str):
     return ctx.labels.fields[name]
 
 
+def attention_lowering(s: int, hd: int, causal: bool, segmented: bool,
+                       window: int = 0) -> str:
+    """What :func:`_single_device_attention` computes attention with at
+    these shapes, by name: ``flash_band`` (the flash kernels on the band's
+    grid, ``window > 0``), ``flash_tri`` (the causal triangle's), ``flash``
+    (the full grid) or ``dense`` (XLA, direct or in chunks of keys)."""
+    from ..engine import on_tpu, opts
+    from ..ops import pallas_kernels as pk
+    if (on_tpu() and pk.flash_attention_available(s, hd)
+            and opts.flash_attn == "1" and (not segmented or causal)):
+        return "flash_band" if window else "flash_tri" if causal else "flash"
+    return "dense"
+
+
 def _single_device_attention(q, k, v, causal: bool, seg=None, on_flash=None,
-                             scale=None):
+                             scale=None, window: int = 0):
     """Single-device attention dispatch: the Pallas flash kernel on TPU
     (VMEM-resident scores; measured 3.2x the XLA chunked path forward at
     s=8192 on v5e, and the only path whose backward fits at that length),
-    XLA dense/chunked otherwise.  Config key ``flash_attn = 0`` (or env
-    CXXNET_NO_FLASH_ATTN=1) opts out.  ``seg`` (b, s) segment ids select
-    the segment-masked variants (packed documents): the triangular-flash
-    segment kernel where the grid allows, the lax fallback elsewhere —
-    the two are pairtested in interpret mode (tests/test_text.py).
-    ``on_flash`` is called where a flash kernel is taken, with what the
-    kernel names for a loop's save set (``pk.flash_saved``).  ``scale``
-    multiplies the scores (None: ``1/sqrt(hd)``)."""
-    from ..engine import on_tpu, opts
+    XLA dense/chunked otherwise (:func:`attention_lowering`).  Config key
+    ``flash_attn = 0`` (or env CXXNET_NO_FLASH_ATTN=1) opts out.  ``seg``
+    (b, s) segment ids select the segment-masked variants (packed
+    documents): the triangular-flash segment kernel where the grid allows,
+    the lax fallback elsewhere — the two are pairtested in interpret mode
+    (tests/test_text.py).  ``on_flash`` is called where a flash kernel is
+    taken, with what the kernel names for a loop's save set
+    (``pk.flash_saved``).  ``scale`` multiplies the scores (None:
+    ``1/sqrt(hd)``); ``window > 0`` (causal) keeps a query to the keys less
+    than ``window`` positions back, on the band's grid in the kernels."""
     from ..ops import pallas_kernels as pk
     s, hd = q.shape[2], q.shape[3]
-    if (on_tpu() and pk.flash_attention_available(s, hd)
-            and opts.flash_attn == "1" and (seg is None or causal)):
+    if attention_lowering(s, hd, causal, seg is not None, window) != "dense":
         if on_flash is not None:
             on_flash(pk.flash_saved(q))
         if seg is not None:
-            return pk.flash_attention_segmented(q, k, v, seg, scale)
-        return pk.flash_attention(q, k, v, causal, scale)
-    return ring.dense_attention(q, k, v, causal=causal, scale=scale, seg=seg)
+            return pk.flash_attention_segmented(q, k, v, seg, scale,
+                                                window=window)
+        return pk.flash_attention(q, k, v, causal, scale, window=window)
+    return ring.dense_attention(q, k, v, causal=causal, scale=scale, seg=seg,
+                                window=window)
 
 
-def _rotary(q, k, pos, theta: float):
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's rotary frequencies over ``dim`` channels (Peng et al. 2023,
+    arXiv:2309.00071; HF ``_compute_yarn_parameters``): the extrapolated
+    ``theta^(-2i/dim)`` and the interpolated ``/ factor`` blended by a
+    linear ramp between the correction dimensions of ``beta_fast`` and
+    ``beta_slow`` rotations over ``original`` positions, floored and
+    ceiled.  float32 ``(dim / 2,)``."""
+
+    def correction(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / ((high - low) or 0.001), 0.0, 1.0)
+    extra = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def _rotary(q, k, pos, theta: float, dim: int = 0, inv_freq=None,
+            mult: float = 1.0):
     """Rotary position embedding in the rotate-half form on ``q`` and ``k``
     ``(b, h, s, hd)`` at positions ``pos`` ``(b or 1, s)``: the pair
     ``(x[i], x[i + hd/2])`` turns by ``pos * theta^(-2i/hd)``.  Angles and
-    the rotation are float32; the result has the inputs' dtype."""
+    the rotation are float32; the result has the inputs' dtype.  ``dim``
+    (0: all ``hd``) turns the first ``dim`` channels alone, rotate-half
+    within them, and passes the rest; ``inv_freq`` ``(dim / 2,)`` replaces
+    the frequencies (YaRN's) and ``mult`` multiplies cos and sin."""
+    if dim and dim < q.shape[-1]:
+        turned = _rotary(q[..., :dim], k[..., :dim], pos, theta, 0,
+                         inv_freq, mult)
+        return tuple(jnp.concatenate([t, x[..., dim:]], axis=-1)
+                     for t, x in zip(turned, (q, k)))
     hd = q.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
     angle = pos.astype(jnp.float32)[:, None, :, None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)  # (b or 1, 1, s, hd/2)
+    if mult != 1.0:
+        cos, sin = cos * mult, sin * mult
 
     def turn(x):
         x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -377,17 +427,29 @@ class AttentionLayer(Layer):
     key/value head (query head ``i`` reads head ``i // group``) and "wqkv"
     is ``((nhead + 2 nkvhead) hd, d)``: q's rows, then k's, then v's.  K and
     V are repeated to ``nhead`` heads in front of the kernels and the decode
-    cache, which know one head count.  ``score_scale`` multiplies the scores
-    in every path (0, the default: ``1/sqrt(hd)``).  ``qk_norm = 1``: each
-    head's q and k pass an RMSNorm over the head's channels, with one gain
-    of ``hd`` for q and one for k shared by the heads ("q_norm", "k_norm"),
-    AHEAD of the rotary turn.
+    cache, which know one head count.  ``head_dim`` sets ``hd`` (0, the
+    default: ``d / nhead``); "wout" is then ``(d, nhead hd)``.  ``score_scale``
+    multiplies the scores in every path (0, the default: ``1/sqrt(hd)``).
+    ``qk_norm = 1``: each head's q and k pass an RMSNorm over the head's
+    channels, with one gain of ``hd`` for q and one for k shared by the
+    heads ("q_norm", "k_norm"), AHEAD of the rotary turn.  ``rope_dim``
+    turns the first ``rope_dim`` channels of a head alone (0: all);
+    ``rope_yarn_factor > 0`` takes YaRN's frequencies over them
+    (:func:`yarn_inv_freq`, with ``rope_yarn_orig``, ``rope_yarn_beta_fast``
+    and ``rope_yarn_beta_slow``) and multiplies their cos and sin by
+    ``rope_yarn_attn_factor``; each of the four is then required.  ``window > 0``
+    (causal): a query sees the keys less than ``window`` positions back
+    alone (sliding-window attention), in every single-device path.
+    ``gate = 1``: head ``h``'s output is multiplied by ``sigmoid(x
+    wgate[h])`` of the layer's input ``x`` ahead of "wout" ("wgate" ``(nhead,
+    d)``).
 
     When the trainer mesh has a ``seq`` axis the score computation runs as
     ring attention (K/V rotating over ICI, online softmax — see
     ``parallel/ring.py``); otherwise dense attention.  Head count must
-    divide d; when a ``model`` axis exists and divides nhead, heads are
-    additionally sharded over it inside the ring (Ulysses-style hybrid).
+    divide d unless ``head_dim`` is set; when a ``model`` axis exists and
+    divides nhead, heads are additionally sharded over it inside the ring
+    (Ulysses-style hybrid).
     """
 
     type_names = ("attention",)
@@ -413,6 +475,27 @@ class AttentionLayer(Layer):
           help="RMSNorm of each head's q and k (a gain of the head size "
                "each, shared by the heads) ahead of rope"),
         K("qk_norm_eps", "float", lo=0.0),
+        K("head_dim", "int", lo=0,
+          help="channels a head; 0 = dim / nhead"),
+        K("window", "int", lo=0,
+          help="sliding window: a query sees the keys less than this many "
+               "positions back (causal only); 0 = all"),
+        K("rope_dim", "int", lo=0,
+          help="channels of a head the rotary turn covers, the first ones "
+               "(rotate-half within them); 0 = all"),
+        K("rope_yarn_factor", "float", lo=0.0,
+          help="YaRN: interpolation factor of the rotary frequencies; "
+               "0 = plain rotary"),
+        K("rope_yarn_orig", "int", lo=1,
+          help="YaRN: the positions trained before the extension"),
+        K("rope_yarn_beta_fast", "float", lo=0.0,
+          help="YaRN: rotations where interpolation stops"),
+        K("rope_yarn_beta_slow", "float", lo=0.0,
+          help="YaRN: rotations where extrapolation starts"),
+        K("rope_yarn_attn_factor", "float", lo=0.0,
+          help="YaRN: multiplier of cos and sin"),
+        K("gate", "int", lo=0, hi=1,
+          help="a sigmoid gate a head on its output, from the layer's input"),
     )
 
     def __init__(self):
@@ -427,12 +510,29 @@ class AttentionLayer(Layer):
         self.pos_key = ""
         self.qk_norm = 0
         self.qk_norm_eps = 1e-6
+        self.head_dim = 0
+        self.window = 0
+        self.rope_dim = 0
+        self.rope_yarn_factor = 0.0
+        self.rope_yarn_orig = 0
+        self.rope_yarn_beta_fast = 0.0
+        self.rope_yarn_beta_slow = 0.0
+        self.rope_yarn_attn_factor = 0.0
+        self.gate = 0
+        # a note of the last training trace (NetTrainer.attn_sites)
+        self.attn_site = None
 
     def set_param(self, name, val):
         if name == "nhead":
             self.nhead = int(val)
         elif name == "nkvhead":
             self.nkvhead = int(val)
+        elif name in ("head_dim", "window", "rope_dim", "rope_yarn_orig",
+                      "gate"):
+            setattr(self, name, int(val))
+        elif name in ("rope_yarn_factor", "rope_yarn_beta_fast",
+                      "rope_yarn_beta_slow", "rope_yarn_attn_factor"):
+            setattr(self, name, float(val))
         elif name == "score_scale":
             self.score_scale = float(val)
         elif name == "causal":
@@ -452,38 +552,77 @@ class AttentionLayer(Layer):
         else:
             super().set_param(name, val)
 
+    def head_size(self, d: int) -> int:
+        return self.head_dim or d // self.nhead
+
+    def _single_device_only(self) -> List[str]:
+        """The keys set here that only the single-device training and
+        evaluation paths compute: the ring (a ``seq`` mesh) and the decode
+        cache have none of them."""
+        return [k for k in ("window", "gate", "rope_dim", "rope_yarn_factor")
+                if getattr(self, k)]
+
     def infer_shapes(self, in_shapes: List[Shape4]) -> List[Shape4]:
         assert len(in_shapes) == 1, "attention: 1-1 connection only"
         n, c, s, d = in_shapes[0]
         assert c == 1, "attention: input must be (b,1,s,d)"
         assert self.nhead > 0, "attention: must set nhead"
-        assert d % self.nhead == 0, "attention: nhead must divide dim"
+        assert self.head_dim or d % self.nhead == 0, \
+            "attention: nhead must divide dim (or set head_dim)"
         assert self.nhead % (self.nkvhead or self.nhead) == 0, \
             "attention: nkvhead must divide nhead"
+        assert not self.window or self.causal, \
+            "attention: window > 0 needs causal = 1"
+        yarn = ("rope_yarn_orig", "rope_yarn_beta_fast", "rope_yarn_beta_slow",
+                "rope_yarn_attn_factor")
+        assert not self.rope_yarn_factor or all(getattr(self, k)
+                                                for k in yarn), \
+            f"attention: rope_yarn_factor > 0 needs {', '.join(yarn)} set"
+        hd = self.head_size(d)
+        assert self.rope_dim % 2 == 0 and self.rope_dim <= hd, \
+            f"attention: rope_dim must be even and at most the head's {hd}"
         return [in_shapes[0]]
 
     def init_params(self, key, in_shapes, dtype=jnp.float32):
         d = in_shapes[0][3]
-        nqkv = d + 2 * (self.nkvhead or self.nhead) * (d // self.nhead)
+        hd = self.head_size(d)
+        dq = self.nhead * hd
+        nqkv = dq + 2 * (self.nkvhead or self.nhead) * hd
         kq, ko = jax.random.split(key)
         params = {
             "wqkv": self.param.rand_init_weight(kq, (nqkv, d), d, nqkv, dtype),
-            "wout": self.param.rand_init_weight(ko, (d, d), d, d, dtype),
+            "wout": self.param.rand_init_weight(ko, (d, dq), dq, d, dtype),
         }
         if not self.param.no_bias:
             params["bqkv"] = jnp.zeros((nqkv,), dtype)
             params["bout"] = jnp.zeros((d,), dtype)
         if self.qk_norm:
-            params["q_norm"] = jnp.ones((d // self.nhead,), dtype)
-            params["k_norm"] = jnp.ones((d // self.nhead,), dtype)
+            params["q_norm"] = jnp.ones((hd,), dtype)
+            params["k_norm"] = jnp.ones((hd,), dtype)
+        if self.gate:
+            params["wgate"] = self.param.rand_init_weight(
+                jax.random.fold_in(key, 2), (self.nhead, d), d, self.nhead,
+                dtype)
         return params
+
+    def _turn(self, q, k, pos):
+        """The rotary turn with this layer's channels and frequencies."""
+        hd = q.shape[-1]
+        dim = self.rope_dim or hd
+        if not self.rope_yarn_factor:
+            return _rotary(q, k, pos, self.rope_theta, self.rope_dim)
+        return _rotary(q, k, pos, self.rope_theta, dim, yarn_inv_freq(
+            dim, self.rope_theta, self.rope_yarn_factor, self.rope_yarn_orig,
+            self.rope_yarn_beta_fast, self.rope_yarn_beta_slow),
+            self.rope_yarn_attn_factor)
 
     def forward(self, params, buffers, inputs, ctx):
         self.check_n_inputs(inputs, 1)
         x = inputs[0]
         b, _, s, d = x.shape
         h = self.nhead
-        hd = d // h
+        hd = self.head_size(d)
+        dq = h * hd
         qkv = jnp.einsum("bcsd,nd->bcsn", x, params["wqkv"].astype(x.dtype))
         if "bqkv" in params:
             qkv = qkv + params["bqkv"].astype(x.dtype)
@@ -493,8 +632,11 @@ class AttentionLayer(Layer):
             q, k, v = qkv[0], qkv[1], qkv[2]  # (b, h, s, hd)
         else:
             q, k, v = (t.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
-                       for t in jnp.split(qkv[:, 0], [d, d + nkv * hd], -1))
+                       for t in jnp.split(qkv[:, 0], [dq, dq + nkv * hd], -1))
         dec = getattr(ctx, "decode", None)
+        assert dec is None or not self._single_device_only(), (
+            f"attention: {', '.join(self._single_device_only())} have no "
+            "decode cache path")
         if self.qk_norm:
             assert dec is None, "attention: qk_norm = 1 has no decode path"
             q, k = (_head_rms_norm(t, params[g], self.qk_norm_eps)
@@ -502,14 +644,14 @@ class AttentionLayer(Layer):
         if self.rope:
             assert dec is None, "attention: rope = 1 has no decode cache path"
             pos = _label_field(ctx, self.pos_key)
-            q, k = _rotary(q, k, jnp.arange(s)[None] if pos is None else pos,
-                           self.rope_theta)
+            q, k = self._turn(q, k, jnp.arange(s)[None] if pos is None
+                              else pos)
         if nkv != h:  # after the rotary turn: it turns K's heads, not Q's count
             k, v = (jnp.repeat(t, h // nkv, axis=1) for t in (k, v))
         scale = self.score_scale or 1.0 / (hd ** 0.5)
         if dec is not None:
             att = self._decode_attention(dec, q, k, v, scale)
-            att = att.transpose(0, 2, 1, 3).reshape(b, 1, s, d)
+            att = att.transpose(0, 2, 1, 3).reshape(b, 1, s, dq)
             out = jnp.einsum("bcsd,nd->bcsn", att,
                              params["wout"].astype(x.dtype))
             if "bout" in params:
@@ -519,10 +661,16 @@ class AttentionLayer(Layer):
         if seg is not None:
             seg = seg.astype(jnp.int32)  # (b, s) doc segments; 0 = pad
         mesh = _seq_mesh(ctx)
+        if mesh is not None and self._single_device_only():
+            from ..utils.config import ConfigError
+            raise ConfigError(
+                f"attention: {', '.join(self._single_device_only())} have no "
+                "ring path (parallel/ring.py): drop the seq mesh axis")
         if mesh is not None and s % mesh.shape["seq"] == 0:
             att = ring.sharded_attention(q, k, v, mesh,
                                          causal=bool(self.causal), seg=seg,
                                          scale=scale)
+            lowering = "ring"
         else:
             if mesh is not None:
                 warnings.warn(
@@ -533,12 +681,47 @@ class AttentionLayer(Layer):
             att = _single_device_attention(
                 q, k, v, bool(self.causal), seg=seg,
                 on_flash=lambda saved: self.note_pallas(ctx, saved),
-                scale=scale)
-        att = att.transpose(0, 2, 1, 3).reshape(b, 1, s, d)
-        out = jnp.einsum("bcsd,nd->bcsn", att, params["wout"].astype(x.dtype))
+                scale=scale, window=self.window)
+            lowering = attention_lowering(s, hd, bool(self.causal),
+                                          seg is not None, self.window)
+        if ctx.train:
+            self.attn_site = self._site(s, hd, lowering)
+        def project(att, gate, wout):
+            if gate is not None:
+                att = (att.astype(jnp.float32) * gate).astype(att.dtype)
+            att = att.transpose(0, 2, 1, 3).reshape(b, 1, s, dq)
+            return jnp.einsum("bcsd,nd->bcsn", att, wout.astype(x.dtype))
+
+        if self.gate:
+            with jax.named_scope("gate"):
+                g = jax.nn.sigmoid(jnp.einsum(
+                    "bcsd,hd->bhcs", x, params["wgate"].astype(x.dtype),
+                    preferred_element_type=jnp.float32)[:, :, 0, :, None])
+            # the gated heads are made again from the heads (which the flash
+            # backward keeps anyway) and the gate in the backward pass, not
+            # kept: 134 MB a layer of 64 heads of 128 at s8192; at
+            # laguna-xs.2's depth the step's static bytes fall from 16.37 to
+            # 15.68 GB (benchmark/aot_compile.py; the depth table in the
+            # reduced_why of benchmark/configs/laguna-xs.2.json) for about 2%
+            # of its time
+            out = jax.checkpoint(project)(att, g, params["wout"])
+        else:
+            out = project(att, None, params["wout"])
         if "bout" in params:
             out = out + params["bout"].astype(x.dtype)
         return [seq_constraint(out, ctx)], buffers
+
+    def _site(self, s: int, hd: int, lowering: str) -> tuple:
+        """What ``NetTrainer.attn_sites`` reports of this layer's trace."""
+        from ..ops import pallas_kernels as pk
+        ratio = round(pk._fa_plan(s, hd, "fwd", self.window).area_ratio, 4) \
+            if lowering in ("flash_band", "flash_tri") else None
+        rope = "none" if not self.rope else \
+            "yarn" if self.rope_yarn_factor else "default"
+        return (self.nhead, self.nkvhead or self.nhead, hd, self.window, rope,
+                self.rope_theta if self.rope else None,
+                (self.rope_dim or hd) if self.rope else 0,
+                self.rope_yarn_factor or None, self.gate, lowering, ratio)
 
     def _decode_attention(self, dec, q, k, v, scale):
         """Cache-aware attention for incremental decode (serve/decode.py).

@@ -1127,6 +1127,7 @@ class LearnTask:
                               pallas_sites=self.net.pallas_sites(),
                               loop_saved=self.net.loop_saved(),
                               ssm_sites=self.net.ssm_sites(),
+                              attn_sites=self.net.attn_sites(),
                               moe_sites=self.net.moe_sites(),
                               loss_sites=self.net.loss_sites())
         mlog.info(f"compile: {seconds:.1f} sec (first dispatch, excluded "

@@ -9,7 +9,7 @@ section are independent (``src/nnet/nnet_config.h:255-287``).
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import List, Mapping, Optional, Sequence, Union
 
 
 def mlp(num_class: int = 10, input_dim: int = 784,
@@ -431,9 +431,9 @@ def looped_lm(vocab: int, seq: int, dim: int, nlayer: int, nhead: int,
 
 
 def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
-              nhead: int, nkvhead: int, ffn: int, ssm_heads: int = 0,
-              ssm_head_dim: int = 0, ssm_state: int = 0, ssm_groups: int = 1,
-              ssm_conv: int = 4, ssm_chunk: int = 256,
+              nhead: Union[int, Sequence[int]], nkvhead: int, ffn: int,
+              ssm_heads: int = 0, ssm_head_dim: int = 0, ssm_state: int = 0,
+              ssm_groups: int = 1, ssm_conv: int = 4, ssm_chunk: int = 256,
               att_scale: float = 0.0, emb_mult: float = 1.0,
               res_mult: float = 1.0, logit_div: float = 1.0,
               eps: float = 1e-5, packed: bool = False, *,
@@ -443,7 +443,10 @@ def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
               experts_per_token: int = 1, expert_ffn: int = 0,
               score_func: str = "sigmoid", expert_bias: bool = False,
               expert_bias_rate: float = 0.0, norm_topk: bool = True,
-              routed_scale: float = 1.0) -> str:
+              routed_scale: float = 1.0, head_dim: int = 0, window: int = 0,
+              window_rope_theta: float = 0.0, rope_dim: int = 0,
+              yarn: Optional[Mapping[str, float]] = None, gate: bool = False,
+              shared_ffn: int = 0, tie: bool = True) -> str:
     """Hybrid decoder-only LM: per layer one of three mixers and one of two
     feed-forwards (the Granite 4.0-H family's block; Mamba-2: Dao & Gu 2024,
     arXiv:2405.21060; and the LFM2 family's: gated short convolutions beside
@@ -469,6 +472,18 @@ def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
     the selection that starts at zero and moves by ``expert_bias_rate`` a step
     toward an even load.  A multiplier of exactly 1 writes no ``scale``
     layer.
+
+    ``nhead`` is a count for every attention layer or a list of one a layer
+    of ``layer_types``; heads are ``head_dim`` wide (0: ``dim / nhead``).  A
+    ``window_attention`` layer is attention over the ``window`` keys up to
+    the query (sliding-window), turned at ``window_rope_theta`` (0:
+    ``rope_theta``) over all its channels; an ``attention`` layer turns the
+    first ``rope_dim`` channels (0: all) at ``rope_theta``, with YaRN's
+    frequencies where ``yarn`` gives ``factor``, ``orig``, ``beta_fast``,
+    ``beta_slow`` and ``attn_factor``.  ``gate``: a sigmoid gate a head on
+    the attention's output.  ``shared_ffn``: a shared expert of that width
+    in every ``moe_topk`` layer.  ``tie = False``: the head has a table of
+    its own.
     """
     seg = ["  segment_key = segment"] if packed else []
 
@@ -492,24 +507,45 @@ def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
                      f"  kernel_size = {ssm_conv}",
                      f"  chunk = {ssm_chunk}",
                      f"  eps = {eps}"]
-        elif kind == "attention":
+        elif kind in ("attention", "window_attention"):
+            heads = nhead if isinstance(nhead, int) else nhead[i]
             mixer = [f"layer[{a}_n->{a}_o] = attention:l{i}_att",
-                     f"  nhead = {nhead}",
+                     f"  nhead = {heads}",
                      f"  nkvhead = {nkvhead}",
+                     *([f"  head_dim = {head_dim}"] if head_dim else []),
                      f"  score_scale = {att_scale}",
                      "  causal = 1",
                      "  no_bias = 1"]
-            if rope_theta:
-                mixer += ["  rope = 1", f"  rope_theta = {rope_theta}",
+            theta = rope_theta
+            if kind == "window_attention":
+                assert window > 0, "hybrid_lm: a window_attention layer " \
+                    "needs window > 0"
+                mixer += [f"  window = {window}"]
+                theta = window_rope_theta or rope_theta
+            if theta:
+                mixer += ["  rope = 1", f"  rope_theta = {theta}",
                           *(["  pos_key = position"] if packed else [])]
+            if theta and kind == "attention":
+                mixer += [f"  rope_dim = {rope_dim}"] * bool(rope_dim)
+                if yarn:
+                    mixer += [
+                        f"  rope_yarn_factor = {float(yarn['factor'])}",
+                        f"  rope_yarn_orig = {int(yarn['orig'])}",
+                        f"  rope_yarn_beta_fast = {float(yarn['beta_fast'])}",
+                        f"  rope_yarn_beta_slow = {float(yarn['beta_slow'])}",
+                        "  rope_yarn_attn_factor = "
+                        f"{float(yarn['attn_factor'])}"]
             if qk_norm:
                 mixer += ["  qk_norm = 1", f"  qk_norm_eps = {eps}"]
+            if gate:
+                mixer += ["  gate = 1"]
         elif kind == "conv":
             mixer = [f"layer[{a}_n->{a}_o] = shortconv:l{i}_conv",
                      f"  kernel_size = {conv_taps}"]
         else:
             raise ValueError(f"hybrid_lm: layer type {kind!r} is none of "
-                             "'mamba', 'attention' and 'conv'")
+                             "'mamba', 'attention', 'window_attention' and "
+                             "'conv'")
         if experts and i >= (dense_layers or 0):
             feed_forward = [
                 f"layer[{m}_n->{m}_o] = moe_topk:l{i}_moe",
@@ -522,7 +558,8 @@ def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
                 f"  expert_bias = {int(expert_bias)}",
                 f"  expert_bias_rate = {expert_bias_rate}",
                 f"  norm_topk = {int(norm_topk)}",
-                f"  routed_scale = {routed_scale}"]
+                f"  routed_scale = {routed_scale}",
+                *([f"  shared_nhidden = {shared_ffn}"] if shared_ffn else [])]
         else:
             feed_forward = [
                 f"layer[{m}_n->{m}_n1,{m}_n2] = split",
@@ -556,7 +593,7 @@ def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
               "layer[fin->logits] = seq_fullc:head",
               f"  nhidden = {vocab}",
               "  no_bias = 1",
-              "  tie = embed",
+              *(["  tie = embed"] if tie else []),
               *scaled(1.0 / logit_div),
               "layer[+0] = softmax_seq",
               *(["  packed = 1"] if packed else []),

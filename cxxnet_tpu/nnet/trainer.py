@@ -1957,15 +1957,36 @@ class NetTrainer:
                 for c in self.net.connections
                 if getattr(c.layer, "ssm_site", None) and c.owns_params]
 
+    def attn_sites(self) -> List[dict]:
+        """The ``attention`` layers a training forward has traced, in net
+        order: the layer's name with its query ``heads``, ``kv_heads``,
+        ``head_dim`` and ``window`` (0: none), the ``rope`` kind (``none``,
+        ``default``, ``yarn``) with its ``rope_theta``, ``rope_dim`` (channels
+        turned) and ``yarn_factor``, the output ``gate``, the ``lowering``
+        the scores took (``layers/sequence.attention_lowering``:
+        ``flash_band``, ``flash_tri``, ``flash``, ``dense``; ``ring`` on a
+        seq mesh) and, on the flash band or triangle, ``area_ratio``, the
+        forward kernel's computed over live score area
+        (``ops/pallas_kernels._fa_plan``).
+        ``[]`` for a net without such a layer."""
+        fields = ("heads", "kv_heads", "head_dim", "window", "rope",
+                  "rope_theta", "rope_dim", "yarn_factor", "gate", "lowering",
+                  "area_ratio")
+        return [dict(zip(fields, c.layer.attn_site),
+                     layer=c.param_key.split("-", 1)[1])
+                for c in self.net.connections
+                if getattr(c.layer, "attn_site", None) and c.owns_params]
+
     def moe_sites(self) -> List[dict]:
         """The ``moe_topk`` layers a training forward has traced, in net
         order: the layer's name with the experts ``published`` (the router's
         width), ``held`` and the ``first`` held index, the experts a token
         (``top_k``), the experts' ``width``, the ``score`` function and the
-        ``lowering`` (``layers/moe.GMM_LOWERING``) and the ``rows`` of a window
-        (``layers/moe.window_rows``).  ``[]`` for a net without such a layer."""
+        ``lowering`` (``layers/moe.GMM_LOWERING``), the ``rows`` of a window
+        (``layers/moe.window_rows``) and the ``shared`` expert's width (0:
+        none).  ``[]`` for a net without such a layer."""
         fields = ("published", "held", "first", "top_k", "width", "score",
-                  "lowering", "rows")
+                  "lowering", "rows", "shared")
         return [dict(zip(fields, c.layer.moe_site),
                      layer=c.param_key.split("-", 1)[1])
                 for c in self.net.connections

@@ -91,12 +91,16 @@ def _fa_strip(s_len, d, bq, bk, kernel):
 
 
 FaPlan = collections.namedtuple(
-    "FaPlan", "bq bk bs offsets interior crossing area_ratio")
+    "FaPlan", "bq bk bs offsets interior crossing area_ratio window",
+    defaults=(0,))
 
 
-def _fa_live_pairs(nq, nk, bq, bk):
+def _fa_live_pairs(nq, nk, bq, bk, window=0):
+    """The (i, j) block pairs with a live score: causal, and with a
+    ``window`` only those whose keys reach ``i*bq - window + 1``."""
     return [(i, j) for i in range(nq) for j in range(nk)
-            if i * bq + bq - 1 >= j * bk]
+            if i * bq + bq - 1 >= j * bk
+            and (not window or j * bk + bk - 1 >= i * bq - window + 1)]
 
 
 def _fa_crosses(i, j, bq, bk):
@@ -105,12 +109,15 @@ def _fa_crosses(i, j, bq, bk):
     return j * bk + bk - 1 > i * bq
 
 
-def _fa_plan(s_len, d=64, kernel="fwd"):
+def _fa_plan(s_len, d=64, kernel="fwd", window=0):
     """Everything one causal kernel's work follows from, from the shapes
     alone: block and strip sizes, the offsets ``i*bq - j*bk`` at which a
     block crosses the diagonal (one static case each in the kernel bodies),
     the number of interior and diagonal-crossing programs per (batch, head)
-    and the computed-over-live area ratio (live = s^2 / 2)."""
+    and the computed-over-live area ratio (live = s^2 / 2).  A ``window``
+    gives the band's plan (:func:`_fa_band_plan`)."""
+    if window:
+        return _fa_band_plan(s_len, window)
     bq, bk = _fa_blocks(s_len, d)
     bs = _fa_strip(s_len, d, bq, bk, kernel)
     assert bq % bs == 0 and bk % bs == 0, (bq, bk, bs)
@@ -156,11 +163,94 @@ def _fa_full(bq, bk):
     return [[(slice(0, bq), slice(0, bk), False)]]
 
 
+# The band (sliding-window attention: query i sees key j iff 0 <= i - j <
+# window).  Only the block pairs whose keys reach into the band are on the
+# grid (_fa_live_pairs), so the work grows as s * window, not s^2.  Blocks are
+# square and the smallest of 128..1024 that holds the window
+# (_fa_band_blocks): a 512 window under 1024-blocks would leave most of each
+# edge block dead.  Inside a pair every bs x bs tile is dead, whole or cut by
+# one of the band's two edges (the causal diagonal, the window's far edge);
+# the cut tiles carry the offset ``qpos - kpos`` of their first element and
+# are masked by both edges and the segments together (_fa_scores), whole
+# tiles side by side are one unmasked rectangle.  s8192, window 512, d128:
+# 512-blocks of 4 strips, 31 live pairs a (batch, head) of 256, computed over
+# live 1.25.
+
+
+def _fa_band_blocks(s_len, window):
+    b = 128
+    while b < min(window, 1024) and s_len % (2 * b) == 0:
+        b *= 2
+    return b
+
+
+def _fa_band_plan(s_len, window):
+    b = _fa_band_blocks(s_len, window)
+    bs = max(b // 4, 128)
+    pairs = _fa_live_pairs(s_len // b, s_len // b, b, b, window)
+    offs = sorted({(i - j) * b for i, j in pairs})
+    rects = {off: _fa_band_rects(b, bs, off, window, "q") for off in offs}
+    whole = [off for off, g in rects.items() if g == _fa_full(b, b)]
+    area = sum((r.stop - r.start) * (c.stop - c.start)
+               for i, j in pairs for g in rects[(i - j) * b]
+               for r, c, _ in g)
+    live = sum(min(q + 1, window) for q in range(s_len))
+    n_whole = sum((i - j) * b in whole for i, j in pairs)
+    return FaPlan(b, b, bs, tuple(offs), n_whole, len(pairs) - n_whole,
+                  area / live, window)
+
+
+def _fa_band_tile(delta, bs, window):
+    """A bs x bs tile whose first query lies ``delta`` positions past its
+    first key: None where no score of it lies in the band, False where
+    every score does, else ``(delta, window)``, the mask's terms."""
+    if delta + bs - 1 < 0 or delta - bs + 1 >= window:
+        return None
+    if delta - bs + 1 >= 0 and delta + bs - 1 < window:
+        return False
+    return (delta, window)
+
+
+def _fa_band_rects(b, bs, off, window, by):
+    """The live part of a band pair at offset ``off`` as groups of
+    rectangles ``(rows, cols, mask)``, one group a query strip (``by="q"``)
+    or a key strip (``by="k"``); runs of whole tiles in a strip are one
+    rectangle, a cut tile is one of its own."""
+    n = b // bs
+    groups = []
+    for t in range(n):
+        g, run = [], None
+        for u in range(n):
+            rt, ct = (t, u) if by == "q" else (u, t)
+            mask = _fa_band_tile(off + (rt - ct) * bs, bs, window)
+            if mask is None:
+                run = None
+                continue
+            rows, cols = slice(rt * bs, (rt + 1) * bs), \
+                slice(ct * bs, (ct + 1) * bs)
+            if mask is False and run is not None:
+                r, c, _ = g[run]
+                g[run] = (r, slice(c.start, cols.stop), False) if by == "q" \
+                    else (slice(r.start, rows.stop), c, False)
+                continue
+            run = len(g) if mask is False else None
+            g.append((rows, cols, mask))
+        if g:
+            groups.append(g)
+    return groups
+
+
 def _fa_block_cases(i, j, plan, by, body):
     """Run ``body(groups)`` under the one static case the live pair (i, j)
     falls in: interior (the whole block, one rectangle, unmasked) or
-    crossing the diagonal at one of the plan's offsets."""
+    crossing the diagonal at one of the plan's offsets; on a band, each of
+    its offsets."""
     bq, bk = plan.bq, plan.bk
+    if plan.window:
+        for off in plan.offsets:
+            pl.when(i * bq - j * bk == off)(functools.partial(
+                body, _fa_band_rects(bq, plan.bs, off, plan.window, by)))
+        return
     if plan.interior:
         pl.when(jnp.logical_not(_fa_crosses(i, j, bq, bk)))(
             functools.partial(body, _fa_full(bq, bk)))
@@ -208,10 +298,18 @@ def _fa_scores(q_ref, k_ref, rect, scale, seg):
         segq = segq[rows]
         segk = seg_ref[0, 0, pl.ds(k0 + cols.start, cols.stop - cols.start)]
         keep = (segq == segk[None, :]) & (segq != 0)
-    if diag:
+    if diag is True:
         r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         keep = r >= c if keep is None else (keep & (r > c)) | (r == c)
+    elif diag:
+        # a tile of the band cut by one of its edges: qpos - kpos is delta
+        # at the tile's first element
+        delta, window = diag
+        apart = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
+            - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + delta
+        band = (apart >= 0) & (apart < window)
+        keep = band if keep is None else (keep & band) | (apart == 0)
     return (s if keep is None else jnp.where(keep, s, NEG_INF)), qb, kb
 
 
@@ -366,15 +464,16 @@ def flash_attention_available(s_len: int, d: int) -> bool:
     return s_len % 128 == 0 and d <= 256
 
 
-def _fa_tri_pairs(nq, nk, bq, bk, order):
-    """Live (i, j) block pairs of the causal triangle, as int32 arrays.
+def _fa_tri_pairs(nq, nk, bq, bk, order, window=0):
+    """Live (i, j) block pairs of the causal triangle (of the band with a
+    ``window``), as int32 arrays.
     order="ij": i-major (dq/fwd: j accumulates within a row);
     order="ji": j-major (dkv: i accumulates within a column).  Dead
     blocks (i*bq+bq-1 < j*bk) are EXCLUDED from the grid entirely, so
     neither their DMA nor their program overhead is paid — with equal
     1024-blocks that is 1 of 4 programs at s2048 and 6 of 16 at s4096."""
     import numpy as _np
-    pairs = _fa_live_pairs(nq, nk, bq, bk)
+    pairs = _fa_live_pairs(nq, nk, bq, bk, window)
     if order == "ji":
         pairs.sort(key=lambda ij: (ij[1], ij[0]))
     ii = _np.asarray([p[0] for p in pairs], _np.int32)
@@ -393,6 +492,21 @@ def _fa_tri_pairs(nq, nk, bq, bk, order):
 # pairtests hold the two paths together (tests/test_text.py).
 
 
+def _fa_jfirst(i, plan):
+    """The first key block of query block ``i``'s row of the grid."""
+    if not plan.window:
+        return 0
+    return jnp.maximum(i * plan.bq - plan.window + 1, 0) // plan.bk
+
+
+def _fa_ilast(j, plan, n_q):
+    """The last query block of key block ``j``'s column of the grid."""
+    if not plan.window:
+        return n_q - 1
+    return jnp.minimum((j * plan.bk + plan.bk + plan.window - 2) // plan.bq,
+                       n_q - 1)
+
+
 def _fa_fwd_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, *refs, scale,
                        plan, seg):
     seg_ref = refs[0] if seg else None
@@ -401,7 +515,7 @@ def _fa_fwd_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, *refs, scale,
     i, j = ii_ref[t], jj_ref[t]
     jlast = (i * plan.bq + plan.bq - 1) // plan.bk
 
-    @pl.when(j == 0)
+    @pl.when(j == _fa_jfirst(i, plan))
     def _():
         _fa_fwd_init(acc, m, l)
 
@@ -422,7 +536,7 @@ def _fa_dq_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     i, j = ii_ref[t], jj_ref[t]
     jlast = (i * plan.bq + plan.bq - 1) // plan.bk
 
-    @pl.when(j == 0)
+    @pl.when(j == _fa_jfirst(i, plan))
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -452,7 +566,7 @@ def _fa_dkv_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         _fa_dkv_step, i * plan.bq, j * plan.bk, q_ref, k_ref, v_ref, do_ref,
         lse_ref, delta_ref, dk_acc, dv_acc, scale=scale, seg_ref=seg_ref))
 
-    @pl.when(i == lse_ref.shape[2] // plan.bq - 1)  # the last query block
+    @pl.when(i == _fa_ilast(j, plan, lse_ref.shape[2] // plan.bq))
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -502,7 +616,8 @@ def _fa_tri_call(kernel, nbh, s_len, d, dtype, scale, seg, interpret, plan):
     in_specs = [q_spec, k_spec, k_spec] + [q_spec, row_spec, row_spec][:n_in]
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(nbh, len(_fa_live_pairs(s_len // bq, s_len // bk, bq, bk))),
+        grid=(nbh, len(_fa_live_pairs(s_len // bq, s_len // bk, bq, bk,
+                                      plan.window))),
         in_specs=in_specs + [row_spec] * seg, out_specs=out_specs,
         scratch_shapes=_scratch(*scratch))
     return pl.pallas_call(
@@ -510,21 +625,23 @@ def _fa_tri_call(kernel, nbh, s_len, d, dtype, scale, seg, interpret, plan):
         grid_spec=gs, interpret=interpret, out_shape=out_shape)
 
 
-def _fa_tri(kernel, args, scale, interpret, seg3):
+def _fa_tri(kernel, args, scale, interpret, seg3, window=0):
     """Run one triangular-grid kernel on (nbh, s, d) operands ``args`` (the
-    first is q) and, for the segmented form, the (nbh, 1, s) segment row."""
+    first is q) and, for the segmented form, the (nbh, 1, s) segment row;
+    with a ``window``, on the band's grid."""
     nbh, s_len, d = args[0].shape
-    plan = _fa_plan(s_len, d, kernel)
+    plan = _fa_plan(s_len, d, kernel, window)
     segs = [] if seg3 is None else [seg3]
     ii, jj = _fa_tri_pairs(s_len // plan.bq, s_len // plan.bk, plan.bq,
-                           plan.bk, "ji" if kernel == "dkv" else "ij")
+                           plan.bk, "ji" if kernel == "dkv" else "ij",
+                           window)
     return _fa_tri_call(kernel, nbh, s_len, d, args[0].dtype, scale,
                         len(segs), interpret, plan)(ii, jj, *args, *segs)
 
 
-def _fa_fwd(q3, k3, v3, scale, causal, interpret, seg3=None):
+def _fa_fwd(q3, k3, v3, scale, causal, interpret, seg3=None, window=0):
     if causal:
-        return _fa_tri("fwd", (q3, k3, v3), scale, interpret, seg3)
+        return _fa_tri("fwd", (q3, k3, v3), scale, interpret, seg3, window)
     nbh, s_len, d = q3.shape
     bq, bk = _fa_blocks(s_len, d)
     q_spec, k_spec, row_spec = _fa_specs(nbh, s_len, d, bq, bk)
@@ -542,14 +659,15 @@ def _fa_fwd(q3, k3, v3, scale, causal, interpret, seg3=None):
     return o, lse
 
 
-def _fa_bwd(q3, k3, v3, o3, lse, g3, scale, causal, interpret, seg3=None):
+def _fa_bwd(q3, k3, v3, o3, lse, g3, scale, causal, interpret, seg3=None,
+            window=0):
     nbh, s_len, d = q3.shape
     delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)[:, None, :]  # (nbh, 1, s)
     if causal:
         args = (q3, k3, v3, g3, lse, delta)
-        dq = _fa_tri("dq", args, scale, interpret, seg3)
-        dk, dv = _fa_tri("dkv", args, scale, interpret, seg3)
+        dq = _fa_tri("dq", args, scale, interpret, seg3, window)
+        dk, dv = _fa_tri("dkv", args, scale, interpret, seg3, window)
         return dq, dk, dv
     bq, bk = _fa_blocks(s_len, d)
     q_spec, k_spec, row_spec = _fa_specs(nbh, s_len, d, bq, bk)
@@ -580,15 +698,18 @@ def _fa_bwd(q3, k3, v3, o3, lse, g3, scale, causal, interpret, seg3=None):
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = False,
-                    scale: float = None, interpret: bool = None):
+                    scale: float = None, interpret: bool = None,
+                    window: int = 0):
     """Flash attention, (b, h, s, d) -> (b, h, s, d).
 
     Requires s divisible by 128 (use ``flash_attention_available``);
     ``interpret`` defaults to off-TPU detection so tests run on CPU.
+    ``window > 0`` (with ``causal``): query i sees keys i - window + 1 ..
+    i alone, on the band's grid.
     """
-    out, _ = _flash_fwd_res(q, k, v, causal, scale, interpret)
+    out, _ = _flash_fwd_res(q, k, v, causal, scale, interpret, window)
     return out
 
 
@@ -623,22 +744,24 @@ def flash_saved(q):
             (FLASH_LSE, (b * h, 1, s_len), jnp.float32)]
 
 
-def _flash_fwd_res(q, k, v, causal, scale, interpret):
+def _flash_fwd_res(q, k, v, causal, scale, interpret, window=0):
     scale, interpret = _norm_args(q, causal, scale, interpret)
     b, h, s_len, d = q.shape
     sh3 = (b * h, s_len, d)
     o3, lse = _fa_saved(*_fa_fwd(q.reshape(sh3), k.reshape(sh3),
-                                 v.reshape(sh3), scale, causal, interpret))
+                                 v.reshape(sh3), scale, causal, interpret,
+                                 window=window))
     return o3.reshape(q.shape), (q, k, v, o3, lse)
 
 
-def _flash_bwd_res(causal, scale, interpret, res, g):
+def _flash_bwd_res(causal, scale, interpret, window, res, g):
     q, k, v, o3, lse = res
     scale, interpret = _norm_args(q, causal, scale, interpret)
     b, h, s_len, d = q.shape
     sh3 = (b * h, s_len, d)
     dq, dk, dv = _fa_bwd(q.reshape(sh3), k.reshape(sh3), v.reshape(sh3),
-                         o3, lse, g.reshape(sh3), scale, causal, interpret)
+                         o3, lse, g.reshape(sh3), scale, causal, interpret,
+                         window=window)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
@@ -651,31 +774,31 @@ def _seg_tile(seg, h):
     return jnp.repeat(seg.astype(jnp.int32)[:, None, :], h, axis=0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flash_seg(q, k, v, seg, scale, interpret):
-    out, _ = _flash_seg_fwd_res(q, k, v, seg, scale, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_seg(q, k, v, seg, scale, interpret, window):
+    out, _ = _flash_seg_fwd_res(q, k, v, seg, scale, interpret, window)
     return out
 
 
-def _flash_seg_fwd_res(q, k, v, seg, scale, interpret):
+def _flash_seg_fwd_res(q, k, v, seg, scale, interpret, window):
     scale, interpret = _norm_args(q, True, scale, interpret)
     b, h, s_len, d = q.shape
     sh3 = (b * h, s_len, d)
     seg3 = _seg_tile(seg, h)
     o3, lse = _fa_saved(*_fa_fwd(q.reshape(sh3), k.reshape(sh3),
                                  v.reshape(sh3), scale, True, interpret,
-                                 seg3))
+                                 seg3, window))
     return o3.reshape(q.shape), (q, k, v, seg, o3, lse)
 
 
-def _flash_seg_bwd_res(scale, interpret, res, g):
+def _flash_seg_bwd_res(scale, interpret, window, res, g):
     q, k, v, seg, o3, lse = res
     scale, interpret = _norm_args(q, True, scale, interpret)
     b, h, s_len, d = q.shape
     sh3 = (b * h, s_len, d)
     dq, dk, dv = _fa_bwd(q.reshape(sh3), k.reshape(sh3), v.reshape(sh3),
                          o3, lse, g.reshape(sh3), scale, True, interpret,
-                         _seg_tile(seg, h))
+                         _seg_tile(seg, h), window)
     import numpy as _np
     dseg = _np.zeros(seg.shape, jax.dtypes.float0)  # int input: no tangent
     return (dq.reshape(q.shape), dk.reshape(k.shape),
@@ -685,7 +808,8 @@ def _flash_seg_bwd_res(scale, interpret, res, g):
 _flash_seg.defvjp(_flash_seg_fwd_res, _flash_seg_bwd_res)
 
 
-def flash_attention_segmented(q, k, v, seg, scale=None, interpret=None):
+def flash_attention_segmented(q, k, v, seg, scale=None, interpret=None,
+                              window=0):
     """Segment-masked causal flash attention, (b, h, s, d) + (b, s) int
     segment ids -> (b, h, s, d).
 
@@ -693,8 +817,9 @@ def flash_attention_segmented(q, k, v, seg, scale=None, interpret=None):
     padding; the diagonal is always allowed — see ``_fa_scores``).
     Same availability gate as :func:`flash_attention`
     (``flash_attention_available``); ``interpret`` defaults to off-TPU
-    detection so the CPU pairtests run this exact code."""
-    return _flash_seg(q, k, v, seg, scale, interpret)
+    detection so the CPU pairtests run this exact code.  ``window > 0``:
+    the band of :func:`flash_attention` inside each document."""
+    return _flash_seg(q, k, v, seg, scale, interpret, window)
 
 
 # --------------------------------------------------------------------------

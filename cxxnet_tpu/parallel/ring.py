@@ -46,26 +46,30 @@ NEG_INF = -1e30  # large-negative instead of -inf: keeps exp()/where() NaN-free
 def _block_scores(q: jnp.ndarray, k: jnp.ndarray, scale: float,
                   q_off, k_off, causal: bool,
                   seg_q: Optional[jnp.ndarray] = None,
-                  seg_k: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                  seg_k: Optional[jnp.ndarray] = None,
+                  window: int = 0) -> jnp.ndarray:
     """(b,h,sq,d) x (b,h,sk,d) -> (b,h,sq,sk) float32 scores with causal
     and segment masking in *global* positions (offsets account for ring
     rotation).  ``seg_q``/``seg_k`` are (b, sq)/(b, sk) int segment ids
-    (see module docstring for the shared mask rule)."""
+    (see module docstring for the shared mask rule).  ``window > 0`` (with
+    ``causal``) also masks keys ``window`` or more positions back."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     qpos = q_off + jnp.arange(q.shape[2])
     kpos = k_off + jnp.arange(k.shape[2])
     diag = qpos[:, None] == kpos[None, :]
+    causal_mask = qpos[:, None] >= kpos[None, :]
+    if window:
+        causal_mask = causal_mask & (qpos[:, None] - kpos[None, :] < window)
     if seg_q is not None:
         same = (seg_q[:, :, None] == seg_k[:, None, :]) \
             & (seg_q[:, :, None] != 0)
         allowed = same | diag[None]
         if causal:
-            allowed = allowed & (qpos[:, None] >= kpos[None, :])[None]
+            allowed = allowed & causal_mask[None]
         s = jnp.where(allowed[:, None], s, NEG_INF)
     elif causal:
-        mask = qpos[:, None] >= kpos[None, :]
-        s = jnp.where(mask[None, None], s, NEG_INF)
+        s = jnp.where(causal_mask[None, None], s, NEG_INF)
     return s
 
 
@@ -81,7 +85,7 @@ def _online_update(s, v, acc, m, l):
 
 
 def _accumulate_block(q, k, v, scale, q_off, k_off, causal, acc, m, l,
-                      seg_q=None, seg_k=None):
+                      seg_q=None, seg_k=None, window=0):
     """Fold one K/V block into the (acc, m, l) online-softmax state.
 
     Chunks the block's key axis under ``lax.scan`` when it is long, so
@@ -92,7 +96,8 @@ def _accumulate_block(q, k, v, scale, q_off, k_off, causal, acc, m, l,
     s_len = k.shape[2]
     chunk = _chunk_for(s_len)
     if chunk == s_len or s_len <= CHUNKED_ATTN_THRESHOLD:
-        s = _block_scores(q, k, scale, q_off, k_off, causal, seg_q, seg_k)
+        s = _block_scores(q, k, scale, q_off, k_off, causal, seg_q, seg_k,
+                          window)
         return _online_update(s, v, acc, m, l)
     n_chunks = s_len // chunk
     kc = jnp.moveaxis(
@@ -106,7 +111,8 @@ def _accumulate_block(q, k, v, scale, q_off, k_off, causal, acc, m, l,
         acc, m, l, off = carry
         kb, vb = inp[0], inp[1]
         sb = inp[2] if seg_k is not None else None
-        s = _block_scores(q, kb, scale, q_off, off, causal, seg_q, sb)
+        s = _block_scores(q, kb, scale, q_off, off, causal, seg_q, sb,
+                          window)
         acc, m, l = _online_update(s, vb, acc, m, l)
         return (acc, m, l, off + chunk), None
 
@@ -122,19 +128,21 @@ CHUNKED_ATTN_THRESHOLD = 2048  # above this seq len, never materialize s x s
 def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = False,
                     scale: Optional[float] = None,
-                    seg: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                    seg: Optional[jnp.ndarray] = None,
+                    window: int = 0) -> jnp.ndarray:
     """Plain softmax attention, (b, h, s, d) -> (b, h, s, d).
 
     Short sequences take the direct path; past ``CHUNKED_ATTN_THRESHOLD``
     the K/V axis is processed in online-softmax chunks under ``lax.scan``
     so peak memory is O(s·chunk) instead of O(s²) — the single-chip
     long-context path (ring_attention is the multi-chip one).  ``seg``
-    (b, s) applies the shared segment mask rule (module docstring)."""
+    (b, s) applies the shared segment mask rule (module docstring);
+    ``window > 0`` with ``causal`` keeps keys less than ``window`` back."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     s_len = k.shape[2]
     if s_len <= CHUNKED_ATTN_THRESHOLD:
-        s = _block_scores(q, k, scale, 0, 0, causal, seg, seg)
+        s = _block_scores(q, k, scale, 0, 0, causal, seg, seg, window)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bhqk,bhkd->bhqd", p,
                           v.astype(p.dtype)).astype(q.dtype)
@@ -142,7 +150,7 @@ def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     m = jnp.full(q.shape[:3] + (1,), NEG_INF, jnp.float32)
     l = jnp.zeros(q.shape[:3] + (1,), jnp.float32)
     acc, m, l = _accumulate_block(q, k, v, scale, 0, 0, causal, acc, m, l,
-                                  seg_q=seg, seg_k=seg)
+                                  seg_q=seg, seg_k=seg, window=window)
     return (acc / l).astype(q.dtype)
 
 

@@ -801,7 +801,8 @@ def test_the_step_carries_the_expert_counters():
                        index=np.arange(B, dtype=np.uint32)))
     assert t.last_expert_selection() == []
     want = dict(published=E, held=HELD, first=FIRST, top_k=TOPK, width=24,
-                score="sigmoid", lowering=moe.GMM_LOWERING, rows=288)
+                score="sigmoid", lowering=moe.GMM_LOWERING, rows=288,
+                shared=0)
     assert t.moe_sites() == [dict(want, layer=f"l{i}_moe") for i in (1, 2, 3)]
 
 
