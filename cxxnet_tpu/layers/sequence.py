@@ -81,7 +81,10 @@ def _single_device_attention(q, k, v, causal: bool, seg=None, on_flash=None,
     than ``window`` positions back, on the band's grid in the kernels."""
     from ..ops import pallas_kernels as pk
     s, hd = q.shape[2], q.shape[3]
-    if attention_lowering(s, hd, causal, seg is not None, window) != "dense":
+    lowering = attention_lowering(s, hd, causal, seg is not None, window)
+    if lowering not in ("flash_band", "flash_tri"):
+        k, v = repeat_kv(q, k, v)
+    if lowering != "dense":
         if on_flash is not None:
             on_flash(pk.flash_saved(q))
         if seg is not None:
@@ -90,6 +93,17 @@ def _single_device_attention(q, k, v, causal: bool, seg=None, on_flash=None,
         return pk.flash_attention(q, k, v, causal, scale, window=window)
     return ring.dense_attention(q, k, v, causal=causal, scale=scale, seg=seg,
                                 window=window)
+
+
+def repeat_kv(q, k, v):
+    """K and V ``(b, nkv, s, hd)`` repeated to q's heads (query head ``i``
+    reads KV head ``i // (h / nkv)``), for the paths that take one head
+    count: the lax paths, the full flash grid, the ring and the decode
+    cache.  The causal flash kernels take them as they are."""
+    group = q.shape[1] // k.shape[1]
+    if group == 1:
+        return k, v
+    return tuple(jnp.repeat(t, group, axis=1) for t in (k, v))
 
 
 def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
@@ -425,11 +439,14 @@ class AttentionLayer(Layer):
     ``no_bias``.  Config: ``nhead`` (required), ``causal = 0|1``.  With
     ``nkvhead < nhead`` groups of ``nhead / nkvhead`` query heads share a
     key/value head (query head ``i`` reads head ``i // group``) and "wqkv"
-    is ``((nhead + 2 nkvhead) hd, d)``: q's rows, then k's, then v's.  K and
-    V are repeated to ``nhead`` heads in front of the kernels and the decode
-    cache, which know one head count.  ``head_dim`` sets ``hd`` (0, the
-    default: ``d / nhead``); "wout" is then ``(d, nhead hd)``.  ``score_scale``
-    multiplies the scores in every path (0, the default: ``1/sqrt(hd)``).
+    is ``((nhead + 2 nkvhead) hd, d)``: q's rows, then k's, then v's.  The
+    causal flash kernels take K and V at ``nkvhead`` heads (one K/V block
+    serves a group's query heads); the lax paths, the full flash grid, the
+    ring and the decode cache, which know one head count, take them
+    repeated to ``nhead`` (:func:`repeat_kv`).  ``head_dim`` sets ``hd``
+    (0, the default: ``d / nhead``); "wout" is then ``(d, nhead hd)``.
+    ``score_scale`` multiplies the scores in every path (0, the default:
+    ``1/sqrt(hd)``).
     ``qk_norm = 1``: each head's q and k pass an RMSNorm over the head's
     channels, with one gain of ``hd`` for q and one for k shared by the
     heads ("q_norm", "k_norm"), AHEAD of the rotary turn.  ``rope_dim``
@@ -646,11 +663,9 @@ class AttentionLayer(Layer):
             pos = _label_field(ctx, self.pos_key)
             q, k = self._turn(q, k, jnp.arange(s)[None] if pos is None
                               else pos)
-        if nkv != h:  # after the rotary turn: it turns K's heads, not Q's count
-            k, v = (jnp.repeat(t, h // nkv, axis=1) for t in (k, v))
         scale = self.score_scale or 1.0 / (hd ** 0.5)
         if dec is not None:
-            att = self._decode_attention(dec, q, k, v, scale)
+            att = self._decode_attention(dec, q, *repeat_kv(q, k, v), scale)
             att = att.transpose(0, 2, 1, 3).reshape(b, 1, s, dq)
             out = jnp.einsum("bcsd,nd->bcsn", att,
                              params["wout"].astype(x.dtype))
@@ -667,7 +682,7 @@ class AttentionLayer(Layer):
                 f"attention: {', '.join(self._single_device_only())} have no "
                 "ring path (parallel/ring.py): drop the seq mesh axis")
         if mesh is not None and s % mesh.shape["seq"] == 0:
-            att = ring.sharded_attention(q, k, v, mesh,
+            att = ring.sharded_attention(q, *repeat_kv(q, k, v), mesh,
                                          causal=bool(self.causal), seg=seg,
                                          scale=scale)
             lowering = "ring"
@@ -714,14 +729,18 @@ class AttentionLayer(Layer):
     def _site(self, s: int, hd: int, lowering: str) -> tuple:
         """What ``NetTrainer.attn_sites`` reports of this layer's trace."""
         from ..ops import pallas_kernels as pk
-        ratio = round(pk._fa_plan(s, hd, "fwd", self.window).area_ratio, 4) \
+        nkv = self.nkvhead or self.nhead
+        plan = pk._fa_plan(s, hd, "fwd", self.window, self.nhead // nkv) \
             if lowering in ("flash_band", "flash_tri") else None
         rope = "none" if not self.rope else \
             "yarn" if self.rope_yarn_factor else "default"
-        return (self.nhead, self.nkvhead or self.nhead, hd, self.window, rope,
+        return (self.nhead, nkv, hd, self.window, rope,
                 self.rope_theta if self.rope else None,
                 (self.rope_dim or hd) if self.rope else 0,
-                self.rope_yarn_factor or None, self.gate, lowering, ratio)
+                self.rope_yarn_factor or None, self.gate, lowering,
+                round(plan.area_ratio, 4) if plan else None,
+                plan.g if plan else 1 if lowering == "flash" else None,
+                nkv < self.nhead and plan is None)
 
     def _decode_attention(self, dec, q, k, v, scale):
         """Cache-aware attention for incremental decode (serve/decode.py).

@@ -1967,11 +1967,17 @@ class NetTrainer:
         ``flash_band``, ``flash_tri``, ``flash``, ``dense``; ``ring`` on a
         seq mesh) and, on the flash band or triangle, ``area_ratio``, the
         forward kernel's computed over live score area
-        (``ops/pallas_kernels._fa_plan``).
+        (``ops/pallas_kernels._fa_plan``); ``heads_per_step``, the query
+        heads of a KV group one grid step of the layer's flash kernels
+        takes (the plan's ``g``; 1 on the full grid, None off the flash
+        kernels), and ``kv_repeated``, whether K and V were repeated to the
+        query heads in front of the scores (true on the lax paths, the full
+        grid and the ring with ``kv_heads < heads``, never on the band or
+        triangle).
         ``[]`` for a net without such a layer."""
         fields = ("heads", "kv_heads", "head_dim", "window", "rope",
                   "rope_theta", "rope_dim", "yarn_factor", "gate", "lowering",
-                  "area_ratio")
+                  "area_ratio", "heads_per_step", "kv_repeated")
         return [dict(zip(fields, c.layer.attn_site),
                      layer=c.param_key.split("-", 1)[1])
                 for c in self.net.connections

@@ -91,8 +91,8 @@ def _fa_strip(s_len, d, bq, bk, kernel):
 
 
 FaPlan = collections.namedtuple(
-    "FaPlan", "bq bk bs offsets interior crossing area_ratio window",
-    defaults=(0,))
+    "FaPlan", "bq bk bs offsets interior crossing area_ratio window g subs",
+    defaults=(0, 1, 1))
 
 
 def _fa_live_pairs(nq, nk, bq, bk, window=0):
@@ -109,16 +109,20 @@ def _fa_crosses(i, j, bq, bk):
     return j * bk + bk - 1 > i * bq
 
 
-def _fa_plan(s_len, d=64, kernel="fwd", window=0):
+def _fa_plan(s_len, d=64, kernel="fwd", window=0, group=1):
     """Everything one causal kernel's work follows from, from the shapes
     alone: block and strip sizes, the offsets ``i*bq - j*bk`` at which a
     block crosses the diagonal (one static case each in the kernel bodies),
-    the number of interior and diagonal-crossing programs per (batch, head)
-    and the computed-over-live area ratio (live = s^2 / 2).  A ``window``
-    gives the band's plan (:func:`_fa_band_plan`)."""
+    the number of interior and diagonal-crossing programs per (batch, query
+    head) and the computed-over-live area ratio (live = s^2 / 2); with
+    ``group`` query heads a KV head, the heads a grid step ``g`` and the
+    ``subs = group / g`` grid steps of one KV head on a block pair
+    (:func:`_fa_heads`).  A ``window`` gives the band's plan
+    (:func:`_fa_band_plan`)."""
     if window:
-        return _fa_band_plan(s_len, window)
+        return _fa_band_plan(s_len, window, group)
     bq, bk = _fa_blocks(s_len, d)
+    g = _fa_heads(bq, bk, group)
     bs = _fa_strip(s_len, d, bq, bk, kernel)
     assert bq % bs == 0 and bk % bs == 0, (bq, bk, bs)
     pairs = _fa_live_pairs(s_len // bq, s_len // bk, bq, bk)
@@ -129,7 +133,36 @@ def _fa_plan(s_len, d=64, kernel="fwd", window=0):
         for r, c, _ in g)
     return FaPlan(bq, bk, bs, tuple(sorted(set(offs))),
                   len(pairs) - len(offs), len(offs),
-                  area / (s_len * s_len / 2))
+                  area / (s_len * s_len / 2), 0, g, group // g)
+
+
+# Query heads that share a KV head share a grid step.  The kernels take K and
+# V at their own head count; a q-side block (q, o, dO, dq) holds ``g`` heads of
+# one KV group, ``(1, g, bq, d)``, and the kernel bodies take a rectangle's
+# rows of all ``g`` heads as ONE operand of ``g`` times the rows
+# (:func:`_fa_q`), so a strip's matmuls are ``g`` times as tall, a cut tile's
+# mask is built once and broadcast over the heads, and in the dkv kernel the
+# contraction over those rows sums the group's dK and dV.  The lse and delta
+# rows of such a block lie one query block after the other, the block's ``g``
+# heads' rows together (:func:`_fa_rows`), so that a block's column is one
+# slice.  A KV group of more heads than a step holds takes ``subs`` steps on
+# each block pair: the K/V index maps read ``head // subs``, and the dkv grid
+# runs the sub-groups innermost so that one K/V block's accumulators take
+# them all before the block is written.  At ``g = 1`` the blocks, index maps
+# and bodies are the one-head kernels' (``(1, bq, d)`` blocks, 2-D scratch).
+
+_FA_SCORES = 1024 * 1024  # f32 scores a grid step: a 1024-block pair's
+
+
+def _fa_heads(bq, bk, group):
+    """The most heads ``g`` of a KV group of ``group`` (a divisor of it) a
+    grid step takes at the blocks the shapes give: the scores a step
+    computes, ``g bq bk``, stay within the one-head 1024-blocks'
+    (``_FA_SCORES``).  The blocks are never cut to make room for heads:
+    where no two heads fit (1024-blocks), a step takes one head and the
+    group ``group`` steps on a K/V block."""
+    return max(n for n in range(1, group + 1)
+               if group % n == 0 and (n == 1 or n * bq * bk <= _FA_SCORES))
 
 
 def _fa_rects(bq, bk, bs, off, by):
@@ -184,8 +217,9 @@ def _fa_band_blocks(s_len, window):
     return b
 
 
-def _fa_band_plan(s_len, window):
+def _fa_band_plan(s_len, window, group=1):
     b = _fa_band_blocks(s_len, window)
+    g = _fa_heads(b, b, group)
     bs = max(b // 4, 128)
     pairs = _fa_live_pairs(s_len // b, s_len // b, b, b, window)
     offs = sorted({(i - j) * b for i, j in pairs})
@@ -197,7 +231,7 @@ def _fa_band_plan(s_len, window):
     live = sum(min(q + 1, window) for q in range(s_len))
     n_whole = sum((i - j) * b in whole for i, j in pairs)
     return FaPlan(b, b, bs, tuple(offs), n_whole, len(pairs) - n_whole,
-                  area / live, window)
+                  area / live, window, g, group // g)
 
 
 def _fa_band_tile(delta, bs, window):
@@ -259,12 +293,56 @@ def _fa_block_cases(i, j, plan, by, body):
             functools.partial(body, _fa_rects(bq, bk, plan.bs, off, by)))
 
 
+def _fa_q(ref, rows):
+    """Rows ``rows`` (a static slice of ``bq``) of a q-side block as one
+    operand: ``(r, d)`` of a one-head ``(1, bq, d)`` block; of a ``(1, g,
+    bq, d)`` block the ``g`` heads' rows one head after the other, ``(g r,
+    d)``."""
+    if ref.ndim == 3:
+        return ref[0, rows]
+    x = ref[0, :, rows]
+    return x.reshape(-1, x.shape[-1])
+
+
+def _fa_get(x, rows):
+    """Rows ``rows`` of a query block's scratch or column in
+    :func:`_fa_q`'s layout: ``x[rows]`` of a one-head ``(bq, c)``, the
+    heads' rows stacked of a ``(g, bq, c)``."""
+    if x.ndim == 2:
+        return x[rows]
+    y = x[:, rows]
+    return y.reshape(-1, y.shape[-1])
+
+
+def _fa_put(ref, rows, val):
+    """Write ``val``, in :func:`_fa_get`'s layout, to rows ``rows`` of a
+    query block's scratch."""
+    if ref.ndim == 2:
+        ref[rows] = val
+    else:
+        ref[:, rows] = val.reshape(ref.shape[0], -1, val.shape[-1])
+
+
 def _fa_col(ref, q0, q_ref):
-    """The query block's entries of a (1, 1, s) row block (lse, delta,
-    segment ids) as a (bq, 1) column.  Turning lanes into sublanes costs
-    about a cycle an element on the v5e (PR 25's sweep), so it is done once
-    a program and the rectangles slice the column."""
-    return ref[0, 0, pl.ds(q0, q_ref.shape[1])][:, None]
+    """The query block's entries of a (1, 1, s) row block (segment ids;
+    lse and delta of a one-head block) as a (bq, 1) column.  Turning lanes
+    into sublanes costs about a cycle an element on the v5e (PR 25's
+    sweep), so it is done once a program and the rectangles slice the
+    column."""
+    return ref[0, 0, pl.ds(q0, q_ref.shape[-2])][:, None]
+
+
+def _fa_rows_col(ref, q0, q_ref, buf):
+    """The query block's lse or delta as a column: :func:`_fa_col` of a
+    one-head block; of a block of ``g`` heads, whose rows lie together
+    (:func:`_fa_rows`), one slice of ``g bq`` written to the ``(g bq, 1)``
+    scratch ``buf`` and read as ``(g, bq, 1)`` (Mosaic casts the shape of
+    a column it loads, not of one it has just turned from lanes)."""
+    if buf is None:
+        return _fa_col(ref, q0, q_ref)
+    g, bq = q_ref.shape[1], q_ref.shape[2]
+    buf[...] = ref[0, 0, pl.ds(q0 * g, g * bq)][:, None]
+    return buf.reshape(g, bq, 1)
 
 
 def _fa_seg(seg_ref, q0, k0, q_ref):
@@ -285,13 +363,15 @@ def _fa_scores(q_ref, k_ref, rect, scale, seg):
     kpos`` never does, on it both are a fixed lower-triangular pattern.
     The diagonal stays unconditionally allowed so padding rows (segment 0)
     attend themselves and the online softmax never renormalizes a
-    fully-masked row."""
+    fully-masked row.  With a block's heads stacked (:func:`_fa_q`) the
+    mask is built for one head's rows and broadcast over the heads."""
     rows, cols, diag = rect
     # keep matmul operands in the input dtype (bf16 hits the MXU's fast
     # path); accumulate in f32 via preferred_element_type
-    qb, kb = q_ref[0, rows], k_ref[0, cols]
+    qb, kb = _fa_q(q_ref, rows), k_ref[0, cols]
     s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
+    tile = (rows.stop - rows.start, cols.stop - cols.start)
     keep = None
     if seg is not None:
         segq, seg_ref, k0 = seg
@@ -299,18 +379,23 @@ def _fa_scores(q_ref, k_ref, rect, scale, seg):
         segk = seg_ref[0, 0, pl.ds(k0 + cols.start, cols.stop - cols.start)]
         keep = (segq == segk[None, :]) & (segq != 0)
     if diag is True:
-        r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        r = jax.lax.broadcasted_iota(jnp.int32, tile, 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, tile, 1)
         keep = r >= c if keep is None else (keep & (r > c)) | (r == c)
     elif diag:
         # a tile of the band cut by one of its edges: qpos - kpos is delta
         # at the tile's first element
         delta, window = diag
-        apart = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-            - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + delta
+        apart = jax.lax.broadcasted_iota(jnp.int32, tile, 0) \
+            - jax.lax.broadcasted_iota(jnp.int32, tile, 1) + delta
         band = (apart >= 0) & (apart < window)
         keep = band if keep is None else (keep & band) | (apart == 0)
-    return (s if keep is None else jnp.where(keep, s, NEG_INF)), qb, kb
+    if keep is None:
+        return s, qb, kb
+    if s.shape[0] == tile[0]:
+        return jnp.where(keep, s, NEG_INF), qb, kb
+    heads = s.reshape(-1, *tile)
+    return jnp.where(keep, heads, NEG_INF).reshape(s.shape), qb, kb
 
 
 def _fa_fwd_init(acc, m, l):
@@ -334,26 +419,31 @@ def _fa_fwd_step(q0, k0, q_ref, k_ref, v_ref, acc, m, l, groups, *, scale,
     softmaxed = []
     for g, ss in zip(groups, scores):
         rows = g[0][0]
-        m_prev = m[rows]
+        m_prev = _fa_get(m, rows)
         m_new = functools.reduce(
             jnp.maximum, [s.max(axis=-1, keepdims=True) for s in ss], m_prev)
         corr = jnp.exp(m_prev - m_new)
         ps = [jnp.exp(s - m_new) for s in ss]
-        l[rows] = l[rows] * corr + sum(
-            p.sum(axis=-1, keepdims=True) for p in ps)
-        m[rows] = m_new
+        _fa_put(l, rows, _fa_get(l, rows) * corr + sum(
+            p.sum(axis=-1, keepdims=True) for p in ps))
+        _fa_put(m, rows, m_new)
         softmaxed.append((corr, [p.astype(v_ref.dtype) for p in ps]))
     for g, (corr, ps) in zip(groups, softmaxed):
         rows = g[0][0]
-        acc[rows] = acc[rows] * corr + sum(
+        _fa_put(acc, rows, _fa_get(acc, rows) * corr + sum(
             jax.lax.dot_general(p, v_ref[0, cols], (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-            for p, (_, cols, _) in zip(ps, g))
+            for p, (_, cols, _) in zip(ps, g)))
 
 
 def _fa_fwd_emit(i, o_ref, lse_ref, acc, m, l, bq):
     o_ref[0] = (acc[...] / l[...]).astype(o_ref.dtype)
-    lse_ref[0, 0, pl.ds(i * bq, bq)] = (m[...] + jnp.log(l[...]))[:, 0]
+    lse = m[...] + jnp.log(l[...])
+    if lse.ndim == 2:
+        lse_ref[0, 0, pl.ds(i * bq, bq)] = lse[:, 0]
+    else:  # the block's heads' rows together (_fa_rows)
+        n = lse.shape[0] * bq
+        lse_ref[0, 0, pl.ds(i * n, n)] = lse.reshape(n, 1)[:, 0]
 
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l,
@@ -376,21 +466,23 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l,
 def _fa_p_ds(q_ref, k_ref, v_ref, do_ref, rect, lse, delta, seg, *, scale):
     """Recompute p and ds for one rectangle of a block pair — the SINGLE
     copy of the backward score math, shared by dq/dkv in both grid forms.
-    ``lse`` and ``delta`` are the query block's columns (_fa_col)."""
+    ``lse`` and ``delta`` are the query block's columns (_fa_rows_col)."""
     rows, cols, _ = rect
     s, qb, kb = _fa_scores(q_ref, k_ref, rect, scale, seg)
-    p = jnp.exp(s - lse[rows])
-    dob = do_ref[0, rows]
+    p = jnp.exp(s - _fa_get(lse, rows))
+    dob = _fa_q(do_ref, rows)
     dp = jax.lax.dot_general(dob, v_ref[0, cols], (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[rows]) * scale
+    ds = p * (dp - _fa_get(delta, rows)) * scale
     return p, ds, dob, qb, kb
 
 
 def _fa_bwd_rects(q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                  groups, *, scale, seg_ref):
-    """p and ds of every rectangle of a block, one after the other."""
-    lse, delta = _fa_col(lse_ref, q0, q_ref), _fa_col(delta_ref, q0, q_ref)
+                  groups, *, scale, seg_ref, cols):
+    """p and ds of every rectangle of a block, one after the other; ``cols``
+    the two column scratches of a block of several heads."""
+    lse, delta = (_fa_rows_col(r, q0, q_ref, buf) for r, buf in
+                  zip((lse_ref, delta_ref), cols or (None, None)))
     seg = _fa_seg(seg_ref, q0, k0, q_ref)
     for rect in itertools.chain.from_iterable(groups):
         yield rect, _fa_p_ds(q_ref, k_ref, v_ref, do_ref, rect, lse, delta,
@@ -398,13 +490,14 @@ def _fa_bwd_rects(q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fa_dq_step(q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_acc, groups, *, scale, seg_ref=None):
+                dq_acc, groups, *, scale, seg_ref=None, cols=()):
     for rect, (_, ds, _, _, kb) in _fa_bwd_rects(
             q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, groups,
-            scale=scale, seg_ref=seg_ref):
-        dq_acc[rect[0]] += jax.lax.dot_general(
+            scale=scale, seg_ref=seg_ref, cols=cols):
+        rows = rect[0]
+        _fa_put(dq_acc, rows, _fa_get(dq_acc, rows) + jax.lax.dot_general(
             ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32))
 
 
 def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -425,10 +518,10 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _fa_dkv_step(q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_acc, dv_acc, groups, *, scale, seg_ref=None):
+                 dk_acc, dv_acc, groups, *, scale, seg_ref=None, cols=()):
     for rect, (p, ds, dob, qb, _) in _fa_bwd_rects(
             q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, groups,
-            scale=scale, seg_ref=seg_ref):
+            scale=scale, seg_ref=seg_ref, cols=cols):
         dv_acc[rect[1]] += jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -531,7 +624,7 @@ def _fa_fwd_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, *refs, scale,
 def _fa_dq_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, *refs, scale, plan, seg):
     seg_ref = refs[0] if seg else None
-    dq_ref, dq_acc = refs[seg:]
+    dq_ref, dq_acc, *cols = refs[seg:]
     t = pl.program_id(1)
     i, j = ii_ref[t], jj_ref[t]
     jlast = (i * plan.bq + plan.bq - 1) // plan.bk
@@ -542,7 +635,7 @@ def _fa_dq_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     _fa_block_cases(i, j, plan, "q", functools.partial(
         _fa_dq_step, i * plan.bq, j * plan.bk, q_ref, k_ref, v_ref, do_ref,
-        lse_ref, delta_ref, dq_acc, scale=scale, seg_ref=seg_ref))
+        lse_ref, delta_ref, dq_acc, scale=scale, seg_ref=seg_ref, cols=cols))
 
     @pl.when(j == jlast)
     def _():
@@ -552,34 +645,80 @@ def _fa_dq_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _fa_dkv_kernel_tri(ii_ref, jj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                        delta_ref, *refs, scale, plan, seg):
     seg_ref = refs[0] if seg else None
-    dk_ref, dv_ref, dk_acc, dv_acc = refs[seg:]
+    dk_ref, dv_ref, dk_acc, dv_acc, *cols = refs[seg:]
     t = pl.program_id(1)
     i, j = ii_ref[t], jj_ref[t]
     ifirst = (j * plan.bk) // plan.bq
 
-    @pl.when(i == ifirst)
+    @pl.when(_fa_sub_edge(i == ifirst, plan, 0))
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     _fa_block_cases(i, j, plan, "k", functools.partial(
         _fa_dkv_step, i * plan.bq, j * plan.bk, q_ref, k_ref, v_ref, do_ref,
-        lse_ref, delta_ref, dk_acc, dv_acc, scale=scale, seg_ref=seg_ref))
+        lse_ref, delta_ref, dk_acc, dv_acc, scale=scale, seg_ref=seg_ref,
+        cols=cols))
 
-    @pl.when(i == _fa_ilast(j, plan, lse_ref.shape[2] // plan.bq))
+    n_q = lse_ref.shape[2] // (plan.g * plan.bq)
+
+    @pl.when(_fa_sub_edge(i == _fa_ilast(j, plan, n_q), plan, plan.subs - 1))
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _fa_tri_specs(s_len, d, bq, bk):
-    """Block specs for the (nbh, T) triangular grid: index maps read the
-    live pair arrays from scalar prefetch (convention: index_map(*grid,
-    *scalar_refs))."""
-    q_spec = pl.BlockSpec((1, bq, d), lambda b, t, ii, jj: (b, ii[t], 0))
-    k_spec = pl.BlockSpec((1, bk, d), lambda b, t, ii, jj: (b, jj[t], 0))
-    row_spec = pl.BlockSpec((1, 1, s_len), lambda b, t, ii, jj: (b, 0, 0))
-    return q_spec, k_spec, row_spec
+def _fa_sub_edge(at, plan, u):
+    """``at`` and, where a KV head's group takes ``plan.subs`` steps on a
+    block pair (the dkv grid's innermost axis), sub-group ``u``'s step."""
+    if plan.subs == 1:
+        return at
+    return at & (pl.program_id(2) == u)
+
+
+def _fa_tri_specs(s_len, d, plan, sub_axis=False):
+    """Block specs for the (n, T) triangular grid, n the q-side blocks'
+    head blocks: index maps read the live pair arrays from scalar prefetch
+    (convention: index_map(*grid, *scalar_refs)).  A q-side block holds
+    ``plan.g`` heads (:func:`_fa_q`); K/V blocks are read at ``n //
+    plan.subs``.  The dkv grid of a group of several sub-groups is ``(n /
+    subs, T, subs)`` (``sub_axis``).  Returns the q, K/V, row (lse, delta:
+    ``g`` heads' rows, :func:`_fa_rows`) and segment-row specs."""
+    bq, bk, g, subs = plan.bq, plan.bk, plan.g, plan.subs
+    if g == subs == 1:
+        q_spec = pl.BlockSpec((1, bq, d), lambda b, t, ii, jj: (b, ii[t], 0))
+        k_spec = pl.BlockSpec((1, bk, d), lambda b, t, ii, jj: (b, jj[t], 0))
+        row_spec = pl.BlockSpec((1, 1, s_len), lambda b, t, ii, jj: (b, 0, 0))
+        return q_spec, k_spec, row_spec, row_spec
+    if sub_axis:
+        def head(b, t, u):
+            return b * subs + u
+
+        def kv(b, t, u):
+            return b
+    else:
+        def head(b, t):
+            return b
+
+        def kv(b, t):
+            return b // subs
+
+    def at_q(*ix):
+        (*grid, ii, _), t = ix, ix[1]
+        return (head(*grid), ii[t], 0) if g == 1 else \
+            (head(*grid), 0, ii[t], 0)
+
+    def at_kv(*ix):
+        (*grid, _, jj), t = ix, ix[1]
+        return kv(*grid), jj[t], 0
+
+    def at_row(*ix):
+        return head(*ix[:-2]), 0, 0
+
+    q_spec = pl.BlockSpec((1, bq, d) if g == 1 else (1, g, bq, d), at_q)
+    return (q_spec, pl.BlockSpec((1, bk, d), at_kv),
+            pl.BlockSpec((1, 1, g * s_len), at_row),
+            pl.BlockSpec((1, 1, s_len), at_row))
 
 
 def _fa_specs(nbh, s_len, d, bq, bk):
@@ -601,24 +740,32 @@ def _fa_tri_call(kernel, nbh, s_len, d, dtype, scale, seg, interpret, plan):
     warm compile cache (PERF.md section 6, PR 25).  Dead above-diagonal
     blocks are not in the grid, so neither their k/v DMA nor their program
     overhead is paid; also runs under interpret, so the CPU parity tests
-    cover this path."""
-    bq, bk = plan.bq, plan.bk
-    q_spec, k_spec, row_spec = _fa_tri_specs(s_len, d, bq, bk)
-    x = jax.ShapeDtypeStruct((nbh, s_len, d), dtype)
-    row = jax.ShapeDtypeStruct((nbh, 1, s_len), jnp.float32)
+    cover this path.  ``nbh`` counts the q-side blocks' head blocks, of
+    ``plan.g`` heads each (:func:`_fa_tri_specs`)."""
+    bq, bk, g, subs = plan.bq, plan.bk, plan.g, plan.subs
+    sub_axis = kernel == "dkv" and subs > 1
+    q_spec, k_spec, row_spec, seg_spec = _fa_tri_specs(s_len, d, plan,
+                                                       sub_axis)
+    qs = (bq,) if g == 1 else (g, bq)
+    x = jax.ShapeDtypeStruct(
+        (nbh, s_len, d) if g == 1 else (nbh, g, s_len, d), dtype)
+    kx = jax.ShapeDtypeStruct((nbh // subs, s_len, d), dtype)
+    row = jax.ShapeDtypeStruct((nbh, 1, g * s_len), jnp.float32)
+    cols = ((g * bq, 1),) * 2 if g > 1 else ()  # lse's, delta's column
     body, n_in, out_specs, out_shape, scratch = {
         "fwd": (_fa_fwd_kernel_tri, 0, [q_spec, row_spec], [x, row],
-                ((bq, d), (bq, 1), (bq, 1))),
-        "dq": (_fa_dq_kernel_tri, 3, q_spec, x, ((bq, d),)),
-        "dkv": (_fa_dkv_kernel_tri, 3, [k_spec, k_spec], [x, x],
-                ((bk, d), (bk, d))),
+                (qs + (d,), qs + (1,), qs + (1,))),
+        "dq": (_fa_dq_kernel_tri, 3, q_spec, x, (qs + (d,),) + cols),
+        "dkv": (_fa_dkv_kernel_tri, 3, [k_spec, k_spec], [kx, kx],
+                ((bk, d), (bk, d)) + cols),
     }[kernel]
     in_specs = [q_spec, k_spec, k_spec] + [q_spec, row_spec, row_spec][:n_in]
+    pairs = len(_fa_live_pairs(s_len // bq, s_len // bk, bq, bk,
+                               plan.window))
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(nbh, len(_fa_live_pairs(s_len // bq, s_len // bk, bq, bk,
-                                      plan.window))),
-        in_specs=in_specs + [row_spec] * seg, out_specs=out_specs,
+        grid=(nbh // subs, pairs, subs) if sub_axis else (nbh, pairs),
+        in_specs=in_specs + [seg_spec] * seg, out_specs=out_specs,
         scratch_shapes=_scratch(*scratch))
     return pl.pallas_call(
         functools.partial(body, scale=scale, plan=plan, seg=seg),
@@ -626,22 +773,62 @@ def _fa_tri_call(kernel, nbh, s_len, d, dtype, scale, seg, interpret, plan):
 
 
 def _fa_tri(kernel, args, scale, interpret, seg3, window=0):
-    """Run one triangular-grid kernel on (nbh, s, d) operands ``args`` (the
-    first is q) and, for the segmented form, the (nbh, 1, s) segment row;
-    with a ``window``, on the band's grid."""
-    nbh, s_len, d = args[0].shape
-    plan = _fa_plan(s_len, d, kernel, window)
+    """Run one triangular-grid kernel on operands ``args`` in the kernels'
+    layout (q first, then K, V: :func:`_fa_in`) and, for the segmented
+    form, the (n, 1, s) segment row; with a ``window``, on the band's
+    grid."""
+    q3 = args[0]
+    nbh, s_len, d = q3.shape[0], q3.shape[-2], q3.shape[-1]
+    plan = _fa_tri_plan(kernel, q3, args[1], window)
+    assert q3.ndim == (3 if plan.g == 1 else 4), (q3.shape, plan)
     segs = [] if seg3 is None else [seg3]
     ii, jj = _fa_tri_pairs(s_len // plan.bq, s_len // plan.bk, plan.bq,
                            plan.bk, "ji" if kernel == "dkv" else "ij",
                            window)
-    return _fa_tri_call(kernel, nbh, s_len, d, args[0].dtype, scale,
+    return _fa_tri_call(kernel, nbh, s_len, d, q3.dtype, scale,
                         len(segs), interpret, plan)(ii, jj, *args, *segs)
+
+
+def _fa_tri_plan(kernel, q3, k3, window):
+    """The plan of one causal kernel on q and K in the kernels' layout."""
+    return _fa_plan(q3.shape[-2], q3.shape[-1], kernel, window,
+                    q3.size // k3.size)
+
+
+def _fa_rows(x, bq):
+    """Per-head rows ``(n, g, s)`` of blocks of ``g`` heads (lse, delta) in
+    the kernels' layout ``(n, 1, g s)``: query block after query block, each
+    the ``g`` heads' ``bq`` rows one head after the other, so that a
+    block's column is one slice (:func:`_fa_rows_col`)."""
+    n, g, s_len = x.shape
+    return x.reshape(n, g, s_len // bq, bq).transpose(0, 2, 1, 3).reshape(
+        n, 1, g * s_len)
+
+
+def _fa_in(x, g=1):
+    """``(b, n, s, d)`` in the kernels' layout: ``(b n, s, d)``, or of
+    blocks of ``g`` heads ``(b n / g, g, s, d)`` (a q-side array of a plan
+    that stacks heads); free, the order of the elements is kept."""
+    b, n, s_len, d = x.shape
+    if g == 1:
+        return x.reshape(b * n, s_len, d)
+    return x.reshape(b * n // g, g, s_len, d)
+
+
+def _fa_step_heads(q, k, causal, window):
+    """The heads a grid step of one call's causal kernels takes (the
+    plan's ``g``, which the three kernels share), which sets the layout of
+    the call's q-side arrays, lse and delta; 1 on the full grid."""
+    if not causal:
+        return 1
+    return _fa_plan(q.shape[2], q.shape[3], "fwd", window,
+                    q.shape[1] // k.shape[1]).g
 
 
 def _fa_fwd(q3, k3, v3, scale, causal, interpret, seg3=None, window=0):
     if causal:
         return _fa_tri("fwd", (q3, k3, v3), scale, interpret, seg3, window)
+    assert k3.shape == q3.shape, "the full grid takes K and V at q's heads"
     nbh, s_len, d = q3.shape
     bq, bk = _fa_blocks(s_len, d)
     q_spec, k_spec, row_spec = _fa_specs(nbh, s_len, d, bq, bk)
@@ -661,14 +848,18 @@ def _fa_fwd(q3, k3, v3, scale, causal, interpret, seg3=None, window=0):
 
 def _fa_bwd(q3, k3, v3, o3, lse, g3, scale, causal, interpret, seg3=None,
             window=0):
-    nbh, s_len, d = q3.shape
     delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1)[:, None, :]  # (nbh, 1, s)
+                    axis=-1)
+    if g3.ndim == 3:
+        delta = delta[:, None, :]  # (nbh, 1, s)
+    else:  # of blocks of g heads, in lse's layout
+        delta = _fa_rows(delta, _fa_tri_plan("dq", q3, k3, window).bq)
     if causal:
         args = (q3, k3, v3, g3, lse, delta)
         dq = _fa_tri("dq", args, scale, interpret, seg3, window)
         dk, dv = _fa_tri("dkv", args, scale, interpret, seg3, window)
         return dq, dk, dv
+    nbh, s_len, d = q3.shape
     bq, bk = _fa_blocks(s_len, d)
     q_spec, k_spec, row_spec = _fa_specs(nbh, s_len, d, bq, bk)
     dq = pl.pallas_call(
@@ -704,6 +895,11 @@ def flash_attention(q, k, v, causal: bool = False,
                     window: int = 0):
     """Flash attention, (b, h, s, d) -> (b, h, s, d).
 
+    With ``causal``, ``k`` and ``v`` are ``(b, nkv, s, d)`` with ``nkv``
+    dividing ``h``: query head ``i`` reads KV head ``i // (h / nkv)``, a
+    grid step takes ``_fa_heads`` of a KV head's query heads, and the
+    gradients by ``k`` and ``v`` are at ``nkv`` heads, summed over the
+    group inside the dkv kernel; the full grid takes them at ``h``.
     Requires s divisible by 128 (use ``flash_attention_available``);
     ``interpret`` defaults to off-TPU detection so tests run on CPU.
     ``window > 0`` (with ``causal``): query i sees keys i - window + 1 ..
@@ -738,7 +934,8 @@ def _fa_saved(o3, lse):
 
 def flash_saved(q):
     """``(name, shape, dtype)`` of the two tensors a flash forward on ``q``
-    ``(b, h, s, d)`` names, for a loop's account of what a pass keeps."""
+    ``(b, h, s, d)`` names, for a loop's account of what a pass keeps (in
+    the one-head layout: a plan that stacks heads keeps the same bytes)."""
     b, h, s_len, d = q.shape
     return [(FLASH_O, (b * h, s_len, d), q.dtype),
             (FLASH_LSE, (b * h, 1, s_len), jnp.float32)]
@@ -746,21 +943,18 @@ def flash_saved(q):
 
 def _flash_fwd_res(q, k, v, causal, scale, interpret, window=0):
     scale, interpret = _norm_args(q, causal, scale, interpret)
-    b, h, s_len, d = q.shape
-    sh3 = (b * h, s_len, d)
-    o3, lse = _fa_saved(*_fa_fwd(q.reshape(sh3), k.reshape(sh3),
-                                 v.reshape(sh3), scale, causal, interpret,
-                                 window=window))
+    heads = _fa_step_heads(q, k, causal, window)
+    o3, lse = _fa_saved(*_fa_fwd(_fa_in(q, heads), _fa_in(k), _fa_in(v),
+                                 scale, causal, interpret, window=window))
     return o3.reshape(q.shape), (q, k, v, o3, lse)
 
 
 def _flash_bwd_res(causal, scale, interpret, window, res, g):
     q, k, v, o3, lse = res
     scale, interpret = _norm_args(q, causal, scale, interpret)
-    b, h, s_len, d = q.shape
-    sh3 = (b * h, s_len, d)
-    dq, dk, dv = _fa_bwd(q.reshape(sh3), k.reshape(sh3), v.reshape(sh3),
-                         o3, lse, g.reshape(sh3), scale, causal, interpret,
+    heads = _fa_step_heads(q, k, causal, window)
+    dq, dk, dv = _fa_bwd(_fa_in(q, heads), _fa_in(k), _fa_in(v), o3, lse,
+                         _fa_in(g, heads), scale, causal, interpret,
                          window=window)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
@@ -769,8 +963,8 @@ flash_attention.defvjp(_flash_fwd_res, _flash_bwd_res)
 
 
 def _seg_tile(seg, h):
-    """(b, s) segment ids -> the kernels' (b*h, 1, s) int32 layout
-    (b-major, matching ``q.reshape(b*h, s, d)``)."""
+    """(b, s) segment ids -> the kernels' (b*h, 1, s) int32 layout, ``h``
+    the head blocks of a row (b-major, matching :func:`_fa_in`)."""
     return jnp.repeat(seg.astype(jnp.int32)[:, None, :], h, axis=0)
 
 
@@ -782,23 +976,20 @@ def _flash_seg(q, k, v, seg, scale, interpret, window):
 
 def _flash_seg_fwd_res(q, k, v, seg, scale, interpret, window):
     scale, interpret = _norm_args(q, True, scale, interpret)
-    b, h, s_len, d = q.shape
-    sh3 = (b * h, s_len, d)
-    seg3 = _seg_tile(seg, h)
-    o3, lse = _fa_saved(*_fa_fwd(q.reshape(sh3), k.reshape(sh3),
-                                 v.reshape(sh3), scale, True, interpret,
-                                 seg3, window))
+    heads = _fa_step_heads(q, k, True, window)
+    seg3 = _seg_tile(seg, q.shape[1] // heads)
+    o3, lse = _fa_saved(*_fa_fwd(_fa_in(q, heads), _fa_in(k), _fa_in(v),
+                                 scale, True, interpret, seg3, window))
     return o3.reshape(q.shape), (q, k, v, seg, o3, lse)
 
 
 def _flash_seg_bwd_res(scale, interpret, window, res, g):
     q, k, v, seg, o3, lse = res
     scale, interpret = _norm_args(q, True, scale, interpret)
-    b, h, s_len, d = q.shape
-    sh3 = (b * h, s_len, d)
-    dq, dk, dv = _fa_bwd(q.reshape(sh3), k.reshape(sh3), v.reshape(sh3),
-                         o3, lse, g.reshape(sh3), scale, True, interpret,
-                         _seg_tile(seg, h), window)
+    heads = _fa_step_heads(q, k, True, window)
+    dq, dk, dv = _fa_bwd(_fa_in(q, heads), _fa_in(k), _fa_in(v), o3, lse,
+                         _fa_in(g, heads), scale, True, interpret,
+                         _seg_tile(seg, q.shape[1] // heads), window)
     import numpy as _np
     dseg = _np.zeros(seg.shape, jax.dtypes.float0)  # int input: no tangent
     return (dq.reshape(q.shape), dk.reshape(k.shape),
@@ -811,7 +1002,8 @@ _flash_seg.defvjp(_flash_seg_fwd_res, _flash_seg_bwd_res)
 def flash_attention_segmented(q, k, v, seg, scale=None, interpret=None,
                               window=0):
     """Segment-masked causal flash attention, (b, h, s, d) + (b, s) int
-    segment ids -> (b, h, s, d).
+    segment ids -> (b, h, s, d); ``k``, ``v`` ``(b, nkv, s, d)`` as in
+    :func:`flash_attention`.
 
     Block-diagonal causal masking for packed documents (segment 0 =
     padding; the diagonal is always allowed — see ``_fa_scores``).

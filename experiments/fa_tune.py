@@ -14,7 +14,19 @@ computed-over-live area ratio beside each time.  The winner is the rule in
 ``blocks``: the (bq, bk) block sizes for both head geometries of d2048
 (h32/dh64 and h16/dh128) at one shape, as in round 5 (``_fa_blocks``).
 
-Usage: python experiments/fa_tune.py [strips|blocks] [s_len,batch ...]
+``heads``: the query heads ``g`` of one KV group a grid step takes at the
+blocks the shapes give (``pk._fa_heads``; the rest of the group takes more
+steps on the same K/V block), at the grouped shapes the language-model
+cells run, segmented, one row: s8192 d128 with a window of 512 and 64 heads
+over 8 (``laguna_s8192_docmask_b1``'s window layers), s8192 d128 with 48
+over 8 (its full layers), s8192 d64 with 32 over 8
+(``lfm2moe_s8192_docmask_b1``, ``granite4h_docmask_b1``).  Each shape is
+timed with K and V repeated to the query heads in front of the kernels (as
+before the kernels took K/V at their own head count), then at every ``g``
+tried (``pk._FA_SCORES`` set to let exactly that many in); ``all`` is the
+device time of every operation of the pass, XLA's repeat included.
+
+Usage: python experiments/fa_tune.py [strips|blocks|heads] [s_len,batch ...]
 """
 import glob
 import os
@@ -70,13 +82,15 @@ def measure(fn, *args):
         path, = glob.glob(os.path.join(tdir, "plugins/profile/*/*.xplane.pb"))
         plane = next(p for p in jax.profiler.ProfileData.from_file(path).planes
                      if p.name.startswith("/device:TPU:0"))
-        ns = dict.fromkeys(KERNELS, 0.0)
+        ns = dict.fromkeys(KERNELS + ("all",), 0.0)
         for line in plane.lines:
             if line.name == "XLA Ops":
                 for ev in line.events:
                     kern = _kernel_of(ev.name)
                     if kern:
                         ns[kern] += ev.duration_ns
+                    if " while(" not in ev.name:  # the loop holds the rest
+                        ns["all"] += ev.duration_ns
         return {k: v / 1e6 / ITERS for k, v in ns.items()}
     finally:
         shutil.rmtree(tdir, ignore_errors=True)
@@ -121,7 +135,8 @@ def time_kinds(label, b, h, s_len, d, area):
             cols = "  ".join(f"{kn} {ms[kn]:6.3f} ({i / ms[kn] * 100:4.1f}%)"
                              for kn, i in zip(KERNELS, ideal))
             print(f"b{b} h{h} s{s_len} d{d} {label} area {area} "
-                  f"{kind:9s}: {cols}  fwd+bwd {sum(ms.values()):6.3f} ms  "
+                  f"{kind:9s}: {cols}  fwd+bwd "
+                  f"{sum(ms[kn] for kn in KERNELS):6.3f} ms  "
                   f"[{time.time() - t0:.0f} s]", flush=True)
         except Exception as e:
             print(f"b{b} h{h} s{s_len} d{d} {label} {kind}: FAILED "
@@ -183,9 +198,78 @@ def sweep_blocks(shapes):
             pk._fa_blocks = base_blocks
 
 
+GROUPED = (  # (name, h, nkv, s, d, window, the heads a step tried)
+    ("band", 64, 8, 8192, 128, 512, (1, 2, 4, 8)),
+    ("triangle", 48, 8, 8192, 128, 0, (1, 2, 3, 6)),
+    ("triangle", 32, 8, 8192, 64, 0, (1, 2, 4)),
+)
+
+
+def make_train_kv(attn):
+    """``make_train`` with K and V at their own head count: each pass's
+    cotangents feed the next pass's q, k and v."""
+    def train(q, k, v, g):
+        def body(_, qkv):
+            out, vjp = jax.vjp(attn, *qkv)
+            dq, dk, dv = vjp(g)
+            qc, kc, vc = qkv
+            return ((dq + out * 0.5).astype(qc.dtype),
+                    (kc + dk * 0.25).astype(kc.dtype),
+                    (vc + dv * 0.125).astype(vc.dtype))
+        q, k, v = jax.lax.fori_loop(0, ITERS, body, (q, k, v))
+        return (q.sum() + k.sum() + v.sum()).astype(jnp.float32)
+    return jax.jit(train)
+
+
+def sweep_heads(_shapes):
+    base = pk._FA_SCORES
+    for name, h, nkv, s_len, d, window, tried in GROUPED:
+        group = h // nkv
+        q, _, _, g, seg = make_inputs(1, h, s_len, d)
+        k, v = (x[:, :nkv] for x in make_inputs(1, h, s_len, d)[1:3])
+        rule = pk._fa_plan(s_len, d, "fwd", window, group)
+        live = sum(min(i + 1, window or s_len) for i in range(s_len))
+        ideal = [4 * h * live * d / 197e12 * 1e3 * f for f in (1, 1.5, 2)]
+        print(f"{name} h{h} over {nkv} s{s_len} d{d} window {window}: "
+              f"blocks {rule.bq} x {rule.bk}, the rule's g {rule.g}; least "
+              f"MXU ms of the live "
+              f"scores fwd / dq / dkv "
+              + " / ".join(f"{x:.3f}" for x in ideal), flush=True)
+
+        def attn(q, k, v, w=window):
+            return pk.flash_attention_segmented(q, k, v, seg, window=w)
+
+        def repeated(q, k, v, w=window):
+            k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+            return pk.flash_attention_segmented(q, k, v, seg, window=w)
+
+        for label, fn, heads in [("K/V repeated", repeated, None)] + [
+                (f"g {hg}" + " (rule)" * (hg == rule.g), attn, hg)
+                for hg in tried]:
+            if heads is not None:
+                pk._FA_SCORES = heads * rule.bq * rule.bk
+            try:
+                jax.clear_caches()
+                pk._fa_tri_call.cache_clear()
+                t0 = time.time()
+                ms = measure(make_train_kv(fn), q, k, v, g)
+                cols = "  ".join(f"{kn} {ms[kn]:6.3f}" for kn in KERNELS)
+                print(f"  {label:22s}: {cols}  kernels "
+                      f"{sum(ms[kn] for kn in KERNELS):7.3f}  all "
+                      f"{ms['all']:7.3f} ms  [{time.time() - t0:.0f} s]",
+                      flush=True)
+            except Exception as e:
+                print(f"  {label:22s}: FAILED "
+                      f"{str(e).splitlines()[0][:160]}", flush=True)
+            finally:
+                pk._FA_SCORES = base
+
+
 def main():
     args = sys.argv[1:]
-    mode = args.pop(0) if args and args[0] in ("strips", "blocks") else "strips"
+    modes = {"strips": sweep_strips, "blocks": sweep_blocks,
+             "heads": sweep_heads}
+    mode = args.pop(0) if args and args[0] in modes else "strips"
     shapes = [tuple(int(t) for t in a.split(",")) for a in args] or (
         [(2048, 8), (4096, 4)] if mode == "strips" else [(4096, 4)])
     assert jax.default_backend() == "tpu", "run on TPU"
@@ -194,7 +278,7 @@ def main():
           f"{dev.platform} {dev.device_kind} x{jax.device_count()} jax "
           f"{jax.__version__}; ms a call of b x h heads, device time, "
           f"(share of the MXU-bound least time)", flush=True)
-    (sweep_strips if mode == "strips" else sweep_blocks)(shapes)
+    modes[mode](shapes)
 
 
 if __name__ == "__main__":

@@ -55,3 +55,18 @@ def assert_f32_roundoff(got, want, what="", units=8):
     tol = units * np.finfo(np.float32).eps * np.abs(want).max()
     err = np.abs(got.astype(np.float64) - want).max()
     assert err <= tol, f"{what}: off by {err:.3e}, allowed {tol:.3e}"
+
+
+def eqns_outside_kernels(jaxpr):
+    """Every equation of a jaxpr and of its sub-jaxprs, but a Pallas
+    kernel's body."""
+    from jax.extend.core import Jaxpr
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else [p]:
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, Jaxpr):
+                    yield from eqns_outside_kernels(sub)

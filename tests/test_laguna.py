@@ -27,6 +27,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 from benchmark.lib import cells, moecheck  # noqa: E402
+from helpers import eqns_outside_kernels  # noqa: E402
 
 REF = cells.load_module("reference", "laguna-xs.2.py")
 CONF = cells.load_module("configs", "laguna-xs.2.py")
@@ -375,6 +376,46 @@ def test_the_band_kernels_against_a_masked_softmax(window, segmented):
                                    atol=1e-5 * float(jnp.abs(theirs).max()))
 
 
+@pytest.mark.parametrize("h,nkv,heads,g", [
+    (2, 2, None, 1),   # one query head a KV head: the one-head kernels
+    (4, 2, None, 2),
+    (8, 2, None, 4),
+    (8, 1, None, 8),   # the window layers' group of 8, all in one step
+    (8, 1, 2, 2),      # 2 heads a step: four steps on a K/V block
+    (4, 1, 1, 1),      # one head a step, K/V read at head // 4
+])
+def test_the_band_kernels_take_kv_heads_of_their_own(monkeypatch, h, nkv,
+                                                     heads, g):
+    """K/V at ``(b, nkv, s, hd)`` on the band's grid, ``g`` query heads of
+    a KV group a grid step: forward and the q, k, v gradients against the
+    masked softmax on K/V repeated here, with documents."""
+    b, s, hd, window = 1, 512, 64, 200
+    blocks = pk._fa_band_blocks(s, window)
+    if heads:
+        monkeypatch.setattr(pk, "_FA_SCORES", heads * blocks * blocks)
+    group = h // nkv
+    plan = pk._fa_plan(s, hd, "dkv", window, group)
+    assert (plan.g, plan.subs) == (g, group // g)
+    ks = jax.random.split(jax.random.PRNGKey(h + 10 * nkv), 4)
+    q, gq = (jax.random.normal(key, (b, h, s, hd)) for key in ks[:2])
+    k, v = (jax.random.normal(key, (b, nkv, s, hd)) for key in ks[2:])
+    seg = seg_rows(s)
+
+    def theirs(q, k, v):
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+        return oracle(q, k, v, seg, window, hd ** -0.5)
+
+    got = jax.value_and_grad(lambda *a: (pk.flash_attention_segmented(
+        *a, seg, window=window) * gq).sum(), (0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(lambda *a: (theirs(*a) * gq).sum(),
+                              (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for mine, theirs_ in zip(got[1], want[1]):
+        assert mine.shape == theirs_.shape
+        np.testing.assert_allclose(mine, theirs_, rtol=0,
+                                   atol=1e-5 * float(jnp.abs(theirs_).max()))
+
+
 @pytest.mark.parametrize("s", [2048, 4096, 8192, 16384])
 def test_the_band_grid_grows_as_s_times_window(s):
     """The live block pairs of the band are about ``s W / b^2 + s / b``, not
@@ -630,9 +671,58 @@ def test_the_compile_sites_name_each_layer():
         and sites[1]["yarn_factor"] is None \
         and sites[1]["rope_theta"] == 1e4
     assert all(s["area_ratio"] is None for s in sites)
+    # the lax path takes K/V at the query heads
+    assert all(s["heads_per_step"] is None and s["kv_repeated"]
+               for s in sites)
     assert [s["shared"] for s in t.moe_sites()] == [8, 8, 8]
     diags = t.last_diagnostics()
     assert diags["moe_dropped"] == 0 and diags["moe_local_pairs"] > 0
+
+
+@pytest.mark.parametrize("nkv,heads,per_step", [
+    (1, HEADS, HEADS),          # groups of 3 and 4 query heads, one step
+    (2, [2, 2, 2, 2], [1] * 4),  # as many KV heads as query heads
+])
+def test_the_compile_sites_count_the_heads_a_flash_step_takes(
+        monkeypatch, nkv, heads, per_step):
+    """``attn_sites`` of a toy net traced on the flash kernels (interpret
+    mode; the lowering steered here as a chip would take it, rows of 128):
+    ``heads_per_step`` is the group where K/V heads are shared and 1 where
+    each query head has its own, ``kv_repeated`` false on the band and the
+    triangle; the layer's traced step holds no K or V at the query heads."""
+    monkeypatch.setattr(
+        sequence, "attention_lowering",
+        lambda s, hd, causal, segmented, window=0:
+        "flash_band" if window else "flash_tri")
+    s = 128
+    data, label = packed_batch(s, cuts=((40, 90), (10, 70)))
+    t = make_trainer(hybrid_lm(**dict(SIZES, seq=s, nhead=heads,
+                                      nkvhead=nkv), packed=True))
+    batch = DataBatch(data=data, label=label,
+                      index=np.arange(B, dtype=np.uint32))
+    t.update(batch)
+    sites = t.attn_sites()
+    assert [(x["lowering"], x["heads_per_step"], x["kv_repeated"])
+            for x in sites] == [
+        ("flash_tri" if not x["window"] else "flash_band", n, False)
+        for x, n in zip(sites, per_step)]
+    assert [x["window"] for x in sites] == [0, W, W, 0]
+    assert np.isfinite(t.last_diagnostics()["moe_local_pairs"])
+    # the step repeats no K or V: jnp.repeat's broadcast to (b, nkv, group,
+    # s, hd), which the lax path takes, is not in it
+    def repeats():
+        step = jax.make_jaxpr(lambda p: t._loss_and_grads(
+            p, t.buffers, jnp.asarray(data), jnp.asarray(label), (),
+            jnp.int32(0), t._rng_base, ()))(t.params).jaxpr
+        return [e for e in eqns_outside_kernels(step)
+                if e.primitive.name == "broadcast_in_dim"
+                and e.params["broadcast_dimensions"] == (0, 1, 3, 4)
+                and e.outvars[0].aval.shape[:2] == (B, nkv)
+                and e.outvars[0].aval.shape[3:] == (s, HD)]
+
+    assert repeats() == []
+    monkeypatch.undo()
+    assert len(repeats()) == (8 if nkv == 1 else 0)  # 4 layers, k and v
 
 
 # ------------------------------------------------------------ config files

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from cxxnet_tpu.ops import nn as N
+from helpers import eqns_outside_kernels
 
 
 def _xla_lrn(x, nsize, alpha, beta, knorm):
@@ -578,6 +579,145 @@ def test_fa_strip_rule():
         assert plan.bq % plan.bs == 0 and plan.bk % plan.bs == 0
         assert max(plan.bq, plan.bk) // plan.bs <= 8
         assert 1.0 < plan.area_ratio <= 2.0
+
+
+# the planner's plans before K and V were taken at their own head count:
+# (s, d, kernel, window) -> bq, bk, bs, offsets, interior, crossing, ratio
+_ONE_HEAD_PLANS = {
+    (2048, 128, "fwd", 0): (1024, 1024, 256, (0,), 1, 2, 1.125),
+    (2048, 128, "dkv", 0): (1024, 1024, 512, (0,), 1, 2, 1.25),
+    (4096, 128, "dq", 0): (1024, 1024, 256, (0,), 6, 4, 1.0625),
+    (8192, 64, "fwd", 0): (1024, 1024, 256, (0,), 28, 8, 1.03125),
+    (8192, 64, "dkv", 0): (1024, 1024, 512, (0,), 28, 8, 1.0625),
+    (8192, 128, "fwd", 0): (1024, 1024, 256, (0,), 28, 8, 1.03125),
+    (8192, 128, "fwd", 512): (512, 512, 128, (0, 512), 0, 31,
+                              1.24992124992125),
+    (8192, 128, "dkv", 512): (512, 512, 128, (0, 512), 0, 31,
+                              1.24992124992125),
+    (512, 64, "fwd", 200): (256, 256, 128, (0, 256), 0, 3,
+                            1.7873454545454546),
+}
+
+
+@pytest.mark.parametrize("key", sorted(_ONE_HEAD_PLANS))
+def test_fa_plan_of_one_query_head_a_kv_head_is_the_one_head_plan(key):
+    """Where h == nkv the planner gives the one-head plan: one head a
+    step, one step a block pair, the blocks, strips, cases and area the
+    kernels took before K/V came at their own head count."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    s, d, kernel, window = key
+    plan = pk._fa_plan(s, d, kernel, window, 1)
+    assert plan == pk._fa_plan(s, d, kernel, window)
+    assert (plan.g, plan.subs, plan.window) == (1, 1, window)
+    assert tuple(plan[:6]) == _ONE_HEAD_PLANS[key][:6]
+    assert plan.area_ratio == pytest.approx(_ONE_HEAD_PLANS[key][6])
+    for s, d, kernel in itertools.product((128, 1024, 4096), (64, 128, 256),
+                                          ("fwd", "dq", "dkv")):
+        plan = pk._fa_plan(s, d, kernel, 0, 1)
+        assert (plan.bq, plan.bk) == pk._fa_blocks(s, d)
+        assert plan.bs == pk._fa_strip(s, d, plan.bq, plan.bk, kernel)
+        assert (plan.g, plan.subs) == (1, 1)
+
+
+@pytest.mark.parametrize("s,window,group,g", [
+    (8192, 0, 2, 1),    # 1024-blocks: one head a step, K/V at head // group
+    (8192, 0, 4, 1),    # lfm2moe / granite4h: 32 heads over 8
+    (8192, 0, 6, 1),    # laguna's full layers: 48 over 8
+    (8192, 512, 8, 4),  # laguna's window layers: 4 of 8 heads, 2 steps
+    (8192, 512, 6, 3),
+    (8192, 512, 2, 2),
+    (512, 0, 4, 4),     # 512-blocks: four heads' scores fit a step
+    (512, 0, 8, 4),
+])
+def test_fa_heads_rule_keeps_a_steps_scores(s, window, group, g):
+    """The heads a step: the most of the group whose scores at the blocks
+    the shapes give stay within a one-head 1024-block pair's.  The blocks,
+    strips and cases are the one-head plan's whatever the group: a block
+    is never cut to make room for heads."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    for d in (64, 128):
+        plan = pk._fa_plan(s, d, "fwd", window, group)
+        assert plan._replace(g=1, subs=1) == pk._fa_plan(s, d, "fwd", window)
+        assert (plan.g, plan.subs) == (g, group // g)
+        assert plan.g * plan.bq * plan.bk <= pk._FA_SCORES
+
+
+def _repeat(t, group):
+    return jnp.repeat(t, group, axis=1)
+
+
+@pytest.mark.parametrize("h,nkv,scores,g", [
+    (2, 2, None, 1),            # one query head a KV head
+    (4, 2, None, 2),
+    (8, 2, None, 4),
+    (8, 1, None, 8),
+    (8, 1, 2 * 256 * 256, 2),   # 2 heads a step, 4 steps a K/V block
+    (4, 1, 256 * 256, 1),       # one head a step, 4 steps a K/V block
+])
+def test_flash_attention_kv_heads_of_their_own(monkeypatch, h, nkv, scores,
+                                               g):
+    """K/V at ``(b, nkv, s, d)``: the causal kernels take ``g`` heads of a
+    KV group a step (several blocks a row, strips of 128), forward and
+    the q, k, v gradients against dense attention on K/V repeated here."""
+    from cxxnet_tpu.parallel.ring import dense_attention
+    pk = _force_fa(monkeypatch, (256, 256), 128)
+    if scores:
+        monkeypatch.setattr(pk, "_FA_SCORES", scores)
+    s, d, group = 512, 32, h // nkv
+    plan = pk._fa_plan(s, d, "dkv", 0, group)
+    assert (plan.g, plan.subs) == (g, group // g)
+    rnd = np.random.RandomState(h + nkv)
+    q, gq = (jnp.asarray(rnd.randn(2, h, s, d).astype(np.float32) * 0.5)
+             for _ in range(2))
+    k, v = (jnp.asarray(rnd.randn(2, nkv, s, d).astype(np.float32) * 0.5)
+            for _ in range(2))
+
+    def dense(q, k, v):
+        return dense_attention(q, _repeat(k, group), _repeat(v, group),
+                               causal=True)
+
+    np.testing.assert_allclose(
+        np.asarray(pk.flash_attention(q, k, v, True)),
+        np.asarray(dense(q, k, v)), atol=1e-5)
+    gf = jax.grad(lambda *a: jnp.sum(pk.flash_attention(*a, True) * gq),
+                  argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(dense(*a) * gq), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b, nm in zip(gf, gr, ("dq", "dk", "dv")):
+        assert a.shape == b.shape, nm
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   err_msg=nm)
+
+
+@pytest.mark.parametrize("segmented,window", [
+    (False, 0), (True, 0), (True, 128), (False, 128)])
+def test_flash_path_holds_no_repeated_kv(segmented, window):
+    """For nkv < h the flash path, forward and backward, never makes K or V
+    at the query heads: nothing outside the kernels broadcasts to ``(b, h,
+    s, d)``, the one sum is delta's over the head's channels (none over a
+    group), and the dkv kernel writes dK and dV at ``(b nkv, s, d)``."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    b, h, nkv, s, d = 1, 8, 2, 256, 32
+    q, k = jnp.ones((b, h, s, d)), jnp.ones((b, nkv, s, d))
+    seg = jnp.ones((b, s), jnp.int32)
+
+    def attn(q, k, v):
+        if segmented:
+            return pk.flash_attention_segmented(q, k, v, seg, window=window)
+        return pk.flash_attention(q, k, v, True, window=window)
+
+    jaxpr = jax.make_jaxpr(lambda q, k, v: jax.vjp(attn, q, k, v)[1](q))(
+        q, k, k).jaxpr
+    eqns = list(eqns_outside_kernels(jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 3
+    assert [[o.aval.shape for o in e.outvars] for e in calls].count(
+        [(b * nkv, s, d)] * 2) == 1
+    for e in eqns:
+        if e.primitive.name in ("broadcast_in_dim", "gather", "concatenate"):
+            assert all(o.aval.size < b * h * s * d for o in e.outvars), e
+        if e.primitive.name == "reduce_sum":
+            assert e.params["axes"] == (e.invars[0].aval.ndim - 1,), e
 
 
 def test_layernorm_pallas_matches_xla():

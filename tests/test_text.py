@@ -444,6 +444,59 @@ def test_flash_segment_strips_interpret(monkeypatch, s, blocks, bs, bounds,
                                    rtol=2e-3, atol=2e-4, err_msg=nm)
 
 
+@pytest.mark.parametrize("h,nkv,scores,g", [
+    (2, 2, None, 1),
+    (4, 2, None, 2),
+    (4, 1, None, 4),            # the group of lfm2moe and granite4h
+    (8, 1, None, 8),
+    (8, 1, 2 * 256 * 256, 2),   # 2 heads a step, 4 steps a K/V block
+    (4, 2, 256 * 256, 1),       # one head a step, 2 steps a K/V block
+])
+def test_flash_segment_kv_heads_of_their_own(monkeypatch, h, nkv, scores,
+                                             g):
+    """The segmented kernels with K/V at ``(b, nkv, s, d)`` and ``g`` heads
+    of a KV group a step (several blocks a row, a boundary inside a strip,
+    a padding tail): forward and the q, k, v gradients against the lax
+    fallback on K/V repeated here."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    from cxxnet_tpu.parallel import ring
+    monkeypatch.setattr(pk, "_fa_blocks", lambda s, d=64: (256, 256))
+    monkeypatch.setattr(pk, "_fa_strip", lambda *a: 128)
+    if scores:
+        monkeypatch.setattr(pk, "_FA_SCORES", scores)
+    s, group = 512, h // nkv
+    plan = pk._fa_plan(s, 16, "fwd", 0, group)
+    assert (plan.g, plan.subs) == (g, group // g)
+    rnd = np.random.RandomState(h * nkv)
+    q, gq = (jnp.asarray(rnd.randn(2, h, s, 16).astype(np.float32))
+             for _ in range(2))
+    k, v = (jnp.asarray(rnd.randn(2, nkv, s, 16).astype(np.float32))
+            for _ in range(2))
+    seg = np.ones((2, s), np.int64)
+    for at in (100, 256, 300):
+        seg[:, at:] += 1
+    seg[1, -40:] = 0
+    seg = jnp.asarray(seg)
+
+    def lax_path(q, k, v):
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+        return ring.dense_attention(q, k, v, causal=True, seg=seg)
+
+    np.testing.assert_allclose(
+        np.asarray(pk.flash_attention_segmented(q, k, v, seg,
+                                                interpret=True)),
+        np.asarray(lax_path(q, k, v)), rtol=2e-4, atol=2e-5)
+    g_ref = jax.grad(lambda *a: jnp.sum(lax_path(*a) * gq),
+                     argnums=(0, 1, 2))(q, k, v)
+    g_out = jax.grad(lambda *a: jnp.sum(
+        pk.flash_attention_segmented(*a, seg, interpret=True) * gq),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b_, nm in zip(g_ref, g_out, ("dq", "dk", "dv")):
+        assert a.shape == b_.shape, nm
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-3, atol=2e-4, err_msg=nm)
+
+
 def test_ring_segment_matches_dense():
     """Segment ids rotate around the ring with their K/V blocks; the
     sharded result must match the single-device oracle."""

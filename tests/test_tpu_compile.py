@@ -48,6 +48,40 @@ def test_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, seg):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
+@pytest.mark.parametrize("h,nkv,d,window", [
+    (64, 8, 128, 512),  # laguna_s8192_docmask_b1's window layers: the band
+    (48, 8, 128, 0),    # its full layers: the triangle, a group of 6
+    (32, 8, 64, 0),     # lfm2moe_s8192_docmask_b1, granite4h_docmask_b1
+])
+def test_grouped_flash_kernels_compile_for_v5e(one_chip, h, nkv, d, window):
+    """The segmented kernels with K/V at their own head count, one row of
+    8,192, at the heads a step ``_fa_heads`` gives (the band: 4 heads of 8
+    a step, two steps; the triangles: one head a step, the group's steps on
+    one K/V block): the stacked q-side blocks, the K/V index maps and the
+    dkv kernel's sub-group axis fit the default scoped VMEM, and dK, dV
+    leave the dkv kernel at ``nkv`` heads."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    s = 8192
+    plan = pk._fa_plan(s, d, "fwd", window, h // nkv)
+    assert (plan.g, plan.subs) == ((4, 2) if window else (1, h // nkv))
+    q = jax.ShapeDtypeStruct((1, h, s, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, nkv, s, d), jnp.bfloat16,
+                              sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
+
+    def fwd_bwd(q, k, v, g, ids):
+        out, vjp = jax.vjp(lambda q, k, v: pk.flash_attention_segmented(
+            q, k, v, ids, interpret=False, window=window), q, k, v)
+        return (out,) + vjp(g)
+
+    text = jax.jit(fwd_bwd).lower(q, kv, kv, q, ids).compile().as_text()
+    calls = [ln for ln in text.split("\n")
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 3
+    assert sum(f"(bf16[{nkv},{s},{d}]" in ln.split(" custom-call(")[0]
+               for ln in calls) == 1
+
+
 @pytest.mark.parametrize("rows,d,x_dtype,gain", [
     # ouro26_s4096_loop4_docmask's norm call
     (4096, 2048, jnp.bfloat16, jnp.float32),
