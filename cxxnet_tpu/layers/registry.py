@@ -18,6 +18,7 @@ from .conv import (AvgPoolingLayer, ConvolutionLayer, InsanityPoolingLayer,
                    LRNLayer, MaxPoolingLayer, ReluMaxPoolingLayer,
                    SumPoolingLayer)
 from .fullc import FixConnectLayer, FullConnectLayer
+from .latent import LatentAttentionLayer
 from .loss import L2LossLayer, MultiLogisticLayer, SoftmaxLayer
 from .moe import MoELayer, TopKExpertLayer
 from .norm import BatchNormLayer, DropoutLayer
@@ -49,7 +50,7 @@ for _cls in (ReluLayer, SigmoidLayer, TanhLayer, SoftplusLayer, XeluLayer,
              SeqFullcLayer, AttentionLayer, SoftmaxSeqLayer, MoELayer,
              SiluLayer, EltMulLayer, RMSNormLayer, SeqXentLayer,
              ExitLossLayer, ScaleLayer, Mamba2Layer, TopKExpertLayer,
-             ShortConvLayer):
+             ShortConvLayer, LatentAttentionLayer):
     register(_cls)
 
 

@@ -86,7 +86,7 @@ def _single_device_attention(q, k, v, causal: bool, seg=None, on_flash=None,
         k, v = repeat_kv(q, k, v)
     if lowering != "dense":
         if on_flash is not None:
-            on_flash(pk.flash_saved(q))
+            on_flash(pk.flash_saved(q, v.shape[-1]))
         if seg is not None:
             return pk.flash_attention_segmented(q, k, v, seg, scale,
                                                 window=window)
@@ -157,8 +157,9 @@ def _rotary(q, k, pos, theta: float, dim: int = 0, inv_freq=None,
 
 
 def _head_rms_norm(x, gain, eps: float):
-    """RMSNorm of ``x`` ``(b, h, s, hd)`` over a head's ``hd`` channels with
-    the gain ``(hd,)`` shared by the heads, in float32."""
+    """RMSNorm of ``x`` over its last axis (a head's ``hd`` channels of ``(b,
+    h, s, hd)``, the gain ``(hd,)`` shared by the heads; or a latent's), in
+    float32."""
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.square(x32).mean(-1, keepdims=True) + eps)
     return (y * gain.astype(jnp.float32)).astype(x.dtype)
@@ -180,6 +181,13 @@ class EmbeddingLayer(Layer):
     Params: "wmat" (vocab, d); with ``pos_embed = 1`` also "wpos" (s, d)
     learned positional embeddings (sequence length is static under jit, so
     the table is sized at shape inference).
+
+    ``ids_key = <label field>`` embeds that field's ``(b, s)`` ids in place
+    of the input's (an id < 0, a target packseq marks invalid, takes row 0);
+    a forward without labels embeds the input's.  ``tie = <embedding
+    layer>`` reads that layer's table (``Network._build``): one parameter
+    group, whose gradient is the sum of both uses.  Together they embed the
+    next token a second time, as a multi-token-prediction module does.
     """
 
     type_names = ("embedding",)
@@ -191,6 +199,12 @@ class EmbeddingLayer(Layer):
           help="label field carrying per-position ids (packed documents "
                "reset positions at each doc start — io/text.py); empty = "
                "sequential 0..s-1"),
+        K("ids_key", "str",
+          help="label field whose (b, s) ids are embedded in place of the "
+               "input's (ids < 0 take row 0); empty = the input's"),
+        K("tie", "str",
+          help="name of an embedding layer whose table this one reads; "
+               "empty = a table of its own"),
     )
 
     def __init__(self):
@@ -198,14 +212,16 @@ class EmbeddingLayer(Layer):
         self.vocab_size = 0
         self.pos_embed = 0
         self.pos_key = ""
+        self.ids_key = ""
+        self.tie = ""
 
     def set_param(self, name, val):
         if name == "vocab_size":
             self.vocab_size = int(val)
         elif name == "pos_embed":
             self.pos_embed = int(val)
-        elif name == "pos_key":
-            self.pos_key = val
+        elif name in ("pos_key", "ids_key", "tie"):
+            setattr(self, name, val)
         else:
             super().set_param(name, val)
 
@@ -231,6 +247,9 @@ class EmbeddingLayer(Layer):
     def forward(self, params, buffers, inputs, ctx):
         self.check_n_inputs(inputs, 1)
         ids = inputs[0].reshape(inputs[0].shape[0], -1).astype(jnp.int32)
+        field = _label_field(ctx, self.ids_key)
+        if field is not None:
+            ids = jnp.maximum(field.astype(jnp.int32), 0)
         out = jnp.take(params["wmat"], ids, axis=0)  # (b, s, d)
         if "wpos" in params:
             dec = getattr(ctx, "decode", None)
@@ -386,14 +405,18 @@ class SeqFullcLayer(Layer):
     table as its "wmat" (``Network._build`` points the connection at that
     layer's parameter group), so the table's gradient is the sum of both
     uses and the optimizer keeps one state for it.  Needs ``no_bias = 1``
-    and ``nhidden`` equal to the table's rows.
+    and ``nhidden`` equal to the table's rows.  The named layer may be
+    another ``seq_fullc`` head without a bias and of the same ``nhidden``
+    instead: a second head over one matrix (a multi-token-prediction
+    module's).
     """
 
     type_names = ("seq_fullc",)
     extra_config_keys = (
         K("tie", "str",
-          help="name of the embedding layer whose table this head reads "
-               "(a tied output head); empty = a weight of its own"),
+          help="name of the embedding layer (or seq_fullc head) whose table "
+               "this head reads (a tied output head); empty = a weight of "
+               "its own"),
     )
 
     def __init__(self):
@@ -831,6 +854,17 @@ class SoftmaxSeqLayer(LossLayerBase):
     padding — they contribute zero loss AND zero gradient, and the
     per-instance mean divides by the VALID-token count, so a row's loss
     weight does not depend on how many doc boundaries it packed.
+
+    ``target_offset = k > 1`` (with ``packed = 1``) scores position ``i``
+    against the token ``k`` ahead, ``t_{i+k}``: the target field's entry at
+    ``i + k - 1``, where it and the field's entries ``i .. i + k - 2`` are
+    valid.  ``packseq`` marks a target invalid where the next token leaves
+    the document, so those valid entries put positions ``i + 1 .. i + k``
+    in ``i``'s document; the last ``k - 1`` positions of a row are not
+    scored.  The loss of a multi-token-prediction module, weighted by
+    ``grad_scale``.  Its step diagnostics: ``mtp_valid_targets``, the
+    positions scored, and ``mtp_loss``, the batch mean of the rows' mean
+    cross-entropy, unweighted.
     """
 
     type_names = ("softmax_seq",)
@@ -838,11 +872,15 @@ class SoftmaxSeqLayer(LossLayerBase):
         K("packed", "int", lo=0, hi=1,
           help="mask target ids < 0 (packed-document boundaries/padding) "
                "out of the loss; mean over valid tokens only"),
+        K("target_offset", "int", lo=1,
+          help="score position i against the token this many ahead (the "
+               "target field's entry at i + offset - 1); 1 = the next"),
     )
 
     def __init__(self):
         super().__init__()
         self.packed = 0
+        self.target_offset = 1
         # (b, s, V, the logits' dtype, bytes kept, float32 bytes avoided) of
         # the last training trace: a note of the trace (NetTrainer.loss_sites)
         self.loss_site = None
@@ -850,8 +888,30 @@ class SoftmaxSeqLayer(LossLayerBase):
     def set_param(self, name, val):
         if name == "packed":
             self.packed = int(val)
+        elif name == "target_offset":
+            self.target_offset = int(val)
         else:
             super().set_param(name, val)
+
+    def infer_shapes(self, in_shapes: List[Shape4]) -> List[Shape4]:
+        assert self.target_offset == 1 or self.packed, \
+            "softmax_seq: target_offset > 1 needs packed = 1"
+        return super().infer_shapes(in_shapes)
+
+    def _targets_ahead(self, y):
+        """The ``(b, s)`` targets ``target_offset`` tokens ahead, -1 where
+        position ``i`` scores none (class docstring)."""
+        k = self.target_offset
+        b, s = y.shape
+
+        def ahead(x, j, fill):  # x[:, i + j], ``fill`` past the row's end
+            return jnp.concatenate(
+                [x[:, j:], jnp.full((b, j), fill, x.dtype)], axis=1)
+
+        ok = y >= 0
+        for j in range(1, k):
+            ok = ok & (ahead(y, j, -1) >= 0)
+        return jnp.where(ok, ahead(y, k - 1, -1), -1)
 
     def forward(self, params, buffers, inputs, ctx):
         self.check_n_inputs(inputs, 1)
@@ -862,6 +922,8 @@ class SoftmaxSeqLayer(LossLayerBase):
             # loads the module
             from ..ops.xent import token_xent
             y = ctx.labels.get(self.target)  # (b, s) float ids
+            if self.target_offset > 1:
+                y = self._targets_ahead(y)
             yi = y.astype(jnp.int32)
             b, _, s, v = x.shape
             self.loss_site = (b, s, v, x.dtype.name,
@@ -890,6 +952,12 @@ class SoftmaxSeqLayer(LossLayerBase):
                 # as LossLayerBase (DataBatch.tail_mask_padd)
                 per_inst = per_inst * ctx.labels.mask.astype(per_inst.dtype)
             ctx.losses.append(per_inst.sum() * (self.grad_scale * ctx.loss_scale))
+            if self.target_offset > 1:
+                diag = ctx.diagnostics
+                diag["mtp_valid_targets"] = \
+                    diag.get("mtp_valid_targets", 0) + valid.sum()
+                diag["mtp_loss"] = diag.get("mtp_loss", 0) \
+                    + jax.lax.stop_gradient(per_inst).sum() / b
         return [out], buffers
 
 

@@ -430,6 +430,43 @@ def looped_lm(vocab: int, seq: int, dim: int, nlayer: int, nhead: int,
     return "\n".join(lines) + "\n"
 
 
+def _gated_ffn(m: str, name: str, dim: int, ffn: int) -> List[str]:
+    """The dense gated feed-forward ``W_d(silu(W_g u) * (W_u u))`` from node
+    ``<m>_n`` to ``<m>_o``, its layers named ``<name>ffn_*``."""
+    return [f"layer[{m}_n->{m}_n1,{m}_n2] = split",
+            f"layer[{m}_n1->{m}_g] = seq_fullc:{name}ffn_gate",
+            f"  nhidden = {ffn}",
+            "  no_bias = 1",
+            "layer[+0] = silu",
+            f"layer[{m}_n2->{m}_u] = seq_fullc:{name}ffn_up",
+            f"  nhidden = {ffn}",
+            "  no_bias = 1",
+            f"layer[{m}_g,{m}_u->{m}_h] = eltmul",
+            f"layer[{m}_h->{m}_o] = seq_fullc:{name}ffn_down",
+            f"  nhidden = {dim}",
+            "  no_bias = 1"]
+
+
+def _moe_topk(m: str, name: str, experts: int, held: int, first: int,
+              top_k: int, width: int, score_func: str, bias: bool,
+              bias_rate: float, norm_topk: bool, routed_scale: float,
+              shared: int) -> List[str]:
+    """A ``moe_topk`` layer ``<name>moe`` from node ``<m>_n`` to ``<m>_o``
+    (``hybrid_lm``'s keys)."""
+    return [f"layer[{m}_n->{m}_o] = moe_topk:{name}moe",
+            f"  num_expert = {experts}",
+            f"  expert_held = {held or experts}",
+            f"  expert_first = {first}",
+            f"  top_k = {top_k}",
+            f"  nhidden = {width}",
+            f"  score_func = {score_func}",
+            f"  expert_bias = {int(bias)}",
+            f"  expert_bias_rate = {bias_rate}",
+            f"  norm_topk = {int(norm_topk)}",
+            f"  routed_scale = {routed_scale}",
+            *([f"  shared_nhidden = {shared}"] if shared else [])]
+
+
 def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
               nhead: Union[int, Sequence[int]], nkvhead: int, ffn: int,
               ssm_heads: int = 0, ssm_head_dim: int = 0, ssm_state: int = 0,
@@ -547,33 +584,12 @@ def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
                              "'mamba', 'attention', 'window_attention' and "
                              "'conv'")
         if experts and i >= (dense_layers or 0):
-            feed_forward = [
-                f"layer[{m}_n->{m}_o] = moe_topk:l{i}_moe",
-                f"  num_expert = {experts}",
-                f"  expert_held = {experts_held or experts}",
-                f"  expert_first = {expert_first}",
-                f"  top_k = {experts_per_token}",
-                f"  nhidden = {expert_ffn}",
-                f"  score_func = {score_func}",
-                f"  expert_bias = {int(expert_bias)}",
-                f"  expert_bias_rate = {expert_bias_rate}",
-                f"  norm_topk = {int(norm_topk)}",
-                f"  routed_scale = {routed_scale}",
-                *([f"  shared_nhidden = {shared_ffn}"] if shared_ffn else [])]
+            feed_forward = _moe_topk(
+                m, f"l{i}_", experts, experts_held, expert_first,
+                experts_per_token, expert_ffn, score_func, expert_bias,
+                expert_bias_rate, norm_topk, routed_scale, shared_ffn)
         else:
-            feed_forward = [
-                f"layer[{m}_n->{m}_n1,{m}_n2] = split",
-                f"layer[{m}_n1->{m}_g] = seq_fullc:l{i}_ffn_gate",
-                f"  nhidden = {ffn}",
-                "  no_bias = 1",
-                "layer[+0] = silu",
-                f"layer[{m}_n2->{m}_u] = seq_fullc:l{i}_ffn_up",
-                f"  nhidden = {ffn}",
-                "  no_bias = 1",
-                f"layer[{m}_g,{m}_u->{m}_h] = eltmul",
-                f"layer[{m}_h->{m}_o] = seq_fullc:l{i}_ffn_down",
-                f"  nhidden = {dim}",
-                "  no_bias = 1"]
+            feed_forward = _gated_ffn(m, f"l{i}_", dim, ffn)
         lines += [
             f"layer[x{i}->{a}_r,{a}_in] = split",
             f"layer[{a}_in->{a}_n] = rmsnorm:l{i}_norm1",
@@ -603,6 +619,118 @@ def hybrid_lm(vocab: int, seq: int, dim: int, layer_types: Sequence[str],
     if packed:
         lines += [f"label_vec[{seq},{2 * seq}) = segment",
                   f"label_vec[{2 * seq},{3 * seq}) = position"]
+    return "\n".join(lines) + "\n"
+
+
+def latent_lm(vocab: int, seq: int, dim: int, n_layer: int, nhead: int,
+              q_lora_rank: int, kv_lora_rank: int, qk_nope_dim: int,
+              qk_rope_dim: int, v_head_dim: int, ffn: int, *,
+              rope_theta: float = 10000.0, dense_layers: int = 1,
+              experts: int, experts_held: int = 0, expert_first: int = 0,
+              experts_per_token: int = 1, expert_ffn: int = 0,
+              shared_ffn: int = 0, score_func: str = "sigmoid",
+              expert_bias: bool = False, expert_bias_rate: float = 0.0,
+              norm_topk: bool = True, routed_scale: float = 1.0,
+              eps: float = 1e-6, mtp_weight: float = 0.0) -> str:
+    """Decoder-only LM of multi-head latent attention (DeepSeek-V2/V3;
+    ``layers/latent.py``) and one multi-token-prediction module (DeepSeek-V3,
+    arXiv:2412.19437, section 2.2, depth 1).
+
+    ``h0 = E[tokens]``; layer ``l`` of ``n_layer``: ``a = h +
+    mla(rmsnorm(h))``, ``h' = a + ffn(rmsnorm(a))``, the ffn the dense
+    gated feed-forward of width ``ffn`` in the first ``dense_layers`` and
+    ``moe_topk`` after them (``experts_per_token`` of ``experts`` of width
+    ``expert_ffn``, ``experts_held`` held from ``expert_first`` on, a shared
+    expert of ``shared_ffn``); ``logits = H rmsnorm(h_L)`` over a head of its
+    own, ``softmax_seq``.  ``mtp_weight > 0`` adds the module: the embedding
+    of the next token (the target field, through the same table) and ``h_L``
+    (before the final norm), each normed, concatenated and projected back to
+    ``dim``; one more block of latent attention and ``moe_topk``; its own
+    final norm and the main head's matrix (``tie = head``); ``softmax_seq``
+    on the token two ahead (``target_offset = 2``) at that weight.  The
+    module's layers are named ``mtp_*``.  Rows are packed documents: segment
+    ids mask attention and the losses, rotary positions restart at each
+    document.
+    """
+    assert experts > 0, "latent_lm: the layers after the dense ones are routed"
+
+    def block(x: str, out: str, i, dense: bool) -> List[str]:
+        a, m = f"b{i}a", f"b{i}m"
+        name = "mtp_" if i == "mtp" else f"l{i}_"
+        ff = _gated_ffn(m, name, dim, ffn) if dense else _moe_topk(
+            m, name, experts, experts_held, expert_first, experts_per_token,
+            expert_ffn, score_func, expert_bias, expert_bias_rate, norm_topk,
+            routed_scale, shared_ffn)
+        return [
+            f"layer[{x}->{a}_r,{a}_in] = split",
+            f"layer[{a}_in->{a}_n] = rmsnorm:{name}norm1",
+            f"  eps = {eps}",
+            f"layer[{a}_n->{a}_o] = latent_attention:{name}att",
+            f"  nhead = {nhead}",
+            f"  q_lora_rank = {q_lora_rank}",
+            f"  kv_lora_rank = {kv_lora_rank}",
+            f"  qk_nope_dim = {qk_nope_dim}",
+            f"  qk_rope_dim = {qk_rope_dim}",
+            f"  v_head_dim = {v_head_dim}",
+            f"  rope_theta = {float(rope_theta)}",
+            f"  eps = {eps}",
+            "  segment_key = segment",
+            "  pos_key = position",
+            f"layer[{a}_r,{a}_o->{m}] = eltsum",
+            f"layer[{m}->{m}_r,{m}_in] = split",
+            f"layer[{m}_in->{m}_n] = rmsnorm:{name}norm2",
+            f"  eps = {eps}",
+            *ff,
+            f"layer[{m}_r,{m}_o->{out}] = eltsum"]
+
+    lines = ["netconfig=start",
+             "layer[0->x0] = embedding:embed",
+             f"  vocab_size = {vocab}",
+             f"  nhidden = {dim}",
+             "  init_sigma = 0.02"]
+    for i in range(n_layer):
+        lines += block(f"x{i}", f"x{i + 1}", i, i < dense_layers)
+    last = f"x{n_layer}"
+    if mtp_weight:
+        lines += [f"layer[{last}->{last}_f,{last}_m] = split"]
+        last = f"{last}_f"
+    lines += [f"layer[{last}->fin] = rmsnorm:final_norm",
+              f"  eps = {eps}",
+              "layer[fin->logits] = seq_fullc:head",
+              f"  nhidden = {vocab}",
+              "  no_bias = 1",
+              "layer[+0] = softmax_seq",
+              "  packed = 1"]
+    if mtp_weight:
+        lines += [f"layer[x{n_layer}_m->mtp_hn] = rmsnorm:mtp_hnorm",
+                  f"  eps = {eps}",
+                  "layer[0->mtp_e] = embedding:mtp_embed",
+                  f"  vocab_size = {vocab}",
+                  f"  nhidden = {dim}",
+                  "  ids_key = label",
+                  "  tie = embed",
+                  "layer[mtp_e->mtp_en] = rmsnorm:mtp_enorm",
+                  f"  eps = {eps}",
+                  "layer[mtp_hn,mtp_en->mtp_c] = concat",
+                  "layer[mtp_c->mtp_x] = seq_fullc:mtp_proj",
+                  f"  nhidden = {dim}",
+                  "  no_bias = 1",
+                  *block("mtp_x", "mtp_y", "mtp", False),
+                  "layer[mtp_y->mtp_fin] = rmsnorm:mtp_final_norm",
+                  f"  eps = {eps}",
+                  "layer[mtp_fin->mtp_logits] = seq_fullc:mtp_head",
+                  f"  nhidden = {vocab}",
+                  "  no_bias = 1",
+                  "  tie = head",
+                  "layer[+0] = softmax_seq:mtp_xent",
+                  "  target_offset = 2",
+                  f"  grad_scale = {mtp_weight}",
+                  "  packed = 1"]
+    lines += ["netconfig=end",
+              f"input_shape = 1,1,{seq}",
+              f"label_vec[0,{seq}) = label",
+              f"label_vec[{seq},{2 * seq}) = segment",
+              f"label_vec[{2 * seq},{3 * seq}) = position"]
     return "\n".join(lines) + "\n"
 
 

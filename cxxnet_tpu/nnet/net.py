@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from ..layers.base import ForwardContext, LabelInfo, Layer, Shape4
 from ..layers.registry import create_layer
+from ..layers.sequence import EmbeddingLayer, SeqFullcLayer
 from ..layers.shape_ops import SplitLayer
 from ..utils.config import ConfigError
 from .netconfig import NetConfig
@@ -104,27 +105,45 @@ class Network:
 
     def _tied_to(self, layer: Layer, index: int) -> Optional[Connection]:
         """The connection whose parameter group a ``tie = <name>`` layer
-        reads (a tied output head over an embedding's table), or None.
-        Unlike ``share[tag]`` the two connections are different layer types
-        over one group: the group's gradient is the sum of both uses and
-        the optimizer keeps one state."""
+        reads, or None: a tied output head over an embedding's table or
+        over another head's matrix, or a second embedding over the first's
+        table.  Unlike ``share[tag]`` the two connections are layers of
+        their own over one group, each under its own scope: the group's
+        gradient is the sum of both uses and the optimizer keeps one
+        state."""
         name = getattr(layer, "tie", "")
         if not name:
             return None
         at = self.cfg.layer_name_map.get(name, index)
-        if at >= index or self.cfg.layers[at].type_name != "embedding":
+        kind = layer.type_names[0]
+        allowed = ("embedding",) if kind == "embedding" \
+            else ("embedding", "seq_fullc")
+        if at >= index or self.cfg.layers[at].type_name not in allowed:
             raise ConfigError(
-                f"tie = {name}: no embedding layer of that name is declared "
-                "before this layer")
+                f"tie = {name}: no embedding layer"
+                + (" (or seq_fullc head)" if kind != "embedding" else "")
+                + " of that name is declared before this layer")
+        table = self.connections[at].layer
+        if kind == "embedding":
+            if (table.vocab_size, table.param.num_hidden) != (
+                    layer.vocab_size, layer.param.num_hidden) \
+                    or layer.pos_embed:
+                raise ConfigError(
+                    f"tie = {name}: a tied embedding reads the table alone, "
+                    "with its vocab_size and nhidden and no pos_embed")
+            return self.connections[at]
         if not layer.param.no_bias:
             raise ConfigError(
                 f"tie = {name}: a tied head reads the table alone; set "
                 "no_bias = 1")
-        table = self.connections[at].layer
-        if layer.param.num_hidden != table.vocab_size:
+        rows = table.vocab_size if isinstance(table, EmbeddingLayer) \
+            else table.param.num_hidden
+        if isinstance(table, SeqFullcLayer) and not table.param.no_bias:
+            raise ConfigError(f"tie = {name}: the head it reads has a bias")
+        if layer.param.num_hidden != rows:
             raise ConfigError(
                 f"tie = {name}: the head's nhidden {layer.param.num_hidden} "
-                f"is not the table's {table.vocab_size} rows")
+                f"is not the table's {rows} rows")
         return self.connections[at]
 
     def _loop_outs(self, loop) -> List[int]:

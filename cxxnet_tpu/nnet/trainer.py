@@ -1973,12 +1973,18 @@ class NetTrainer:
         kernels), and ``kv_repeated``, whether K and V were repeated to the
         query heads in front of the scores (true on the lax paths, the full
         grid and the ring with ``kv_heads < heads``, never on the band or
-        triangle).
+        triangle).  A ``latent_attention`` layer reports ``kind``
+        ``latent``, its ``heads``, ``q_lora_rank`` and ``kv_lora_rank``, the
+        query and key heads' ``head_dim`` beside the value heads'
+        ``v_head_dim``, its ``rope``, ``rope_theta`` and ``rope_dim``, the
+        ``lowering`` and ``rope_key_broadcast``, whether the one rotary key
+        head was broadcast to the query heads in front of the scores.
         ``[]`` for a net without such a layer."""
         fields = ("heads", "kv_heads", "head_dim", "window", "rope",
                   "rope_theta", "rope_dim", "yarn_factor", "gate", "lowering",
                   "area_ratio", "heads_per_step", "kv_repeated")
-        return [dict(zip(fields, c.layer.attn_site),
+        return [dict(c.layer.attn_site if isinstance(c.layer.attn_site, dict)
+                     else zip(fields, c.layer.attn_site),
                      layer=c.param_key.split("-", 1)[1])
                 for c in self.net.connections
                 if getattr(c.layer, "attn_site", None) and c.owns_params]

@@ -731,7 +731,8 @@ def _fa_specs(nbh, s_len, d, bq, bk):
 
 
 @functools.lru_cache(maxsize=None)
-def _fa_tri_call(kernel, nbh, s_len, d, dtype, scale, seg, interpret, plan):
+def _fa_tri_call(kernel, nbh, s_len, d, dtype, scale, seg, interpret, plan,
+                 dv=None):
     """The pallas_call of one triangular-grid kernel ("fwd", "dq", "dkv").
     Cached because pallas_call's wrapper is a jit: the attention layers of
     a model make the same call, and through the one wrapper they trace its
@@ -741,25 +742,35 @@ def _fa_tri_call(kernel, nbh, s_len, d, dtype, scale, seg, interpret, plan):
     blocks are not in the grid, so neither their k/v DMA nor their program
     overhead is paid; also runs under interpret, so the CPU parity tests
     cover this path.  ``nbh`` counts the q-side blocks' head blocks, of
-    ``plan.g`` heads each (:func:`_fa_tri_specs`)."""
+    ``plan.g`` heads each (:func:`_fa_tri_specs`).  q and K are ``d`` wide;
+    V, o and dO ``dv`` (None: ``d``), whose blocks are the same specs at
+    that width."""
     bq, bk, g, subs = plan.bq, plan.bk, plan.g, plan.subs
+    dv = d if dv is None else dv
     sub_axis = kernel == "dkv" and subs > 1
     q_spec, k_spec, row_spec, seg_spec = _fa_tri_specs(s_len, d, plan,
                                                        sub_axis)
+    o_spec, v_spec = (q_spec, k_spec) if dv == d else \
+        _fa_tri_specs(s_len, dv, plan, sub_axis)[:2]
     qs = (bq,) if g == 1 else (g, bq)
-    x = jax.ShapeDtypeStruct(
-        (nbh, s_len, d) if g == 1 else (nbh, g, s_len, d), dtype)
-    kx = jax.ShapeDtypeStruct((nbh // subs, s_len, d), dtype)
+
+    def q_side(width):
+        return jax.ShapeDtypeStruct(
+            (nbh, s_len, width) if g == 1 else (nbh, g, s_len, width), dtype)
+
+    x, xo = q_side(d), q_side(dv)
+    kx, vx = (jax.ShapeDtypeStruct((nbh // subs, s_len, w), dtype)
+              for w in (d, dv))
     row = jax.ShapeDtypeStruct((nbh, 1, g * s_len), jnp.float32)
     cols = ((g * bq, 1),) * 2 if g > 1 else ()  # lse's, delta's column
     body, n_in, out_specs, out_shape, scratch = {
-        "fwd": (_fa_fwd_kernel_tri, 0, [q_spec, row_spec], [x, row],
-                (qs + (d,), qs + (1,), qs + (1,))),
+        "fwd": (_fa_fwd_kernel_tri, 0, [o_spec, row_spec], [xo, row],
+                (qs + (dv,), qs + (1,), qs + (1,))),
         "dq": (_fa_dq_kernel_tri, 3, q_spec, x, (qs + (d,),) + cols),
-        "dkv": (_fa_dkv_kernel_tri, 3, [k_spec, k_spec], [kx, kx],
-                ((bk, d), (bk, d)) + cols),
+        "dkv": (_fa_dkv_kernel_tri, 3, [k_spec, v_spec], [kx, vx],
+                ((bk, d), (bk, dv)) + cols),
     }[kernel]
-    in_specs = [q_spec, k_spec, k_spec] + [q_spec, row_spec, row_spec][:n_in]
+    in_specs = [q_spec, k_spec, v_spec] + [o_spec, row_spec, row_spec][:n_in]
     pairs = len(_fa_live_pairs(s_len // bq, s_len // bk, bq, bk,
                                plan.window))
     gs = pltpu.PrefetchScalarGridSpec(
@@ -785,8 +796,10 @@ def _fa_tri(kernel, args, scale, interpret, seg3, window=0):
     ii, jj = _fa_tri_pairs(s_len // plan.bq, s_len // plan.bk, plan.bq,
                            plan.bk, "ji" if kernel == "dkv" else "ij",
                            window)
+    dv = args[2].shape[-1]
     return _fa_tri_call(kernel, nbh, s_len, d, q3.dtype, scale,
-                        len(segs), interpret, plan)(ii, jj, *args, *segs)
+                        len(segs), interpret, plan,
+                        *([dv] if dv != d else []))(ii, jj, *args, *segs)
 
 
 def _fa_tri_plan(kernel, q3, k3, window):
@@ -828,7 +841,8 @@ def _fa_step_heads(q, k, causal, window):
 def _fa_fwd(q3, k3, v3, scale, causal, interpret, seg3=None, window=0):
     if causal:
         return _fa_tri("fwd", (q3, k3, v3), scale, interpret, seg3, window)
-    assert k3.shape == q3.shape, "the full grid takes K and V at q's heads"
+    assert k3.shape == q3.shape == v3.shape, \
+        "the full grid takes K and V at q's heads and width"
     nbh, s_len, d = q3.shape
     bq, bk = _fa_blocks(s_len, d)
     q_spec, k_spec, row_spec = _fa_specs(nbh, s_len, d, bq, bk)
@@ -895,7 +909,11 @@ def flash_attention(q, k, v, causal: bool = False,
                     window: int = 0):
     """Flash attention, (b, h, s, d) -> (b, h, s, d).
 
-    With ``causal``, ``k`` and ``v`` are ``(b, nkv, s, d)`` with ``nkv``
+    With ``causal``, ``v`` may be of a width ``dv`` of its own (q and k of
+    192 over v of 128 in latent attention): the output, o and dO are then
+    ``dv`` wide, and the kernels take that width as a static argument
+    (:func:`_fa_tri_call`; a call at ``dv == d`` builds the one-width
+    kernels).  ``k`` and ``v`` are ``(b, nkv, s, d)`` with ``nkv``
     dividing ``h``: query head ``i`` reads KV head ``i // (h / nkv)``, a
     grid step takes ``_fa_heads`` of a KV head's query heads, and the
     gradients by ``k`` and ``v`` are at ``nkv`` heads, summed over the
@@ -932,12 +950,13 @@ def _fa_saved(o3, lse):
     return checkpoint_name(o3, FLASH_O), checkpoint_name(lse, FLASH_LSE)
 
 
-def flash_saved(q):
+def flash_saved(q, dv=None):
     """``(name, shape, dtype)`` of the two tensors a flash forward on ``q``
-    ``(b, h, s, d)`` names, for a loop's account of what a pass keeps (in
-    the one-head layout: a plan that stacks heads keeps the same bytes)."""
+    ``(b, h, s, d)`` (and V ``dv`` wide, None: ``d``) names, for a loop's
+    account of what a pass keeps (in the one-head layout: a plan that
+    stacks heads keeps the same bytes)."""
     b, h, s_len, d = q.shape
-    return [(FLASH_O, (b * h, s_len, d), q.dtype),
+    return [(FLASH_O, (b * h, s_len, dv or d), q.dtype),
             (FLASH_LSE, (b * h, 1, s_len), jnp.float32)]
 
 
@@ -946,7 +965,7 @@ def _flash_fwd_res(q, k, v, causal, scale, interpret, window=0):
     heads = _fa_step_heads(q, k, causal, window)
     o3, lse = _fa_saved(*_fa_fwd(_fa_in(q, heads), _fa_in(k), _fa_in(v),
                                  scale, causal, interpret, window=window))
-    return o3.reshape(q.shape), (q, k, v, o3, lse)
+    return o3.reshape(q.shape[:3] + v.shape[3:]), (q, k, v, o3, lse)
 
 
 def _flash_bwd_res(causal, scale, interpret, window, res, g):
@@ -980,7 +999,7 @@ def _flash_seg_fwd_res(q, k, v, seg, scale, interpret, window):
     seg3 = _seg_tile(seg, q.shape[1] // heads)
     o3, lse = _fa_saved(*_fa_fwd(_fa_in(q, heads), _fa_in(k), _fa_in(v),
                                  scale, True, interpret, seg3, window))
-    return o3.reshape(q.shape), (q, k, v, seg, o3, lse)
+    return o3.reshape(q.shape[:3] + v.shape[3:]), (q, k, v, seg, o3, lse)
 
 
 def _flash_seg_bwd_res(scale, interpret, window, res, g):

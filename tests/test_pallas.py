@@ -910,3 +910,67 @@ def test_rmsnorm_gate_is_layernorms():
     assert not pk.rmsnorm_pallas_supported(4096, 100)  # off the lane width
     with pytest.raises(AssertionError, match="rmsnorm_pallas_supported"):
         pk.rmsnorm_pallas(jnp.zeros((5, 128)), jnp.ones((128,)), 1e-6, True)
+
+
+@pytest.mark.parametrize("segmented,blocks", [
+    (False, None), (True, None), (False, (256, 256)), (True, (256, 256))])
+def test_flash_attention_v_of_its_own_width(monkeypatch, segmented, blocks):
+    """Latent attention's widths: q and K of 192 over V of 128.  The causal
+    and segmented kernels (the plan of 192-wide heads, and several blocks a
+    row with strips of 128), forward and the q, k, v gradients against the
+    lax path on the same widths; the output and dV are V's width."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    from cxxnet_tpu.parallel.ring import dense_attention
+    if blocks:
+        pk = _force_fa(monkeypatch, blocks, 128)
+    b, h, s, d, dv = 1, 2, 512, 192, 128
+    rnd = np.random.RandomState(3 + segmented)
+    q, k = (jnp.asarray(rnd.randn(b, h, s, d).astype(np.float32) * 0.5)
+            for _ in range(2))
+    v, gq = (jnp.asarray(rnd.randn(b, h, s, dv).astype(np.float32) * 0.5)
+             for _ in range(2))
+    seg = jnp.asarray(np.repeat([1, 2, 3], [100, 250, 162])[None],
+                      jnp.int32) if segmented else None
+
+    def flash(q, k, v):
+        if segmented:
+            return pk.flash_attention_segmented(q, k, v, seg)
+        return pk.flash_attention(q, k, v, True)
+
+    def lax(q, k, v):
+        return dense_attention(q, k, v, causal=True, seg=seg)
+
+    got = flash(q, k, v)
+    assert got.shape == (b, h, s, dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(lax(q, k, v)),
+                               atol=1e-5)
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) * gq), argnums=(0, 1, 2))(
+        q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(lax(*a) * gq), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, want, nm in zip(gf, gr, ("dq", "dk", "dv")):
+        assert a.shape == want.shape, nm
+        np.testing.assert_allclose(np.asarray(a), np.asarray(want),
+                                   atol=2e-4, err_msg=nm)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("seg", [0, 1])
+def test_a_call_at_the_default_v_width_is_the_one_width_call(kernel, seg):
+    """V's width left at its default and passed as q's build the same
+    pallas_call: the same lowered program, kernel body included, so every
+    one-width model compiles what it compiled before V had a width."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    s, d, nbh = 2048, 128, 4
+    plan = pk._fa_plan(s, d, kernel)
+    calls = [pk._fa_tri_call(kernel, nbh, s, d, jnp.bfloat16, 0.125, seg,
+                             True, plan, *dv) for dv in ((), (d,))]
+    assert calls[0] is not calls[1]
+    ii, jj = pk._fa_tri_pairs(s // plan.bq, s // plan.bk, plan.bq, plan.bk,
+                              "ji" if kernel == "dkv" else "ij")
+    x = jax.ShapeDtypeStruct((nbh, s, d), jnp.bfloat16)
+    row = jax.ShapeDtypeStruct((nbh, 1, s), jnp.float32)
+    args = [x, x, x] + ([] if kernel == "fwd" else [x, row, row]) \
+        + [jax.ShapeDtypeStruct((nbh, 1, s), jnp.int32)] * seg
+    texts = [jax.jit(c).lower(ii, jj, *args).as_text() for c in calls]
+    assert texts[0] == texts[1]

@@ -48,6 +48,32 @@ def test_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, seg):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
+@pytest.mark.parametrize("seg", [True, False])
+def test_latent_flash_kernels_compile_for_v5e(one_chip, seg):
+    """joyai_s8192_docmask_b1's latent attention: 32 heads, q and K of 192
+    over V of 128, one row of 8,192, causal and segmented; the output and
+    dV leave the kernels 128 wide."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    b, h, s = 1, 32, 8192
+    qk = jax.ShapeDtypeStruct((b, h, s, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, h, s, 128), jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
+
+    def fwd_bwd(q, k, v, g, ids):
+        attn = ((lambda q, k, v: pk.flash_attention_segmented(
+            q, k, v, ids, interpret=False)) if seg else
+            (lambda q, k, v: pk.flash_attention(q, k, v, True, None, False)))
+        out, vjp = jax.vjp(attn, q, k, v)
+        return (out,) + vjp(g)
+
+    compiled = jax.jit(fwd_bwd).lower(qk, qk, v, v, ids).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert [o.shape for o in jax.eval_shape(fwd_bwd, qk, qk, v, v, ids)] \
+        == [(b, h, s, 128), (b, h, s, 192), (b, h, s, 192), (b, h, s, 128)]
+
+
 @pytest.mark.parametrize("h,nkv,d,window", [
     (64, 8, 128, 512),  # laguna_s8192_docmask_b1's window layers: the band
     (48, 8, 128, 0),    # its full layers: the triangle, a group of 6
