@@ -170,7 +170,7 @@ class LatentAttentionLayer(Layer):
             from ..utils.config import ConfigError
             raise ConfigError("latent_attention: no ring path "
                               "(parallel/ring.py): drop the seq mesh axis")
-        from ..ops.pallas_kernels import FLASH_SAVED
+        from ..ops.pallas_kernels import FLASH_SAVED, flash_bwd_pass
         pos = _label_field(ctx, self.pos_key)
         pos = jnp.arange(s)[None] if pos is None else pos
         seg = _label_field(ctx, self.segment_key)
@@ -196,12 +196,15 @@ class LatentAttentionLayer(Layer):
         out = jax.checkpoint(attend, policy=jax.checkpoint_policies
                              .save_only_these_names(*FLASH_SAVED))(params, x)
         if ctx.train:
+            lowering = attention_lowering(s, self.qk_dim, True,
+                                          seg is not None)
             self.attn_site = dict(
                 kind="latent", heads=self.nhead, q_lora_rank=self.q_lora_rank,
                 kv_lora_rank=self.kv_lora_rank, head_dim=self.qk_dim,
                 v_head_dim=self.v_head_dim, rope="default",
                 rope_theta=self.rope_theta, rope_dim=self.qk_rope_dim,
-                lowering=attention_lowering(s, self.qk_dim, True,
-                                            seg is not None),
-                rope_key_broadcast=True)
+                lowering=lowering, rope_key_broadcast=True,
+                bwd_pass=flash_bwd_pass(s, self.qk_dim, self.v_head_dim,
+                                        itemsize=x.dtype.itemsize)
+                if lowering == "flash_tri" else None)
         return [seq_constraint(out, ctx)], buffers

@@ -723,7 +723,7 @@ class AttentionLayer(Layer):
             lowering = attention_lowering(s, hd, bool(self.causal),
                                           seg is not None, self.window)
         if ctx.train:
-            self.attn_site = self._site(s, hd, lowering)
+            self.attn_site = self._site(s, hd, lowering, q.dtype.itemsize)
         def project(att, gate, wout):
             if gate is not None:
                 att = (att.astype(jnp.float32) * gate).astype(att.dtype)
@@ -749,12 +749,15 @@ class AttentionLayer(Layer):
             out = out + params["bout"].astype(x.dtype)
         return [seq_constraint(out, ctx)], buffers
 
-    def _site(self, s: int, hd: int, lowering: str) -> tuple:
+    def _site(self, s: int, hd: int, lowering: str, itemsize: int) -> tuple:
         """What ``NetTrainer.attn_sites`` reports of this layer's trace."""
         from ..ops import pallas_kernels as pk
         nkv = self.nkvhead or self.nhead
         plan = pk._fa_plan(s, hd, "fwd", self.window, self.nhead // nkv) \
             if lowering in ("flash_band", "flash_tri") else None
+        bwd = pk.flash_bwd_pass(s, hd, hd, self.nhead // nkv, self.window,
+                                itemsize) if plan else \
+            "split" if lowering == "flash" else None
         rope = "none" if not self.rope else \
             "yarn" if self.rope_yarn_factor else "default"
         return (self.nhead, nkv, hd, self.window, rope,
@@ -763,7 +766,7 @@ class AttentionLayer(Layer):
                 self.rope_yarn_factor or None, self.gate, lowering,
                 round(plan.area_ratio, 4) if plan else None,
                 plan.g if plan else 1 if lowering == "flash" else None,
-                nkv < self.nhead and plan is None)
+                nkv < self.nhead and plan is None, bwd)
 
     def _decode_attention(self, dec, q, k, v, scale):
         """Cache-aware attention for incremental decode (serve/decode.py).

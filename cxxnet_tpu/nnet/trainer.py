@@ -1970,19 +1970,25 @@ class NetTrainer:
         (``ops/pallas_kernels._fa_plan``); ``heads_per_step``, the query
         heads of a KV group one grid step of the layer's flash kernels
         takes (the plan's ``g``; 1 on the full grid, None off the flash
-        kernels), and ``kv_repeated``, whether K and V were repeated to the
+        kernels), ``kv_repeated``, whether K and V were repeated to the
         query heads in front of the scores (true on the lax paths, the full
         grid and the ring with ``kv_heads < heads``, never on the band or
-        triangle).  A ``latent_attention`` layer reports ``kind``
-        ``latent``, its ``heads``, ``q_lora_rank`` and ``kv_lora_rank``, the
-        query and key heads' ``head_dim`` beside the value heads'
-        ``v_head_dim``, its ``rope``, ``rope_theta`` and ``rope_dim``, the
-        ``lowering`` and ``rope_key_broadcast``, whether the one rotary key
-        head was broadcast to the query heads in front of the scores.
-        ``[]`` for a net without such a layer."""
+        triangle), and ``bwd_pass``, the backward the layer's flash kernels
+        take (``ops/pallas_kernels.flash_bwd_pass``, the rule the backward
+        dispatch follows): ``fused``, one k-major pass for dq, dK and dV,
+        where each query head has its own KV head and there is no window,
+        ``split``, the dq and dkv kernels, elsewhere (the full grid among
+        them), None off the flash kernels.  A ``latent_attention`` layer
+        reports ``kind`` ``latent``, its ``heads``, ``q_lora_rank`` and
+        ``kv_lora_rank``, the query and key heads' ``head_dim`` beside the
+        value heads' ``v_head_dim``, its ``rope``, ``rope_theta`` and
+        ``rope_dim``, the ``lowering``, ``rope_key_broadcast``, whether the
+        one rotary key head was broadcast to the query heads in front of
+        the scores, and ``bwd_pass``.  ``[]`` for a net without such a
+        layer."""
         fields = ("heads", "kv_heads", "head_dim", "window", "rope",
                   "rope_theta", "rope_dim", "yarn_factor", "gate", "lowering",
-                  "area_ratio", "heads_per_step", "kv_repeated")
+                  "area_ratio", "heads_per_step", "kv_repeated", "bwd_pass")
         return [dict(c.layer.attn_site if isinstance(c.layer.attn_site, dict)
                      else zip(fields, c.layer.attn_site),
                      layer=c.param_key.split("-", 1)[1])

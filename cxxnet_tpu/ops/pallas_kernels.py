@@ -3,7 +3,7 @@
 What is here is what a benchmark cell runs, plus one opt-in:
 
 - flash attention, causal and document-segmented, forward and the dq / dkv
-  backward kernels (``flash_attention``, ``flash_attention_segmented``);
+  or one fused backward (``flash_attention``, ``flash_attention_segmented``);
 - LayerNorm with an output-derived backward (``layernorm_pallas``);
 - RMSNorm, a row sweep that saves its input (``rmsnorm_pallas``);
 - the one-sweep adam step for bf16-master tensors (``fused_adam_pallas``:
@@ -77,7 +77,7 @@ def _fa_blocks(s_len, d=64):
     return bq, bk
 
 
-_FA_STRIPS = {"fwd": 4, "dq": 4, "dkv": 2}
+_FA_STRIPS = {"fwd": 4, "dq": 4, "dkv": 2, "bwd": 2}
 
 
 def _fa_strip(s_len, d, bq, bk, kernel):
@@ -109,19 +109,19 @@ def _fa_crosses(i, j, bq, bk):
     return j * bk + bk - 1 > i * bq
 
 
-def _fa_plan(s_len, d=64, kernel="fwd", window=0, group=1):
+def _fa_plan(s_len, d=64, kernel="fwd", window=0, group=1, blocks=None):
     """Everything one causal kernel's work follows from, from the shapes
-    alone: block and strip sizes, the offsets ``i*bq - j*bk`` at which a
-    block crosses the diagonal (one static case each in the kernel bodies),
-    the number of interior and diagonal-crossing programs per (batch, query
-    head) and the computed-over-live area ratio (live = s^2 / 2); with
-    ``group`` query heads a KV head, the heads a grid step ``g`` and the
-    ``subs = group / g`` grid steps of one KV head on a block pair
-    (:func:`_fa_heads`).  A ``window`` gives the band's plan
-    (:func:`_fa_band_plan`)."""
+    alone: block (``blocks``, None: :func:`_fa_blocks`') and strip sizes,
+    the offsets ``i*bq - j*bk`` at which a block crosses the diagonal (one
+    static case each in the kernel bodies), the number of interior and
+    diagonal-crossing programs per (batch, query head) and the computed-
+    over-live area ratio (live = s^2 / 2); with ``group`` query heads a KV
+    head, the heads a grid step ``g`` and the ``subs = group / g`` grid
+    steps of one KV head on a block pair (:func:`_fa_heads`).  A ``window``
+    gives the band's plan (:func:`_fa_band_plan`)."""
     if window:
         return _fa_band_plan(s_len, window, group)
-    bq, bk = _fa_blocks(s_len, d)
+    bq, bk = blocks or _fa_blocks(s_len, d)
     g = _fa_heads(bq, bk, group)
     bs = _fa_strip(s_len, d, bq, bk, kernel)
     assert bq % bs == 0 and bk % bs == 0, (bq, bk, bs)
@@ -862,17 +862,17 @@ def _fa_fwd(q3, k3, v3, scale, causal, interpret, seg3=None, window=0):
 
 def _fa_bwd(q3, k3, v3, o3, lse, g3, scale, causal, interpret, seg3=None,
             window=0):
-    delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1)
+    delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32), -1)
     if g3.ndim == 3:
         delta = delta[:, None, :]  # (nbh, 1, s)
     else:  # of blocks of g heads, in lse's layout
         delta = _fa_rows(delta, _fa_tri_plan("dq", q3, k3, window).bq)
     if causal:
         args = (q3, k3, v3, g3, lse, delta)
-        dq = _fa_tri("dq", args, scale, interpret, seg3, window)
-        dk, dv = _fa_tri("dkv", args, scale, interpret, seg3, window)
-        return dq, dk, dv
+        if _fa_bwd_pass(q3, k3, v3, window) == "fused":
+            return _fa_fused_bwd(args, scale, interpret, seg3)
+        return (_fa_tri("dq", args, scale, interpret, seg3, window),
+                *_fa_tri("dkv", args, scale, interpret, seg3, window))
     nbh, s_len, d = q3.shape
     bq, bk = _fa_blocks(s_len, d)
     q_spec, k_spec, row_spec = _fa_specs(nbh, s_len, d, bq, bk)
@@ -1450,3 +1450,190 @@ def fused_adam_pallas(g, m1, m2, w32, lr_t, *, d1, d2, wd=0.0, clip=0.0,
     shape = w32.shape
     return (p_new.reshape(shape), m1n.reshape(shape),
             m2n.reshape(shape), wn.reshape(shape))
+
+
+# --------------------------------------------------------------------------
+# The causal triangle's backward as ONE k-major pass, where every query head
+# has its own K/V head and there is no window.  The dq and dkv kernels each
+# recompute s = q k^T, p and dp = dO v^T for every live pair: seven block
+# matmuls and two exponentials a pair between them.  The fused pass walks the
+# pairs in the dkv kernel's order ("ji": a K/V block's column, the query
+# blocks from the diagonal down), takes p and ds once a rectangle
+# (_fa_p_ds) and makes all three products of them: dV and dK into the
+# column's (bk, d) scratch as _fa_dkv_step does, and dq into a float32
+# accumulator of the head's whole sequence, (s, d) in VMEM, which a query
+# block leaves as dq's dtype at its last pair (the column of its diagonal;
+# _fa_bwd_pairs).  Five matmuls and one exponential a pair.  The
+# accumulator costs s d 4 bytes of VMEM (8 MiB at s8192 d192, whose rows
+# are padded to 256 lanes), so the pass is taken only where what it holds
+# fits the VMEM a Mosaic call gets inside a loop, 16 MiB (_fa_fused_plan,
+# which halves the blocks to make room); elsewhere the dq and dkv kernels
+# run as before.
+
+_FA_VMEM = 16 * 1024 * 1024  # what a Mosaic call gets inside a scan
+
+
+def _fa_bwd_step(q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                 dq_acc, dk_acc, dv_acc, groups, *, scale, seg_ref=None):
+    """The fused pass's update a block pair: dV, dK and dq of the one p and
+    ds of each rectangle."""
+    for rect, (p, ds, dob, qb, kb) in _fa_bwd_rects(
+            q0, k0, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, groups,
+            scale=scale, seg_ref=seg_ref, cols=()):
+        rows, cols, _ = rect
+        dv_acc[cols] += jax.lax.dot_general(
+            p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = ds.astype(qb.dtype)
+        dk_acc[cols] += jax.lax.dot_general(
+            ds, qb, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        at = pl.ds(q0 + rows.start, rows.stop - rows.start)
+        dq_acc[at] += jax.lax.dot_general(
+            ds, kb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def _fa_bwd_kernel_tri(ii_ref, jj_ref, oo_ref, q_ref, k_ref, v_ref, do_ref,
+                       lse_ref, delta_ref, *refs, scale, plan, seg):
+    seg_ref = refs[0] if seg else None
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[seg:]
+    bq, bk = plan.bq, plan.bk
+    t = pl.program_id(1)
+    i, j = ii_ref[t], jj_ref[t]
+    q0 = pl.multiple_of(i * bq, bq)
+
+    @pl.when(t == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(i == (j * bk) // bq)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    _fa_block_cases(i, j, plan, "k", functools.partial(
+        _fa_bwd_step, q0, j * bk, q_ref, k_ref, v_ref, do_ref, lse_ref,
+        delta_ref, dq_acc, dk_acc, dv_acc, scale=scale, seg_ref=seg_ref))
+
+    @pl.when(i == lse_ref.shape[2] // bq - 1)
+    def _():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    # the query block's last pair: its dq is whole, and the dq block the
+    # grid names (oo) is this one, written back once the name moves on
+    @pl.when(j == (i * bq + bq - 1) // bk)
+    def _():
+        dq_ref[0] = dq_acc[pl.ds(q0, bq)].astype(dq_ref.dtype)
+
+
+def _fa_bwd_pairs(s_len, plan):
+    """The fused pass's live pairs in the dkv kernel's order ("ji"), and
+    for each the dq block the grid names: the next query block whose last
+    pair (the column of its diagonal) has not passed.  The last pairs come
+    in the order of the blocks, so each dq block is named by one run of
+    steps that ends at its last pair and is written back to HBM once,
+    whole.  Named at each of its pairs, a block would be written back
+    after each, what it held never read back (Pallas's TPU interpreter
+    refuses such a revisit)."""
+    import numpy as _np
+    bq, bk = plan.bq, plan.bk
+    pairs = sorted(_fa_live_pairs(s_len // bq, s_len // bk, bq, bk),
+                   key=lambda ij: (ij[1], ij[0]))
+    done = [t for t, (i, j) in enumerate(pairs)
+            if j == (i * bq + bq - 1) // bk]
+    assert [pairs[t][0] for t in done] == list(range(s_len // bq)), done
+    oo = _np.searchsorted(_np.asarray(done), _np.arange(len(pairs)))
+    return tuple(jnp.asarray(_np.asarray(x, _np.int32)) for x in (
+        [i for i, _ in pairs], [j for _, j in pairs], oo))
+
+
+@functools.lru_cache(maxsize=None)
+def _fa_bwd_call(nbh, s_len, d, dtype, scale, seg, interpret, plan,
+                 dv=None):
+    """The fused backward's pallas_call (:func:`_fa_tri_call`'s arguments;
+    one-head blocks on the grid of :func:`_fa_bwd_pairs`): dq, dK and dV."""
+    dv = d if dv is None else dv
+    bq, bk = plan.bq, plan.bk
+
+    def spec(rows, width, at):
+        return pl.BlockSpec((1, rows, width),
+                            lambda b, t, ii, jj, oo: (b, at(ii, jj, oo)[t], 0))
+
+    q_spec, o_spec = (spec(bq, w, lambda ii, jj, oo: ii) for w in (d, dv))
+    k_spec, v_spec = (spec(bk, w, lambda ii, jj, oo: jj) for w in (d, dv))
+    row_spec = pl.BlockSpec((1, 1, s_len), lambda b, t, *_: (b, 0, 0))
+    x, xv = (jax.ShapeDtypeStruct((nbh, s_len, w), dtype) for w in (d, dv))
+    pairs = len(_fa_live_pairs(s_len // bq, s_len // bk, bq, bk))
+    gs = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(nbh, pairs),
+        in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec]
+        + [row_spec] * seg,
+        out_specs=[spec(bq, d, lambda ii, jj, oo: oo), k_spec, v_spec],
+        scratch_shapes=_scratch((s_len, d), (bk, d), (bk, dv)))
+    return pl.pallas_call(
+        functools.partial(_fa_bwd_kernel_tri, scale=scale, plan=plan,
+                          seg=seg),
+        grid_spec=gs, interpret=interpret, out_shape=[x, x, xv])
+
+
+def _fa_fused_vmem(s_len, d, dv, plan, itemsize):
+    """Bytes of VMEM the fused pass takes at a plan, an upper estimate from
+    what it holds: the float32 scratch (dq's of the sequence, dK's and
+    dV's of a column), q, dO, K and V blocks in and dq, dK and dV blocks
+    out, two of each, the lse, delta and segment rows, two of each and
+    padded to 8 sublanes, and two float32 score blocks of temporaries.
+    Rows are padded to whole 128-lane tiles.  At every v5e shape tried
+    (s1024-8192, d64-256) it lies 0.5 MiB or more above what Mosaic asks
+    for, the least at s2048 d128: 13.88 MiB for 13.35."""
+    w, wv = (-(-n // 128) * 128 for n in (d, dv))
+    bq, bk = plan.bq, plan.bk
+    scratch = 4 * (s_len * w + bk * (w + wv))
+    blocks = 2 * itemsize * ((bq + bk) * (w + wv) + bq * w + bk * (w + wv))
+    return scratch + blocks + 2 * 3 * 8 * s_len * 4 + 2 * 4 * bq * bk
+
+
+def _fa_fused_plan(s_len, d, dv, itemsize):
+    """The fused pass's plan: the kernels' blocks (:func:`_fa_blocks`), the
+    longer side halved while what the pass takes does not fit a call's
+    VMEM inside a loop (:func:`_fa_fused_vmem`, ``_FA_VMEM``): (512, 1024)
+    to (512, 512) at s8192 d192.  None where no block of sides of 512 or
+    less fits: the dq and dkv kernels run there."""
+    bq, bk = _fa_blocks(s_len, d)
+    while True:
+        plan = _fa_plan(s_len, d, "bwd", blocks=(bq, bk))
+        if _fa_fused_vmem(s_len, d, dv, plan, itemsize) <= _FA_VMEM:
+            return plan
+        if max(bq, bk) <= 512:
+            return None
+        bq, bk = (bq, bk // 2) if bk >= bq else (bq // 2, bk)
+
+
+def flash_bwd_pass(s_len, d, dv, group=1, window=0, itemsize=2):
+    """Which backward the causal flash kernels take at these shapes (q and
+    K ``d`` wide, V ``dv``, ``group`` query heads a KV head, operands of
+    ``itemsize`` bytes): ``"fused"``, one k-major pass
+    (:func:`_fa_bwd_kernel_tri`), where each query head has its own KV
+    head, there is no window and the pass fits (:func:`_fa_fused_plan`);
+    ``"split"``, the dq and dkv kernels, elsewhere."""
+    fits = group == 1 and not window and _fa_fused_plan(
+        s_len, d, dv, itemsize) is not None
+    return "fused" if fits else "split"
+
+
+def _fa_bwd_pass(q3, k3, v3, window):
+    """:func:`flash_bwd_pass` of operands in the kernels' layout."""
+    return flash_bwd_pass(q3.shape[-2], q3.shape[-1], v3.shape[-1],
+                          q3.size // k3.size, window, q3.dtype.itemsize)
+
+
+def _fa_fused_bwd(args, scale, interpret, seg3):
+    """dq, dK and dV of the fused pass on :func:`_fa_bwd`'s operands."""
+    q3, dv = args[0], args[2].shape[-1]
+    nbh, s_len, d = q3.shape
+    plan = _fa_fused_plan(s_len, d, dv, q3.dtype.itemsize)
+    segs = [] if seg3 is None else [seg3]
+    return _fa_bwd_call(nbh, s_len, d, q3.dtype, scale, len(segs),
+                        interpret, plan, *([dv] if dv != d else []))(
+                            *_fa_bwd_pairs(s_len, plan), *args, *segs)

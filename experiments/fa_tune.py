@@ -26,7 +26,18 @@ before the kernels took K/V at their own head count), then at every ``g``
 tried (``pk._FA_SCORES`` set to let exactly that many in); ``all`` is the
 device time of every operation of the pass, XLA's repeat included.
 
-Usage: python experiments/fa_tune.py [strips|blocks|heads] [s_len,batch ...]
+``fused``: the causal backward where each query head has its own K/V head,
+as ONE k-major pass (``pk.flash_bwd_pass`` "fused") against the dq and dkv
+kernels it replaces ("split"), segmented, at the shapes the cells run it:
+s8192 b1 h32 q/k 192 over v 128 (``joyai_s8192_docmask_b1``), s2048 b8 h16
+d128 (``gpt13_*``), s4096 b1 h16 d128 (``ouro26_s4096_loop4_docmask``).
+Each line gives the kernels' device time and the backward's share of the
+least MXU time of its algorithm (5 block matmuls a live pair, FLOPs
+``2 (3 d + 2 dv)`` a pair), and the fused pass's largest distance from the
+split kernels' dq, dK and dV over each one's largest entry (bf16).
+
+Usage: python experiments/fa_tune.py [strips|blocks|heads|fused]
+           [s_len,batch ...]
 """
 import glob
 import os
@@ -47,6 +58,7 @@ from cxxnet_tpu.ops import pallas_kernels as pk  # noqa: E402
 PEAK_MACS = 197e12 / 2
 ITERS = 10
 KERNELS = ("fwd", "dq", "dkv")
+FUSED_KERNELS = KERNELS + ("bwd",)
 
 
 def ideal_ms(b, h, s, d, mms):
@@ -63,6 +75,8 @@ def _kernel_of(event_name):
     result = event_name.split(" = ", 1)[-1].split(" custom-call(", 1)[0]
     if "f32[" in result:
         return "fwd"
+    if result.count("[") == 3:  # dq, dK and dV: the fused pass
+        return "bwd"
     return "dkv" if result.startswith("(") else "dq"
 
 
@@ -82,7 +96,7 @@ def measure(fn, *args):
         path, = glob.glob(os.path.join(tdir, "plugins/profile/*/*.xplane.pb"))
         plane = next(p for p in jax.profiler.ProfileData.from_file(path).planes
                      if p.name.startswith("/device:TPU:0"))
-        ns = dict.fromkeys(KERNELS + ("all",), 0.0)
+        ns = dict.fromkeys(FUSED_KERNELS + ("all",), 0.0)
         for line in plane.lines:
             if line.name == "XLA Ops":
                 for ev in line.events:
@@ -265,10 +279,82 @@ def sweep_heads(_shapes):
                 pk._FA_SCORES = base
 
 
+FUSED = (  # (cell, b, h, s, d, dv)
+    ("joyai", 1, 32, 8192, 192, 128),
+    ("gpt13", 8, 16, 2048, 128, 128),
+    ("ouro26", 1, 16, 4096, 128, 128),
+)
+
+
+def make_train_widths(attn):
+    """``make_train_kv`` for V of a width of its own: the output feeds the
+    next pass's V."""
+    def train(q, k, v, g):
+        def body(_, qkv):
+            out, vjp = jax.vjp(attn, *qkv)
+            dq, dk, dv = vjp(g)
+            qc, kc, vc = qkv
+            return ((qc + dq * 0.5).astype(qc.dtype),
+                    (kc + dk * 0.25).astype(kc.dtype),
+                    (vc + dv * 0.125 + out * 0.5).astype(vc.dtype))
+        q, k, v = jax.lax.fori_loop(0, ITERS, body, (q, k, v))
+        return (q.sum() + k.sum() + v.sum()).astype(jnp.float32)
+    return jax.jit(train)
+
+
+def sweep_fused(_shapes):
+    base = pk.flash_bwd_pass
+    for cell, b, h, s_len, d, dv in FUSED:
+        q, k, _, _, seg = make_inputs(b, h, s_len, d)
+        v, g = (x[..., :dv] for x in make_inputs(b, h, s_len, d)[2:4])
+        plan = pk._fa_fused_plan(s_len, d, dv, 2)
+        least = b * h * s_len * (s_len + 1) / 2 * 2 * (3 * d + 2 * dv) \
+            / 197e12 * 1e3
+        print(f"{cell} b{b} h{h} s{s_len} d{d} dv{dv}: the rule "
+              f"{base(s_len, d, dv)}, fused blocks {plan.bq} x {plan.bk} "
+              f"strips {plan.bs}; least MXU ms of the backward "
+              f"{least:.3f}", flush=True)
+
+        def attn(q, k, v):
+            return pk.flash_attention_segmented(q, k, v, seg)
+
+        grads = {}
+        for mode in ("split", "fused"):
+            pk.flash_bwd_pass = lambda *a, _m=mode, **kw: _m
+            try:
+                jax.clear_caches()
+                t0 = time.time()
+                ms = measure(make_train_widths(attn), q, k, v, g)
+                bwd = ms["dq"] + ms["dkv"] + ms["bwd"]
+                cols = "  ".join(f"{kn} {ms[kn]:6.3f}"
+                                 for kn in FUSED_KERNELS)
+                print(f"  {mode:6s}: {cols}  backward {bwd:7.3f} "
+                      f"({least / bwd * 100:4.1f}%)  all {ms['all']:7.3f} ms"
+                      f"  [{time.time() - t0:.0f} s]", flush=True)
+                grads[mode] = jax.jit(lambda q, k, v: jax.vjp(
+                    attn, q, k, v)[1](g))(q, k, v)
+            except Exception as e:
+                print(f"  {mode:6s}: FAILED "
+                      f"{str(e).splitlines()[0][:160]}", flush=True)
+            finally:
+                pk.flash_bwd_pass = base
+        if len(grads) == 2:
+            print("  fused against split, largest distance over largest "
+                  "entry: " + "  ".join(
+                      f"{nm} {_distance(a, w):.3e}" for nm, a, w in zip(
+                          ("dq", "dk", "dv"), grads["fused"],
+                          grads["split"])), flush=True)
+
+
+def _distance(a, want):
+    a, want = (x.astype(jnp.float32) for x in (a, want))
+    return float(jnp.abs(a - want).max() / jnp.abs(want).max())
+
+
 def main():
     args = sys.argv[1:]
     modes = {"strips": sweep_strips, "blocks": sweep_blocks,
-             "heads": sweep_heads}
+             "heads": sweep_heads, "fused": sweep_fused}
     mode = args.pop(0) if args and args[0] in modes else "strips"
     shapes = [tuple(int(t) for t in a.split(",")) for a in args] or (
         [(2048, 8), (4096, 4)] if mode == "strips" else [(4096, 4)])

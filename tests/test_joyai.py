@@ -22,6 +22,7 @@ from cxxnet_tpu.layers.registry import create_layer
 from cxxnet_tpu.models import latent_lm
 from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.utils.config import ConfigError, parse_config_string
+from helpers import eqns_outside_kernels
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -559,6 +560,41 @@ def test_the_compile_sites_name_each_latent_layer():
     assert diags["moe_dropped"] == 0 and diags["moe_local_pairs"] > 0
     assert [s["layer"] for s in t.moe_sites()] == ["l1_moe", "l2_moe",
                                                    "mtp_moe"]
+
+
+def test_the_compile_sites_count_the_fused_backward_pass(monkeypatch):
+    """``bwd_pass`` of the toy net's latent layers: None on the lax path;
+    on the flash kernels (interpret mode, the lowering steered as a chip
+    would take it, rows of 128) ``fused``, one k-major pass for dq, dK and
+    dV, where every query head has its own K/V head and no window, and the
+    traced step makes two Mosaic calls a latent layer, not three."""
+    s = 128
+    data, label = packed_batch(s, cuts=((40, 41, 90), (10, 70, 71)))
+    batch = DataBatch(data=data, label=label,
+                      index=np.arange(B, dtype=np.uint32))
+    t = make_trainer(latent_lm(V, s, D, N_LAYER, H, RQ, RKV, NOPE, ROPE, DV,
+                               FFN, **dict(SIZES, mtp_weight=LAM)))
+    t.update(batch)
+    assert [x["bwd_pass"] for x in t.attn_sites()] == [None] * 4
+    import cxxnet_tpu.layers.latent as latent_mod
+    monkeypatch.setattr(latent_mod, "attention_lowering",
+                        lambda *a, **kw: "flash_tri")
+    monkeypatch.setattr(sequence, "attention_lowering",
+                        lambda *a, **kw: "flash_tri")
+    t = make_trainer(latent_lm(V, s, D, N_LAYER, H, RQ, RKV, NOPE, ROPE, DV,
+                               FFN, **dict(SIZES, mtp_weight=LAM)))
+    t.update(batch)
+    sites = t.attn_sites()
+    assert [(x["layer"], x["lowering"], x["bwd_pass"]) for x in sites] == [
+        (name, "flash_tri", "fused")
+        for name in ("l0_att", "l1_att", "l2_att", "mtp_att")]
+    assert np.isfinite(t.last_diagnostics()["mtp_loss"])
+    step = jax.make_jaxpr(lambda p: t._loss_and_grads(
+        p, t.buffers, jnp.asarray(data), jnp.asarray(label), (),
+        jnp.int32(0), t._rng_base, ()))(t.params).jaxpr
+    calls = [e for e in eqns_outside_kernels(step)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 2 * len(sites)
 
 
 def test_without_the_module_no_module_layer_and_no_counter():

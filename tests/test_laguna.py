@@ -671,9 +671,9 @@ def test_the_compile_sites_name_each_layer():
         and sites[1]["yarn_factor"] is None \
         and sites[1]["rope_theta"] == 1e4
     assert all(s["area_ratio"] is None for s in sites)
-    # the lax path takes K/V at the query heads
+    # the lax path takes K/V at the query heads, and no flash backward
     assert all(s["heads_per_step"] is None and s["kv_repeated"]
-               for s in sites)
+               and s["bwd_pass"] is None for s in sites)
     assert [s["shared"] for s in t.moe_sites()] == [8, 8, 8]
     diags = t.last_diagnostics()
     assert diags["moe_dropped"] == 0 and diags["moe_local_pairs"] > 0
@@ -689,7 +689,9 @@ def test_the_compile_sites_count_the_heads_a_flash_step_takes(
     mode; the lowering steered here as a chip would take it, rows of 128):
     ``heads_per_step`` is the group where K/V heads are shared and 1 where
     each query head has its own, ``kv_repeated`` false on the band and the
-    triangle; the layer's traced step holds no K or V at the query heads."""
+    triangle, ``bwd_pass`` the fused pass on a triangle of one query head
+    a KV head and the split kernels on the band and wherever a KV head is
+    shared; the layer's traced step holds no K or V at the query heads."""
     monkeypatch.setattr(
         sequence, "attention_lowering",
         lambda s, hd, causal, segmented, window=0:
@@ -707,6 +709,11 @@ def test_the_compile_sites_count_the_heads_a_flash_step_takes(
         ("flash_tri" if not x["window"] else "flash_band", n, False)
         for x, n in zip(sites, per_step)]
     assert [x["window"] for x in sites] == [0, W, W, 0]
+    assert [x["bwd_pass"] for x in sites] == [
+        "fused" if x["heads"] == nkv and not x["window"] else "split"
+        for x in sites]
+    assert [x["bwd_pass"] for x in sites].count("fused") == (
+        2 if nkv == 2 else 0)
     assert np.isfinite(t.last_diagnostics()["moe_local_pairs"])
     # the step repeats no K or V: jnp.repeat's broadcast to (b, nkv, group,
     # s, hd), which the lax path takes, is not in it

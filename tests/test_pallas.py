@@ -6,6 +6,7 @@ layernorm, rmsnorm, the adam sweep) and each alternative XLA lowering
 forward and backward.
 """
 
+import functools
 import itertools
 
 import jax
@@ -954,7 +955,7 @@ def test_flash_attention_v_of_its_own_width(monkeypatch, segmented, blocks):
                                    atol=2e-4, err_msg=nm)
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv", "bwd"])
 @pytest.mark.parametrize("seg", [0, 1])
 def test_a_call_at_the_default_v_width_is_the_one_width_call(kernel, seg):
     """V's width left at its default and passed as q's build the same
@@ -963,14 +964,156 @@ def test_a_call_at_the_default_v_width_is_the_one_width_call(kernel, seg):
     from cxxnet_tpu.ops import pallas_kernels as pk
     s, d, nbh = 2048, 128, 4
     plan = pk._fa_plan(s, d, kernel)
-    calls = [pk._fa_tri_call(kernel, nbh, s, d, jnp.bfloat16, 0.125, seg,
-                             True, plan, *dv) for dv in ((), (d,))]
+    make = pk._fa_bwd_call if kernel == "bwd" else functools.partial(
+        pk._fa_tri_call, kernel)
+    calls = [make(nbh, s, d, jnp.bfloat16, 0.125, seg, True, plan, *dv)
+             for dv in ((), (d,))]
     assert calls[0] is not calls[1]
-    ii, jj = pk._fa_tri_pairs(s // plan.bq, s // plan.bk, plan.bq, plan.bk,
-                              "ji" if kernel == "dkv" else "ij")
+    grid = pk._fa_bwd_pairs(s, plan) if kernel == "bwd" else \
+        pk._fa_tri_pairs(s // plan.bq, s // plan.bk, plan.bq, plan.bk,
+                         "ji" if kernel == "dkv" else "ij")
     x = jax.ShapeDtypeStruct((nbh, s, d), jnp.bfloat16)
     row = jax.ShapeDtypeStruct((nbh, 1, s), jnp.float32)
     args = [x, x, x] + ([] if kernel == "fwd" else [x, row, row]) \
         + [jax.ShapeDtypeStruct((nbh, 1, s), jnp.int32)] * seg
-    texts = [jax.jit(c).lower(ii, jj, *args).as_text() for c in calls]
+    texts = [jax.jit(c).lower(*grid, *args).as_text() for c in calls]
     assert texts[0] == texts[1]
+
+
+# ------------------------------------------- the fused k-major backward pass
+
+def _pallas_calls(fn, *args):
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    return [e for e in eqns_outside_kernels(jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("s,blocks,bs,d,dv,segmented", [
+    (256, (256, 256), 128, 32, 32, False),    # one block, all of it diagonal
+    (512, (256, 256), 128, 32, 32, True),     # two K/V columns, an interior
+    (512, (256, 256), 64, 64, 64, True),      # four strips a crossing block
+    (1024, (256, 512), 128, 32, 32, False),   # bq < bk: two offsets a column
+    (1024, (256, 512), 128, 32, 32, True),
+    (512, (256, 128), 128, 32, 32, True),     # bq > bk
+    (512, (128, 256), 128, 192, 128, True),   # latent widths, bq < bk
+    (512, (256, 256), 128, 192, 128, False),  # latent widths, square
+])
+def test_fused_backward_matches_the_split_kernels_and_dense(
+        monkeypatch, s, blocks, bs, d, dv, segmented):
+    """Where each query head has its own K/V head the causal kernels'
+    backward is ONE k-major pass (two Mosaic calls a layer, forward and
+    backward): dq, dK and dV against the dq and dkv kernels it replaces
+    and against the lax path, causal and segmented, q/k and v of one width
+    and of two, several K/V columns, strips and blocks of both shapes."""
+    from cxxnet_tpu.parallel.ring import dense_attention
+    pk = _force_fa(monkeypatch, blocks, bs)
+    plan = pk._fa_fused_plan(s, d, dv, 4)
+    assert (plan.bq, plan.bk, plan.bs) == blocks + (bs,)
+    assert pk.flash_bwd_pass(s, d, dv, itemsize=4) == "fused"
+    rnd = np.random.RandomState(s + d + segmented)
+    b, h = 1, 2
+    q, k = (jnp.asarray(rnd.randn(b, h, s, d).astype(np.float32) * 0.5)
+            for _ in range(2))
+    v, gq = (jnp.asarray(rnd.randn(b, h, s, dv).astype(np.float32) * 0.5)
+             for _ in range(2))
+    cuts = np.repeat([1, 2, 3, 0], [s // 4, s // 2 - 30, s // 4 + 10, 20])
+    seg = jnp.asarray(cuts[None], jnp.int32) if segmented else None
+
+    def flash(q, k, v):
+        if segmented:
+            return pk.flash_attention_segmented(q, k, v, seg)
+        return pk.flash_attention(q, k, v, True)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * gq), argnums=(0, 1, 2))(
+            q, k, v)
+
+    def vjp(q, k, v):
+        return jax.vjp(flash, q, k, v)[1](gq)
+
+    # the traced backward rule is cached: cleared about the split's trace,
+    # so that the tests after this one trace the rule as it stands
+    with monkeypatch.context() as patch:
+        patch.setattr(pk, "flash_bwd_pass", lambda *a, **kw: "split")
+        jax.clear_caches()
+        assert len(_pallas_calls(vjp, q, k, v)) == 3
+        split = grads(flash)
+    jax.clear_caches()
+    assert len(_pallas_calls(vjp, q, k, v)) == 2
+    fused = grads(flash)
+    want = grads(lambda q, k, v: dense_attention(q, k, v, causal=True,
+                                                 seg=seg))
+    for a, sp, w, nm in zip(fused, split, want, ("dq", "dk", "dv")):
+        assert a.shape == w.shape, nm
+        np.testing.assert_allclose(np.asarray(a), np.asarray(sp), atol=2e-6,
+                                   err_msg=nm)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), atol=2e-4,
+                                   err_msg=nm)
+
+
+@pytest.mark.parametrize("s,blocks,segmented", [
+    (512, (128, 256), True),    # two last pairs a column
+    (512, (256, 256), False),   # one a column, the first
+])
+def test_fused_backward_in_the_tpu_interpreter(monkeypatch, s, blocks,
+                                               segmented):
+    """The fused pass in Pallas's TPU interpreter, which models the grid's
+    pipeline: memory nothing has written reads NaN, a race is an error and
+    so is an output block the grid names again after it moved on.  dq's
+    blocks leave the pass once each, whole (``_fa_bwd_pairs``)."""
+    from jax.experimental.pallas import tpu as pltpu
+    from cxxnet_tpu.parallel.ring import dense_attention
+    pk = _force_fa(monkeypatch, blocks, 128)
+    tpu = pltpu.InterpretParams(uninitialized_memory="nan",
+                                detect_races=True)
+    rnd = np.random.RandomState(s + segmented)
+    q, k, v, gq = (jnp.asarray(rnd.randn(1, 2, s, 64).astype(np.float32)
+                               * 0.5) for _ in range(4))
+    seg = jnp.asarray(np.repeat([1, 2, 3], [100, s - 200, 100])[None],
+                      jnp.int32) if segmented else None
+
+    def flash(q, k, v):
+        if segmented:
+            return pk.flash_attention_segmented(q, k, v, seg, interpret=tpu)
+        return pk.flash_attention(q, k, v, True, None, tpu)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * gq), argnums=(0, 1, 2))(
+            q, k, v)
+
+    got = grads(flash)
+    want = grads(lambda q, k, v: dense_attention(q, k, v, causal=True,
+                                                 seg=seg))
+    for a, w, nm in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), atol=2e-4,
+                                   err_msg=nm)
+
+
+@pytest.mark.parametrize("s,d,dv,group,window,itemsize,want,blocks", [
+    (2048, 128, 128, 1, 0, 2, "fused", (1024, 1024)),  # gpt13_*
+    (4096, 128, 128, 1, 0, 2, "fused", (1024, 1024)),  # ouro26: in a loop
+    (8192, 192, 128, 1, 0, 2, "fused", (512, 512)),    # joyai: 1024 halved
+    (8192, 192, 128, 1, 0, 4, "split", None),          # float32 blocks
+    (32768, 256, 256, 1, 0, 2, "split", None),   # dq's scratch alone 32 MB
+    (8192, 64, 64, 4, 0, 2, "split", None),      # lfm2moe, granite4h: GQA
+    (8192, 128, 128, 6, 0, 2, "split", None),    # laguna's full layers
+    (8192, 128, 128, 8, 512, 2, "split", None),  # laguna's band
+    (8192, 128, 128, 1, 512, 2, "split", None),  # a band of its own heads
+])
+def test_the_fused_pass_is_taken_where_it_fits(s, d, dv, group, window,
+                                               itemsize, want, blocks):
+    """The backward the causal kernels take follows from the shapes: the
+    fused pass where each query head has its own K/V head, with no window,
+    at blocks whose VMEM (an upper estimate) fits a call's inside a loop;
+    the dq and dkv kernels everywhere else."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    assert pk.flash_bwd_pass(s, d, dv, group, window, itemsize) == want
+    plan = pk._fa_fused_plan(s, d, dv, itemsize)
+    if want == "fused":
+        assert (plan.bq, plan.bk) == blocks
+        assert pk._fa_fused_vmem(s, d, dv, plan, itemsize) <= 16 * 2 ** 20
+        k = jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16)
+        assert pk._fa_bwd_pass(k, k, jax.ShapeDtypeStruct(
+            (1, s, dv), jnp.bfloat16), window) == "fused"
+    elif group == 1 and not window:
+        assert plan is None

@@ -26,14 +26,25 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+# the calls the cells run inside a loop: gpt13's scan of steps, ouro26's
+# loop over passes
+_IN_A_LOOP = {(8, 16, 2048, 128, False), (1, 16, 4096, 128, True)}
+
+
 @pytest.mark.parametrize("b,h,s,d,seg", [
     (8, 16, 2048, 128, False),  # gpt13_s2048_plain_scan2's attention call
     (8, 16, 2048, 128, True),   # gpt13_s2048_docmask's
+    (1, 16, 4096, 128, True),   # ouro26_s4096_loop4_docmask's
     (1, 2, 2048, 256, True),    # (512, 1024) blocks: two crossing offsets
     (1, 2, 512, 64, False),     # one block, all of it diagonal
 ])
 def test_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, seg):
+    """Each query head its own K/V head: the forward and the fused backward
+    pass, two calls, inside a ``lax.scan`` where the cell runs them in a
+    loop (a call there gets 16 MiB of VMEM, which none of them asks more
+    than: they compile at the default scoped limit)."""
     from cxxnet_tpu.ops import pallas_kernels as pk
+    assert pk.flash_bwd_pass(s, d, d) == "fused"
     x = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16, sharding=one_chip)
     ids = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
 
@@ -44,16 +55,26 @@ def test_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, seg):
         out, vjp = jax.vjp(attn, q, k, v)
         return (out,) + vjp(g)
 
-    text = jax.jit(fwd_bwd).lower(x, x, x, x, ids).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    def in_scan(q, k, v, g, ids):
+        def body(q, _):
+            out, dq, dk, dv = fwd_bwd(q, k, v, g, ids)
+            return (q + dq + out).astype(q.dtype), (dk, dv)
+        return jax.lax.scan(body, q, None, length=2)
+
+    scan = (b, h, s, d, seg) in _IN_A_LOOP
+    text = jax.jit(in_scan if scan else fwd_bwd).lower(
+        x, x, x, x, ids).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 @pytest.mark.parametrize("seg", [True, False])
 def test_latent_flash_kernels_compile_for_v5e(one_chip, seg):
     """joyai_s8192_docmask_b1's latent attention: 32 heads, q and K of 192
-    over V of 128, one row of 8,192, causal and segmented; the output and
-    dV leave the kernels 128 wide."""
+    over V of 128, one row of 8,192, causal and segmented, the forward and
+    the fused backward pass on (512, 512) blocks; the output and dV leave
+    the kernels 128 wide."""
     from cxxnet_tpu.ops import pallas_kernels as pk
+    assert pk.flash_bwd_pass(8192, 192, 128) == "fused"
     b, h, s = 1, 32, 8192
     qk = jax.ShapeDtypeStruct((b, h, s, 192), jnp.bfloat16,
                               sharding=one_chip)
@@ -69,7 +90,7 @@ def test_latent_flash_kernels_compile_for_v5e(one_chip, seg):
 
     compiled = jax.jit(fwd_bwd).lower(qk, qk, v, v, ids).compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert [o.shape for o in jax.eval_shape(fwd_bwd, qk, qk, v, v, ids)] \
         == [(b, h, s, 128), (b, h, s, 192), (b, h, s, 192), (b, h, s, 128)]
 
